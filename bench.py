@@ -3,7 +3,7 @@
 Prints exactly ONE JSON line:
   {"metric": ..., "value": N, "unit": ..., "vs_baseline": N, ...}
 
-North-star metric (BASELINE.json / BASELINE.md): **committed tx/s** for
+North-star metric (BASELINE.json): **committed tx/s** for
 1000-tx blocks under a 3-of-5 (MAJORITY over 5 orgs) endorsement policy
 — and this round the timed loop really commits: every measured run
 drives `Committer.store_stream`, so MVCC validation, block-file append,
@@ -21,9 +21,14 @@ semantics), committing each block serially after validation the way
 coordinator.StoreBlock does (gossip/privdata/coordinator.go:149).
 
 Fairness: BOTH sides take best-of-N with the SAME N (4) over fresh
-on-disk ledgers, after one warmup each — on a time-shared chip/host an
-asymmetric N would score scheduling luck, not the pipeline
-(round-4 verdict, weak #5).
+on-disk ledgers, after one warmup each — on a shared host an asymmetric
+N would score scheduling luck, not the pipeline.
+
+The measured side runs on the accelerator or not at all: the line names
+the device as JAX reports it and carries the provider's lane tally (who
+sealed each verified lane's mask), and the run exits non-zero when the
+platform is not `tpu`, when the native marshaller is unavailable, or
+when a device failure path (failover, breaker) fired during it.
 
 Also reported: p99 block-validate latency (the second north-star
 metric) over every per-block validate duration observed on the
@@ -475,12 +480,15 @@ def main() -> None:
 
     from bench_pipeline import _build_world, _make_blocks
 
+    from fabric_tpu import native
     from fabric_tpu.csp import SWCSP
+    from fabric_tpu.csp.tpu.provider import TPUCSP
     from fabric_tpu.ledger import LedgerProvider
     from fabric_tpu.ledger.kvstore import (
         _sqlite_sync_level as _sync_level,
         _sqlite_wal_checkpoint as _wal_ckpt,
     )
+    from fabric_tpu.node import quiesce
     from fabric_tpu.peer.committer import Committer
     from fabric_tpu.peer.txvalidator import TxValidator
     from fabric_tpu.protos.common import common_pb2
@@ -488,6 +496,20 @@ def main() -> None:
     sweep_sqlite = "--sweep-sqlite" in sys.argv
     trace_out = early_trace
     profile_out = early_profile
+
+    # before the minutes of host set-up below: no chip, no number
+    device = TPUCSP.device_info()
+    if device["platform"] != "tpu":
+        sys.exit(
+            f"bench.py: the measured side runs on a TPU, JAX reports "
+            f"{device}; nothing measured"
+        )
+    if not native.available():
+        sys.exit(
+            "bench.py: the native marshaller/collector is unavailable, "
+            f"the measured path would be the pure-Python one: "
+            f"{native.load_error()}"
+        )
 
     # sqlite tuning applied to BOTH sides (baseline and measured): a
     # larger WAL autocheckpoint keeps checkpoint I/O out of the timed
@@ -544,20 +566,15 @@ def main() -> None:
         baseline = n_blocks * n_txs / base_best
 
     # -- measured: pipelined validate+commit stream, TPU batch verify -----
-    try:
-        from fabric_tpu.csp.tpu.provider import TPUCSP
-
-        # flush/depth point measured on the real chip (round-5 sweep):
-        # ~1-block flushes at depth 6 beat the old 2-block flushes at
-        # depth 4 — the fixed dispatch cost amortizes worse than the
-        # lost overlap from waiting for a second block's lanes
-        csp = TPUCSP(min_device_batch=1, coalesce_lanes=4096)
-        wl2 = fresh_ledger()
-        Committer(
-            TxValidator("benchch", wl2, bundle, csp), wl2
-        ).store_block(copies(1)[0])  # compile + first transfer
-    except Exception:
-        csp = sw
+    # coalesce_lanes=4096 under a depth-6 stream flushes two blocks at a
+    # time (8,000 lanes, the 8192 bucket: chip_smoke.py leg A prints the
+    # sizes); the flush/depth point itself has not been re-measured on
+    # this machine and is ROADMAP Queue 1 item 2's to revisit
+    csp = TPUCSP(min_device_batch=1, coalesce_lanes=4096)
+    wl2 = fresh_ledger()
+    Committer(
+        TxValidator("benchch", wl2, bundle, csp), wl2
+    ).store_block(copies(1)[0])  # compile + first transfer
 
     def run_stream(passes: int = 4):
         """Best-of-N pipelined validate+commit stream; returns
@@ -577,10 +594,8 @@ def main() -> None:
         validate_stages: dict = {}
         trace: dict | None = None
         prof: dict | None = None
-        stream_drain = getattr(csp, "drain", None)
         for _ in range(passes):
-            if stream_drain is not None:
-                stream_drain()
+            csp.drain()
             if tracing.enabled():
                 tracing.reset()
             if profile.enabled():
@@ -636,7 +651,7 @@ def main() -> None:
         del os.environ["FABRIC_TPU_SQLITE_SYNC"]
         del os.environ["FABRIC_TPU_WAL_CHECKPOINT"]
         sys.stdout.flush()
-        _quiesce(csp)
+        quiesce(csp)
         tmp.cleanup()
         return
 
@@ -674,10 +689,8 @@ def main() -> None:
     # dispatches, inflating that block's wall time — the tail of one
     # pass must not become the head of the next.
     lat = []
-    drain = getattr(csp, "drain", None)
     for _ in range(3):
-        if drain is not None:
-            drain()
+        csp.drain()
         led = fresh_ledger()
         v = TxValidator("benchch", led, bundle, csp)
         for b in copies(n_blocks):
@@ -689,10 +702,24 @@ def main() -> None:
     lat.sort()
     p99 = lat[min(len(lat) - 1, int(0.99 * len(lat)))]
 
+    csp.drain()  # every flush sealed: the tally below is final
+    lanes = csp.lane_tally()
+    if lanes["failover"] or lanes["breaker"] or csp.breaker.trips:
+        quiesce(csp)
+        sys.exit(
+            "bench.py: a device failure path fired during the run "
+            f"(lanes {lanes}, breaker trips {csp.breaker.trips}); "
+            "nothing measured"
+        )
+
     line = {
         "metric": "committed_tx_per_s_1000tx_3of5_stream",
         "value": round(value, 2),
         "unit": "tx/s",
+        "platform": device["platform"],
+        "device_kind": device["kind"],
+        "device_count": device["count"],
+        "lanes_sealed_by": lanes,
         "vs_baseline": round(value / baseline, 3),
         "baseline_tx_per_s": round(baseline, 2),
         "p99_block_validate_ms": round(p99 * 1e3, 2),
@@ -737,7 +764,7 @@ def main() -> None:
         line["self_cpu_ms"] = prof["otherData"]["self_cpu_ms"]
         line["profile_out"] = profile_out
         # stop the sampler service thread before teardown (same
-        # reasoning as _quiesce joining the flush waiters)
+        # reasoning as quiesce joining the flush waiters)
         profile.disarm()
     print(json.dumps(line))
     sys.stdout.flush()
@@ -750,21 +777,8 @@ def main() -> None:
     # replaces).  close() is the indefinite join: exiting under a live
     # waiter would reproduce the abort, while a genuinely wedged chip
     # is the harness timeout's problem.
-    _quiesce(csp)
+    quiesce(csp)
     tmp.cleanup()
-
-
-def _quiesce(csp) -> None:
-    """Join every worker this process spun up: the CSP's flush waiters
-    AND the shared host work pool behind the parallel collect/prepare
-    stages — a pool worker alive at interpreter exit is the same
-    teardown hazard as a flush waiter."""
-    close = getattr(csp, "close", None)
-    if close is not None:
-        close()
-    from fabric_tpu.common import workpool
-
-    workpool.shutdown()
 
 
 if __name__ == "__main__":

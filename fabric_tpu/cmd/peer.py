@@ -55,10 +55,20 @@ def cmd_node_start(args) -> int:
     # can override (viper precedence)
     cfg = Config.load("core", "CORE")
     host, port = parse_endpoint(args.listen)
+    # bccsp block selects SW/TPU and the SKI-keyed file keystore
+    csp = csp_from_config(cfg)
+    if hasattr(csp, "device_info"):
+        # the TPU provider: initialize the backend NOW and say what it
+        # is — where JAX cannot reach the device it was told to use the
+        # peer fails at start-up, not inside its first block, and the
+        # log names the platform every later verify ran on
+        import json
+
+        print(f"bccsp TPU device: {json.dumps(csp.device_info())}",
+              flush=True)
     node = PeerNode(
         args.root,
-        # bccsp block selects SW/TPU and the SKI-keyed file keystore
-        csp_from_config(cfg),
+        csp,
         load_signer(args.msp_dir, args.mspid),
         host=host,
         port=port,
@@ -121,11 +131,16 @@ def cmd_node_start(args) -> int:
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *a: stop.set())
     signal.signal(signal.SIGINT, lambda *a: stop.set())
-    stop.wait()
-    node.stop()
-    from fabric_tpu.common import profile as _profile
+    try:
+        stop.wait()
+    finally:
+        # stop() joins the CSP's flush waiters and the work pool, so the
+        # process leaves through normal interpreter shutdown with no
+        # thread inside the device runtime
+        node.stop()
+        from fabric_tpu.common import profile as _profile
 
-    _profile.disarm()  # joins the sampler thread; no-op when disarmed
+        _profile.disarm()  # joins the sampler thread; no-op when disarmed
     return 0
 
 

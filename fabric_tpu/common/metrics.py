@@ -459,8 +459,9 @@ class CommitMetrics:
 class CSPMetrics:
     """TPU-CSP degraded-mode instrumentation (the faultline tentpole's
     hardening half): the circuit breaker's state and trip counts, raw
-    device-path failures, and recovery probes — the signals an operator
-    watches to know the node is serving from the host oracle."""
+    device-path failures, recovery probes, and the lane tally (who
+    sealed each verified lane's mask) — the signals an operator watches
+    to know how much of the node's verification the chip really did."""
 
     def __init__(self, provider):
         self.breaker_state = provider.new_gauge(GaugeOpts(
@@ -492,6 +493,15 @@ class CSPMetrics:
             help="Recovery probe batches sent while the breaker was "
                  "open, labeled by result.",
             statsd_format="%{result}",
+        ))
+        self.lanes = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="tpu",
+            name="lanes_total",
+            help="Signature lanes verified, labeled by who sealed the "
+                 "mask: device, host_race, failover, breaker, small, "
+                 "host_fraction.",
+            statsd_format="%{sealed_by}",
         ))
         self.breaker_state.set(0)
 

@@ -285,6 +285,13 @@ class CustodyCSP(CSP):
     def verify_batch_async(self, items):
         return self._local.verify_batch_async(self._publicized(items))
 
+    def close(self) -> None:
+        """Quiesce the local verify provider (a TPUCSP joins its flush
+        waiters); node shutdown calls this on whatever CSP it holds."""
+        close = getattr(self._local, "close", None)
+        if close is not None:
+            close()
+
     @staticmethod
     def _publicized(items):
         return [

@@ -49,9 +49,11 @@ class IdemixCSP:
     """Stateless provider; keys are passed explicitly (reference keeps them
     behind bccsp.Key handles — our callers hold the dataclasses directly)."""
 
-    # Measured host/device crossover (BASELINE.md round-4 table: the
-    # Pallas ladder wins from ~100 signatures — 1.75x at 128, 2.97x at
-    # 1024; below it, per-dispatch overhead makes the host path faster).
+    # Host/device crossover: the Pallas ladder won from ~100 signatures
+    # in the rounds before PR 21 (BASELINE.md, deleted there, in git
+    # history); below it per-dispatch overhead made the host path
+    # faster.  Not re-measured on the directly attached v5e yet
+    # (ROADMAP Queue 1 item 6).
     DEVICE_CROSSOVER = 100
 
     def __init__(self, rng=None, device: bool | None = None,
@@ -136,7 +138,7 @@ class IdemixCSP:
     def verify_batch(
         self, items: Sequence[IdemixVerifyItem], ipk: IssuerPublicKey
     ) -> list[bool]:
-        """Per-item mask, two pairings for the whole batch (BASELINE.md
+        """Per-item mask, two pairings for the whole batch (BASELINE.json
         BN256 batch-verify configuration).  Ref being beaten: the
         reference verifies serially per signature
         (idemix/signature.go:290)."""

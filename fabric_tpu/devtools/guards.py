@@ -77,6 +77,10 @@ DECLARED_GUARDS: dict[str, str] = {
     "fabric_tpu.csp.tpu.provider.TPUCSP._gen": "csp.tpu.pend",
     "fabric_tpu.csp.tpu.provider.TPUCSP._lane_wall_ewma":
         "fabric_tpu.csp.tpu.provider.TPUCSP._ewma_lock",
+    # who-sealed-it lane tally: bumped by callers, host-race consumers
+    # and tpu-flush-waiter threads alike
+    "fabric_tpu.csp.tpu.provider.TPUCSP._lane_tally":
+        "fabric_tpu.csp.tpu.provider.TPUCSP._tally_lock",
     # process-wide measured host verify rate (module global)
     "fabric_tpu.csp.tpu.provider._host_rate_ewma":
         "fabric_tpu.csp.tpu.provider._host_rate_lock",

@@ -33,7 +33,7 @@ issuer key collapse — with random weights t_i — into TWO pairings:
 
     e(sum_i t_i * APrime_i, W) * e(-sum_i t_i * ABar_i, g2) == 1
 
-This is the BN256 batch-verify baseline configuration (BASELINE.md): the
+This is the BN256 batch-verify baseline configuration (BASELINE.json): the
 reference spends two FP256BN.Ate calls per signature
 (signature.go:290-291); the batch spends two per *block*.
 """

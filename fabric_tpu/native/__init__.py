@@ -3,7 +3,9 @@
 `marshal_batch` is the batch signature marshaller feeding the TPU verify
 kernel (SURVEY.md §7 native-components policy).  The shared library is
 compiled on first use with the system g++ and cached next to the source;
-callers fall back to the pure-Python path when no compiler is available.
+callers fall back to the pure-Python path when the build or load fails,
+and `load_error()` says why (measured paths — chip_smoke.py, bench.py —
+refuse to run on the fallback).
 """
 
 from __future__ import annotations
@@ -24,10 +26,11 @@ _LIB = os.path.join(_DIR, "libfabricmarshal.so")
 _lock = threading.Lock()
 _lib = None
 _tried = False
+_load_error: str | None = None
 
 
 def _load():
-    global _lib, _tried
+    global _lib, _tried, _load_error
     with _lock:
         if _tried:
             return _lib
@@ -90,13 +93,31 @@ def _load():
                 ctypes.c_char_p, i32p, i32p, u8p,
             ]
             _lib = lib
-        except Exception:
+        except Exception as exc:
             _lib = None
+            detail = getattr(exc, "stderr", None)  # g++'s own words
+            if isinstance(detail, bytes):
+                detail = detail.decode("utf-8", "replace")
+            _load_error = f"{type(exc).__name__}: {exc}" + (
+                f"\n{detail.strip()}" if detail else ""
+            )
+            from fabric_tpu.common.flogging import must_get_logger
+
+            must_get_logger("native").warning(
+                "native library unavailable, every caller takes the "
+                "pure-Python path: %s", _load_error,
+            )
         return _lib
 
 
 def available() -> bool:
     return _load() is not None
+
+
+def load_error() -> str | None:
+    """Why the library could not be built or loaded (the compiler's
+    stderr included); None while it is available or untried."""
+    return _load_error
 
 
 def marshal_batch(xs: bytes, ys: bytes, digests: bytes, sigs: bytes,
@@ -334,6 +355,6 @@ def bn254_pairing_check(pairs) -> bool:
 
 
 __all__ = [
-    "available", "marshal_batch", "collect_block", "bn254_msm",
+    "available", "load_error", "marshal_batch", "collect_block", "bn254_msm",
     "bn254_mul_many", "bn254_pairing_check", "ecdsa_verify_host",
 ]

@@ -1,6 +1,6 @@
 // Native block-collect pass for the txvalidator (SURVEY.md §7 native
-// components policy; the "move the collect phase into the C++
-// marshaller" step recorded in BASELINE.md).
+// components policy: the collect phase moved into the C++
+// marshaller).
 //
 // Walks the protobuf wire format of every envelope in a block —
 // Envelope / Payload / Header / ChannelHeader / SignatureHeader /

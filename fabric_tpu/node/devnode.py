@@ -17,6 +17,7 @@ import queue
 from fabric_tpu.common.channelconfig import bundle_from_genesis
 from fabric_tpu.csp import factory as csp_factory
 from fabric_tpu.ledger import BlockStore, LedgerProvider
+from fabric_tpu.node import quiesce
 from fabric_tpu.orderer.blockcutter import BlockCutter
 from fabric_tpu.orderer.blockwriter import BlockWriter
 from fabric_tpu.orderer.msgprocessor import (
@@ -184,6 +185,7 @@ class DevNode:
 
     def shutdown(self) -> None:
         self.chain.halt()
+        quiesce(self.csp)  # the chain is halted: no new verify can arrive
         self.provider.close()
 
 

@@ -43,6 +43,7 @@ from fabric_tpu.common.deliver import BlockNotifier, DeliverService
 from fabric_tpu.common.privdata import LedgerBackedCollectionStore
 from fabric_tpu.gossip.privdata import PrivDataCoordinator
 from fabric_tpu.ledger import LedgerProvider
+from fabric_tpu.node import quiesce
 from fabric_tpu.ledger.transientstore import TransientStore
 from fabric_tpu.peer import aclmgmt
 from fabric_tpu.peer.aclmgmt import ACLProvider
@@ -905,6 +906,9 @@ class PeerNode:
             stream.stop()
         for ch in self.channels.values():
             ch.stop()
+        # only now, with every channel's deliver client stopped, can no
+        # new verify arrive
+        quiesce(self.csp)
 
 
 __all__ = ["PeerNode"]

@@ -1,4 +1,4 @@
-"""Idemix BN254 batch-verify benchmark (BASELINE.md config #5).
+"""Idemix BN254 batch-verify benchmark (BASELINE.json config #5).
 
 The reference verifies each idemix signature with ~10 G1/G2 scalar
 multiplications re-deriving the ZK commitments plus TWO pairings

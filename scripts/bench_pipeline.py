@@ -1,4 +1,4 @@
-"""Block-validation pipeline benchmark (BASELINE.md configs #3/#4):
+"""Block-validation pipeline benchmark (BASELINE.json configs #3/#4):
 VALIDATED tx/s (no commit in the timed loop — bench.py owns the
 committed-tx/s headline via Committer.store_stream) and per-block
 validate latency for 1000-tx blocks at
@@ -47,9 +47,13 @@ def _build_world(n_orgs: int):
 
 
 def _make_blocks(orgs, genesis, csp, n_txs: int, endorsers: int,
-                 n_blocks: int = 1):
+                 n_blocks: int = 1, on_endorsed=None):
     """`n_blocks` blocks of distinct endorsed txs (each endorsed by
-    `endorsers` orgs)."""
+    `endorsers` orgs).  `on_endorsed(bno, i, responses)`, when given,
+    sees each transaction's proposal responses before the client
+    assembles and signs the envelope: chip_smoke.py corrupts an
+    endorsement signature there (done afterwards, the edit would also
+    break the creator's signature over the payload)."""
     from fabric_tpu import protoutil
     from fabric_tpu.common.channelconfig import bundle_from_genesis
     from fabric_tpu.ledger import LedgerProvider
@@ -84,6 +88,8 @@ def _make_blocks(orgs, genesis, csp, n_txs: int, endorsers: int,
                 signature=client.sign(prop.SerializeToString()),
             )
             resps = [e.process_proposal(signed) for e in ends]
+            if on_endorsed is not None:
+                on_endorsed(bno, i, resps)
             envs.append(protoutil.create_signed_tx(prop, client, resps))
         blk = common_pb2.Block()
         blk.header.number = 1 + bno

@@ -137,6 +137,11 @@ def test_device_failure_mid_flush_reseals_on_host():
     finally:
         csp.close()
     assert any(want) and not all(want)
+    # lane tally: the failed flush is the failover's, the healthy one
+    # the device's, and together they are what was submitted
+    tally = csp.lane_tally()
+    assert (tally["failover"], tally["device"]) == (24, 24)
+    assert sum(tally.values()) == 48
 
 
 def test_breaker_opens_routes_host_probes_and_recovers():
@@ -182,6 +187,15 @@ def test_breaker_opens_routes_host_probes_and_recovers():
     assert "csp_tpu_breaker_trips_total 1" in exposed
     assert 'csp_tpu_breaker_probes_total{result="ok"} 1' in exposed
     assert "csp_tpu_device_failures_total 2" in exposed
+    # four 16-lane calls: two failed over, one held by the open breaker,
+    # one back on the device; the probe's own lanes are not counted
+    assert csp.lane_tally() == {
+        "device": 16, "host_race": 0, "failover": 32, "breaker": 16,
+        "small": 0, "host_fraction": 0,
+    }
+    assert 'csp_tpu_lanes_total{sealed_by="failover"} 32' in exposed
+    assert 'csp_tpu_lanes_total{sealed_by="breaker"} 16' in exposed
+    assert 'csp_tpu_lanes_total{sealed_by="device"} 16' in exposed
 
 
 def test_probe_fails_while_device_still_down():
@@ -226,6 +240,8 @@ def test_dispatch_failure_counts_toward_breaker():
             assert csp.breaker.open
     finally:
         csp.close()
+    assert csp.lane_tally()["failover"] == len(items)
+    assert sum(csp.lane_tally().values()) == len(items)
 
 
 def test_hash_batch_routes_host_while_open_and_on_failure():

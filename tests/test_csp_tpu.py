@@ -47,6 +47,11 @@ def test_verify_batch_parity_with_tampering():
     want = sw.verify_batch(items)
     assert got == want
     assert any(got) and not all(got)
+    # the lane tally names who sealed the mask: here the device alone
+    tpu.drain()
+    tally = tpu.lane_tally()
+    assert tally.pop("device") == len(items)
+    assert not any(tally.values()), tally
 
 
 def test_verify_batch_small_falls_back_to_host():
@@ -56,6 +61,8 @@ def test_verify_batch_small_falls_back_to_host():
     d = sw.hash(b"x")
     items = [VerifyBatchItem(key.public_key(), d, sw.sign(key, d))]
     assert tpu.verify_batch(items) == [True]
+    assert tpu.lane_tally()["small"] == 1
+    assert sum(tpu.lane_tally().values()) == 1
 
 
 # -- flush waiter / deadline host-race mechanics -------------------------
@@ -152,13 +159,16 @@ def test_flush_deadline_host_race_beats_stalled_device():
         release.wait(10)
         return [True] * len(items)
 
+    sealed = []
     res = _FlushResult(
         [(stalled_collect, len(items))], len(items), sw=sw,
         device_items=items, deadline=0.05,
+        on_sealed=lambda kind, lanes: sealed.append((kind, lanes)),
     )
     got = res.collect()
     release.set()
     assert got == sw.verify_batch(items)
+    assert sealed == [("host_race", len(items))]
 
 
 def test_flush_race_yields_to_device_completion():

@@ -31,7 +31,8 @@ def test_partitioned_peer_converges_via_pull(tmp_path):
     pa, pb, pc = _free_port(), _free_port(), _free_port()
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO
-    env.pop("JAX_PLATFORMS", None)
+    # never the chip: one process owns it, and no test child may claim it
+    env["JAX_PLATFORMS"] = "cpu"
     outs = {n: str(tmp_path / f"{n}.json") for n in "ABC"}
 
     def spawn(name, port, bootstrap, lo, hi):

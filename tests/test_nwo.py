@@ -56,7 +56,8 @@ class Network:
         self.procs: dict[str, subprocess.Popen] = {}
         self.env = dict(os.environ)
         self.env["PYTHONPATH"] = REPO + os.pathsep + root
-        self.env.pop("JAX_PLATFORMS", None)
+        # never the chip: one process owns it, and no test child may claim it
+        self.env["JAX_PLATFORMS"] = "cpu"
         self.orderer_port = _free_port()
         self.peer_port = _free_port()
         self._generate()

@@ -31,7 +31,7 @@ def test_no_os_exit_in_entry_points():
     # the next lifecycle regression instead of failing loudly
     import ast
 
-    for rel in ("bench.py", "__graft_entry__.py"):
+    for rel in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
         with open(os.path.join(ROOT, rel), "r", encoding="utf-8") as f:
             tree = ast.parse(f.read())
         calls = [
