@@ -15,6 +15,8 @@ import math
 import threading
 from typing import Sequence
 
+from fabric_tpu.common import tracing
+
 
 @dataclasses.dataclass(frozen=True)
 class CounterOpts:
@@ -503,6 +505,31 @@ class CSPMetrics:
                  "host_fraction.",
             statsd_format="%{sealed_by}",
         ))
+        self.dispatches = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="tpu",
+            name="dispatches_total",
+            help="Kernel executions enqueued, labeled by the bucket "
+                 "(padded lanes) each ran at.",
+            statsd_format="%{bucket}",
+        ))
+        self.host_races = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="tpu",
+            name="host_races_total",
+            help="Host races started because a flush's deadline "
+                 "expired, labeled by outcome: won (the host sealed "
+                 "the mask) or lost (the device finished first).",
+            statsd_format="%{outcome}",
+        ))
+        self.compile_events = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="tpu",
+            name="compile_events_total",
+            help="JAX trace, lower and compile events in this process, "
+                 "labeled by event; growth under load is a recompile.",
+            statsd_format="%{event}",
+        ))
         self.breaker_state.set(0)
 
 
@@ -815,32 +842,6 @@ class LockMetrics:
         ))
 
 
-# process-wide GC pause accounting for ProcessMetrics: one idempotent
-# gc callback accumulates collection time; plain float adds are
-# GIL-atomic enough for a monotone scrape-time read
-_gc_pause_total = [0.0]
-_gc_cb_state = {"installed": False, "t0": None}
-
-
-def _install_gc_callback() -> None:
-    if _gc_cb_state["installed"]:
-        return
-    _gc_cb_state["installed"] = True
-    import gc
-    import time
-
-    def _cb(phase, info):
-        if phase == "start":
-            _gc_cb_state["t0"] = time.monotonic()
-        else:
-            t0 = _gc_cb_state["t0"]
-            if t0 is not None:
-                _gc_pause_total[0] += time.monotonic() - t0
-                _gc_cb_state["t0"] = None
-
-    gc.callbacks.append(_cb)
-
-
 class ProcessMetrics:
     """Standard process-level gauges (the prometheus client-library
     conventions) so netscope series can correlate node saturation with
@@ -872,7 +873,9 @@ class ProcessMetrics:
             help="Cumulative seconds spent inside cyclic GC "
                  "collections (gc callback timing).",
         ))
-        _install_gc_callback()
+        # the process has ONE gc callback and tracelens owns it: armed,
+        # the same entry also records `gc.pause` spans
+        tracing.watch_gc()
 
     def collect(self) -> None:
         import gc
@@ -894,7 +897,7 @@ class ProcessMetrics:
             self.gc_collections.With(
                 "generation", str(gen)
             ).set(st.get("collections", 0))
-        self.gc_pause_seconds.set(_gc_pause_total[0])
+        self.gc_pause_seconds.set(tracing.gc_pause_seconds())
 
 
 __all__ = [

@@ -28,17 +28,28 @@ same seam style as faultline/clockskew:
 
 Export is Chrome trace-event JSON (``chrome://tracing`` / Perfetto
 load it directly): the operations endpoint serves the flight recorder
-at ``GET /traces``, ``bench.py --trace-out`` writes the winning stream
-pass, and faultfuzz drops a dump next to every repro artifact.
+at ``GET /traces``, and faultfuzz drops a dump next to every repro
+artifact.  The reader of record for performance work is the benchmark:
+``benchmarks/run.py --trace 1`` arms tracelens for the measured window,
+keeps every event in ``obs["spans"]`` for the per-layer readers
+(``benchmarks/layer_metrics/``) and lays the ``stage`` and ``bench``
+spans over the device's idle gaps.
+
+Generation-2 collections stop every thread, so an armed process also
+records them: :func:`arm` installs the process's one ``gc.callbacks``
+entry and each long collection becomes a ``gc.pause`` span on the
+thread it ran on (see :func:`watch_gc`).
 """
 
 from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import json
 import os
 import threading
+import time
 
 from fabric_tpu.devtools import clockskew, knob_registry
 
@@ -477,6 +488,90 @@ def split_frame_token(frame: bytes) -> tuple[bytes, SpanContext | None]:
     return frame[end + 1:], from_wire(token)
 
 
+# -- gc pauses ----------------------------------------------------------------
+
+# The process's ONE `gc.callbacks` entry.  It is installed on first
+# need: by arm(), which wants a span for every collection long enough
+# to explain an idle gap, or by common.metrics.ProcessMetrics, which
+# wants the summed pause for its gauge.  Where neither asked, nothing
+# is installed.  A collection stops every thread of the process, so a
+# pause belongs to no block's trace: the event carries no span id (the
+# seeded id counter is left alone, and span_sequence() skips it).
+GC_SPAN_MIN_S = 1e-3  # a younger-generation pause shorter than this only counts
+
+_gc_keep = False  # ProcessMetrics reads the total for the life of the process
+# the recorder arm() made: pauses are kept only while IT is the armed
+# one, so a scope() (the seeded tests' entry) records none
+_gc_rec: FlightRecorder | None = None
+_gc_t0: float | None = None
+_gc_pause_total = [0.0]
+# The callback runs between two bytecodes of whatever thread crossed
+# the collector's threshold, possibly one that holds the recorder's
+# lock: it takes no lock and only appends here (atomic under the GIL);
+# export() moves the events into the recorder.
+_gc_pending: collections.deque = collections.deque(maxlen=4096)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_t0
+    if phase == "start":
+        _gc_t0 = time.monotonic()
+        return
+    t0, _gc_t0 = _gc_t0, None
+    if t0 is None:
+        return
+    dt = time.monotonic() - t0
+    _gc_pause_total[0] += dt
+    rec = _gc_rec
+    # a virtual clock has no place for a wall-clock pause
+    if rec is None or rec is not _recorder or clockskew.installed() is not None:
+        return
+    gen = info.get("generation", 0)
+    if gen < 2 and dt < GC_SPAN_MIN_S:
+        return
+    ts = round(t0 * 1e6)
+    _gc_pending.append({
+        "ph": "X",
+        "name": "gc.pause",
+        # "stage": the benchmark lays these over the device's idle gaps
+        "cat": "stage",
+        "ts": ts,
+        "dur": max(0, round((t0 + dt) * 1e6) - ts),
+        "pid": 0,
+        "tid": threading.current_thread().name,
+        "args": {
+            "generation": gen,
+            "collected": info.get("collected", 0),
+            "uncollectable": info.get("uncollectable", 0),
+        },
+    })
+
+
+def watch_gc(keep: bool = True) -> None:
+    """Install the process's gc callback (idempotent).  ``keep`` pins
+    it for the life of the process (the ProcessMetrics gauge reads a
+    running total); arm() installs it unpinned and disarm() takes it
+    out again."""
+    global _gc_keep
+    with _state_lock:
+        _gc_keep = _gc_keep or keep
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+
+def gc_pause_seconds() -> float:
+    """Seconds spent inside cyclic collections since the callback was
+    installed (every generation; a float add is atomic enough for a
+    monotone scrape-time read)."""
+    return _gc_pause_total[0]
+
+
+def _drain_gc(rec: FlightRecorder) -> None:
+    if rec is _gc_rec:
+        while _gc_pending:
+            rec.record(_gc_pending.popleft())
+
+
 # -- lifecycle ----------------------------------------------------------------
 
 
@@ -496,17 +591,22 @@ def lookup_count() -> int:
 
 def arm(capacity: int = DEFAULT_CAPACITY) -> FlightRecorder:
     """Arm tracing process-wide (idempotent per capacity: re-arming
-    replaces the recorder)."""
-    global _recorder
+    replaces the recorder), and record ``gc.pause`` spans while armed."""
+    global _recorder, _gc_rec
+    watch_gc(keep=False)
     with _state_lock:
-        _recorder = FlightRecorder(capacity)
+        _gc_pending.clear()
+        _recorder = _gc_rec = FlightRecorder(capacity)
         return _recorder
 
 
 def disarm() -> None:
-    global _recorder
+    global _recorder, _gc_rec
     with _state_lock:
-        _recorder = None
+        _recorder = _gc_rec = None
+        _gc_pending.clear()
+        if not _gc_keep and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
 
 
 def reset_ids(start: int = 0) -> None:
@@ -522,6 +622,7 @@ def reset() -> None:
     rec = _recorder
     if rec is not None:
         rec.clear()
+        _gc_pending.clear()
     reset_ids()
 
 
@@ -559,6 +660,7 @@ def export(rec: FlightRecorder | None = None,
     cursor) and answered with the full buffer so the poller resyncs."""
     rec = rec if rec is not None else _recorder
     if rec is not None:
+        _drain_gc(rec)
         events, cursor = rec.snapshot_with_cursor(since)
     else:
         events, cursor = [], 0
@@ -599,9 +701,12 @@ def dump_to(path: str, rec: FlightRecorder | None = None) -> str:
 def span_sequence(doc: dict) -> list[tuple]:
     """The determinism view of a trace: (name, trace, span, parent)
     per event in recorded order, timestamps stripped — what same-seed
-    campaign runs must reproduce byte-identically."""
+    campaign runs must reproduce byte-identically.  ``gc.*`` events are
+    left out: when the collector runs is not the seed's to decide."""
     out = []
     for ev in doc.get("traceEvents", []):
+        if str(ev.get("name", "")).startswith("gc."):
+            continue
         args = ev.get("args", {})
         out.append((
             ev.get("name"), args.get("trace"), args.get("span"),
@@ -696,6 +801,8 @@ __all__ = [
     "lookup_count",
     "arm",
     "disarm",
+    "watch_gc",
+    "gc_pause_seconds",
     "reset",
     "reset_ids",
     "scope",

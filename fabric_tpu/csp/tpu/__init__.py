@@ -45,3 +45,19 @@ def _place_compile_cache() -> None:
 
 
 _place_compile_cache()
+
+
+def named_jit(fn, name: str):
+    """`fn` jitted under a stable name, so that a device trace can be
+    read after a refactor: the XLA module is `jit_<name>` and every
+    operation's `op_name` begins `jit(<name>)/<name>/`.  A bare
+    `jax.jit` of a `pallas_call` or a closure is `jit_wrapped` or
+    `jit__lambda_` to every reader of the trace."""
+    import jax
+
+    def call(*args):
+        with jax.named_scope(name):
+            return fn(*args)
+
+    call.__name__ = call.__qualname__ = name
+    return jax.jit(call)

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from fabric_tpu.csp.api import P256_GX, P256_GY, P256_N, P256_P
-from fabric_tpu.csp.tpu import limbs
+from fabric_tpu.csp.tpu import limbs, named_jit
 from fabric_tpu.csp.tpu.limbs import WIDE, ints_to_limbs, mod_ctx
 
 WINDOW_BITS = 4
@@ -292,7 +292,7 @@ def verify_kernel(qx, qy, d1, d2, cand0, cand1, cand1_ok, valid):
 
 @functools.lru_cache(maxsize=None)
 def _jit_verify():
-    return jax.jit(verify_kernel)
+    return named_jit(verify_kernel, "xla_p256_verify")
 
 
 def verify_prepared(qx, qy, d1, d2, cand0, cand1, cand1_ok, valid):

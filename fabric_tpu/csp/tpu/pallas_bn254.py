@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from fabric_tpu.csp.tpu import limbs
+from fabric_tpu.csp.tpu import limbs, named_jit
 from fabric_tpu.csp.tpu import bn254_batch as _xla_engine
 from fabric_tpu.csp.tpu.limbs import LIMB_BITS, MASK, WIDE, int_to_limbs
 from fabric_tpu.idemix import bn254 as bn
@@ -587,8 +587,9 @@ def _build_call(nblocks: int, blk: int, n_terms: int, n_tables: int,
             vmem_limit_bytes=100 * 1024 * 1024,
         ),
         interpret=interpret,
+        name="pallas_bn254_pairing",
     )
-    return jax.jit(fn)
+    return named_jit(fn, "pallas_bn254_pairing")
 
 
 # ---------------------------------------------------------------------------

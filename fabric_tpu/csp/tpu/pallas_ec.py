@@ -45,7 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from fabric_tpu.csp.api import P256_GX, P256_GY, P256_P
-from fabric_tpu.csp.tpu import ec
+from fabric_tpu.csp.tpu import ec, named_jit
 from fabric_tpu.csp.tpu.limbs import (
     LIMB_BITS,
     MASK,
@@ -752,9 +752,10 @@ def _build_call(nblocks: int, blk: int, interpret: bool):
             lane_spec(8),      # cand0
             lane_spec(2),      # flags: [cand1_ok; valid]
         ] + _common_specs(const_spec),
+        name="pallas_ec_p256_verify",
         **_pallas_opts(nblocks, blk, interpret),
     )
-    return jax.jit(fn)
+    return named_jit(fn, "pallas_ec_p256_verify")
 
 
 @functools.lru_cache(maxsize=None)
@@ -771,9 +772,10 @@ def _build_call_dedup(nblocks: int, blk: int, interpret: bool):
             lane_spec(8),      # cand0
             lane_spec(2),      # flags
         ] + _common_specs(const_spec),
+        name="pallas_ec_p256_verify_ktab",
         **_pallas_opts(nblocks, blk, interpret),
     )
-    return jax.jit(fn)
+    return named_jit(fn, "pallas_ec_p256_verify_ktab")
 
 
 def _use_interpret() -> bool:

@@ -69,6 +69,49 @@ LANE_SEALERS = (
 )
 
 
+# (kernel, bucket) pairs this process has enqueued: the first enqueue
+# of a pair traces, lowers and compiles (or loads) inside its
+# `tpu.enqueue` span, which is then marked `cold`
+_enqueued: set = set()
+
+# JAX reports what it traces, lowers and compiles as duration events;
+# one listener for the process, registered with the first TPUCSP, turns
+# them into a counter on the CSPMetrics bound last and, while tracelens
+# is armed, into `jax.compile` instants (inside a cold `tpu.enqueue`
+# they say what it spent; under load they are a recompile)
+_COMPILE_EVENTS = "/jax/core/compile/"
+_compile_lock = threading.Lock()
+_compile_listening = False
+_compile_metrics: list = [None]
+
+
+def _on_compile_event(name: str, secs: float, **_kw) -> None:
+    if not name.startswith(_COMPILE_EVENTS):
+        return
+    event = name[len(_COMPILE_EVENTS):]
+    metrics = _compile_metrics[0]
+    if metrics is not None:
+        metrics.compile_events.With("event", event).add()
+    # tracing one kernel reports thousands of sub-millisecond nested
+    # traces: they count, the ring keeps the ones that took time
+    if secs >= 1e-3:
+        tracing.instant("jax.compile", event=event, secs=secs)
+
+
+def _watch_compiles(metrics) -> None:
+    global _compile_listening
+    import jax.monitoring
+
+    with _compile_lock:
+        if metrics is not None:
+            _compile_metrics[0] = metrics
+        if not _compile_listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event
+            )
+            _compile_listening = True
+
+
 def _bucket(n: int, buckets) -> int:
     for b in buckets:
         if n <= b:
@@ -116,6 +159,7 @@ class _KeyTable:
         self._ktabx = np.zeros((8, self.cap), np.uint32)
         self._ktaby = np.zeros((8, self.cap), np.uint32)
         self._dev: tuple | None = None
+        self.uploads = 0  # device copies made (one per new key per device)
 
     @staticmethod
     def _words(be32: bytes) -> np.ndarray:
@@ -164,6 +208,7 @@ class _KeyTable:
             self._dev = {}
         key = device
         if key not in self._dev:
+            self.uploads += 1
             self._dev[key] = (
                 jax.device_put(self._ktabx.copy(), device),
                 jax.device_put(self._ktaby.copy(), device),
@@ -372,7 +417,8 @@ class _FlushResult:
     Whoever seals the mask reports its lanes once through `on_sealed`
     (the provider's lane tally): "device", "host_race", "failover"
     (device error, host oracle answered) and `host_kind` for the host
-    tail."""
+    tail.  The same writer ends the flush's detached `tpu.flush` span
+    (`span`, handed over by the provider) with `sealed_by`."""
 
     # host mini-batch between device-completion polls: sized so a poll
     # happens every ~20-100ms — larger when the native batch verifier
@@ -384,8 +430,11 @@ class _FlushResult:
                  host_items=(), sw: SWCSP | None = None,
                  device_items=None, deadline: float | None = None,
                  on_device_wall=None, on_device_outcome=None,
-                 on_sealed=None, host_kind: str = "host_fraction"):
+                 on_sealed=None, host_kind: str = "host_fraction",
+                 on_race=None, buckets=()):
         self._pending = pending  # [(collect, kept_lanes)]
+        self.buckets = tuple(buckets)  # the padded size of each chunk
+        self.span = None  # tracelens `tpu.flush`, dispatch begun -> sealed
         self._mask: list[bool] | None = None
         self._exc: Exception | None = None
         self._outstanding = total_lanes
@@ -409,6 +458,9 @@ class _FlushResult:
         # that wins the seal; `host_kind` names the host tail's share
         self._on_sealed = on_sealed
         self._host_kind = host_kind
+        # called (won: bool) on the consumer's thread when its deadline
+        # expired and the host race ran
+        self._on_race = on_race
         # True once the device (not the host fallback) produced the
         # device lanes' mask — the breaker probe's success criterion
         self.device_ok = False
@@ -431,7 +483,8 @@ class _FlushResult:
         )
         self._waiter.start()
 
-    def _seal(self, mask: list | None, exc: Exception | None = None) -> bool:
+    def _seal(self, mask: list | None, exc: Exception | None = None,
+              by: str = "error") -> bool:
         """First writer wins; every consumer wakes.  Drops the input
         references (device collectors, item lists) either way — a flush
         coalesces thousands of VerifyBatchItems and the late loser of a
@@ -443,6 +496,9 @@ class _FlushResult:
             if won:
                 self._mask = mask
                 self._exc = exc
+        if won and self.span is not None:
+            self.span.annotate(sealed_by=by)
+            self.span.end()
         self._pending = ()
         self._host_items = ()
         self._device_items = None
@@ -469,6 +525,7 @@ class _FlushResult:
             pending, host_items = self._pending, self._host_items
             device_items = self._device_items
             device_phase = False
+            ctx = None if self.span is None else self.span.ctx
             try:
                 # host tail FIRST: it runs while the device crunches
                 # (that overlap is the whole point of host_fraction);
@@ -487,7 +544,13 @@ class _FlushResult:
                 for collect, keep in pending:
                     # pallas chunks hand back a lazy collector; the XLA
                     # fallback hands back the device array itself
-                    mask = collect() if callable(collect) else np.asarray(collect)
+                    with tracing.attached(ctx), tracing.span(
+                        "tpu.device_wait", lanes=keep,
+                    ):
+                        mask = (
+                            collect() if callable(collect)
+                            else np.asarray(collect)
+                        )
                     out.extend(bool(v) for v in mask[:keep])
                 if pending:
                     self.device_ok = True
@@ -511,7 +574,7 @@ class _FlushResult:
                     try:
                         out = list(self._host_verify(device_items))
                         out.extend(self._host_verify(host_items))
-                        if self._seal(out):
+                        if self._seal(out, by="failover"):
                             self._note_sealed(
                                 "failover", len(device_items),
                                 len(host_items),
@@ -521,7 +584,10 @@ class _FlushResult:
                         e = e2
                 self._seal(None, e)
                 return
-            won = self._seal(out)
+            won = self._seal(
+                out,
+                by="device" if self._n_device_lanes else self._host_kind,
+            )
             if won:
                 self._note_sealed(
                     "device", self._n_device_lanes, len(host_items)
@@ -568,15 +634,19 @@ class _FlushResult:
         )
         items = list(device_items) + list(host_items)
         out: list[bool] = []
+        won = True
         for off in range(0, len(items), step):
             if self._done.is_set():
-                return False  # device finished after all — use it
+                won = False  # device finished after all — use it
+                break
             out.extend(self._host_verify(items[off:off + step]))
-        won = self._seal(out)
+        won = won and self._seal(out, by="host_race")
         if won:
             self._note_sealed(
                 "host_race", len(device_items), len(host_items)
             )
+        if self._on_race is not None:
+            self._on_race(won)
         return won
 
     def collect(self, deadline: float | None = None) -> list[bool]:
@@ -697,6 +767,7 @@ class TPUCSP(CSP):
         self._metrics = metrics
         self._tally_lock = threading.Lock()
         self._lane_tally = dict.fromkeys(LANE_SEALERS, 0)
+        _watch_compiles(metrics)
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -721,6 +792,7 @@ class TPUCSP(CSP):
         device failures and the lane tally surface on /metrics."""
         self._breaker.set_metrics(metrics)
         self._metrics = metrics
+        _watch_compiles(metrics)
 
     def lane_tally(self) -> dict[str, int]:
         """Lanes verified so far, keyed by who sealed their mask
@@ -736,6 +808,27 @@ class TPUCSP(CSP):
             self._lane_tally[kind] += lanes
         if self._metrics is not None:
             self._metrics.lanes.With("sealed_by", kind).add(lanes)
+
+    def _note_race(self, won: bool) -> None:
+        """A consumer's deadline expired and it raced the chip on the
+        host; runs on that consumer's thread, inside its `tpu.collect`
+        span."""
+        tracing.annotate(raced=True, race_won=won)
+        if self._metrics is not None:
+            self._metrics.host_races.With(
+                "outcome", "won" if won else "lost"
+            ).add()
+
+    def _enqueue_span(self, kernel: str, lanes: int, bucket: int, dev):
+        """Count one chunk's enqueue by bucket and open its span."""
+        cold = (kernel, bucket) not in _enqueued
+        _enqueued.add((kernel, bucket))
+        if self._metrics is not None:
+            self._metrics.dispatches.With("bucket", str(bucket)).add()
+        return tracing.span(
+            "tpu.enqueue", lanes=lanes, bucket=bucket,
+            device=0 if dev is None else dev.id, cold=cold,
+        )
 
     @property
     def breaker(self) -> "_Breaker":
@@ -941,16 +1034,22 @@ class TPUCSP(CSP):
             deadline = None
             if sole and res.deadline is not None:
                 deadline = self._sole_deadline_for(res._n_device_lanes)
+            budget = res.deadline if deadline is None else deadline
             with tracing.span(
                 "tpu.collect", batch=gen, lanes=n,
-                device_lanes=res._n_device_lanes,
+                device_lanes=res._n_device_lanes, sole=sole,
+                deadline_ms=None if budget is None else budget * 1e3,
+                raced=False,  # _note_race rewrites it
             ):
                 mask = res.collect(deadline)
                 if tracing.enabled():
+                    # the two measurements the deadlines follow
                     with self._ewma_lock:
                         wall = self._lane_wall_ewma
-                    if wall is not None:
-                        tracing.annotate(lane_wall_ewma_us=wall * 1e6)
+                    tracing.annotate(
+                        lane_wall_ewma_us=None if wall is None else wall * 1e6,
+                        host_rate_ewma=_host_rate_ewma[0],
+                    )
             out = mask[seg_start:seg_start + n]
             with self._pend_lock:
                 if memo:  # lost a race after collect: keep first result
@@ -973,11 +1072,24 @@ class TPUCSP(CSP):
         self._pend_lanes = 0
         gen = self._gen
         self._gen += 1
+        # dispatch begun -> mask sealed, ended by whoever seals it; it
+        # shares `batch` with tpu.dispatch and the segments' tpu.collect
+        fspan = tracing.begin(
+            "tpu.flush", detach=True, batch=gen, lanes=len(items),
+        )
         try:
             with tracing.span(
-                "tpu.dispatch", batch=gen, lanes=len(items),
+                "tpu.dispatch", parent=fspan.ctx, batch=gen,
+                lanes=len(items),
             ):
                 res = self._dispatch(items)
+            res.span = fspan
+            fspan.annotate(
+                buckets=list(res.buckets),
+                deadline_ms=(
+                    None if res.deadline is None else res.deadline * 1e3
+                ),
+            )
             # park a waiter on the device result NOW, so the mask is
             # materialized off the caller's thread (see _FlushResult)
             res.start_background()
@@ -995,6 +1107,7 @@ class TPUCSP(CSP):
                 [], len(items), host_items=items, sw=self._sw,
                 on_sealed=self._note_sealed, host_kind="failover",
             )
+            res.span = fspan
         self._flushed[gen] = res
         self._inflight = [
             r for r in self._inflight
@@ -1093,15 +1206,23 @@ class TPUCSP(CSP):
             # collector so pipelined callers keep their overlap.
             from fabric_tpu.csp.tpu import ec
 
-            pending = []
-            for i, (chunk, keep) in enumerate(self._tuple_chunks(items)):
-                prep = ec.prepare_batch(chunk)
+            pending, buckets = [], []
+            with tracing.span("tpu.marshal", lanes=len(items)):
+                chunks = list(self._tuple_chunks(items))
+            for i, (chunk, keep) in enumerate(chunks):
+                with tracing.span("tpu.marshal", lanes=keep):
+                    prep = ec.prepare_batch(chunk)
                 dev = place(i)
-                if dev is not None:
-                    prep = {
-                        k: jax.device_put(v, dev) for k, v in prep.items()
-                    }
-                pending.append((ec.verify_prepared(**prep), keep))
+                buckets.append(len(chunk))
+                with self._enqueue_span(
+                    "xla_p256_verify", keep, len(chunk), dev
+                ):
+                    if dev is not None:
+                        prep = {
+                            k: jax.device_put(v, dev)
+                            for k, v in prep.items()
+                        }
+                    pending.append((ec.verify_prepared(**prep), keep))
             self.last_dispatch_devices = tuple(dict.fromkeys(used))
             return _FlushResult(
                 pending, len(items) + len(host_items),
@@ -1109,6 +1230,7 @@ class TPUCSP(CSP):
                 device_items=list(items),
                 on_device_outcome=self._breaker.record,
                 on_sealed=self._note_sealed,
+                on_race=self._note_race, buckets=buckets,
             )
 
         from fabric_tpu.csp.tpu import pallas_ec
@@ -1119,88 +1241,116 @@ class TPUCSP(CSP):
         # k+1 overlap chunk k's device time.  Host prep runs in the C++
         # marshaller when available (DER + prechecks + batch inversion +
         # packing in one pass), else the numpy path.
-        packed_all = self._marshal_native(items)
-        pending = []
+        with tracing.span("tpu.marshal", lanes=len(items)):
+            packed_all = self._marshal_native(items)
+        pending, buckets = [], []
         if packed_all is not None:
-            # persistent SKI-keyed table: per-lane keys collapse to a
-            # u32 index, and the table buffers stay resident on device
-            # across blocks (uploaded again only when a new key shows
-            # up); chunks slice only the per-lane arrays (the shared
-            # ktab rides along by reference)
-            kidx = self._key_table.assign(
-                [
-                    it.key.public_key()
-                    if isinstance(it.key, ECDSAP256PrivateKey)
-                    else it.key
-                    for it in items
-                ]
-            )
-            use_table = kidx is not None
-            if use_table:
-                packed_all = {
-                    k: v
-                    for k, v in packed_all.items()
-                    if k not in ("qx", "qy")
-                }
-                packed_all["kidx"] = kidx
-            else:
-                packed_all = pallas_ec.dedup_keys(packed_all)
-            shared = ("ktabx", "ktaby")
-            off = 0
-            for i, (take, bsz) in enumerate(
-                _chunk_plan(len(items), self._max_chunk, min_bucket=256)
-            ):
-                sl = {}
-                for k, v in packed_all.items():
-                    if k in shared:
-                        sl[k] = v
-                    elif v.ndim == 2:
-                        sl[k] = v[:, off:off + take]
-                    else:
-                        sl[k] = v[off:off + take]
-                off += take
-                if take < bsz:
-                    # zero-pad (valid=False lanes) to the bucket size so
-                    # every chunk reuses the same compiled kernel shape
-                    sl = {
-                        k: (v if k in shared else np.concatenate(
-                            [v, np.zeros(
-                                v.shape[:-1] + (bsz - take,), v.dtype
-                            )],
-                            axis=-1,
-                        ))
-                        for k, v in sl.items()
-                    }
-                dev = place(i)
-                if dev is not None:
-                    # cand1_ok/valid stay host-side: verify_packed
-                    # np.asarray's them into its flags stack anyway
-                    host_side = ("cand1_ok", "valid")
-                    sl = {
-                        k: (
-                            v
-                            if k in shared or k in host_side
-                            else jax.device_put(v, dev)
-                        )
-                        for k, v in sl.items()
-                    }
-                if use_table:
-                    # persistent table: one resident copy per device
-                    sl["ktabx"], sl["ktaby"] = (
-                        self._key_table.device_tables(dev)
-                    )
-                pending.append((pallas_ec.verify_packed(sl), take))
-        else:
-            for i, (chunk, keep) in enumerate(self._tuple_chunks(items, min_bucket=256)):
-                packed = pallas_ec.dedup_keys(
-                    pallas_ec.prepare_packed(chunk)
+            plan = _chunk_plan(len(items), self._max_chunk, min_bucket=256)
+            with tracing.span("tpu.keytable") as kspan:
+                # persistent SKI-keyed table: per-lane keys collapse to
+                # a u32 index, and the table buffers stay resident on
+                # device across blocks (uploaded again only when a new
+                # key shows up); chunks slice only the per-lane arrays
+                # (the shared ktab rides along by reference)
+                kidx = self._key_table.assign(
+                    [
+                        it.key.public_key()
+                        if isinstance(it.key, ECDSAP256PrivateKey)
+                        else it.key
+                        for it in items
+                    ]
                 )
-                dev = place(i)
-                if dev is not None:
-                    packed = {
-                        k: jax.device_put(v, dev) for k, v in packed.items()
+                use_table = kidx is not None
+                if use_table:
+                    packed_all = {
+                        k: v
+                        for k, v in packed_all.items()
+                        if k not in ("qx", "qy")
                     }
-                pending.append((pallas_ec.verify_packed(packed), keep))
+                    packed_all["kidx"] = kidx
+                    # one resident copy per device the chunks go to
+                    uploads = self._key_table.uploads
+                    targets = (
+                        [None] if len(devices) <= 1
+                        else devices[:len(plan)]
+                    )
+                    for dev in targets:
+                        self._key_table.device_tables(dev)
+                    kspan.annotate(
+                        uploaded=self._key_table.uploads != uploads
+                    )
+                else:
+                    packed_all = pallas_ec.dedup_keys(packed_all)
+            shared = ("ktabx", "ktaby")
+            kernel = (
+                "pallas_ec_p256_verify_ktab" if "kidx" in packed_all
+                else "pallas_ec_p256_verify"
+            )
+            off = 0
+            for i, (take, bsz) in enumerate(plan):
+                dev = place(i)
+                buckets.append(bsz)
+                with self._enqueue_span(kernel, take, bsz, dev):
+                    sl = {}
+                    for k, v in packed_all.items():
+                        if k in shared:
+                            sl[k] = v
+                        elif v.ndim == 2:
+                            sl[k] = v[:, off:off + take]
+                        else:
+                            sl[k] = v[off:off + take]
+                    off += take
+                    if take < bsz:
+                        # zero-pad (valid=False lanes) to the bucket
+                        # size so every chunk reuses the same compiled
+                        # kernel shape
+                        sl = {
+                            k: (v if k in shared else np.concatenate(
+                                [v, np.zeros(
+                                    v.shape[:-1] + (bsz - take,), v.dtype
+                                )],
+                                axis=-1,
+                            ))
+                            for k, v in sl.items()
+                        }
+                    if dev is not None:
+                        # cand1_ok/valid stay host-side: verify_packed
+                        # np.asarray's them into its flags stack anyway
+                        host_side = ("cand1_ok", "valid")
+                        sl = {
+                            k: (
+                                v
+                                if k in shared or k in host_side
+                                else jax.device_put(v, dev)
+                            )
+                            for k, v in sl.items()
+                        }
+                    if use_table:
+                        sl["ktabx"], sl["ktaby"] = (
+                            self._key_table.device_tables(dev)
+                        )
+                    pending.append((pallas_ec.verify_packed(sl), take))
+        else:
+            with tracing.span("tpu.marshal", lanes=len(items)):
+                chunks = list(self._tuple_chunks(items, min_bucket=256))
+            for i, (chunk, keep) in enumerate(chunks):
+                with tracing.span("tpu.marshal", lanes=keep):
+                    packed = pallas_ec.dedup_keys(
+                        pallas_ec.prepare_packed(chunk)
+                    )
+                dev = place(i)
+                buckets.append(len(chunk))
+                with self._enqueue_span(
+                    "pallas_ec_p256_verify_ktab" if "kidx" in packed
+                    else "pallas_ec_p256_verify",
+                    keep, len(chunk), dev,
+                ):
+                    if dev is not None:
+                        packed = {
+                            k: jax.device_put(v, dev)
+                            for k, v in packed.items()
+                        }
+                    pending.append((pallas_ec.verify_packed(packed), keep))
         self.last_dispatch_devices = tuple(dict.fromkeys(used))
         return _FlushResult(
             pending, len(items) + len(host_items),
@@ -1210,6 +1360,7 @@ class TPUCSP(CSP):
             on_device_wall=self._note_device_wall,
             on_device_outcome=self._breaker.record,
             on_sealed=self._note_sealed,
+            on_race=self._note_race, buckets=buckets,
         )
 
     def _note_device_wall(self, lanes: int, wall: float) -> None:

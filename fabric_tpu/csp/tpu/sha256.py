@@ -22,6 +22,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from fabric_tpu.csp.tpu import named_jit
+
 _K = np.array(
     [
         0x428A2F98, 0x71374491, 0xB5C0FBCF, 0xE9B5DBA5, 0x3956C25B, 0x59F111F1,
@@ -99,7 +101,7 @@ def sha256_kernel(words, nblk):
 
 @functools.lru_cache(maxsize=None)
 def _jit_sha():
-    return jax.jit(sha256_kernel)
+    return named_jit(sha256_kernel, "sha256_batch")
 
 
 def pad_messages(msgs, n_blocks: int | None = None) -> tuple[np.ndarray, np.ndarray]:

@@ -45,7 +45,11 @@ class Committer:
         t0 = time.perf_counter()
         self._validator.validate(block)  # sets sig/policy flags
         t_validate = time.perf_counter() - t0
-        with self._lock:
+        # the commit stages join the block's trace, as they do through
+        # CommitAssist.trace_ctx in store_stream
+        with self._lock, tracing.attached(
+            getattr(self._validator, "last_block_trace", None)
+        ):
             self._ledger.commit(block)  # MVCC + persist (updates flags again)
         if self.metrics is not None:
             self.metrics.observe(
@@ -118,7 +122,13 @@ class Committer:
                 grouped.clear()
 
             while True:
-                item = commit_q.get()
+                # whoever waits longer sets the pace: this thread here
+                # (validator-bound), the main thread in
+                # commit.backpressure / commit.await_flags below
+                with tracing.span(
+                    "commit.idle", cat="stage", depth=commit_q.qsize(),
+                ):
+                    item = commit_q.get()
                 if item is None:
                     if not failed and grouped:
                         try:
@@ -168,10 +178,15 @@ class Committer:
                 tee(blocks), depth=depth, release=releases.append,
                 rwsets_out=rwsets_q.append,
             ):
-                commit_q.put(
-                    (pending.popleft(), releases.popleft(),
-                     rwsets_q.popleft())
-                )
+                assist = rwsets_q.popleft()
+                with tracing.span(
+                    "commit.backpressure", cat="stage",
+                    parent=getattr(assist, "trace_ctx", None),
+                    depth=commit_q.qsize(),
+                ):
+                    commit_q.put(
+                        (pending.popleft(), releases.popleft(), assist)
+                    )
                 n_in += 1
                 while not done_q.empty():
                     r = done_q.get()
@@ -180,7 +195,10 @@ class Committer:
                     n_out += 1
                     yield r
             while n_out < n_in:
-                r = done_q.get()
+                with tracing.span(
+                    "commit.await_flags", cat="stage", depth=n_in - n_out,
+                ):
+                    r = done_q.get()
                 if isinstance(r, Exception):
                     raise r
                 n_out += 1

@@ -241,6 +241,8 @@ class TxValidator:
         # blocks whose collect actually fanned out (the tier-1 smoke
         # asserts the parallel path ran, not just that flags matched)
         self.parallel_collect_blocks = 0
+        # trace root of the block validate() saw last (tracing armed)
+        self.last_block_trace = None
 
     def _committed_metadata(self, ns: str, key: str) -> dict[str, bytes]:
         return self._ledger.get_state_metadata(ns, key)
@@ -492,6 +494,9 @@ class TxValidator:
         block, flags, works, collect, _envs, bspan = self._start_block(
             block, set()
         )
+        # Committer.store_block attaches the commit stages to it (None
+        # while tracing is disarmed)
+        self.last_block_trace = bspan.ctx
         return self._finish_block(block, flags, works, collect, bspan)
 
     def validate_pipeline(self, blocks, depth: int = 2, release=None,

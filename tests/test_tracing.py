@@ -467,3 +467,304 @@ def test_operations_system_builds_workpool_metrics_lazily():
     m = sys_.workpool_metrics()
     assert m is sys_.workpool_metrics()  # memoized
     sys_._server.server_close()
+
+
+# -- PR 24: gc pauses, queue waits, the flush's anatomy ----------------------
+
+
+def test_arm_records_gc_pauses_and_scope_records_none():
+    """arm() owns the process's one gc callback while armed: a forced
+    generation-2 collection becomes a `gc.pause` span with no span id
+    (the seeded counter is left alone).  scope(), the seeded tests'
+    entry, records none, and disarm() takes the callback out again."""
+    import gc
+
+    assert tracing._on_gc not in gc.callbacks
+    with tracing.scope() as rec:
+        gc.collect(2)
+        assert _by_name(tracing.export(rec), "gc.pause") == []
+    assert tracing._on_gc not in gc.callbacks
+
+    tracing.arm(256)
+    try:
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        with tracing.span("work"):
+            gc.collect(2)
+        doc = tracing.export()
+        with tracing.scope() as inner:       # a scope inside an armed run
+            gc.collect(2)
+            assert _by_name(tracing.export(inner), "gc.pause") == []
+    finally:
+        tracing.disarm()
+    assert tracing._on_gc not in gc.callbacks
+    (pause,) = _by_name(doc, "gc.pause")
+    assert pause["cat"] == "stage" and pause["ph"] == "X"
+    assert pause["tid"] == "MainThread" and pause["dur"] >= 0
+    assert pause["args"]["generation"] == 2
+    assert set(pause["args"]) == {"generation", "collected", "uncollectable"}
+    (work,) = _by_name(doc, "work")
+    assert work["ts"] <= pause["ts"] <= work["ts"] + work["dur"]
+    assert work["args"]["span"] == "1"       # the pause took no id
+    assert [s[0] for s in tracing.span_sequence(doc)] == ["work"]
+
+
+def test_process_metrics_and_tracelens_share_one_gc_callback():
+    import gc
+
+    from fabric_tpu.common.metrics import ProcessMetrics, PrometheusProvider
+
+    prov = PrometheusProvider()
+    pm = ProcessMetrics(prov)
+    try:
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        tracing.arm(64)
+        tracing.disarm()                     # pinned by the gauge: stays
+        assert gc.callbacks.count(tracing._on_gc) == 1
+        before = tracing.gc_pause_seconds()
+        gc.collect(2)
+        assert tracing.gc_pause_seconds() > before
+        pm.collect()
+        assert "process_gc_pause_seconds_total" in prov.registry.expose()
+    finally:
+        gc.callbacks.remove(tracing._on_gc)
+        tracing._gc_keep = False
+
+
+@pytest.fixture(scope="module")
+def tpu_world():
+    """One org, a few signed blocks, and a TPUCSP on whatever backend
+    JAX has (here the XLA fallback): min_device_batch=1 sends every
+    batch through `_dispatch`, and one 32-lane kernel shape serves all
+    the tests below."""
+    from orgfix import make_org
+
+    from fabric_tpu import protoutil
+    from fabric_tpu.common import configtx_builder as ctx
+    from fabric_tpu.common.metrics import CSPMetrics, PrometheusProvider
+    from fabric_tpu.csp.tpu.provider import TPUCSP
+    from fabric_tpu.msp import msp_config_from_ca
+    from fabric_tpu.peer.endorser import Endorser
+    from fabric_tpu.protos.common import common_pb2
+    from fabric_tpu.protos.peer import proposal_pb2
+
+    org, oorg = make_org("Org1MSP"), make_org("OrdererMSP")
+    genesis = ctx.genesis_block("trch", ctx.channel_group(
+        ctx.application_group({"Org1": ctx.org_group(
+            "Org1MSP", msp_config_from_ca(org.ca, "Org1MSP"))}),
+        ctx.orderer_group({"O": ctx.org_group(
+            "OrdererMSP", msp_config_from_ca(oorg.ca, "OrdererMSP"))},
+            consensus_type="solo"),
+    ))
+
+    def cc(sim, args):
+        sim.set_state("trcc", args[0].decode(), args[1])
+        return 200, "", b""
+
+    ledger, bundle = _fresh_ledger(org, genesis)
+    endorser = Endorser("trch", ledger, bundle,
+                        org.signer("peer0", role_ou="peer"), {"trcc": cc}, org.csp)
+    client = org.signer("user1", role_ou="client")
+    blocks = []
+    for b in range(3):
+        blk = common_pb2.Block()
+        blk.header.number = b + 1
+        for i in range(3):
+            prop, _ = protoutil.create_chaincode_proposal(
+                client.serialize(), "trch", "trcc", [b"k%d-%d" % (b, i), b"v"])
+            raw = prop.SerializeToString()
+            resp = endorser.process_proposal(proposal_pb2.SignedProposal(
+                proposal_bytes=raw, signature=client.sign(raw)))
+            blk.data.data.append(
+                protoutil.create_signed_tx(prop, client, [resp]).SerializeToString())
+        while len(blk.metadata.metadata) < 3:
+            blk.metadata.metadata.append(b"")
+        blocks.append(blk.SerializeToString())
+    prov = PrometheusProvider()
+    csp = TPUCSP(min_device_batch=1, metrics=CSPMetrics(prov))
+    yield org, genesis, blocks, csp, prov
+    csp.close()
+
+
+def _fresh_ledger(org, genesis):
+    from fabric_tpu.common.channelconfig import bundle_from_genesis
+    from fabric_tpu.ledger import LedgerProvider
+
+    ledger = LedgerProvider(None).create(genesis)
+    return ledger, bundle_from_genesis(genesis, org.csp)
+
+
+def _committer(tpu_world):
+    from fabric_tpu.peer.committer import Committer
+    from fabric_tpu.peer.txvalidator import TxValidator
+    from fabric_tpu.protos.common import common_pb2
+
+    org, genesis, blocks, csp, _prov = tpu_world
+    ledger, bundle = _fresh_ledger(org, genesis)
+    committer = Committer(TxValidator("trch", ledger, bundle, csp), ledger)
+    return committer, [common_pb2.Block.FromString(b) for b in blocks]
+
+
+def test_disarmed_commit_path_consults_nothing(tpu_world):
+    """The zero-overhead pin over the whole commit path, new sites
+    included: a store_stream and a store_block through the TPU provider
+    (queue waits, flush anatomy, kernel enqueue, waiter, collector)
+    with tracing never armed leave the armed-path counter where it was
+    and install no gc callback."""
+    import gc
+
+    assert not tracing.enabled()
+    before = tracing.lookup_count()
+    committer, blocks = _committer(tpu_world)
+    flags = list(committer.store_stream(iter(blocks[:2]), depth=2))
+    flags.append(committer.store_block(blocks[2]))
+    assert len(flags) == 3 and all(f == [0, 0, 0] for f in flags)
+    tpu_world[3].drain()
+    assert tracing.lookup_count() == before
+    assert tracing.recorder() is None
+    assert tracing._on_gc not in gc.callbacks
+
+
+def test_flush_anatomy_and_queue_waits_are_spans(tpu_world):
+    """Armed, the same path shows who waited for whom (commit.idle on
+    the committer thread, commit.backpressure / commit.await_flags on
+    the main one), what a dispatch spent its time on (tpu.marshal and
+    tpu.enqueue under tpu.dispatch under tpu.flush), one tpu.flush per
+    generation ended by whoever sealed it, the waiter's tpu.device_wait,
+    and every span of one block under that block's trace in both
+    store_stream and store_block."""
+    csp = tpu_world[3]
+    committer, blocks = _committer(tpu_world)    # genesis commits untraced
+    with tracing.scope() as rec:
+        list(committer.store_stream(iter(blocks[:2]), depth=2))
+        committer.store_block(blocks[2])
+        csp.drain()
+        doc = tracing.export(rec)
+
+    span_of = {e["args"]["span"]: e for e in doc["traceEvents"] if "span" in e["args"]}
+    flushes = _by_name(doc, "tpu.flush")
+    dispatches = _by_name(doc, "tpu.dispatch")
+    assert len(flushes) == len(dispatches) >= 2
+    assert len({f["args"]["batch"] for f in flushes}) == len(flushes)
+    for f in flushes:
+        assert f["cat"] == "span"            # tpu.* stays out of the gap labels
+        assert f["args"]["sealed_by"] == "device"
+        assert f["args"]["buckets"] == [32] and f["args"]["lanes"] <= 32
+    for d in dispatches:
+        flush = span_of[d["args"]["parent"]]
+        assert flush["name"] == "tpu.flush" and flush["args"]["batch"] == d["args"]["batch"]
+        assert flush["ts"] <= d["ts"] and flush["dur"] >= d["dur"]
+    for name in ("tpu.marshal", "tpu.enqueue"):
+        found = _by_name(doc, name)
+        assert len(found) >= len(dispatches)
+        assert all(span_of[e["args"]["parent"]]["name"] == "tpu.dispatch" for e in found)
+    for e in _by_name(doc, "tpu.enqueue"):
+        assert e["args"]["bucket"] == 32 and 0 < e["args"]["lanes"] <= 32
+        assert e["args"]["device"] == 0 and isinstance(e["args"]["cold"], bool)
+    waits = _by_name(doc, "tpu.device_wait")
+    assert len(waits) == len(flushes)
+    assert all(w["tid"] == "tpu-flush-waiter" for w in waits)
+    assert all(span_of[w["args"]["parent"]]["name"] == "tpu.flush" for w in waits)
+    collects = _by_name(doc, "tpu.collect")
+    assert {c["args"]["batch"] for c in collects} == {f["args"]["batch"] for f in flushes}
+    for c in collects:
+        assert c["args"]["raced"] is False and "sole" in c["args"]
+        assert {"deadline_ms", "lane_wall_ewma_us", "host_rate_ewma"} <= set(c["args"])
+
+    idle = _by_name(doc, "commit.idle")
+    back = _by_name(doc, "commit.backpressure")
+    tail = _by_name(doc, "commit.await_flags")
+    assert len(back) == 2 and len(idle) == 3 and 1 <= len(tail) <= 2
+    assert all(e["cat"] == "stage" and "depth" in e["args"] for e in idle + back + tail)
+    assert {e["tid"] for e in idle} == {"committer-stream"}
+    assert {e["tid"] for e in back + tail} == {"MainThread"}
+
+    # one block, one trace, in both paths
+    roots = {e["args"]["block"]: e for e in _by_name(doc, "block")}
+    assert sorted(roots) == [1, 2, 3]
+    for name in ("collect", "verify_wait", "policy", "mvcc", "block_append", "state"):
+        for e in _by_name(doc, name):
+            root = roots[e["args"]["block"]]
+            assert e["args"]["trace"] == root["args"]["trace"], (name, e["args"])
+            assert e["args"]["parent"] == root["args"]["span"], (name, e["args"])
+    assert {e["args"]["parent"] for e in back} == {
+        roots[1]["args"]["span"], roots[2]["args"]["span"]}
+
+
+def test_a_flush_past_its_deadline_carries_raced(tpu_world):
+    """The deadline machinery on the trace and on /metrics: a device
+    that answers late makes the sole consumer race on the host; its
+    tpu.collect says so, the tpu.flush names who sealed it, and the
+    operator's counters count the race and the dispatch."""
+    import time
+
+    import numpy as np
+
+    from fabric_tpu.csp.api import VerifyBatchItem
+
+    org, _genesis, _blocks, csp, prov = tpu_world
+    key = org.csp.key_gen()
+    digest = org.csp.hash(b"late")
+    items = [VerifyBatchItem(key.public_key(), digest, org.csp.sign(key, digest))] * 4
+    inner = csp._dispatch
+
+    def late(batch):
+        res = inner(batch)
+        res.deadline = 0.2               # the XLA fallback sets none
+
+        def slow(out):
+            time.sleep(0.6)
+            return np.asarray(out)
+
+        res._pending = [(lambda o=o: slow(o), keep) for o, keep in res._pending]
+        return res
+
+    csp._dispatch = late
+    try:
+        with tracing.scope() as rec:
+            assert csp.verify_batch(items) == [True] * 4
+            csp.drain()
+            doc = tracing.export(rec)
+    finally:
+        del csp._dispatch
+    (collect,) = _by_name(doc, "tpu.collect")
+    assert collect["args"]["raced"] is True and collect["args"]["race_won"] is True
+    assert collect["args"]["sole"] is True
+    assert 50.0 <= collect["args"]["deadline_ms"] <= 200.0
+    (flush,) = _by_name(doc, "tpu.flush")
+    assert flush["args"]["sealed_by"] == "host_race"
+    assert flush["args"]["deadline_ms"] == pytest.approx(200.0)
+    exposed = prov.registry.expose()
+    assert 'csp_tpu_host_races_total{outcome="won"} 1' in exposed
+    assert 'csp_tpu_dispatches_total{bucket="32"}' in exposed
+
+
+def test_compile_events_are_counted_and_marked_when_armed(tpu_world):
+    from fabric_tpu.csp.tpu import provider
+
+    prov = tpu_world[4]
+
+    def traces_counted():
+        key = 'csp_tpu_compile_events_total{event="jaxpr_trace_duration"} '
+        return sum(float(line[len(key):]) for line in
+                   prov.registry.expose().splitlines() if line.startswith(key))
+
+    n0 = traces_counted()        # the fixture's own compile may have counted
+    before = tracing.lookup_count()
+    provider._on_compile_event("/jax/core/compile/backend_compile_duration", 0.5)
+    provider._on_compile_event("/jax/not/a/compile/event", 0.5)
+    assert tracing.lookup_count() == before          # disarmed: counter only
+    with tracing.scope() as rec:
+        with tracing.span("tpu.enqueue") as sp:
+            provider._on_compile_event(
+                "/jax/core/compile/jaxpr_trace_duration", 0.25)
+            provider._on_compile_event(      # nested, tiny: counted only
+                "/jax/core/compile/jaxpr_trace_duration", 2e-5)
+        doc = tracing.export(rec)
+    (mark,) = _by_name(doc, "jax.compile")
+    assert mark["args"]["event"] == "jaxpr_trace_duration"
+    assert mark["args"]["secs"] == 0.25
+    assert mark["args"]["parent"] == f"{sp.span_id:x}"
+    exposed = prov.registry.expose()
+    assert 'csp_tpu_compile_events_total{event="backend_compile_duration"}' in exposed
+    assert traces_counted() == n0 + 2
+    assert "not/a/compile" not in exposed
