@@ -127,6 +127,11 @@ def cmd_node_start(args) -> int:
             ),
         )
     node.start()
+    # ledgers recovered, channels rebuilt, services listening: what is
+    # on the heap now stays for the life of the peer (common/gcpolicy.py)
+    from fabric_tpu.common import gcpolicy
+
+    gcpolicy.settle()
     print(f"peer listening on {node.addr[0]}:{node.addr[1]}", flush=True)
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *a: stop.set())

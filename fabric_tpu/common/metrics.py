@@ -873,6 +873,12 @@ class ProcessMetrics:
             help="Cumulative seconds spent inside cyclic GC "
                  "collections (gc callback timing).",
         ))
+        self.gc_frozen_objects = provider.new_gauge(GaugeOpts(
+            name="process_gc_frozen_objects",
+            help="Objects in the collector's permanent generation "
+                 "(gc.freeze): the start-up heap no collection walks "
+                 "any more; 0 until common.gcpolicy.settle() has run.",
+        ))
         # the process has ONE gc callback and tracelens owns it: armed,
         # the same entry also records `gc.pause` spans
         tracing.watch_gc()
@@ -898,6 +904,7 @@ class ProcessMetrics:
                 "generation", str(gen)
             ).set(st.get("collections", 0))
         self.gc_pause_seconds.set(tracing.gc_pause_seconds())
+        self.gc_frozen_objects.set(gc.get_freeze_count())
 
 
 __all__ = [

@@ -491,13 +491,21 @@ def split_frame_token(frame: bytes) -> tuple[bytes, SpanContext | None]:
 # -- gc pauses ----------------------------------------------------------------
 
 # The process's ONE `gc.callbacks` entry.  It is installed on first
-# need: by arm(), which wants a span for every collection long enough
-# to explain an idle gap, or by common.metrics.ProcessMetrics, which
+# need: by arm(), which wants a span for every collection that can
+# explain an idle gap, or by common.metrics.ProcessMetrics, which
 # wants the summed pause for its gauge.  Where neither asked, nothing
 # is installed.  A collection stops every thread of the process, so a
 # pause belongs to no block's trace: the event carries no span id (the
 # seeded id counter is left alone, and span_sequence() skips it).
-GC_SPAN_MIN_S = 1e-3  # a younger-generation pause shorter than this only counts
+#
+# Which collections get a span: every one of generation 1 or older,
+# and a generation-0 one of at least GC_SPAN_MIN_S.  Since the start-up
+# heap is frozen and the young generations are collected where the
+# pipeline runs empty (common/gcpolicy.py), a full walk of the heap is
+# off the block path and the generation-1 collection is the usual one
+# there: it is what an operator reads in a trace to see that the
+# collector is alive and cheap.
+GC_SPAN_MIN_S = 1e-3  # a generation-0 pause shorter than this only counts
 
 _gc_keep = False  # ProcessMetrics reads the total for the life of the process
 # the recorder arm() made: pauses are kept only while IT is the armed
@@ -508,8 +516,9 @@ _gc_pause_total = [0.0]
 # The callback runs between two bytecodes of whatever thread crossed
 # the collector's threshold, possibly one that holds the recorder's
 # lock: it takes no lock and only appends here (atomic under the GIL);
-# export() moves the events into the recorder.
-_gc_pending: collections.deque = collections.deque(maxlen=4096)
+# export() moves the events into the recorder.  arm() sizes this to
+# its recorder, so no pause is dropped that the ring would have kept.
+_gc_pending: collections.deque = collections.deque(maxlen=DEFAULT_CAPACITY)
 
 
 def _on_gc(phase: str, info: dict) -> None:
@@ -527,7 +536,7 @@ def _on_gc(phase: str, info: dict) -> None:
     if rec is None or rec is not _recorder or clockskew.installed() is not None:
         return
     gen = info.get("generation", 0)
-    if gen < 2 and dt < GC_SPAN_MIN_S:
+    if gen < 1 and dt < GC_SPAN_MIN_S:
         return
     ts = round(t0 * 1e6)
     _gc_pending.append({
@@ -592,10 +601,10 @@ def lookup_count() -> int:
 def arm(capacity: int = DEFAULT_CAPACITY) -> FlightRecorder:
     """Arm tracing process-wide (idempotent per capacity: re-arming
     replaces the recorder), and record ``gc.pause`` spans while armed."""
-    global _recorder, _gc_rec
+    global _recorder, _gc_rec, _gc_pending
     watch_gc(keep=False)
     with _state_lock:
-        _gc_pending.clear()
+        _gc_pending = collections.deque(maxlen=capacity)
         _recorder = _gc_rec = FlightRecorder(capacity)
         return _recorder
 

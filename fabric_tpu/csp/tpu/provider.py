@@ -19,6 +19,7 @@ a per-lane mask (hard part (4)).
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import os
 import threading
@@ -27,7 +28,7 @@ from typing import Sequence
 
 import numpy as np
 
-from fabric_tpu.common import tracing
+from fabric_tpu.common import gcpolicy, tracing
 from fabric_tpu.common.flogging import must_get_logger
 from fabric_tpu.csp import api
 from fabric_tpu.devtools import faultline, knob_registry
@@ -819,16 +820,22 @@ class TPUCSP(CSP):
                 "outcome", "won" if won else "lost"
             ).add()
 
+    @contextlib.contextmanager
     def _enqueue_span(self, kernel: str, lanes: int, bucket: int, dev):
-        """Count one chunk's enqueue by bucket and open its span."""
+        """Count one chunk's enqueue by bucket and span it."""
         cold = (kernel, bucket) not in _enqueued
         _enqueued.add((kernel, bucket))
         if self._metrics is not None:
             self._metrics.dispatches.With("bucket", str(bucket)).add()
-        return tracing.span(
+        with tracing.span(
             "tpu.enqueue", lanes=lanes, bucket=bucket,
             device=0 if dev is None else dev.id, cold=cold,
-        )
+        ):
+            yield
+        if cold:
+            # trace-and-lower left a heap behind that lives as long as
+            # the process: keep every later collection off it
+            gcpolicy.absorb()
 
     @property
     def breaker(self) -> "_Breaker":

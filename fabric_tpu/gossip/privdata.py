@@ -18,6 +18,7 @@ from __future__ import annotations
 import threading
 import time
 
+from fabric_tpu.common import gcpolicy
 from fabric_tpu.common.hashing import sha256 as _sha256
 from fabric_tpu.protos.common import common_pb2
 from fabric_tpu.protos.gossip import message_pb2 as gpb
@@ -396,6 +397,7 @@ class PrivDataCoordinator:
         final_flags = list(protoutil.tx_filter(block))
         for fn in self._listeners:
             fn(block, final_flags)
+        gcpolicy.pipeline_empty()  # as Committer.store_block
         return final_flags
 
     def _from_transient(self, txid, ns, coll, expected_hash):

@@ -10,6 +10,7 @@ NEW bundle with fresh MSPs, so caches never go stale).
 
 from __future__ import annotations
 
+import copy
 import threading
 import time
 from collections import OrderedDict
@@ -20,6 +21,23 @@ _PRINCIPAL_CACHE = 100
 # validate() compares wall clock against the cert validity window, so its
 # cache entries expire instead of living for the bundle's lifetime
 _VALIDATE_TTL_S = 60.0
+
+
+def _template(exc: Exception) -> Exception | None:
+    """A copy of ``exc`` that is never raised: same type, arguments and
+    attributes, no traceback, cause or context.  A cached failure is
+    kept as this and every hit raises a fresh copy of it.  Raising the
+    cached object itself would append the raising frames to its
+    ``__traceback__`` on every hit, and those frames keep their callers
+    and all their locals (a block's tx work, rwsets, pending policy
+    evaluations) alive for as long as the cache entry lives.  None where
+    the type cannot be copied: such a failure is not cached."""
+    try:
+        return copy.copy(exc)
+    except Exception:
+        # an exotic error type only costs the cache entry: the caller
+        # still gets the error it raised
+        return None
 
 
 class _LRU:
@@ -75,13 +93,15 @@ class CachedMSP:
         if hit:
             stamp, outcome = res
             if time.monotonic() - stamp < _VALIDATE_TTL_S:
-                if isinstance(outcome, Exception):
-                    raise outcome
+                if outcome is not None:
+                    raise copy.copy(outcome)
                 return
         try:
             self._inner.validate(identity)
         except Exception as exc:
-            self._validate.put(key, (time.monotonic(), exc))
+            failure = _template(exc)
+            if failure is not None:
+                self._validate.put(key, (time.monotonic(), failure))
             raise
         self._validate.put(key, (time.monotonic(), None))
 
@@ -89,13 +109,15 @@ class CachedMSP:
         key = (identity.serialize(), principal.SerializeToString())
         res, hit = self._principal.get(key)
         if hit:
-            if isinstance(res, Exception):
-                raise res
+            if res is not None:
+                raise copy.copy(res)
             return
         try:
             self._inner.satisfies_principal(identity, principal)
         except Exception as exc:
-            self._principal.put(key, exc)
+            failure = _template(exc)
+            if failure is not None:
+                self._principal.put(key, failure)
             raise
         self._principal.put(key, None)
 

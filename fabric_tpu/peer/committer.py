@@ -19,7 +19,7 @@ import queue
 import threading
 import time
 
-from fabric_tpu.common import tracing
+from fabric_tpu.common import gcpolicy, tracing
 from fabric_tpu.devtools.lockwatch import spawn_thread
 
 
@@ -30,6 +30,10 @@ class Committer:
         self._listeners: list = []
         self._lock = threading.Lock()
         self.metrics = metrics
+        # whoever builds a committer is about to validate and commit
+        # blocks, and has imported what that takes: the process is warm
+        # (idempotent, process-wide; see common/gcpolicy.py)
+        gcpolicy.settle()
 
     def add_commit_listener(self, fn) -> None:
         self._listeners.append(fn)
@@ -65,6 +69,7 @@ class Committer:
         flags = list(protoutil.tx_filter(block))
         for fn in self._listeners:
             fn(block, flags)
+        gcpolicy.pipeline_empty()  # a lone block, committed
         return flags
 
     def store_stream(self, blocks, depth: int = 3):
@@ -206,6 +211,7 @@ class Committer:
         finally:
             commit_q.put(None)
             th.join()
+            gcpolicy.pipeline_empty()  # the stream's last flags are out
 
     @property
     def height(self) -> int:

@@ -497,7 +497,11 @@ def test_arm_records_gc_pauses_and_scope_records_none():
     finally:
         tracing.disarm()
     assert tracing._on_gc not in gc.callbacks
-    (pause,) = _by_name(doc, "gc.pause")
+    # a younger collection of the interpreter's own may have fallen in
+    # the armed stretch too (generation 1 gets a span since PR 26)
+    (pause,) = [
+        e for e in _by_name(doc, "gc.pause") if e["args"]["generation"] == 2
+    ]
     assert pause["cat"] == "stage" and pause["ph"] == "X"
     assert pause["tid"] == "MainThread" and pause["dur"] >= 0
     assert pause["args"]["generation"] == 2
@@ -506,6 +510,60 @@ def test_arm_records_gc_pauses_and_scope_records_none():
     assert work["ts"] <= pause["ts"] <= work["ts"] + work["dur"]
     assert work["args"]["span"] == "1"       # the pause took no id
     assert [s[0] for s in tracing.span_sequence(doc)] == ["work"]
+
+
+def test_a_generation_1_collection_gets_a_span_and_a_short_generation_0_none():
+    """The rule since PR 26: every collection of generation 1 or older,
+    and a generation-0 one of at least GC_SPAN_MIN_S."""
+    import gc
+
+    tracing.arm(256)
+    try:
+        gc.collect(1)
+        gc.collect(0)        # nothing to walk: far under a millisecond
+        gc.collect(0)
+        doc = tracing.export()
+    finally:
+        tracing.disarm()
+    by_gen = [e["args"]["generation"] for e in _by_name(doc, "gc.pause")]
+    assert by_gen.count(1) >= 1
+    assert [e for e in _by_name(doc, "gc.pause")
+            if e["args"]["generation"] == 0
+            and e["dur"] < tracing.GC_SPAN_MIN_S * 1e6] == []
+    # a generation-0 pause of a millisecond and more is kept
+    tracing.arm(256)
+    try:
+        tracing._on_gc("start", {"generation": 0})
+        tracing._gc_t0 -= 2 * tracing.GC_SPAN_MIN_S
+        tracing._on_gc("stop", {"generation": 0, "collected": 7, "uncollectable": 0})
+        (slow,) = [e for e in _by_name(tracing.export(), "gc.pause")
+                   if e["args"]["collected"] == 7]
+        assert slow["args"]["generation"] == 0 and slow["dur"] >= 2000
+    finally:
+        tracing.disarm()
+
+
+def test_no_pause_is_dropped_before_export():
+    """The callback cannot take the recorder's lock, so pauses wait in
+    a queue that export() drains; the harness exports once, at the end
+    of its window.  The queue is sized to the recorder: ten thousand
+    generation-1 pauses and then a generation-2 one lose none."""
+    n = 10_000
+    rec = tracing.arm(1 << 14)
+    try:
+        for i in range(n):
+            tracing._on_gc("start", {"generation": 1})
+            tracing._on_gc("stop", {"generation": 1, "collected": i, "uncollectable": 0})
+        tracing._on_gc("start", {"generation": 2})
+        tracing._on_gc("stop", {"generation": 2, "collected": 0, "uncollectable": 0})
+        assert len(rec.snapshot()) == 0      # nothing reached the ring yet
+        pauses = _by_name(tracing.export(), "gc.pause")
+    finally:
+        tracing.disarm()
+    # (a collection of the interpreter's own may have joined them)
+    ours = [e for e in pauses if e["args"]["generation"] == 1]
+    assert {e["args"]["collected"] for e in ours} >= set(range(n))
+    assert [e["args"]["generation"] for e in pauses].count(2) >= 1
 
 
 def test_process_metrics_and_tracelens_share_one_gc_callback():
