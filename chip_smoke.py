@@ -15,8 +15,10 @@ one process owns the chip:
   endorsement signatures, so a kernel that answered all-true would
   fail.  Host race off: every lane must be sealed by the device.  Then
   a pass at the provider's defaults (the split is printed, not judged),
-  the second kernel (an Idemix batch of 128 with one tampered), and the
-  parked-waiter observation.
+  the second kernel (an Idemix batch of 128 with one tampered, then a
+  block of 128 anonymous Idemix creators through `TxValidator` +
+  `Committer.store_block`: flags as planted, no fallback counted), and
+  the parked-waiter observation.
 * Leg B, real daemons: `python -m fabric_tpu.cmd.orderer` (host only)
   and `python -m fabric_tpu.cmd.peer node start` with
   `CORE_BCCSP_DEFAULT=TPU`, mutual TLS, blocks cut at the orderer
@@ -68,6 +70,8 @@ REQUIRED_PLATFORM = "tpu"
 N_ORGS, ENDORSERS, BLOCK_TXS, N_BLOCKS = 5, 3, 1000, 4
 TAMPERED_PER_BLOCK = 3  # creator signatures, and as many endorsements
 IDEMIX_SIGS = 128  # above IdemixCSP.DEVICE_CROSSOVER: the 256 bucket
+# the benchmark's Idemix deployment: its world builds the smoke's block
+IDEMIX_CONFIG = "idemix-nym128"
 # Leg B: three full blocks at the orderer's default MaxMessageCount.
 DAEMON_TXS = 1500
 QUERY_SAMPLE = 8
@@ -462,6 +466,49 @@ def leg_a_child(seed: int) -> int:
           "the Idemix batch never reached the Pallas kernel")
     say(f"[A] idemix: {IDEMIX_SIGS} signatures, 1 tampered, device mask == "
         "host mask, _PALLAS_FAILURES empty")
+
+    # -- the path a peer takes: a block of anonymous creators through
+    # TxValidator + Committer.store_block, its credential proofs and
+    # pseudonym signatures as one launch of the BN254 kernel --------
+    def idemix_block():
+        sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+        from benchlib.manifest import Manifest
+
+        man = Manifest(ROOT)
+        held = man.config({"name": "smoke", "config": IDEMIX_CONFIG})
+        return held, man.world(held)(
+            seed, held["deployment"], held["planted"], 1
+        )
+
+    held, iworld = phases.run("idemix_block_build", idemix_block)
+    from fabric_tpu.common.channelconfig import bundle_from_genesis
+
+    ibundle = bundle_from_genesis(iworld.genesis, csp)
+    iprovider = LedgerProvider(os.path.join(tmp.name, "idemix"))
+    providers.append(iprovider)
+    iledger = iprovider.create(iworld.genesis)
+    before = csp.idemix.tally()
+    committer = Committer(
+        TxValidator(iworld.channel, iledger, ibundle, csp), iledger
+    )
+    blk = common_pb2.Block.FromString(iworld.blocks[0])
+    iflags = phases.run("idemix_block_store", committer.store_block, blk)
+    check([int(f) for f in iflags] == [int(f) for f in iworld.planted[0]],
+          "idemix block: flags differ from the planted ones")
+    after = csp.idemix.tally()
+    reached = len(iflags) - iworld.refused_at_deserialise[0]
+    moved = {
+        k: after["items"].get(k, 0) - before["items"].get(k, 0)
+        for k in after["items"]
+    }
+    check(after["fallbacks"] == before["fallbacks"] == {},
+          f"idemix block: a fallback was counted: {after['fallbacks']}")
+    check(moved == {"proof.pallas": reached, "nym.pallas": reached},
+          f"idemix block: items did not all run on the kernel: {moved}")
+    say(f"[A] idemix block: {len(iflags)} anonymous creators through "
+        f"TxValidator + store_block, {reached} proofs + {reached} "
+        f"pseudonym signatures on the kernel (buckets "
+        f"{sorted(after['batches'])}), flags as planted, no fallback")
 
     # -- leave through normal interpreter shutdown ----------------------
     csp.close()

@@ -7,6 +7,10 @@ Supported schema (subset):
       - Name: Org1
         ID: Org1MSP
         MSPDir: crypto-config/peerOrganizations/org1.example.com/msp
+      - name: idemixMSP1               # anonymous clients (idemix.rst)
+        id: idemixMSPID1
+        msptype: idemix
+        mspdir: idemix-config          # idemixgen ca-keygen's output
     Profiles:
       TwoOrgsApplicationGenesis:
         Orderer:
@@ -36,22 +40,47 @@ from fabric_tpu.msp.config import load_msp_dir
 from fabric_tpu.protos.common import common_pb2
 
 
+def _key(org: dict, key: str, default=None):
+    """An organisation's key, whatever its case (upstream reads
+    configtx.yaml through viper: `idemix.rst` writes `msptype`)."""
+    for k, v in org.items():
+        if k.lower() == key.lower():
+            return v
+    return default
+
+
 def _org_groups(org_names, org_index, config_dir):
     out = {}
     for name in org_names or []:
-        org = org_index[name]
+        org = {
+            canon: _key(org_index[name], canon)
+            for canon in ("Name", "ID", "MSPDir", "MSPType")
+        }
         msp_dir = org["MSPDir"]
         if not os.path.isabs(msp_dir):
             msp_dir = os.path.join(config_dir, msp_dir)
-        conf = load_msp_dir(msp_dir, org["ID"])
-        from fabric_tpu.protos.msp import msp_config_pb2
+        if str(_key(org, "MSPType", "")).lower() == "idemix":
+            # an org of anonymous clients (docs/source/idemix.rst):
+            # MSPDir is idemixgen's output, the issuer's public key
+            from fabric_tpu.msp.idemixmsp import load_idemix_msp_dir
 
-        fconf = msp_config_pb2.FabricMSPConfig.FromString(conf.config)
-        if not fconf.root_certs:
-            raise SystemExit(
-                f"MSPDir {msp_dir!r} for org {org['Name']!r} has no CA "
-                "certs (run cryptogen first?)"
-            )
+            try:
+                conf = load_idemix_msp_dir(msp_dir, org["ID"])
+            except OSError as e:
+                raise SystemExit(
+                    f"MSPDir {msp_dir!r} for org {org['Name']!r} has no "
+                    f"IssuerPublicKey (run idemixgen ca-keygen first?): {e}"
+                ) from e
+        else:
+            conf = load_msp_dir(msp_dir, org["ID"])
+            from fabric_tpu.protos.msp import msp_config_pb2
+
+            fconf = msp_config_pb2.FabricMSPConfig.FromString(conf.config)
+            if not fconf.root_certs:
+                raise SystemExit(
+                    f"MSPDir {msp_dir!r} for org {org['Name']!r} has no CA "
+                    "certs (run cryptogen first?)"
+                )
         out[org["Name"]] = ctx.org_group(org["ID"], conf)
     return out
 
@@ -59,7 +88,7 @@ def _org_groups(org_names, org_index, config_dir):
 def build_genesis(doc: dict, profile_name: str, channel_id: str,
                   config_dir: str) -> common_pb2.Block:
     profile = (doc.get("Profiles") or {})[profile_name]
-    org_index = {o["Name"]: o for o in doc.get("Organizations") or []}
+    org_index = {_key(o, "Name"): o for o in doc.get("Organizations") or []}
 
     app = None
     if profile.get("Application"):

@@ -13,7 +13,11 @@ import os
 import pickle
 import sys
 
-from fabric_tpu.msp.idemixmsp import generate_issuer, issue_signer_config
+from fabric_tpu.msp.idemixmsp import (
+    generate_issuer,
+    issue_signer_config,
+    write_issuer_public_key,
+)
 
 
 def main(argv=None) -> int:
@@ -34,7 +38,10 @@ def main(argv=None) -> int:
         issuer = generate_issuer()
         with open(os.path.join(ca_dir, "IssuerKey.pkl"), "wb") as f:
             pickle.dump(issuer, f)
-        print(f"issuer key material written to {ca_dir}")
+        # what a verifier needs: configtxgen reads it for an
+        # organisation with `msptype: idemix`
+        pub = write_issuer_public_key(issuer, args.output)
+        print(f"issuer key material written to {ca_dir}; public key {pub}")
         return 0
 
     from fabric_tpu.msp.idemixmsp import ROLE_ADMIN, ROLE_MEMBER

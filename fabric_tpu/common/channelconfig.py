@@ -10,7 +10,7 @@ from __future__ import annotations
 import dataclasses
 
 from fabric_tpu.common import configtx_builder as keys
-from fabric_tpu.msp import MSP, MSPManager
+from fabric_tpu.msp import MSP, MSPManager, msp_config_name, msp_from_config
 from fabric_tpu.policies import Manager, manager_from_config_group
 from fabric_tpu.protos.common import common_pb2, configtx_pb2
 from fabric_tpu.protos.msp import msp_config_pb2
@@ -99,7 +99,7 @@ class Bundle:
     def _collect_msps(group: configtx_pb2.ConfigGroup, out: list[MSP], csp) -> None:
         if keys.MSP_KEY in group.values:
             conf = msp_config_pb2.MSPConfig.FromString(group.values[keys.MSP_KEY].value)
-            out.append(MSP.from_config(conf, csp))
+            out.append(msp_from_config(conf, csp))
         for sub in group.groups.values():
             Bundle._collect_msps(sub, out, csp)
 
@@ -119,8 +119,7 @@ class Bundle:
         for sub in og.groups.values():
             if keys.MSP_KEY in sub.values:
                 conf = msp_config_pb2.MSPConfig.FromString(sub.values[keys.MSP_KEY].value)
-                fconf = msp_config_pb2.FabricMSPConfig.FromString(conf.config)
-                mspids.append(fconf.name)
+                mspids.append(msp_config_name(conf))
         return OrdererConfig(
             consensus_type=ct.type,
             consensus_metadata=ct.metadata,
@@ -141,7 +140,7 @@ class Bundle:
             mspid = name
             if keys.MSP_KEY in sub.values:
                 conf = msp_config_pb2.MSPConfig.FromString(sub.values[keys.MSP_KEY].value)
-                mspid = msp_config_pb2.FabricMSPConfig.FromString(conf.config).name
+                mspid = msp_config_name(conf)
             orgs[name] = ApplicationOrg(name=name, mspid=mspid)
         return ApplicationConfig(orgs=orgs)
 

@@ -26,6 +26,8 @@ CONSORTIUM_KEY = "Consortium"
 ENDORSEMENT_POLICY_KEY = "Endorsement"
 ACLS_KEY = "ACLs"
 
+IDEMIX_MSP_TYPE = 1  # MSPConfig.type of an Idemix organisation
+
 
 def _implicit_meta(group: configtx_pb2.ConfigGroup, name: str, rule, sub_policy: str | None = None):
     group.policies[name].policy.type = policies_pb2.Policy.IMPLICIT_META
@@ -55,7 +57,12 @@ def org_group(mspid: str, msp_conf: msp_config_pb2.MSPConfig, anchor=None) -> co
     _signature_policy(g, "Readers", f"'{mspid}.member'")
     _signature_policy(g, "Writers", f"'{mspid}.member'")
     _signature_policy(g, "Admins", f"'{mspid}.admin'")
-    _signature_policy(g, ENDORSEMENT_POLICY_KEY, f"'{mspid}.peer'")
+    if msp_conf.type != IDEMIX_MSP_TYPE:
+        # Idemix is for clients only (docs/source/idemix.rst: peers and
+        # orderers stay X.509): such an org has no peer to endorse, and
+        # its group carries no Endorsement policy, so the application's
+        # MAJORITY Endorsement counts the X.509 orgs alone
+        _signature_policy(g, ENDORSEMENT_POLICY_KEY, f"'{mspid}.peer'")
     return g
 
 

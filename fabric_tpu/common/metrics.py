@@ -530,6 +530,33 @@ class CSPMetrics:
                  "labeled by event; growth under load is a recompile.",
             statsd_format="%{event}",
         ))
+        self.idemix_items = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="idemix",
+            name="items_total",
+            help="Idemix items verified, labeled by kind (proof: a "
+                 "credential proof; nym: a pseudonym signature) and by "
+                 "the path that computed their commitments: pallas "
+                 "(the BN254 kernel), xla (the scan fallback) or host.",
+            statsd_format="%{kind}.%{path}",
+        ))
+        self.idemix_fallbacks = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="idemix",
+            name="fallbacks_total",
+            help="Idemix batches verified elsewhere than the Pallas "
+                 "BN254 kernel, labeled by reason: below_crossover, "
+                 "no_tpu, forced_host, device_error, pallas_to_xla.",
+            statsd_format="%{reason}",
+        ))
+        self.idemix_batches = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="idemix",
+            name="batches_total",
+            help="Idemix batches launched on the device, labeled by "
+                 "the bucket (padded lanes) each ran at.",
+            statsd_format="%{bucket}",
+        ))
         self.breaker_state.set(0)
 
 

@@ -27,6 +27,7 @@ are Jacobian, normalized on host with one batched inversion.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
 
@@ -269,83 +270,178 @@ def _jit_kernel():
     return jax.jit(commitments_kernel)
 
 
-def schnorr_commitments_batch(sigs, ipk) -> list | None:
-    """Device-batched T1/T2/T3 for every signature; returns per-sig
-    [(T1, T2, T3)] as affine int tuples (None = infinity), or None for
-    lanes whose inputs are malformed (caller marks them failed).
-
-    Mirrors signature._relations + schnorr.recompute_commitments; parity
-    is enforced by tests/test_bn254_device.py against the host path.
-    """
-    n = len(sigs)
-    if n == 0:
-        return []
-    if n > _MAX_LANES:
-        # chunk at the largest bucket: bounds pad waste to the tail and
-        # reuses the already-compiled shapes
-        out: list = []
-        for off in range(0, n, _MAX_LANES):
-            out.extend(
-                schnorr_commitments_batch(sigs[off:off + _MAX_LANES], ipk)
-            )
-        return out
-    n_attrs = len(ipk.h_attrs)
-    shared_pts = (bn.G1_GEN, ipk.h_sk, ipk.h_rand, *ipk.h_attrs)
-    n_shared = len(shared_pts)
-    # unified term layout: (table index, accumulator).  Shared tables
-    # occupy indices 0..n_shared-1 of the kernel's table stack, the 4
-    # per-lane bases (_LANE_BASES order) follow at n_shared+0..3.
-    #   T1: h_rand^z_r2, a_bar^{-c}, b_prime^{c}, a_prime^{z_neg_e}
-    #   T2: G1^c, h_sk^z_sk, h_rand^z_s', h_attrs[i]^{s_i}, b'^{z_neg_r3}
-    #   T3: h_sk^z_sk, h_rand^z_r_nym, nym^{-c}
+def _term_layout(n_attrs: int, n_shared: int) -> tuple:
+    """(table index, accumulator) of every term.  Shared tables occupy
+    indices 0..n_shared-1 of the kernel's table stack, the 4 per-lane
+    bases (LANE_BASES order) follow at n_shared+0..3.
+      T1: h_rand^z_r2, a_bar^{-c}, b_prime^{c}, a_prime^{z_neg_e}
+      T2: G1^c, h_sk^z_sk, h_rand^z_s', h_attrs[i]^{s_i}, b'^{z_neg_r3}
+      T3: h_sk^z_sk, h_rand^z_r_nym, nym^{-c}"""
     term_table = (
         2, n_shared + 1, n_shared + 2, n_shared + 0,
         0, 1, 2, *range(3, 3 + n_attrs), n_shared + 2,
         1, 2, n_shared + 3,
     )
     term_acc = (0, 0, 0, 0, 1, 1, 1, *([1] * n_attrs), 1, 2, 2, 2)
+    return term_table, term_acc
 
+
+# (engine, bucket, n_attrs) shapes this process has enqueued: the first
+# enqueue of a shape traces, lowers and compiles (or loads) inside it
+_enqueued: set = set()
+
+
+@dataclasses.dataclass
+class Prepared:
+    """One batch of at most _MAX_LANES lanes, ready for either engine.
+    A lane is a credential proof (T1, T2, T3 all meaningful) or a
+    pseudonym signature, which rides as a lane of its own: bases
+    a', a_bar, b' at infinity, every T1/T2 scalar zero, so only
+    T3 = h_sk^z_sk * h_rand^z_rnym * nym^-c is computed (the kernel
+    needs no change and a block's proofs and pseudonym signatures
+    share one launch: 2 lanes a transaction)."""
+
+    pts: list
+    scalars: list
+    ok: list
+    shared_pts: tuple
+    n_attrs: int
+    bucket: int                # the XLA engine's pad size; the failure budget's key
+    packed: object = None      # the Pallas engine's host arrays
+
+    @property
+    def lanes(self) -> int:
+        return len(self.ok)
+
+
+@dataclasses.dataclass
+class Launched:
+    prepared: Prepared
+    path: str                  # "pallas" | "xla": the engine that ran
+    bucket: int                # lanes the kernel ran at, padding included
+    cold: bool                 # first enqueue of this shape
+    fallback: str | None       # why the preferred engine did not run
+    _reader: object = None     # the engine's: block() then unpack(raw)
+    _raw: object = None
+
+    def wait(self) -> None:
+        """Block on the device and copy the result back.  A Pallas
+        launch that fails only here (a runtime error surfaces at the
+        copy) is rerun on the XLA engine."""
+        try:
+            self._raw = self._reader.block()
+        except Exception as exc:
+            if self.path != "pallas":
+                raise
+            _note_pallas_failure(self.prepared, exc)
+            self.path, self.fallback = "xla", "pallas_to_xla"
+            self.bucket = self.prepared.bucket
+            self._reader = _commitments_xla(self.prepared)
+            self._raw = self._reader.block()
+
+    def jacobians(self) -> list:
+        """Per lane [(x, y, z, inf)] * 3 Jacobian ints, after wait()."""
+        return self._reader.unpack(self._raw)
+
+
+def _bucket_of(n: int) -> int:
+    return next((b for b in _BUCKETS if n <= b), _MAX_LANES)
+
+
+def prepare(sigs, nyms, ipk) -> Prepared:
+    """Host half before the launch: validity, scalars and lane bases of
+    `sigs` (presentation Signatures) followed by `nyms` ((NymSignature,
+    nym point) pairs), and the preferred engine's limb packing.  At
+    most _MAX_LANES lanes; the caller chunks."""
+    n_attrs = len(ipk.h_attrs)
+    shared_pts = (bn.G1_GEN, ipk.h_sk, ipk.h_rand, *ipk.h_attrs)
     pts_l, scalars_l, ok = _prepare_sigs(sigs, ipk, n_attrs)
-
-    # preferred engine: the fused Pallas ladder (VMEM-resident Montgomery
-    # field ops, pallas_bn254.py); the XLA scan kernel is the fallback
-    # when Mosaic is unavailable or fails
-    jac = None
+    p2, s2, ok2 = _prepare_nyms(nyms, n_attrs)
+    pts_l += p2
+    scalars_l += s2
+    ok += ok2
+    if len(ok) > _MAX_LANES:
+        raise ValueError(f"{len(ok)} lanes in one launch (max {_MAX_LANES})")
     # budget key = the COMPILE bucket, not the raw batch length: every
     # length padding to the same bucket shares one compiled kernel, so
     # a deterministic failure is retried per compile unit, not per
     # distinct batch size
-    bucket = next((b for b in _BUCKETS if len(ok) <= b), _MAX_LANES)
-    shape = (bucket, n_attrs)
-    if _pallas_preferred(shape):
+    prep = Prepared(pts_l, scalars_l, ok, shared_pts, n_attrs,
+                    _bucket_of(len(ok)))
+    if _pallas_preferred((prep.bucket, n_attrs)):
         try:
             from fabric_tpu.csp.tpu import pallas_bn254
 
-            jac = pallas_bn254.commitments(
+            term_table, term_acc = _term_layout(n_attrs, len(shared_pts))
+            prep.packed = pallas_bn254.pack(
                 pts_l, scalars_l, ok, term_table, term_acc, shared_pts
             )
-            _PALLAS_FAILURES.pop(shape, None)  # success resets the budget
         except Exception as exc:
-            from fabric_tpu.common.flogging import must_get_logger
+            _note_pallas_failure(prep, exc)
+    return prep
 
-            _PALLAS_FAILURES[shape] = _PALLAS_FAILURES.get(shape, 0) + 1
-            must_get_logger("bn254").warning(
-                "pallas BN254 ladder failed for shape %s (%s: %s), "
-                "failure %d/%d; using the XLA path for this batch",
-                shape, type(exc).__name__, exc,
-                _PALLAS_FAILURES[shape], _PALLAS_MAX_FAILURES,
+
+def _note_pallas_failure(prep: Prepared, exc: Exception) -> None:
+    from fabric_tpu.common.flogging import must_get_logger
+
+    shape = (prep.bucket, prep.n_attrs)
+    _PALLAS_FAILURES[shape] = _PALLAS_FAILURES.get(shape, 0) + 1
+    prep.packed = None
+    must_get_logger("bn254").warning(
+        "pallas BN254 ladder failed for shape %s (%s: %s), "
+        "failure %d/%d; using the XLA path for this batch",
+        shape, type(exc).__name__, exc,
+        _PALLAS_FAILURES[shape], _PALLAS_MAX_FAILURES,
+    )
+
+
+def enqueue(prep: Prepared) -> Launched:
+    """Launch the batch on the preferred engine (the fused Pallas
+    ladder where it runs compiled, pallas_bn254.py; else the XLA scan
+    kernel) and return without waiting for the device."""
+    shape = (prep.bucket, prep.n_attrs)
+    if prep.packed is not None:
+        try:
+            from fabric_tpu.csp.tpu import pallas_bn254
+
+            key = ("pallas", prep.packed.lanes, prep.n_attrs)
+            cold = key not in _enqueued
+            reader = pallas_bn254.enqueue(prep.packed)
+            _enqueued.add(key)
+            _PALLAS_FAILURES.pop(shape, None)  # success resets the budget
+            # the kernel runs whole 128-lane blocks, a power of two of them
+            return Launched(
+                prep, "pallas", prep.packed.lanes, cold, None, reader
             )
-            jac = None
-    if jac is None:
-        jac = _commitments_xla(
-            pts_l, scalars_l, ok, term_table, term_acc, shared_pts
-        )
+        except Exception as exc:
+            _note_pallas_failure(prep, exc)
+    # where the Pallas ladder is the path (a TPU, or a test forcing
+    # it), whatever kept it from running (a failure now, the failure
+    # budget spent, FABRIC_BN254_NO_PALLAS) is a fallback to the scan
+    fallback = (
+        "pallas_to_xla"
+        if jax.default_backend() == "tpu"
+        or os.environ.get("FABRIC_BN254_FORCE_PALLAS")
+        else None
+    )
+    cold = ("xla", prep.bucket, prep.n_attrs) not in _enqueued
+    reader = _commitments_xla(prep)
+    _enqueued.add(("xla", prep.bucket, prep.n_attrs))
+    return Launched(prep, "xla", prep.bucket, cold, fallback, reader)
 
-    # Jacobian -> affine with ONE batched modular inversion (host ints)
+
+def normalize(launched: Launched) -> list:
+    """The copy's limbs to integers, then Jacobian -> affine with ONE
+    batched modular inversion (host ints): per lane (T1, T2, T3) as
+    affine int tuples (None = infinity), or None for lanes whose inputs
+    were malformed.  After `launched.wait()`."""
+    prep = launched.prepared
+    jac = launched.jacobians()
+    n = prep.lanes
     zs, metas = [], []
     results: list = [None] * n
     for j in range(n):
-        if not ok[j]:
+        if not prep.ok[j]:
             continue
         tri = jac[j]
         metas.append((j, tri))
@@ -366,6 +462,64 @@ def schnorr_commitments_batch(sigs, ipk) -> list | None:
                 k += 1
             results[j] = tuple(pts)
     return results
+
+
+def schnorr_commitments_batch(sigs, ipk) -> list | None:
+    """Device-batched T1/T2/T3 for every signature; returns per-sig
+    [(T1, T2, T3)] as affine int tuples (None = infinity), or None for
+    lanes whose inputs are malformed (caller marks them failed).
+
+    Mirrors signature._relations + schnorr.recompute_commitments; parity
+    is enforced by tests/test_bn254_device.py against the host path.
+    """
+    out: list = []
+    # chunk at the largest bucket: bounds pad waste to the tail and
+    # reuses the already-compiled shapes
+    for off in range(0, len(sigs), _MAX_LANES):
+        out.extend(_run(prepare(sigs[off:off + _MAX_LANES], [], ipk)))
+    return out
+
+
+def nym_commitments_batch(nyms, ipk) -> list:
+    """Device-batched pseudonym-signature commitments
+    h_sk^z_sk * h_rand^z_rnym * nym^-c for (NymSignature, nym) pairs:
+    per pair the affine point (None = infinity), or False for a
+    malformed lane."""
+    out: list = []
+    for off in range(0, len(nyms), _MAX_LANES):
+        got = _run(prepare([], nyms[off:off + _MAX_LANES], ipk))
+        out.extend(False if tri is None else tri[2] for tri in got)
+    return out
+
+
+def _run(prep: Prepared) -> list:
+    launched = enqueue(prep)
+    launched.wait()
+    return normalize(launched)
+
+
+def _prepare_nyms(nyms, n_attrs):
+    """Lanes of pseudonym signatures: only the nym base and the three
+    T3 scalars are set."""
+    pts_l: list = []
+    scalars_l: list = []
+    ok = [True] * len(nyms)
+    for j, (sig, nym) in enumerate(nyms):
+        try:
+            if nym is None or not bn.g1_is_on_curve(nym):
+                raise ValueError("bad point")
+            c = sig.challenge % bn.R
+            scalars = [0] * (8 + n_attrs) + [
+                sig.z_sk % bn.R, sig.z_rnym % bn.R, (-c) % bn.R,
+            ]
+            pts_l.append((None, None, None, nym))
+            scalars_l.append(scalars)
+        except (ValueError, IndexError, KeyError, TypeError,
+                OverflowError, AttributeError):
+            ok[j] = False
+            pts_l.append((None,) * 4)
+            scalars_l.append(None)
+    return pts_l, scalars_l, ok
 
 
 def _prepare_sigs(sigs, ipk, n_attrs):
@@ -425,52 +579,38 @@ def _prepare_sigs(sigs, ipk, n_attrs):
     return pts_l, scalars_l, ok
 
 
-def _commitments_xla(pts_l, scalars_l, ok, term_table, term_acc,
-                     shared_pts):
-    """The XLA scan-kernel engine: returns per-sig [(x, y, z, inf)] * 3
-    Jacobian ints in plain (non-Montgomery) form."""
+def _commitments_xla(prep: Prepared):
+    """The XLA scan-kernel engine: launches, and returns the reader
+    whose `block()` waits and whose `unpack()` gives per-lane
+    [(x, y, z, inf)] * 3 Jacobian ints in plain (non-Montgomery) form."""
+    pts_l, scalars_l, ok = prep.pts, prep.scalars, prep.ok
+    term_table, term_acc = _term_layout(prep.n_attrs, len(prep.shared_pts))
     n = len(pts_l)
     n_terms = len(term_table)
-    tabs = shared_tables(tuple(shared_pts))
-
-    lane_x = np.zeros((4, n, WIDE), np.uint32)
-    lane_y = np.zeros((4, n, WIDE), np.uint32)
-    lane_inf = np.zeros((4, n), bool)
-    digits = np.zeros((n_terms, n, NWINDOWS), np.int32)
-    for j in range(n):
-        if not ok[j]:
-            lane_inf[:, j] = True
-            continue
-        for i, p in enumerate(pts_l[j]):
-            lane_x[i, j] = _to_limbs(p[0])
-            lane_y[i, j] = _to_limbs(p[1])
-        for t, u in enumerate(scalars_l[j]):
-            digits[t, j] = _recode(u)
+    tabs = shared_tables(tuple(prep.shared_pts))
 
     # pad lanes to a bucket size so each (bucket, n_attrs) pair compiles
     # once; padded lanes carry zero scalars (every digit selects the
-    # infinity table entry) and are sliced away below
-    bsz = _BUCKETS[0]
-    for b in _BUCKETS:
-        if n <= b:
-            bsz = b
-            break
-    if bsz != n:
-        pad = bsz - n
-        lane_x = np.concatenate(
-            [lane_x, np.zeros((4, pad, WIDE), np.uint32)], axis=1
-        )
-        lane_y = np.concatenate(
-            [lane_y, np.zeros((4, pad, WIDE), np.uint32)], axis=1
-        )
-        lane_inf = np.concatenate(
-            [lane_inf, np.ones((4, pad), bool)], axis=1
-        )
-        digits = np.concatenate(
-            [digits, np.zeros((n_terms, pad, NWINDOWS), np.int32)], axis=1
-        )
+    # infinity table entry) and infinity bases
+    bsz = prep.bucket
+    lane_x = np.zeros((4, bsz, WIDE), np.uint32)
+    lane_y = np.zeros((4, bsz, WIDE), np.uint32)
+    lane_inf = np.ones((4, bsz), bool)
+    digits = np.zeros((n_terms, bsz, NWINDOWS), np.int32)
+    for j in range(n):
+        if not ok[j]:
+            continue
+        for i, p in enumerate(pts_l[j]):
+            if p is None:
+                continue
+            lane_x[i, j] = _to_limbs(p[0])
+            lane_y[i, j] = _to_limbs(p[1])
+            lane_inf[i, j] = False
+        for t, u in enumerate(scalars_l[j]):
+            if u:
+                digits[t, j] = _recode(u)
     kern = _jit_kernel()
-    ax, ay, az, ainf = kern(
+    outs = kern(
         jnp.asarray(lane_x), jnp.asarray(lane_y), jnp.asarray(lane_inf),
         jnp.asarray(tabs["x"]), jnp.asarray(tabs["y"]),
         jnp.asarray(tabs["inf"]),
@@ -478,22 +618,34 @@ def _commitments_xla(pts_l, scalars_l, ok, term_table, term_acc,
         jnp.asarray(term_table, jnp.int32),
         jnp.asarray(term_acc, jnp.int32),
     )
-    ax, ay, az, ainf = (np.asarray(o) for o in (ax, ay, az, ainf))
-    fp = _fp()
-    jac = []
-    for j in range(n):
-        if not ok[j]:
-            jac.append(None)
-            continue
-        tri = []
-        for t in range(3):
-            x = fp.from_mont_int(limbs.limbs_to_int(ax[t, j]))
-            y = fp.from_mont_int(limbs.limbs_to_int(ay[t, j]))
-            zv = fp.from_mont_int(limbs.limbs_to_int(az[t, j]))
-            inf = bool(ainf[t, j])
-            tri.append((x, y, zv, inf))
-        jac.append(tri)
-    return jac
+
+    return _XlaReader(outs, n, ok)
+
+
+class _XlaReader:
+    def __init__(self, outs, n: int, ok: list):
+        self._outs, self._n, self._ok = outs, n, ok
+
+    def block(self):
+        return tuple(np.asarray(o) for o in self._outs)
+
+    def unpack(self, raw) -> list:
+        ax, ay, az, ainf = raw
+        fp = _fp()
+        jac = []
+        for j in range(self._n):
+            if not self._ok[j]:
+                jac.append(None)
+                continue
+            tri = []
+            for t in range(3):
+                x = fp.from_mont_int(limbs.limbs_to_int(ax[t, j]))
+                y = fp.from_mont_int(limbs.limbs_to_int(ay[t, j]))
+                zv = fp.from_mont_int(limbs.limbs_to_int(az[t, j]))
+                inf = bool(ainf[t, j])
+                tri.append((x, y, zv, inf))
+            jac.append(tri)
+        return jac
 
 
 def _batch_inverse(vals: list[int], m: int) -> list[int]:
@@ -509,4 +661,7 @@ def _batch_inverse(vals: list[int], m: int) -> list[int]:
     return out
 
 
-__all__ = ["schnorr_commitments_batch", "shared_tables"]
+__all__ = [
+    "schnorr_commitments_batch", "nym_commitments_batch", "shared_tables",
+    "prepare", "enqueue", "normalize",
+]

@@ -43,6 +43,7 @@ multiplications of idemix Ver (/root/reference/idemix/signature.go:243,
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import numpy as np
@@ -660,14 +661,27 @@ def _shared_limbs(ipk_key: tuple) -> tuple:
     )
 
 
-def commitments(lane_pts, scalars, ok, term_table, term_acc, shared_pts,
-                blk: int = BLK, interpret: bool | None = None):
-    """Run the ladder for a prepared batch.
+@dataclasses.dataclass
+class Packed:
+    """A batch's host arrays in the kernel's layout."""
 
-    lane_pts: per-sig tuple of 4 affine int points (or None); scalars:
-    per-sig list of n_terms ints (None when not ok); ok: per-sig
-    validity (bad lanes run with zero scalars and infinity bases).
-    Returns per-sig [(x, y, z, inf)] * 3 Jacobian ints (plain form)."""
+    n: int          # lanes asked for
+    lanes: int      # lanes the kernel runs: whole blocks, a power of two of them
+    blk: int
+    n_terms: int
+    n_tables: int
+    interpret: bool
+    args: tuple
+
+
+def pack(lane_pts, scalars, ok, term_table, term_acc, shared_pts,
+         blk: int = BLK, interpret: bool | None = None) -> Packed:
+    """Host limb packing of a prepared batch.
+
+    lane_pts: per-lane tuple of 4 affine int points (None = infinity);
+    scalars: per-lane list of n_terms ints (None when not ok); ok:
+    per-lane validity (bad lanes run with zero scalars and infinity
+    bases)."""
     if interpret is None:
         interpret = _use_interpret()
     n = len(lane_pts)
@@ -717,28 +731,72 @@ def commitments(lane_pts, scalars, ok, term_table, term_acc, shared_pts,
     )  # (n_terms, 2)
     sxl, syl, szl, sinf = _shared_limbs(tuple(shared_pts))
     c = _consts()
-    call = _build_call(nb, blk, n_terms, n_tables, bool(interpret))
-    out = np.asarray(call(
-        lanes, laneinf, digits, termmeta, sxl, syl, szl, sinf,
-        c["m"], c["mp"], c["one"], c["sub_c"], c["r256"],
-    ))  # (nb, 10, 17, blk)
+    return Packed(
+        n, padded, blk, n_terms, n_tables, bool(interpret),
+        (lanes, laneinf, digits, termmeta, sxl, syl, szl, sinf,
+         c["m"], c["mp"], c["one"], c["sub_c"], c["r256"]),
+    )
 
+
+class _Reader:
+    """A launch's result: `block()` waits for the device and copies
+    back, `unpack()` turns the copy into Python integers."""
+
+    def __init__(self, dev, n: int, blk: int):
+        self._dev, self._n, self._blk = dev, n, blk
+
+    def block(self):
+        return np.asarray(self._dev)  # (nb, 10, 17, blk)
+
+    def unpack(self, out) -> list:
+        return _unpack(out, self._n, self._blk)
+
+
+def enqueue(packed: Packed) -> _Reader:
+    """Launch the ladder without waiting for it; the reader gives
+    per-lane [(x, y, z, inf)] * 3 Jacobian ints (plain form)."""
+    call = _build_call(
+        packed.lanes // packed.blk, packed.blk, packed.n_terms,
+        packed.n_tables, packed.interpret,
+    )
+    return _Reader(call(*packed.args), packed.n, packed.blk)
+
+
+def _unpack(out, n: int, blk: int) -> list:
+    """The kernel's canonical 16-bit limbs -> ints: the nine coordinates
+    of a lane side by side as little-endian bytes, one `from_bytes` a
+    coordinate (the outputs are canonical: no limb carries)."""
+    ctx = limbs.mont_ctx(bn.P)
+    step = 2 * WIDE
+    raw = np.ascontiguousarray(
+        out[:, :9].transpose(0, 3, 1, 2)
+    ).astype("<u2").tobytes()  # (nb, blk, 9, 17) uint16
+    infs = out[:, 9, :3, :]  # (nb, 3, blk)
     results = []
     for j in range(n):
         b_i, l_i = divmod(j, blk)
-        tri = []
-        for t in range(3):
-            x = ctx.from_mont_int(limbs.limbs_to_int(out[b_i, t, :, l_i]))
-            y = ctx.from_mont_int(
-                limbs.limbs_to_int(out[b_i, 3 + t, :, l_i])
+        at = j * 9 * step
+        vals = [
+            ctx.from_mont_int(
+                int.from_bytes(raw[at + c * step:at + (c + 1) * step], "little")
             )
-            z = ctx.from_mont_int(
-                limbs.limbs_to_int(out[b_i, 6 + t, :, l_i])
-            )
-            inf = bool(out[b_i, 9, t, l_i])
-            tri.append((x, y, z, inf))
-        results.append(tri)
+            for c in range(9)
+        ]
+        results.append([
+            (vals[t], vals[3 + t], vals[6 + t], bool(infs[b_i, t, l_i]))
+            for t in range(3)
+        ])
     return results
 
 
-__all__ = ["commitments", "FpBN254", "BLK"]
+def commitments(lane_pts, scalars, ok, term_table, term_acc, shared_pts,
+                blk: int = BLK, interpret: bool | None = None):
+    """Run the ladder for a prepared batch: pack, launch, read."""
+    reader = enqueue(pack(
+        lane_pts, scalars, ok, term_table, term_acc, shared_pts,
+        blk=blk, interpret=interpret,
+    ))
+    return reader.unpack(reader.block())
+
+
+__all__ = ["commitments", "pack", "enqueue", "Packed", "FpBN254", "BLK"]

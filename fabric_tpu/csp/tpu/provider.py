@@ -47,6 +47,8 @@ from fabric_tpu.csp.api import (
 # SWCSP for the default host oracle — a caller that supplies its own
 # `sw` object (the chaos/degraded-mode tests run one on minimal hosts)
 # can use the full device path without the `cryptography` package.
+from fabric_tpu.csp.idemix_provider import IdemixCSP
+
 try:
     from fabric_tpu.csp.sw import SWCSP
 except ModuleNotFoundError as _exc:  # pragma: no cover - minimal hosts
@@ -768,6 +770,11 @@ class TPUCSP(CSP):
         self._metrics = metrics
         self._tally_lock = threading.Lock()
         self._lane_tally = dict.fromkeys(LANE_SEALERS, 0)
+        # the second kernel's provider (BN254: a channel's Idemix
+        # credential proofs and pseudonym signatures); an IdemixMSP
+        # built with this CSP verifies through it, and drain() and
+        # close() join its flush workers with this provider's waiters
+        self.idemix = IdemixCSP(metrics=metrics)
         _watch_compiles(metrics)
 
     # -- lifecycle ---------------------------------------------------------
@@ -793,6 +800,7 @@ class TPUCSP(CSP):
         device failures and the lane tally surface on /metrics."""
         self._breaker.set_metrics(metrics)
         self._metrics = metrics
+        self.idemix.set_metrics(metrics)
         _watch_compiles(metrics)
 
     def lane_tally(self) -> dict[str, int]:
@@ -891,6 +899,8 @@ class TPUCSP(CSP):
         deadline = (
             None if timeout is None else time.monotonic() + timeout
         )
+        if not self.idemix.drain(timeout):
+            return False
         while True:
             with self._pend_lock:
                 if self._pend_batches:

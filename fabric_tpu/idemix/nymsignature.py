@@ -47,6 +47,24 @@ def new_nym_signature(
     )
 
 
+def challenge_matches(
+    sig: NymSignature, nym: tuple, ipk: IssuerPublicKey, msg: bytes, t
+) -> bool:
+    """The Fiat-Shamir re-hash over the commitment `t` =
+    h_sk^z_sk * h_rand^z_rnym * nym^-c, computed here or on the device
+    (None = the point at infinity; False = a lane refused)."""
+    if t is False:
+        return False
+    c = bn.hash_to_zr(
+        b"idemix-nym-signature",
+        bn.g1_to_bytes(t),
+        bn.g1_to_bytes(nym),
+        ipk.hash(),
+        msg,
+    )
+    return c == sig.challenge
+
+
 def verify_nym(
     sig: NymSignature, nym: tuple, ipk: IssuerPublicKey, msg: bytes
 ) -> bool:
@@ -59,11 +77,4 @@ def verify_nym(
         ),
         bn.g1_mul(nym, (-sig.challenge) % bn.R),
     )
-    c = bn.hash_to_zr(
-        b"idemix-nym-signature",
-        bn.g1_to_bytes(t),
-        bn.g1_to_bytes(nym),
-        ipk.hash(),
-        msg,
-    )
-    return c == sig.challenge
+    return challenge_matches(sig, nym, ipk, msg, t)

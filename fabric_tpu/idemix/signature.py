@@ -301,11 +301,15 @@ def verify_batch(
     return _pairing_mask(sigs, ok, ipk, rng)
 
 
-def _pairing_mask(sigs, ok: list[bool], ipk, rng=None) -> list[bool]:
+def _pairing_mask(sigs, ok: list[bool], ipk, rng=None,
+                  stats: dict | None = None) -> list[bool]:
     """Combined two-pairing check over the Schnorr-surviving items with
     random weights; falls back to per-item pairings when the combined
-    check fails so the result stays a per-signature mask."""
+    check fails so the result stays a per-signature mask.  `stats`, if
+    given, learns `combined_ok` and how many items were `isolated`."""
     live = [i for i, v in enumerate(ok) if v]
+    if stats is not None:
+        stats.update(combined_ok=True, isolated=0)
     if not live:
         return ok
     weights = {i: bn.rand_zr(rng) for i in live}
@@ -314,11 +318,31 @@ def _pairing_mask(sigs, ok: list[bool], ipk, rng=None) -> list[bool]:
     if bn.pairing_check([(acc_ap, ipk.w), (bn.g1_neg(acc_ab), bn.G2_GEN)]):
         return ok
     # Rare path: at least one forged pairing — isolate per item.
+    if stats is not None:
+        stats.update(combined_ok=False, isolated=len(live))
     for i in live:
         ok[i] = bn.pairing_check(
             [(sigs[i].a_prime, ipk.w), (bn.g1_neg(sigs[i].a_bar), bn.G2_GEN)]
         )
     return ok
+
+
+def challenge_matches(sig: Signature, ipk: IssuerPublicKey, msg: bytes,
+                      commitments) -> bool:
+    """The Fiat-Shamir re-hash over commitments (T1, T2, T3) computed
+    elsewhere (the device): True when it gives the signature's
+    challenge.  `commitments` None is a lane the device path refused."""
+    if commitments is None:
+        return False
+    try:
+        c = _challenge_bytes(
+            ipk, list(commitments), sig.a_prime, sig.a_bar, sig.b_prime,
+            sig.nym, sig.disclosure, sig.disclosed_attrs, msg, sig.nonce,
+        )
+        return c == sig.challenge
+    except (ValueError, IndexError, KeyError, TypeError,
+            OverflowError, AttributeError):
+        return False
 
 
 def verify_batch_device(
@@ -328,38 +352,16 @@ def verify_batch_device(
     rng=None,
 ) -> list[bool]:
     """verify_batch with the Schnorr commitment recomputation batched on
-    the device (csp/tpu/bn254_batch.py — one XLA program re-derives
-    every signature's T1/T2/T3 G1 MSMs); challenge re-hash and the
-    RLC-collapsed pairings stay on host.  Any device-path failure falls
-    back to the host implementation, so the result is always the host
-    oracle's mask."""
-    try:
-        from fabric_tpu.csp.tpu import bn254_batch
+    the device (csp/tpu/bn254_batch.py — one program re-derives every
+    signature's T1/T2/T3 G1 MSMs); challenge re-hash and the
+    RLC-collapsed pairings stay on host.  A failure of the device path
+    is the caller's to see: nothing here falls back to the host (the
+    provider, csp/idemix_provider.py, does, and counts it)."""
+    from fabric_tpu.csp.tpu import bn254_batch
 
-        comms = bn254_batch.schnorr_commitments_batch(sigs, ipk)
-    except Exception as exc:
-        # loud fallback: otherwise a broken device path silently
-        # re-measures/re-runs the host implementation
-        from fabric_tpu.common.flogging import must_get_logger
-
-        must_get_logger("idemix").warning(
-            "device Schnorr path failed (%s: %s); falling back to host",
-            type(exc).__name__, exc,
-        )
-        return verify_batch(sigs, ipk, msgs, rng=rng)
-    ok: list[bool] = []
-    for sig, msg, tri in zip(sigs, msgs, comms):
-        if tri is None:
-            ok.append(False)
-            continue
-        try:
-            c = _challenge_bytes(
-                ipk, list(tri), sig.a_prime, sig.a_bar, sig.b_prime,
-                sig.nym, sig.disclosure, sig.disclosed_attrs, msg,
-                sig.nonce,
-            )
-            ok.append(c == sig.challenge)
-        except (ValueError, IndexError, KeyError, TypeError,
-                OverflowError, AttributeError):
-            ok.append(False)
+    comms = bn254_batch.schnorr_commitments_batch(sigs, ipk)
+    ok = [
+        challenge_matches(sig, ipk, msg, tri)
+        for sig, msg, tri in zip(sigs, msgs, comms)
+    ]
     return _pairing_mask(sigs, ok, ipk, rng)

@@ -6,7 +6,13 @@ TPU data plane instead of being verified one at a time.
 """
 
 from fabric_tpu.msp.identity import Identity, SigningIdentity
-from fabric_tpu.msp.msp import MSP, MSPError, MSPManager
+from fabric_tpu.msp.msp import (
+    MSP,
+    MSPError,
+    MSPManager,
+    msp_config_name,
+    msp_from_config,
+)
 from fabric_tpu.msp.config import msp_config_from_ca, load_msp_dir, write_msp_dir
 
 __all__ = [
@@ -15,6 +21,8 @@ __all__ = [
     "MSP",
     "MSPError",
     "MSPManager",
+    "msp_config_name",
+    "msp_from_config",
     "msp_config_from_ca",
     "load_msp_dir",
     "write_msp_dir",

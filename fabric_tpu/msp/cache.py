@@ -84,10 +84,30 @@ class CachedMSP:
         if hit:
             return ident
         ident = self._inner.deserialize_identity(serialized)
-        self._deserialize.put(bytes(serialized), ident)
+        if not getattr(ident, "anonymous", False):
+            self._deserialize.put(bytes(serialized), ident)
+        return ident
+
+    def deserialize_creator(self, serialized: bytes):
+        """A block creator, deserialized and validated under its MSP.
+        An anonymous identity (Idemix: a fresh pseudonym a transaction)
+        comes back with its credential proof deferred to the block's
+        batched verify, and never enters the LRUs: it would not be
+        asked for again, and a block of them would flush every X.509
+        identity out."""
+        deferred = getattr(self._inner, "deserialize_deferred", None)
+        if deferred is not None:
+            ident = deferred(serialized)
+            if ident is not None:
+                self._inner.validate(ident)
+                return ident
+        ident = self.deserialize_identity(serialized)
+        self.validate(ident)
         return ident
 
     def validate(self, identity) -> None:
+        if getattr(identity, "anonymous", False):
+            return self._inner.validate(identity)  # single-use: no entry
         key = identity.serialize()
         res, hit = self._validate.get(key)
         if hit:
@@ -106,6 +126,8 @@ class CachedMSP:
         self._validate.put(key, (time.monotonic(), None))
 
     def satisfies_principal(self, identity, principal) -> None:
+        if getattr(identity, "anonymous", False):
+            return self._inner.satisfies_principal(identity, principal)
         key = (identity.serialize(), principal.SerializeToString())
         res, hit = self._principal.get(key)
         if hit:

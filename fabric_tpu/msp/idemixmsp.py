@@ -21,6 +21,11 @@ from __future__ import annotations
 
 import dataclasses
 
+from fabric_tpu.csp.idemix_provider import (
+    IdemixNymItem,
+    IdemixVerifyItem,
+    for_csp,
+)
 from fabric_tpu.idemix import bn254 as bn
 from fabric_tpu.idemix import nymsignature, revocation as idemix_revocation
 from fabric_tpu.idemix import signature as idemix_signature
@@ -50,9 +55,28 @@ class IdemixMSPError(Exception):
     pass
 
 
+def _parse_nym_signature(sig: bytes) -> nymsignature.NymSignature | None:
+    """The wire form `IdemixSigningIdentity.sign` writes; None for
+    anything else."""
+    import json
+
+    try:
+        d = json.loads(sig)
+        return nymsignature.NymSignature(
+            challenge=int(d["c"]),
+            z_sk=int(d["z_sk"]),
+            z_rnym=int(d["z_rnym"]),
+        )
+    except (ValueError, KeyError, TypeError):
+        return None
+
+
 @dataclasses.dataclass
 class IdemixIdentity:
-    """A deserialized (verified) anonymous identity."""
+    """A deserialized anonymous identity.  From `deserialize_identity`
+    it is verified; from `deserialize_deferred` its credential proof is
+    still owed (`proof_deferred`), and `deferred_items` hands it over
+    with the pseudonym signature to be verified in a batch."""
 
     mspid: str
     nym: tuple
@@ -60,9 +84,24 @@ class IdemixIdentity:
     role: int
     proof: idemix_signature.Signature
     _serialized: bytes = b""
+    proof_deferred: bool = False
+    msp: object | None = None      # the IdemixMSP that deserialized it
+
+    # single-use: a fresh pseudonym a transaction, so caches keep out
+    anonymous = True
 
     def serialize(self) -> bytes:
         return self._serialized
+
+    def deferred_items(self, msg: bytes, sig: bytes) -> tuple:
+        """(credential-proof item, pseudonym-signature item over `msg`)
+        for `IdemixMSP.verify_items_async`: what an X.509 creator's
+        `verification_item` is to the ECDSA batch.  The creator's
+        signature stands when both verify."""
+        return (
+            IdemixVerifyItem(self.proof, b""),
+            IdemixNymItem(_parse_nym_signature(sig), self.nym, msg),
+        )
 
     def get_identifier(self) -> str:
         import hashlib
@@ -137,7 +176,10 @@ class IdemixMSP:
     provider_type = IDEMIX
 
     def __init__(self, mspid: str, ipk: IssuerPublicKey,
-                 revocation_pk=None, epoch: int = 0):
+                 revocation_pk=None, epoch: int = 0, csp=None):
+        """`csp` is the node's CSP: batched verification goes through
+        the Idemix provider beside it (`idemix_provider.for_csp`: a
+        `TPUCSP`'s own, else a host-only one)."""
         ipk.check()
         if ipk.attr_names != ATTR_NAMES:
             raise IdemixMSPError(
@@ -147,17 +189,19 @@ class IdemixMSP:
         self.ipk = ipk
         self.revocation_pk = revocation_pk
         self.epoch = epoch
+        self._idemix = for_csp(csp)
         self._signer: IdemixSigningIdentity | None = None
 
     # -- config -------------------------------------------------------------
 
     @classmethod
-    def from_config(cls, conf: msp_config_pb2.MSPConfig) -> "IdemixMSP":
+    def from_config(cls, conf: msp_config_pb2.MSPConfig,
+                    csp=None) -> "IdemixMSP":
         if conf.type != IDEMIX:
             raise IdemixMSPError("not an idemix MSP config")
         ic = msp_config_pb2.IdemixMSPConfig.FromString(conf.config)
         ipk = IssuerPublicKey.from_dict(__import__("json").loads(ic.ipk))
-        msp = cls(ic.name, ipk, epoch=ic.epoch)
+        msp = cls(ic.name, ipk, epoch=ic.epoch, csp=csp)
         if ic.signer:
             sc = msp_config_pb2.IdemixMSPSignerConfig.FromString(ic.signer)
             msp._signer = IdemixSigningIdentity(
@@ -177,16 +221,33 @@ class IdemixMSP:
 
     # -- identity lifecycle -------------------------------------------------
 
-    def deserialize_identity(self, serialized: bytes) -> IdemixIdentity:
+    def deserialize_identity(self, serialized: bytes,
+                             defer: bool = False) -> IdemixIdentity:
         sid = identities_pb2.SerializedIdentity.FromString(serialized)
         if sid.mspid != self.mspid:
             raise IdemixMSPError(
                 f"expected MSP ID {self.mspid}, got {sid.mspid}"
             )
-        return self._deserialize_inner(sid.id_bytes, serialized)
+        return self._deserialize_inner(sid.id_bytes, serialized, defer)
+
+    def deserialize_deferred(self, serialized: bytes) -> IdemixIdentity:
+        """`deserialize_identity` with the credential proof left owing:
+        the cheap, exact checks run now (wire shape, points on the
+        curve, disclosure [OU, Role], claimed OU and role equal to the
+        disclosed attributes, proof bound to the pseudonym), the Schnorr
+        recomputation and the pairings wait for a batch.  For the block
+        validator, whose every creator is new; whoever takes the
+        identity owes `deferred_items` to `verify_items_async`."""
+        return self.deserialize_identity(serialized, defer=True)
+
+    def verify_items_async(self, items):
+        """One asynchronous batched verify of `deferred_items` against
+        this MSP's issuer key: the collector of
+        `IdemixCSP.verify_batch_async`."""
+        return self._idemix.verify_batch_async(items, self.ipk)
 
     def _deserialize_inner(
-        self, id_bytes: bytes, serialized: bytes
+        self, id_bytes: bytes, serialized: bytes, defer: bool = False
     ) -> IdemixIdentity:
         sii = identities_pb2.SerializedIdemixIdentity.FromString(id_bytes)
         try:
@@ -211,11 +272,11 @@ class IdemixMSP:
             raise IdemixMSPError("idemix identity: OU mismatch")
         if proof.disclosed_attrs.get(ATTR_ROLE) != attribute_to_scalar(role):
             raise IdemixMSPError("idemix identity: role mismatch")
-        if not idemix_signature.verify(proof, self.ipk, b""):
+        if not defer and not idemix_signature.verify(proof, self.ipk, b""):
             raise IdemixMSPError("idemix identity: credential proof invalid")
         return IdemixIdentity(
             mspid=self.mspid, nym=nym, ou=ou, role=role, proof=proof,
-            _serialized=serialized,
+            _serialized=serialized, proof_deferred=defer, msp=self,
         )
 
     def validate(self, identity: IdemixIdentity) -> None:
@@ -226,16 +287,8 @@ class IdemixMSP:
     # -- verification -------------------------------------------------------
 
     def verify(self, identity: IdemixIdentity, msg: bytes, sig: bytes) -> bool:
-        import json
-
-        try:
-            d = json.loads(sig)
-            nsig = nymsignature.NymSignature(
-                challenge=int(d["c"]),
-                z_sk=int(d["z_sk"]),
-                z_rnym=int(d["z_rnym"]),
-            )
-        except (ValueError, KeyError, TypeError):
+        nsig = _parse_nym_signature(sig)
+        if nsig is None:
             return False
         return nymsignature.verify_nym(nsig, identity.nym, self.ipk, msg)
 
@@ -329,6 +382,48 @@ def idemix_msp_config(
     return msp_config_pb2.MSPConfig(type=IDEMIX, config=ic.SerializeToString())
 
 
+ISSUER_PUBLIC_KEY_FILE = "IssuerPublicKey"
+
+
+def write_issuer_public_key(issuer: IssuerKey, out_dir: str) -> str:
+    """`<out_dir>/msp/IssuerPublicKey`, as upstream idemixgen lays it
+    out; this tree's JSON form of the key."""
+    import json
+    import os
+
+    msp_dir = os.path.join(out_dir, "msp")
+    os.makedirs(msp_dir, exist_ok=True)
+    path = os.path.join(msp_dir, ISSUER_PUBLIC_KEY_FILE)
+    with open(path, "w", encoding="utf-8") as f:
+        json.dump(issuer.ipk.to_dict(), f)
+    return path
+
+
+def load_idemix_msp_dir(path: str, mspid: str) -> msp_config_pb2.MSPConfig:
+    """The verifier's MSPConfig of an Idemix organisation from
+    idemixgen's output directory (`msp/IssuerPublicKey`; the file
+    directly under `path` is accepted too).  Raises OSError when the
+    key is not there."""
+    import os
+
+    for candidate in (
+        os.path.join(path, "msp", ISSUER_PUBLIC_KEY_FILE),
+        os.path.join(path, ISSUER_PUBLIC_KEY_FILE),
+    ):
+        if os.path.exists(candidate):
+            with open(candidate, "rb") as f:
+                ipk = f.read()
+            return msp_config_pb2.MSPConfig(
+                type=IDEMIX,
+                config=msp_config_pb2.IdemixMSPConfig(
+                    name=mspid, ipk=ipk,
+                ).SerializeToString(),
+            )
+    raise FileNotFoundError(
+        f"no {ISSUER_PUBLIC_KEY_FILE} under {path!r} or its msp/"
+    )
+
+
 __all__ = [
     "IdemixMSP",
     "IdemixIdentity",
@@ -337,6 +432,8 @@ __all__ = [
     "generate_issuer",
     "issue_signer_config",
     "idemix_msp_config",
+    "load_idemix_msp_dir",
+    "write_issuer_public_key",
     "ROLE_MEMBER",
     "ROLE_ADMIN",
     "IDEMIX",

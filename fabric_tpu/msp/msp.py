@@ -249,6 +249,23 @@ class MSP:
         raise MSPError(f"unknown principal classification {cls}")
 
 
+def msp_config_name(conf: msp_config_pb2.MSPConfig) -> str:
+    """The MSP id an MSPConfig of either type carries."""
+    if conf.type == IDEMIX:
+        return msp_config_pb2.IdemixMSPConfig.FromString(conf.config).name
+    return msp_config_pb2.FabricMSPConfig.FromString(conf.config).name
+
+
+def msp_from_config(conf: msp_config_pb2.MSPConfig, csp=None):
+    """The MSP of a channel organisation, by `MSPConfig.type`: X.509
+    (FABRIC) or Idemix (reference msp/factory.go New + Setup)."""
+    if conf.type == IDEMIX:
+        from fabric_tpu.msp.idemixmsp import IdemixMSP
+
+        return IdemixMSP.from_config(conf, csp)
+    return MSP.from_config(conf, csp)
+
+
 class MSPManager:
     """Per-channel MSP set: routes deserialization by mspid (reference
     msp/mspmgrimpl.go)."""
@@ -274,6 +291,21 @@ class MSPManager:
         sid = identities_pb2.SerializedIdentity.FromString(serialized)
         return self.get_msp(sid.mspid).deserialize_identity(serialized)
 
+    def deserialize_deferred(self, serialized: bytes):
+        """An identity of an MSP that can leave its expensive proof
+        owing (`IdemixMSP.deserialize_deferred`), or None: the identity
+        belongs to an MSP that cannot (X.509), and the caller
+        deserializes and validates it as ever."""
+        if not any(
+            hasattr(m, "deserialize_deferred") for m in self._msps.values()
+        ):
+            return None
+        sid = identities_pb2.SerializedIdentity.FromString(serialized)
+        deferred = getattr(
+            self.get_msp(sid.mspid), "deserialize_deferred", None
+        )
+        return None if deferred is None else deferred(serialized)
+
     def satisfies_principal(self, identity, principal) -> None:
         self.get_msp(identity.mspid).satisfies_principal(identity, principal)
 
@@ -281,4 +313,7 @@ class MSPManager:
         self.get_msp(identity.mspid).validate(identity)
 
 
-__all__ = ["MSP", "MSPManager", "MSPError", "FABRIC", "IDEMIX"]
+__all__ = [
+    "MSP", "MSPManager", "MSPError", "FABRIC", "IDEMIX",
+    "msp_config_name", "msp_from_config",
+]

@@ -12,7 +12,11 @@ validates each tx in its own goroutine, and every tx serially verifies
      deserialization/validation, and endorsement-policy *preparation*
      (fabric_tpu.policies two-phase protocol).  No crypto.
   2. **Verify** (device): ONE `CSP.verify_batch` over every creator and
-     endorsement signature of the whole block.
+     endorsement signature of the whole block, and, where the block
+     carries anonymous (Idemix) creators, ONE batched verify of their
+     credential proofs and pseudonym signatures beside it (the two
+     kinds of lane, `_ItemSink` and `_IdemixSink`; both go out at the
+     end of collect, both are waited for in verify_wait).
   3. **Finish** (host): creator mask -> BAD_CREATOR_SIGNATURE; policy
      closures over the mask -> ENDORSEMENT_POLICY_FAILURE; MVCC runs later
      in the ledger commit (kvledger).
@@ -70,6 +74,8 @@ class _ItemSink:
         self.items: list = []
         self._index: dict = {}
         self._dedup = dedup
+        # the other kind of lane: anonymous creators' deferred items
+        self.idemix = _IdemixSink()
 
     def add(self, item) -> int:
         if not self._dedup:
@@ -87,6 +93,42 @@ class _ItemSink:
         return [self.add(it) for it in items]
 
 
+class _IdemixSink:
+    """A block's deferred Idemix items beside the ECDSA sink: per
+    Idemix MSP (an issuer key each) the credential-proof and
+    pseudonym-signature items of its creators, in tx order.  Nothing
+    is interned: every creator is a fresh pseudonym."""
+
+    def __init__(self):
+        self.by_msp: dict = {}  # IdemixMSP -> [proof, nym, proof, nym, ..]
+
+    def add(self, creator, items: tuple) -> tuple:
+        """(msp, index of the proof item; the nym item follows it)."""
+        lst = self.by_msp.get(creator.msp)
+        if lst is None:
+            lst = self.by_msp[creator.msp] = []
+        lst.extend(items)
+        return creator.msp, len(lst) - 2
+
+    def dispatch(self) -> list:
+        """One asynchronous batched verify an MSP: [(msp, collector)]."""
+        return [
+            (msp, msp.verify_items_async(items))
+            for msp, items in self.by_msp.items()
+        ]
+
+
+class _IdemixCreator:
+    """What `_parse_tx` hands `_integrate_tx` for an anonymous creator
+    where an X.509 creator has its `VerifyBatchItem`."""
+
+    __slots__ = ("creator", "items")
+
+    def __init__(self, creator, payload: bytes, signature: bytes):
+        self.creator = creator
+        self.items = creator.deferred_items(payload, signature)
+
+
 @dataclasses.dataclass
 class _TxWork:
     """Per-tx deferred crypto: creator item index + per-namespace plugin
@@ -94,6 +136,9 @@ class _TxWork:
     endorsement conflict detection."""
 
     creator_item: int | None = None
+    idemix_item: tuple | None = None
+    # an anonymous creator: (msp, index) of its credential-proof item in
+    # the block's Idemix sink, the pseudonym-signature item right after
     pendings: list = dataclasses.field(default_factory=list)
     # [(PendingValidation, [item index, ...])] — one per written namespace
     touched_keys: frozenset = frozenset()  # {(ns_or_hashns, key)}
@@ -139,6 +184,24 @@ class _ParsedTx:
     cc_id: str = ""
     rwset: bytes = b""
     footprint: object | None = None  # parsed RwsetFootprint when usable
+
+
+def _both(ecdsa, idemix: list):
+    """A block's collector when it holds both kinds of lane: the ECDSA
+    mask, with the Idemix masks (by MSP) riding on it."""
+
+    def collect():
+        mask = _MaskWithIdemix(ecdsa())
+        mask.idemix = {msp: c() for msp, c in idemix}
+        return mask
+
+    return collect
+
+
+class _MaskWithIdemix(list):
+    """The ECDSA mask of a block that also carries anonymous creators."""
+
+    idemix: dict
 
 
 class TxValidator:
@@ -275,9 +338,18 @@ class TxValidator:
         identity) is unaffected."""
         if not self._faithful and creator_bytes in memo:
             return memo[creator_bytes]
+        mgr = self._bundle.msp_manager
         try:
-            ident = self._bundle.msp_manager.deserialize_identity(creator_bytes)
-            self._bundle.msp_manager.validate(ident)
+            creator_of = getattr(mgr, "deserialize_creator", None)
+            if creator_of is not None:
+                # an anonymous (Idemix) creator comes back with its
+                # credential proof deferred: it joins the block's
+                # Idemix sink, as an X.509 creator's signature joins
+                # the ECDSA sink
+                ident = creator_of(creator_bytes)
+            else:
+                ident = mgr.deserialize_identity(creator_bytes)
+                mgr.validate(ident)
         except Exception:
             ident = None
         if lock is not None:
@@ -346,7 +418,14 @@ class TxValidator:
             p.pre_flag = V.BAD_CREATOR_SIGNATURE
             return p
         # creator signature over the payload bytes (checkSignatureFromCreator)
-        p.creator_item = creator.verification_item(env.payload, env.signature)
+        if getattr(creator, "proof_deferred", False):
+            p.creator_item = _IdemixCreator(
+                creator, env.payload, env.signature
+            )
+        else:
+            p.creator_item = creator.verification_item(
+                env.payload, env.signature
+            )
 
         if chdr.type == common_pb2.CONFIG:
             # config txs are validated/applied by the channel config engine
@@ -467,7 +546,12 @@ class TxValidator:
         work.txid = p.hdr_txid
         if p.pre_flag is not None:
             return p.pre_flag
-        work.creator_item = sink.add(p.creator_item)
+        if type(p.creator_item) is _IdemixCreator:
+            work.idemix_item = sink.idemix.add(
+                p.creator_item.creator, p.creator_item.items
+            )
+        else:
+            work.creator_item = sink.add(p.creator_item)
         if p.mid_flag is not None:
             return p.mid_flag
         # duplicate detection (checkTxIdDupsLedger): the txid registers
@@ -662,6 +746,12 @@ class TxValidator:
                 if sink.items
                 else (lambda: [])
             )
+            if sink.idemix.by_msp:
+                # the block's Idemix items go out here too, as ONE
+                # asynchronous batched verify (an MSP), before
+                # verify_wait: device and host half of block n overlap
+                # collect of block n+1 and commit of block n-1
+                collect = _both(collect, sink.idemix.dispatch())
         self._observe_stage("collect", time.perf_counter() - t0)
         return block, flags, works, collect, envs, bspan
 
@@ -684,7 +774,13 @@ class TxValidator:
         python path makes the engines agree by construction.  Honest
         blocks contain no malformed envelopes, so the fallback costs
         nothing on the hot path, and an adversarial block degrades to
-        at worst the pure-python engine's cost."""
+        at worst the pure-python engine's cost.
+
+        The glue knows both kinds of creator: an X.509 creator's
+        signature joins the ECDSA sink over the walker's payload digest,
+        an anonymous (Idemix) creator's credential proof and pseudonym
+        signature join the block's Idemix sink (`_IdemixSink`), exactly
+        as the Python half (`_parse_tx` / `_integrate_tx`) does."""
         from fabric_tpu import native
         from fabric_tpu.csp.api import VerifyBatchItem
 
@@ -821,13 +917,23 @@ class TxValidator:
                 flags[i] = V.BAD_CREATOR_SIGNATURE
                 continue
             w = works[i]
-            w.creator_item = sink.add(
-                VerifyBatchItem(
-                    creator.public_key,
-                    digs[32 * i:32 * i + 32],
-                    sl(sig_off_l[i], sig_len_l[i]),
+            if getattr(creator, "proof_deferred", False):
+                # an anonymous creator: its pseudonym signature is over
+                # the payload bytes themselves (no digest lane), which
+                # the walker does not slice out; one Envelope decode
+                env = common_pb2.Envelope.FromString(data[i])
+                w.idemix_item = sink.idemix.add(
+                    creator,
+                    creator.deferred_items(env.payload, env.signature),
                 )
-            )
+            else:
+                w.creator_item = sink.add(
+                    VerifyBatchItem(
+                        creator.public_key,
+                        digs[32 * i:32 * i + 32],
+                        sl(sig_off_l[i], sig_len_l[i]),
+                    )
+                )
             if st == 1:  # CONFIG tx: creator signature only
                 flags[i] = V.VALID
                 continue
@@ -986,6 +1092,15 @@ class TxValidator:
                 if w.creator_item is not None and not mask[w.creator_item]:
                     flags[i] = V.BAD_CREATOR_SIGNATURE
                     continue
+                if w.idemix_item is not None:
+                    # a failed credential proof or a failed pseudonym
+                    # signature: upstream checkSignatureFromCreator
+                    # gives both this code
+                    msp, j = w.idemix_item
+                    im = mask.idemix[msp]
+                    if not (im[j] and im[j + 1]):
+                        flags[i] = V.BAD_CREATOR_SIGNATURE
+                        continue
                 if w.touched_keys & updated:
                     flags[i] = V.ENDORSEMENT_POLICY_FAILURE
                     continue

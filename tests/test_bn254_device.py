@@ -115,3 +115,83 @@ def test_device_malformed_inputs_never_throw(world):
     msgs = [good_msg] * 4
     got = verify_batch_device(sigs, isk.ipk, msgs)
     assert got == [True, False, False, False]
+
+
+# -- pseudonym signatures ride the same launch, a lane each ----------------
+
+
+def _host_nym_commitment(sig, nym, ipk):
+    """`nymsignature.verify_nym`'s one commitment."""
+    return bn.g1_add(
+        bn.g1_add(bn.g1_mul(ipk.h_sk, sig.z_sk), bn.g1_mul(ipk.h_rand, sig.z_rnym)),
+        bn.g1_mul(nym, (-sig.challenge) % bn.R),
+    )
+
+
+def _nyms(world, n=4):
+    from fabric_tpu.idemix import nymsignature
+
+    isk, sk, *_ = world
+    out = []
+    for i in range(n):
+        nym, r_nym = signature.make_nym(sk, isk.ipk)
+        msg = b"payload-%d" % i
+        out.append((nymsignature.new_nym_signature(sk, nym, r_nym, isk.ipk, msg), nym, msg))
+    return out
+
+
+def test_device_nym_commitments_match_verify_nyms(world):
+    """Sound signatures, one whose commitment is the point at infinity,
+    one with a pseudonym off the curve, one whose challenge is off by
+    one: the device's T3 of each lane is the host's commitment."""
+    from fabric_tpu.csp.tpu import bn254_batch
+    from fabric_tpu.idemix import nymsignature
+
+    isk, *_ = world
+    ipk = isk.ipk
+    nyms = _nyms(world)
+    a, b, c = 5, 7, 11
+    at_infinity = (
+        nymsignature.NymSignature(challenge=c, z_sk=c * a % bn.R, z_rnym=c * b % bn.R),
+        bn.g1_add(bn.g1_mul(ipk.h_sk, a), bn.g1_mul(ipk.h_rand, b)), b"m",
+    )
+    sound = nyms[0]
+    off_curve = (sound[0], (sound[1][0], (sound[1][1] + 1) % bn.P), sound[2])
+    import dataclasses
+
+    off_by_one = (dataclasses.replace(sound[0], challenge=(sound[0].challenge + 1) % bn.R),
+                  sound[1], sound[2])
+    lanes = nyms + [at_infinity, off_curve, off_by_one]
+    got = bn254_batch.nym_commitments_batch([(s, n) for s, n, _m in lanes], ipk)
+    for j, (sig, nym, msg) in enumerate(lanes):
+        if j == len(nyms) + 1:
+            assert got[j] is False                      # the malformed lane
+            assert not nymsignature.verify_nym(sig, nym, ipk, msg)
+            continue
+        assert got[j] == _host_nym_commitment(sig, nym, ipk), j
+        assert nymsignature.challenge_matches(sig, nym, ipk, msg, got[j]) == \
+            nymsignature.verify_nym(sig, nym, ipk, msg)
+    assert got[len(nyms)] is None                       # the point at infinity
+    assert [nymsignature.verify_nym(s, n, ipk, m) for s, n, m in lanes] == \
+        [True] * len(nyms) + [False, False, False]
+
+
+def test_proofs_and_nyms_share_one_launch(world):
+    """A block's launch: proof lanes then pseudonym-signature lanes;
+    every lane's commitments are the host's."""
+    from fabric_tpu.csp.tpu import bn254_batch
+
+    isk, *_ = world
+    pairs = _sigs(world, 3)
+    nyms = _nyms(world, 3)
+    prep = bn254_batch.prepare(
+        [s for s, _ in pairs], [(s, n) for s, n, _m in nyms], isk.ipk)
+    assert prep.lanes == 6 and prep.bucket == 16
+    launched = bn254_batch.enqueue(prep)
+    assert launched.path == "xla" and launched.fallback is None
+    launched.wait()
+    got = bn254_batch.normalize(launched)
+    for j, (sig, _msg) in enumerate(pairs):
+        assert list(got[j]) == list(_host_commitments(sig, isk.ipk))
+    for j, (sig, nym, _msg) in enumerate(nyms):
+        assert got[3 + j] == (None, None, _host_nym_commitment(sig, nym, isk.ipk))

@@ -156,3 +156,46 @@ def test_native_load_error_is_recorded(monkeypatch):
     assert not native.available()
     assert "no such compiler" in native.load_error()
     assert native.marshal_batch(b"", b"", b"", b"", [0]) is None
+
+
+def test_the_smokes_idemix_block_is_the_benchmarks_world_through_the_validator(tmp_path):
+    """Leg A's last step on the chip, here on the host path at a small
+    size: the block the smoke builds (the benchmark's Idemix world, by
+    the name `IDEMIX_CONFIG`) through TxValidator + store_block gives
+    the planted flags, and the provider's tally is what the smoke
+    reads (on the chip: only `*.pallas` items and no fallback)."""
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        from benchlib.manifest import Manifest
+    finally:
+        sys.path.remove(os.path.join(ROOT, "benchmarks"))
+    import chip_smoke
+    from fabric_tpu.common.channelconfig import bundle_from_genesis
+    from fabric_tpu.csp.tpu.provider import TPUCSP
+    from fabric_tpu.ledger import LedgerProvider
+    from fabric_tpu.peer.committer import Committer
+    from fabric_tpu.peer.txvalidator import TxValidator
+    from fabric_tpu.protos.common import common_pb2
+
+    sys.path.insert(0, os.path.join(ROOT, "benchmarks"))
+    try:
+        man = Manifest(ROOT)
+        held = man.config({"name": "smoke", "config": chip_smoke.IDEMIX_CONFIG})
+        world = man.world(held)(
+            21, dict(held["deployment"], block_txs=16), held["planted"], 1)
+    finally:
+        sys.path.remove(os.path.join(ROOT, "benchmarks"))
+    csp = TPUCSP()
+    try:
+        ledger = LedgerProvider(str(tmp_path)).create(world.genesis)
+        committer = Committer(
+            TxValidator(world.channel, ledger,
+                        bundle_from_genesis(world.genesis, csp), csp), ledger)
+        flags = committer.store_block(common_pb2.Block.FromString(world.blocks[0]))
+        assert [int(f) for f in flags] == [int(f) for f in world.planted[0]]
+        reached = 16 - world.refused_at_deserialise[0]
+        tally = csp.idemix.tally()
+        assert tally["items"] == {"proof.host": reached, "nym.host": reached}
+        assert tally["fallbacks"] == {"below_crossover": 1}
+    finally:
+        csp.close()

@@ -169,14 +169,15 @@ class TestIdemixCSPDeviceSelect:
             calls.append("host")
             return [True] * len(sigs)
 
-        def device(sigs, ipk, msgs, rng=None):
+        def device(self, items, ipk):
             calls.append("device")
-            return [True] * len(sigs)
+            return [True] * len(items), "pallas", len(items), 128
 
         from fabric_tpu.csp import idemix_provider as ip
 
         monkeypatch.setattr(ip.signature, "verify_batch", host)
-        monkeypatch.setattr(ip.signature, "verify_batch_device", device)
+        # the device path's one entry (the flush worker calls it)
+        monkeypatch.setattr(ip.IdemixCSP, "_device_mask", device)
         # the suite runs on CPU; pretend a TPU backend is present so
         # the auto path's size threshold is what's under test
         monkeypatch.setattr(ip, "_on_tpu", lambda: True)
@@ -192,6 +193,14 @@ class TestIdemixCSPDeviceSelect:
         csp.verify_batch(small, issuer.ipk)
         csp.verify_batch(large, issuer.ipk)
         assert calls == ["host", "device"]
+        # 99 -> the host, and counted with its reason; 100 -> the device
+        tally = csp.tally()
+        assert tally["fallbacks"] == {"below_crossover": 1}
+        assert tally["items"] == {
+            "proof.host": csp.DEVICE_CROSSOVER - 1,
+            "proof.pallas": csp.DEVICE_CROSSOVER,
+        }
+        assert [b["path"] for b in csp.recent_batches()] == ["host", "pallas"]
 
     def test_forced_and_overridden(self, issuer, monkeypatch):
         from fabric_tpu.csp import IdemixCSP, IdemixVerifyItem
@@ -228,6 +237,177 @@ class TestIdemixCSPDeviceSelect:
         csp = IdemixCSP(rng=RNG, device_crossover=4)
         want = [True, True, True, False, True, True]
         assert csp.verify_batch(items, issuer.ipk) == want
+
+
+class TestIdemixFallbacksAreCounted:
+    """Every route by which an Idemix item is verified elsewhere than
+    the Pallas BN254 kernel is counted with its reason, and the verdicts
+    stay the host oracle's."""
+
+    def _items(self, issuer, user):
+        from fabric_tpu.csp.idemix_provider import IdemixNymItem, IdemixVerifyItem
+
+        sk, cred = user
+        items, want = [], []
+        for i in range(3):
+            nym, r_nym = signature.make_nym(sk, issuer.ipk, RNG)
+            proof = signature.new_signature(
+                cred, sk, issuer.ipk, b"", nym=nym, r_nym=r_nym, rng=RNG)
+            msg = b"payload-%d" % i
+            nsig = nymsignature.new_nym_signature(
+                sk, nym, r_nym, issuer.ipk, msg, rng=RNG)
+            items += [IdemixVerifyItem(proof, b""),
+                      IdemixNymItem(nsig, nym, msg if i != 1 else b"another")]
+            want += [True, i != 1]
+        # a signature that did not parse: an item, and False
+        items.append(IdemixNymItem(None, nym, b"x"))
+        return items, want + [False]
+
+    @pytest.mark.parametrize("reason", [
+        "below_crossover", "no_tpu", "forced_host", "device_error", "pallas_to_xla",
+    ])
+    def test_each_reason_increments_its_counter(self, issuer, user, reason, monkeypatch):
+        from fabric_tpu.common.metrics import CSPMetrics, PrometheusProvider
+        from fabric_tpu.csp import idemix_provider as ip
+        from fabric_tpu.csp.tpu import bn254_batch
+
+        assert reason in ip.FALLBACK_REASONS
+        prov = PrometheusProvider()
+        kwargs = {
+            "below_crossover": {},
+            "no_tpu": {"device_crossover": 2},
+            "forced_host": {"device": False},
+            "device_error": {"device": True},
+            "pallas_to_xla": {"device": True},
+        }[reason]
+        csp = ip.IdemixCSP(rng=RNG, metrics=CSPMetrics(prov), **kwargs)
+        if reason == "device_error":
+            def broken(*a, **k):
+                raise RuntimeError("the device path is broken")
+
+            monkeypatch.setattr(bn254_batch, "prepare", broken)
+        if reason == "pallas_to_xla":
+            from fabric_tpu.csp.tpu import pallas_bn254
+
+            def no_mosaic(*a, **k):
+                raise RuntimeError("Mosaic refused the kernel")
+
+            monkeypatch.setenv("FABRIC_BN254_FORCE_PALLAS", "1")
+            monkeypatch.setattr(pallas_bn254, "pack", no_mosaic)
+            monkeypatch.setattr(bn254_batch, "_PALLAS_FAILURES", {})
+        items, want = self._items(issuer, user)
+        try:
+            assert csp.verify_batch_async(items, issuer.ipk)() == want
+        finally:
+            csp.close()
+        tally = csp.tally()
+        assert tally["fallbacks"] == {reason: 1}
+        path = "xla" if reason == "pallas_to_xla" else "host"
+        assert tally["items"] == {f"proof.{path}": 3, f"nym.{path}": 4}
+        assert tally["batches"] == ({16: 1} if path == "xla" else {})
+        text = prov.registry.expose()
+        assert f'csp_idemix_fallbacks_total{{reason="{reason}"}} 1' in text
+        assert f'csp_idemix_items_total{{kind="nym",path="{path}"}} 4' in text
+        assert ('csp_idemix_batches_total{bucket="16"} 1' in text) == (path == "xla")
+
+    def test_the_tpu_provider_builds_and_drains_its_idemix_provider(self):
+        from fabric_tpu.csp import idemix_provider as ip
+        from fabric_tpu.csp.sw import SWCSP
+        from fabric_tpu.csp.tpu.provider import TPUCSP
+
+        csp = TPUCSP()
+        try:
+            assert ip.for_csp(csp) is csp.idemix
+            assert csp.idemix._device is None      # auto: crossover, then a TPU
+        finally:
+            csp.close()
+        host_only = ip.for_csp(SWCSP())
+        assert host_only is ip.for_csp(object()) and host_only._device is False
+
+
+class TestIdemixFlushSpans:
+    """The device path's anatomy in tracelens (forced onto the XLA
+    engine here; the shape is the one the provider tests above built):
+    a detached `idemix.flush` from dispatch begun to mask sealed, and
+    under it prepare, enqueue, device_wait, normalize, rehash, pairing.
+    Disarmed, the same path consults nothing."""
+
+    def _batch(self, issuer, user):
+        return TestIdemixFallbacksAreCounted()._items(issuer, user)
+
+    def test_a_flush_is_a_span_with_its_parts_under_it(self, issuer, user):
+        from fabric_tpu.common import tracing
+        from fabric_tpu.csp.idemix_provider import IdemixCSP
+
+        items, want = self._batch(issuer, user)
+        csp = IdemixCSP(rng=RNG, device=True)
+        with tracing.scope() as rec:
+            collect = csp.verify_batch_async(items, issuer.ipk)
+            assert collect() == want
+            csp.close()
+            events = [e for e in tracing.export(rec)["traceEvents"] if e.get("ph") == "X"]
+        by_name = {}
+        for e in events:
+            by_name.setdefault(e["name"], []).append(e)
+        (flush,) = by_name["idemix.flush"]
+        assert (flush["args"]["proofs"], flush["args"]["nyms"]) == (3, 4)
+        # the unparsed signature has no lane: 3 proofs + 3 pseudonym signatures
+        assert (flush["args"]["lanes"], flush["args"]["bucket"]) == (6, 16)
+        assert flush["args"]["path"] == "xla"
+        parts = ("idemix.prepare", "idemix.enqueue", "idemix.device_wait",
+                 "idemix.normalize", "idemix.rehash", "idemix.pairing")
+        for name in parts:
+            (e,) = by_name[name]
+            assert e["args"]["parent"] == flush["args"]["span"], name
+            assert e["tid"] == "idemix-flush"
+            assert flush["ts"] <= e["ts"] and e["ts"] + e["dur"] <= flush["ts"] + flush["dur"]
+        assert by_name["idemix.enqueue"][0]["args"]["bucket"] == 16
+        assert by_name["idemix.enqueue"][0]["args"]["lanes"] == 6
+        pairing = by_name["idemix.pairing"][0]["args"]
+        assert pairing["combined_ok"] is True and pairing["isolated"] == 0
+
+    def test_a_failed_combined_check_shows_its_isolation(self, issuer, user):
+        """A proof from a credential of a rogue issuer (the same bases,
+        another secret key) passes every Schnorr relation and fails only
+        the pairing: the combined check fails, every surviving proof
+        goes through its own pairings, and that one alone is refused."""
+        import dataclasses
+
+        from fabric_tpu.common import tracing
+        from fabric_tpu.csp.idemix_provider import IdemixCSP, IdemixVerifyItem
+
+        x = bn.rand_zr(RNG)
+        rogue = IssuerKey(isk=x, ipk=dataclasses.replace(
+            issuer.ipk, w=bn.g2_mul(bn.G2_GEN, x)))
+        sk = bn.rand_zr(RNG)
+        req = new_cred_request(sk, b"n", rogue.ipk, rng=RNG)
+        cred = new_credential(rogue, req, [1, 2, 3, 4], rng=RNG)
+        outsider = signature.new_signature(cred, sk, issuer.ipk, b"", rng=RNG)
+        assert signature._check_schnorr(outsider, issuer.ipk, b"")
+        assert not signature.verify(outsider, issuer.ipk, b"")
+        items, want = self._batch(issuer, user)
+        items[2] = IdemixVerifyItem(outsider, b"")
+        csp = IdemixCSP(rng=RNG, device=True)
+        with tracing.scope() as rec:
+            got = csp.verify_batch_async(items, issuer.ipk)()
+            csp.close()
+            events = tracing.export(rec)["traceEvents"]
+        (pairing,) = [e for e in events if e.get("name") == "idemix.pairing"]
+        assert got == want[:2] + [False] + want[3:]
+        assert pairing["args"]["combined_ok"] is False
+        assert pairing["args"]["isolated"] == 3
+
+    def test_disarmed_the_device_path_consults_nothing(self, issuer, user):
+        from fabric_tpu.common import tracing
+        from fabric_tpu.csp.idemix_provider import IdemixCSP
+
+        assert not tracing.enabled()
+        before = tracing.lookup_count()
+        items, want = self._batch(issuer, user)
+        csp = IdemixCSP(rng=RNG, device=True)
+        assert csp.verify_batch_async(items, issuer.ipk)() == want
+        csp.close()
+        assert tracing.lookup_count() == before
 
 
 class TestNymSignature:

@@ -107,3 +107,83 @@ def test_admin_identity():
         ).SerializeToString(),
     )
     msp.satisfies_principal(ident, admin)
+
+
+# -- an `msptype: idemix` organisation reaches a running peer ---------------
+
+
+def test_a_configtx_profile_with_an_msptype_idemix_org_loads_as_a_bundle(tmp_path):
+    """idemixgen ca-keygen -> configtx.yaml as docs/source/idemix.rst
+    writes it (lower-case keys, `msptype: idemix`) -> configtxgen ->
+    bundle_from_genesis."""
+    import yaml
+
+    from fabric_tpu.cmd import configtxgen, idemixgen
+    from fabric_tpu.common.channelconfig import bundle_from_genesis
+    from fabric_tpu.common.crypto import CA
+    from fabric_tpu.msp import write_msp_dir
+    from fabric_tpu.protos.common import common_pb2
+
+    root = str(tmp_path)
+    for name in ("org1", "orderer"):
+        write_msp_dir(f"{root}/{name}/msp", CA(f"ca.{name}.example.com", name))
+    assert idemixgen.main(["ca-keygen", "--output", f"{root}/idemix-config"]) == 0
+    doc = {
+        "Organizations": [
+            {"Name": "Org1", "ID": "Org1MSP", "MSPDir": "org1/msp"},
+            {"Name": "Orderer", "ID": "OrdererMSP", "MSPDir": "orderer/msp"},
+            {"name": "idemixMSP1", "id": "idemixMSPID1", "msptype": "idemix",
+             "mspdir": "idemix-config"},
+        ],
+        "Profiles": {"Anon": {
+            "Orderer": {"OrdererType": "solo", "Organizations": ["Orderer"]},
+            "Application": {"Organizations": ["Org1", "idemixMSP1"]},
+        }},
+    }
+    with open(f"{root}/configtx.yaml", "w") as f:
+        yaml.safe_dump(doc, f)
+    assert configtxgen.main([
+        "-profile", "Anon", "-channelID", "anonch", "-configPath", root,
+        "-outputBlock", f"{root}/genesis.block",
+    ]) == 0
+    with open(f"{root}/genesis.block", "rb") as f:
+        genesis = common_pb2.Block.FromString(f.read())
+    bundle = bundle_from_genesis(genesis)
+    msp = bundle.msp_manager.get_msp("idemixMSPID1")
+    assert isinstance(msp, IdemixMSP)
+    assert bundle.application_config.orgs["idemixMSP1"].mspid == "idemixMSPID1"
+    assert bundle.orderer_config.org_mspids == ["OrdererMSP"]
+    # a member of the idemix org satisfies the channel's Writers
+    assert {m.mspid for m in bundle.msp_manager.msps()} == \
+        {"Org1MSP", "OrdererMSP", "idemixMSPID1"}
+
+
+def test_an_idemix_org_without_its_public_key_is_refused_by_configtxgen(tmp_path):
+    import yaml
+
+    from fabric_tpu.cmd import configtxgen
+
+    root = str(tmp_path)
+    doc = {"Organizations": [{"Name": "I", "ID": "IMSP", "MSPType": "idemix",
+                              "MSPDir": "nowhere"}],
+           "Profiles": {"P": {"Application": {"Organizations": ["I"]}}}}
+    with open(f"{root}/configtx.yaml", "w") as f:
+        yaml.safe_dump(doc, f)
+    with pytest.raises(SystemExit, match="IssuerPublicKey"):
+        configtxgen.main(["-profile", "P", "-configPath", root,
+                          "-outputBlock", f"{root}/g.block"])
+
+
+def test_deserialize_deferred_runs_the_cheap_checks_and_leaves_the_proof(msp):
+    ident = msp.get_default_signing_identity()
+    deferred = msp.deserialize_deferred(ident.serialize())
+    assert deferred.proof_deferred and deferred.msp is msp
+    assert (deferred.nym, deferred.ou, deferred.role) == (ident.nym, "ou1", ROLE_MEMBER)
+    proof_item, nym_item = deferred.deferred_items(b"payload", ident.sign(b"payload"))
+    assert msp.verify_items_async([proof_item, nym_item])() == [True, True]
+    # eager deserialisation keeps its meaning: verified when it returns
+    assert not msp.deserialize_identity(ident.serialize()).proof_deferred
+    # a signature that is no signature is an item that fails, not an error
+    _p, garbage = deferred.deferred_items(b"payload", b"garbage")
+    assert garbage.sig is None
+    assert msp.verify_items_async([garbage])() == [False]
