@@ -557,6 +557,17 @@ class CSPMetrics:
                  "the bucket (padded lanes) each ran at.",
             statsd_format="%{bucket}",
         ))
+        self.idemix_pairing_checks = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="idemix",
+            name="pairing_checks_total",
+            help="Pairing checks of Idemix credential proofs, labeled "
+                 "by stage: combined (one a batch), and, after a "
+                 "combined check failed, subset (the bisection's) and "
+                 "item (one proof's own).  Growth under subset or item "
+                 "means forged credentials are being submitted.",
+            statsd_format="%{stage}",
+        ))
         self.breaker_state.set(0)
 
 

@@ -23,6 +23,21 @@ RNG = random.Random(42)
 ATTRS = ["OU", "Role", "EnrollmentID", "RevocationHandle"]
 
 
+def _rogue_signer(issuer):
+    """A maker of proofs from a credential of a rogue issuer (the same
+    bases, another secret key): each passes every Schnorr relation under
+    `issuer`'s key and fails only the pairing."""
+    import dataclasses
+
+    x = bn.rand_zr(RNG)
+    rogue = IssuerKey(isk=x, ipk=dataclasses.replace(
+        issuer.ipk, w=bn.g2_mul(bn.G2_GEN, x)))
+    sk = bn.rand_zr(RNG)
+    req = new_cred_request(sk, b"n", rogue.ipk, rng=RNG)
+    cred = new_credential(rogue, req, [1, 2, 3, 4], rng=RNG)
+    return lambda: signature.new_signature(cred, sk, issuer.ipk, b"", rng=RNG)
+
+
 @pytest.fixture(scope="module")
 def issuer():
     return IssuerKey.generate(ATTRS, rng=RNG)
@@ -165,7 +180,7 @@ class TestIdemixCSPDeviceSelect:
     def _record_dispatch(self, monkeypatch):
         calls = []
 
-        def host(sigs, ipk, msgs, rng=None):
+        def host(sigs, ipk, msgs, rng=None, stats=None):
             calls.append("host")
             return [True] * len(sigs)
 
@@ -365,24 +380,19 @@ class TestIdemixFlushSpans:
         assert by_name["idemix.enqueue"][0]["args"]["lanes"] == 6
         pairing = by_name["idemix.pairing"][0]["args"]
         assert pairing["combined_ok"] is True and pairing["isolated"] == 0
+        assert pairing["checks"] == 1
+        assert csp.tally()["pairing_checks"] == {"combined": 1, "subset": 0, "item": 0}
 
     def test_a_failed_combined_check_shows_its_isolation(self, issuer, user):
         """A proof from a credential of a rogue issuer (the same bases,
         another secret key) passes every Schnorr relation and fails only
-        the pairing: the combined check fails, every surviving proof
-        goes through its own pairings, and that one alone is refused."""
-        import dataclasses
-
+        the pairing: the combined check fails, the surviving proofs are
+        isolated (three: one pairing check each), and that one alone is
+        refused."""
         from fabric_tpu.common import tracing
         from fabric_tpu.csp.idemix_provider import IdemixCSP, IdemixVerifyItem
 
-        x = bn.rand_zr(RNG)
-        rogue = IssuerKey(isk=x, ipk=dataclasses.replace(
-            issuer.ipk, w=bn.g2_mul(bn.G2_GEN, x)))
-        sk = bn.rand_zr(RNG)
-        req = new_cred_request(sk, b"n", rogue.ipk, rng=RNG)
-        cred = new_credential(rogue, req, [1, 2, 3, 4], rng=RNG)
-        outsider = signature.new_signature(cred, sk, issuer.ipk, b"", rng=RNG)
+        outsider = _rogue_signer(issuer)()
         assert signature._check_schnorr(outsider, issuer.ipk, b"")
         assert not signature.verify(outsider, issuer.ipk, b"")
         items, want = self._batch(issuer, user)
@@ -396,6 +406,8 @@ class TestIdemixFlushSpans:
         assert got == want[:2] + [False] + want[3:]
         assert pairing["args"]["combined_ok"] is False
         assert pairing["args"]["isolated"] == 3
+        assert pairing["args"]["checks"] == 4
+        assert (pairing["args"]["subset_checks"], pairing["args"]["item_checks"]) == (0, 3)
 
     def test_disarmed_the_device_path_consults_nothing(self, issuer, user):
         from fabric_tpu.common import tracing
@@ -408,6 +420,117 @@ class TestIdemixFlushSpans:
         assert csp.verify_batch_async(items, issuer.ipk)() == want
         csp.close()
         assert tracing.lookup_count() == before
+
+
+class TestIsolationByBisection:
+    """After a failed combined check the forged proofs are found by
+    bisection over the same random linear combination: every verdict is
+    `signature.verify`'s, one forgery among 125 costs at most 14 checks
+    after the combined one, and a batch full of forgeries little more
+    than a check an item."""
+
+    N = 125
+
+    @pytest.fixture(scope="class")
+    def proofs(self, issuer, user):
+        """N genuine proofs and N of a rogue issuer, each with the
+        verdict `signature.verify` gives it."""
+        sk, cred = user
+        forge = _rogue_signer(issuer)
+        good = [signature.new_signature(cred, sk, issuer.ipk, b"", rng=RNG)
+                for _ in range(self.N)]
+        bad = [forge() for _ in range(self.N)]
+        assert all(signature.verify(s, issuer.ipk, b"") for s in good)
+        assert not any(signature.verify(s, issuer.ipk, b"") for s in bad)
+        return good, bad
+
+    @pytest.mark.parametrize("n,forged", [
+        (125, "none"), (125, "first"), (125, "last"), (125, "adjacent"),
+        (125, "apart"), (125, "seven"), (125, "all"),
+        (40, "first"), (77, "seven"), (64, "all"), (5, "last"), (4, "all"),
+    ])
+    def test_the_mask_is_verifys_and_the_checks_are_bounded(
+            self, issuer, proofs, n, forged):
+        good, bad = proofs
+        places = {
+            "none": [], "first": [0], "last": [n - 1],
+            "adjacent": [n // 3, n // 3 + 1], "apart": [2, n - 9],
+            "seven": random.Random(n).sample(range(n), min(7, n)),
+            "all": list(range(n)),
+        }[forged]
+        # what signature.verify says of each, item by item (the fixture
+        # holds the genuine and the rogue issuer's proofs to it)
+        want = [i not in places for i in range(n)]
+        sigs = [good[i] if want[i] else bad[i] for i in range(n)]
+        # a Schnorr-level casualty is no survivor: it costs no check
+        ok = [True] * n + [False]
+        stats: dict = {}
+        got = signature._pairing_mask(
+            sigs + [good[0]], ok, issuer.ipk, random.Random(n), stats=stats)
+        assert got == want + [False]
+        assert stats["combined_ok"] is (not places)
+        assert stats["isolated"] == (n if places else 0)
+        assert stats["checks"] == 1 + stats["subset_checks"] + stats["item_checks"]
+        assert stats["checks"] <= 1.25 * n + 8
+        if not places:
+            assert stats["checks"] == 1
+        if len(places) == 1:
+            depth = (n - 1).bit_length()
+            assert depth + 1 <= stats["checks"] <= 2 * depth + 1
+            assert n != 125 or stats["checks"] <= 15
+
+    def test_up_to_three_survivors_get_a_check_each(self, issuer, proofs):
+        good, bad = proofs
+        stats: dict = {}
+        got = signature._pairing_mask(
+            [good[0], bad[0], good[1]], [True] * 3, issuer.ipk, RNG, stats=stats)
+        assert got == [True, False, True]
+        assert (stats["checks"], stats["subset_checks"], stats["item_checks"]) == (4, 0, 3)
+
+    def test_the_pure_python_pairing_gives_the_same_mask(
+            self, issuer, proofs, monkeypatch):
+        good, bad = proofs
+        sigs = [good[0], good[1], bad[0], good[2]]
+        native = signature._pairing_mask(sigs, [True] * 4, issuer.ipk, RNG)
+        monkeypatch.setattr(bn, "_NATIVE", None)
+        stats: dict = {}
+        assert signature._pairing_mask(
+            sigs, [True] * 4, issuer.ipk, RNG, stats=stats) == native
+        assert native == [True, True, False, True]
+        assert 3 <= stats["checks"] <= 6
+
+    def test_the_operator_sees_the_checks_by_stage(self, issuer, proofs):
+        """`csp_idemix_pairing_checks_total{stage}` and `tally()`: a
+        sound batch is one `combined` check; a forged credential shows
+        as `subset` and `item` checks."""
+        from fabric_tpu.common.metrics import CSPMetrics, PrometheusProvider
+        from fabric_tpu.csp.idemix_provider import (
+            PAIRING_STAGES, IdemixCSP, IdemixVerifyItem,
+        )
+
+        good, bad = proofs
+        prov = PrometheusProvider()
+        csp = IdemixCSP(rng=RNG, device=False, metrics=CSPMetrics(prov))
+        sound = [IdemixVerifyItem(s, b"") for s in good[:12]]
+        assert csp.verify_batch(sound, issuer.ipk) == [True] * 12
+        assert csp.tally()["pairing_checks"] == {"combined": 1, "subset": 0, "item": 0}
+        text = prov.registry.expose()
+        for stage, n in zip(PAIRING_STAGES, (1, 0, 0)):
+            assert f'csp_idemix_pairing_checks_total{{stage="{stage}"}} {n}' in text
+        forged = sound[:7] + [IdemixVerifyItem(bad[0], b"")] + sound[7:]
+        assert csp.verify_batch(forged, issuer.ipk) == [True] * 7 + [False] + [True] * 5
+        spent = csp.tally()["pairing_checks"]
+        assert spent["combined"] == 2
+        # 13 survivors: bisection stops after 3 or 4 subset checks, what
+        # is still undecided goes item by item
+        assert 3 <= spent["subset"] <= 4 and spent["subset"] + spent["item"] <= 13
+        text = prov.registry.expose()
+        assert 'csp_idemix_pairing_checks_total{stage="combined"} 2' in text
+        assert f'csp_idemix_pairing_checks_total{{stage="subset"}} {spent["subset"]}' in text
+        # the counts the condition `idemix-on-device` reads stay as they were
+        assert csp.tally()["items"] == {"proof.host": 25}
+        assert csp.tally()["fallbacks"] == {"forced_host": 2}
+        assert csp.tally()["batches"] == {}
 
 
 class TestNymSignature:
