@@ -11,8 +11,9 @@ one process owns the chip:
   1000-tx blocks, ~4,000 signature lanes per block) through
   `TxValidator` -> `Committer.store_stream` with `TPUCSP`, against the
   `faithful=True` serial validator on `SWCSP` committing the same
-  blocks to a second ledger.  The blocks carry corrupted creator and
-  endorsement signatures, so a kernel that answered all-true would
+  blocks to a second ledger.  The blocks are the benchmark's own
+  (`benchlib.generator`, `X509_CONFIG`) with their corrupted creator
+  and endorsement signatures, so a kernel that answered all-true would
   fail.  Host race off: every lane must be sealed by the device.  Then
   a pass at the provider's defaults (the split is printed, not judged),
   the second kernel (an Idemix batch of 128 with one tampered, then a
@@ -38,8 +39,8 @@ The last line of standard output is one JSON object,
 `{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": ...}}`,
 after a `summary:` line with the readings; on any failure the exit
 status is non-zero and neither line is printed.
-Data comes from `--seed` (transaction keys and values, which
-signatures are corrupted, the Idemix credential); X.509 and TLS key
+Data comes from `--seed` (leg A's keys, transactions and corrupted
+signatures, the Idemix credential); leg B's X.509 and TLS key
 material is drawn from the OS generator by the `cryptography` package.
 """
 
@@ -66,9 +67,9 @@ ROOT = os.path.dirname(os.path.abspath(__file__))
 # The platform every chip user must report.  A constant, not an option.
 REQUIRED_PLATFORM = "tpu"
 
-# Leg A: BASELINE.json configuration 4 at full width.
-N_ORGS, ENDORSERS, BLOCK_TXS, N_BLOCKS = 5, 3, 1000, 4
-TAMPERED_PER_BLOCK = 3  # creator signatures, and as many endorsements
+# Leg A: BASELINE.json configuration 4 at full width, as the benchmark's
+# configuration deploys and plants it (benchmarks/configs/).
+X509_CONFIG, N_BLOCKS = "majority5-1000tx", 4
 IDEMIX_SIGS = 128  # above IdemixCSP.DEVICE_CROSSOVER: the 256 bucket
 # the benchmark's Idemix deployment: its world builds the smoke's block
 IDEMIX_CONFIG = "idemix-nym128"
@@ -151,9 +152,22 @@ def cache_entries(path: str) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _flip_last_byte(sig: bytes) -> bytes:
-    """Still strict DER, still low-S with overwhelming odds, wrong s."""
-    return sig[:-1] + bytes([sig[-1] ^ 1])
+def leg_a_world(seed: int, n_blocks: int, block_txs: int | None = None):
+    """Leg A's blocks and the flags planted in them: the benchmark's
+    generator over the deployment of `X509_CONFIG` (`block_txs` narrows
+    it for the CPU test of this function)."""
+    bench = os.path.join(ROOT, "benchmarks")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from benchlib import generator
+
+    with open(os.path.join(bench, "configs", f"{X509_CONFIG}.json"),
+              encoding="utf-8") as f:
+        cfg = json.load(f)
+    deployment = dict(cfg["deployment"])
+    if block_txs is not None:
+        deployment["block_txs"] = block_txs
+    return generator.build_world(seed, deployment, cfg["planted"], n_blocks)
 
 
 def leg_a_child(seed: int) -> int:
@@ -161,8 +175,6 @@ def leg_a_child(seed: int) -> int:
     import random
     import statistics
 
-    for sub in ("scripts", "tests"):
-        sys.path.insert(0, os.path.join(ROOT, sub))
     sys.path.insert(0, ROOT)
     phases = Phases("[A]")
     t_start = time.perf_counter()
@@ -189,61 +201,23 @@ def leg_a_child(seed: int) -> int:
     say(f"[A] compile cache: {cache_dir}  entries before: {entries_before}")
 
     # -- the world: blocks with a few corrupted signatures --------------
-    from bench_pipeline import _build_world, _make_blocks
-
     from fabric_tpu.common import workpool
+    from fabric_tpu.common.channelconfig import bundle_from_genesis
     from fabric_tpu.common.metrics import CSPMetrics, PrometheusProvider
     from fabric_tpu.csp import SWCSP
     from fabric_tpu.ledger import LedgerProvider
     from fabric_tpu.peer.committer import Committer
     from fabric_tpu.peer.txvalidator import TxValidator
     from fabric_tpu.protos.common import common_pb2
-    from fabric_tpu.protos.peer import transaction_pb2 as V
 
     rng = random.Random(seed)
-    picks = [
-        rng.sample(range(BLOCK_TXS), 2 * TAMPERED_PER_BLOCK)
-        for _ in range(N_BLOCKS)
-    ]
-    bad_creator = [set(p[:TAMPERED_PER_BLOCK]) for p in picks]
-    bad_endorsement = [set(p[TAMPERED_PER_BLOCK:]) for p in picks]
-
-    def corrupt_endorsement(bno, i, resps):
-        if i in bad_endorsement[bno]:
-            e = resps[rng.randrange(len(resps))].endorsement
-            e.signature = _flip_last_byte(e.signature)
-
-    def build():
-        sw = SWCSP()
-        orgs, genesis = _build_world(N_ORGS)
-        _, bundle, blocks = _make_blocks(
-            orgs, genesis, sw, BLOCK_TXS, ENDORSERS, N_BLOCKS,
-            on_endorsed=corrupt_endorsement,
-        )
-        for bno, blk in enumerate(blocks):
-            for i in bad_creator[bno]:
-                env = common_pb2.Envelope.FromString(blk.data.data[i])
-                env.signature = _flip_last_byte(env.signature)
-                blk.data.data[i] = env.SerializeToString()
-        return sw, genesis, bundle, blocks
-
-    sw, genesis, bundle, blocks = phases.run("build_blocks", build)
-    want = []
-    for bno in range(N_BLOCKS):
-        row = [V.VALID] * BLOCK_TXS
-        for i in bad_creator[bno]:
-            row[i] = V.BAD_CREATOR_SIGNATURE
-        for i in bad_endorsement[bno]:
-            row[i] = V.ENDORSEMENT_POLICY_FAILURE
-        want.append(row)
+    world = phases.run("build_blocks", leg_a_world, seed, N_BLOCKS)
+    sw, genesis, want = SWCSP(), world.genesis, world.planted
+    bundle = bundle_from_genesis(genesis, sw)
+    n_invalid = sum(f != 0 for row in want for f in row)
 
     def copies():
-        out = []
-        for blk in blocks:
-            b = common_pb2.Block()
-            b.CopyFrom(blk)
-            out.append(b)
-        return out
+        return [common_pb2.Block.FromString(raw) for raw in world.blocks]
 
     tmp = tempfile.TemporaryDirectory(prefix="chip-smoke-a-")
     providers = []
@@ -254,7 +228,7 @@ def leg_a_child(seed: int) -> int:
         return provider.create(genesis)
 
     def state_of(ledger):
-        rows = list(ledger.get_state_range("benchcc", "", ""))
+        rows = list(ledger.get_state_range(world.namespaces[0], "", ""))
         digest = hashlib.sha256(repr(rows).encode()).hexdigest()
         return rows, digest
 
@@ -262,18 +236,18 @@ def leg_a_child(seed: int) -> int:
     def reference():
         led = fresh_ledger("reference")
         committer = Committer(
-            TxValidator("benchch", led, bundle, sw, faithful=True), led
+            TxValidator(world.channel, led, bundle, sw, faithful=True), led
         )
         return led, [list(committer.store_block(b)) for b in copies()]
 
     ref_ledger, ref_flags = phases.run("reference_host", reference)
     check(ref_flags == want,
-          "the host reference did not flag exactly the corrupted "
-          "transactions: the smoke's own data is wrong")
+          "the host reference did not flag exactly the transactions "
+          "the generator planted: the smoke's own data is wrong")
     ref_rows, ref_digest = state_of(ref_ledger)
-    n_invalid = 2 * TAMPERED_PER_BLOCK * N_BLOCKS
-    check(len(ref_rows) == BLOCK_TXS * N_BLOCKS - n_invalid,
-          "reference state holds a key of an invalid transaction")
+    check(dict(ref_rows) == {
+        key: value for (_, key), (value, _) in world.expected_state().items()
+    }, "reference state is not the generator's expected state")
 
     # -- the device, host race OFF --------------------------------------
     class Counting(TPUCSP):
@@ -298,7 +272,7 @@ def leg_a_child(seed: int) -> int:
             return res
 
     prov = PrometheusProvider()
-    # bench.py's settings, plus stall_factor=None: a mask can then come
+    # stall_factor=None: a mask can then come
     # only from the device or from a failure path the tally exposes
     csp = Counting(
         sw=sw, min_device_batch=1, coalesce_lanes=4096,
@@ -308,7 +282,7 @@ def leg_a_child(seed: int) -> int:
     def stream(provider_csp, name: str):
         led = fresh_ledger(name)
         committer = Committer(
-            TxValidator("benchch", led, bundle, provider_csp), led
+            TxValidator(world.channel, led, bundle, provider_csp), led
         )
         flags = [
             list(f) for f in committer.store_stream(iter(copies()), depth=6)
@@ -335,7 +309,7 @@ def leg_a_child(seed: int) -> int:
     for bno in range(N_BLOCKS):
         diff = [
             (i, ref_flags[bno][i], dev_flags[bno][i])
-            for i in range(BLOCK_TXS)
+            for i in range(len(want[bno]))
             if ref_flags[bno][i] != dev_flags[bno][i]
         ]
         check(not diff, f"block {bno + 1}: (tx, reference, device) flags "
@@ -344,9 +318,9 @@ def leg_a_child(seed: int) -> int:
     check(dev_ledger.height == ref_ledger.height == 1 + N_BLOCKS,
           "ledger heights differ")
     check(dev_rows == ref_rows, "final state differs from the reference")
-    say(f"[A] flags agree on {N_BLOCKS} x {BLOCK_TXS} tx "
-        f"({n_invalid} corrupted: {TAMPERED_PER_BLOCK} creator + "
-        f"{TAMPERED_PER_BLOCK} endorsement signatures per block); state "
+    say(f"[A] flags agree on {N_BLOCKS} x {len(want[0])} tx of "
+        f"benchlib.generator's {X509_CONFIG} ({n_invalid} planted invalid: "
+        f"corrupted creator and endorsement signatures, conflicts); state "
         f"digest {dev_digest[:16]} == reference, {len(dev_rows)} keys")
 
     tally = csp.lane_tally()
@@ -358,7 +332,7 @@ def leg_a_child(seed: int) -> int:
     check(failures == 0, f"{failures:.0f} device failures with the race off")
     check(csp.breaker.trips == 0 and not csp.breaker.open,
           "the breaker tripped")
-    check(csp.submitted >= 2 * N_BLOCKS * BLOCK_TXS * (1 + ENDORSERS) * 0.95,
+    check(csp.submitted >= 2 * N_BLOCKS * world.lanes_per_block * 0.95,
           f"only {csp.submitted} lanes submitted: not the full-width path")
     check(tally["device"] == csp.submitted,
           f"device sealed {tally['device']} of {csp.submitted} lanes")

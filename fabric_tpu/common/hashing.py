@@ -28,9 +28,7 @@ def set_hash_backend(csp) -> None:
     The seam now feeds consensus-critical digests (tx ids, block header
     hashes, pvt key hashes), so a backend whose output is not
     byte-identical SHA-256 would silently fork this peer from the
-    hashlib fallback — probe once at install time and fail fast.  The
-    probes are tiny, so batched providers take their host fallback and
-    no device compile is triggered here."""
+    hashlib fallback — probe once at install time and fail fast."""
     if csp is not None:
         probe = b"fabric-tpu hash seam probe"
         want = hashlib.sha256(probe).digest()
@@ -57,8 +55,8 @@ def sha256(data: bytes) -> bytes:
 
 
 def sha256_many(blobs) -> list[bytes]:
-    """Batch SHA-256 through the CSP seam (`hash_batch` — ONE device
-    call on the TPU provider); hashlib fallback host-side."""
+    """Batch SHA-256 through the CSP seam (`hash_batch`: one call for
+    the whole batch); hashlib where no provider is installed."""
     blobs = list(blobs)
     backend = _HASH_BACKEND
     if backend is not None:

@@ -424,9 +424,9 @@ class CommitMetrics:
     tentpole's instrumentation): one histogram labeled (channel, stage)
     over the stages mvcc / block_append / pvt / state / history (per
     block) and fsync / kv_txn (per group boundary), plus how many
-    blocks each fsync+txn boundary made durable — the breakdown the
-    next optimisation round reads off /metrics and bench.py's JSON
-    line."""
+    blocks each fsync+txn boundary made durable — the breakdown an
+    operator reads off /metrics and benchmarks/run.py off
+    `commit_stage_seconds`."""
 
     STAGES = (
         "mvcc", "block_append", "pvt", "state", "history",
@@ -471,7 +471,7 @@ class CSPMetrics:
             subsystem="tpu",
             name="breaker_state",
             help="1 while the TPU degraded-mode circuit breaker is open "
-                 "(verify/hash served by the host path, no device "
+                 "(verify served by the host path, no device "
                  "queuing), 0 when closed.",
         ))
         self.breaker_trips = provider.new_counter(CounterOpts(
@@ -486,7 +486,7 @@ class CSPMetrics:
             subsystem="tpu",
             name="device_failures_total",
             help="Device-path failures observed by the TPU provider "
-                 "(dispatch, collect, or hash).",
+                 "(dispatch or collect).",
         ))
         self.probes = provider.new_counter(CounterOpts(
             namespace="csp",

@@ -21,8 +21,8 @@ tracelens/faultline seam discipline exactly:
   3.12+ ``sys.monitoring`` could drive exact attribution; the sampling
   form is kept because it is version-portable and has no per-bytecode
   cost.)  Samples landing inside a live tracelens span are attributed
-  to it, so ``critical_path_ms`` gains a per-stage ``self_cpu_ms``
-  breakdown.  Lock acquire-wait/hold (fed by lockwatch) and workpool
+  to it (a per-stage ``self_cpu_ms`` breakdown).  Lock
+  acquire-wait/hold (fed by lockwatch) and workpool
   queue-wait/run-time (fed by run_chunked) aggregate here too, and
   mirror into ``lock_wait_seconds{role=...}`` histograms on /metrics
   when a :class:`~fabric_tpu.common.metrics.LockMetrics` bundle is

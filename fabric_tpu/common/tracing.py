@@ -724,54 +724,6 @@ def span_sequence(doc: dict) -> list[tuple]:
     return out
 
 
-# -- critical path ------------------------------------------------------------
-
-
-def critical_path_ms(events, group_attr: str = "block",
-                     cat: str = "stage") -> dict[str, float]:
-    """Per-stage critical-path milliseconds over `events` (Chrome
-    trace dicts), grouped by the ``group_attr`` span attribute (one
-    group per block).
-
-    Within each group the chain is built backwards from the latest
-    finisher: repeatedly take the span with the latest end among those
-    starting before the cursor, attribute ``min(end, cursor) - start``
-    to its stage, and move the cursor to its start.  Gaps (no span
-    covering the cursor) are skipped.  The result sums each stage's
-    contribution across all groups — the "which stage actually gated
-    the wall clock" number aggregate percentiles cannot produce."""
-    groups: dict = {}
-    for ev in events:
-        if ev.get("ph", "X") != "X" or ev.get("cat") != cat:
-            continue
-        g = ev.get("args", {}).get(group_attr)
-        if g is None:
-            continue
-        start = ev["ts"] / 1e3
-        groups.setdefault(g, []).append(
-            (start, start + ev.get("dur", 0) / 1e3, ev["name"])
-        )
-    out: dict[str, float] = {}
-    for spans in groups.values():
-        # deterministic ordering regardless of recorder interleaving
-        remaining = sorted(spans, key=lambda s: (-s[1], s[0], s[2]))
-        cursor = remaining[0][1]
-        while remaining:
-            pick = None
-            for i, s in enumerate(remaining):
-                if s[0] < cursor:
-                    pick = i
-                    break  # latest end among starts-before-cursor
-            if pick is None:
-                break
-            start, end, name = remaining.pop(pick)
-            contrib = min(end, cursor) - start
-            if contrib > 0:
-                out[name] = out.get(name, 0.0) + contrib
-            cursor = min(cursor, start)
-    return out
-
-
 # -- env arming ---------------------------------------------------------------
 
 
@@ -819,6 +771,5 @@ __all__ = [
     "dump_doc",
     "dump_to",
     "span_sequence",
-    "critical_path_ms",
     "DEFAULT_CAPACITY",
 ]

@@ -13,8 +13,8 @@ validationWorkersSemaphore, validator.go:180).
 
 The pool is created lazily through ``lockwatch.tracked_executor`` so
 every worker registers with the threadwatch drain gate — a session that
-spins the pool up MUST call :func:`shutdown` before exit (bench.py, the
-multichip dryrun, and tests/conftest.py all do), otherwise the idle
+spins the pool up MUST call :func:`shutdown` before exit (`node.quiesce`,
+the multichip dryrun, and tests/conftest.py all do), otherwise the idle
 workers are reported as leaked threads, by design.
 
 Stage fan-out widths are env knobs (``0``/``false``/``off`` disables a
@@ -64,7 +64,7 @@ def set_metrics(metrics) -> None:
 
 def stats() -> dict:
     """Always-on fan-out counters (chunks submitted, peak concurrent
-    chunks) — bench.py echoes these in its JSON line."""
+    chunks)."""
     with _stats_lock:
         return {k: v for k, v in _stats.items() if k != "in_flight"}
 

@@ -3,7 +3,7 @@
 Equivalent of the reference's BCCSP (bccsp/bccsp.go:90-134) with one
 deliberate extension the reference lacks: a first-class *batch* API
 (`verify_batch`, `hash_batch`) so a whole block's signatures become a single
-device call. Providers:
+device call (hashing stays hashlib in every provider). Providers:
 
 - sw:  host reference implementation (OpenSSL via `cryptography`, hashlib)
 - tpu: JAX/XLA batched implementation (csp/tpu/)

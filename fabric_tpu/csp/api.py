@@ -270,7 +270,7 @@ class VerifyBatchItem(typing.NamedTuple):
     verification.  A NamedTuple, not a dataclass: the validator creates
     one per creator/endorsement lane (thousands per block), and tuple
     construction runs in C at roughly half the dataclass __init__
-    cost — this is hot-path object churn, measured in profile_host."""
+    cost — this is hot-path object churn."""
 
     key: ECDSAP256PublicKey
     digest: bytes  # 32-byte SHA-256 digest of the signed message

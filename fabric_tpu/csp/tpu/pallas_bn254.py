@@ -3,8 +3,7 @@
 The XLA ladder in `bn254_batch.py` is HBM-bound the same way `ec.py`'s
 was: every field multiplication round-trips (B, ~600)-wide limb-product
 intermediates through HBM, so the whole 64-window ladder runs ~100x
-slower than its arithmetic (scripts/bench_fieldops.py measures a
-point-add at ~25 us/1024 lanes; the ladder pays ~1.4 s).  This kernel is
+slower than its arithmetic.  This kernel is
 the `pallas_ec.py` treatment for BN254: the entire joint T1/T2/T3 ladder
 stays resident in VMEM — inputs stream in once, nine coordinates stream
 out.

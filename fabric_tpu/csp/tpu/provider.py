@@ -2,8 +2,8 @@
 
 The sibling the reference never had (BASELINE.json north star): same SPI as
 the `sw` provider (bccsp/sw/impl.go dispatch surface), but `verify_batch`
-and `hash_batch` execute as single jitted XLA programs over the whole batch
-instead of per-item host calls.
+executes as one kernel launch over the whole batch instead of per-item
+host calls.  (`hash_batch` is hashlib: host SHA-NI wins at every size.)
 
 Key management and signing delegate to the host `sw` provider — the
 reference's hot path is *verification* at commit time (SURVEY.md §3.4:
@@ -59,7 +59,6 @@ except ModuleNotFoundError as _exc:  # pragma: no cover - minimal hosts
 _BATCH_BUCKETS = (32, 128, 512, 2048, 4096, 8192, 32768)  # single dispatch
 # for big batches: per-call overhead beats chunk-pipelining wins
 # (4096 matters: a 1000-tx block at 3-of-5 is 4000 sigs)
-_HASH_BUCKETS = (32, 128, 512, 2048, 8192)
 _MAX_CHUNK = 8192  # largest single kernel execution
 
 # Who can seal a verified lane's mask (TPUCSP.lane_tally keys, the
@@ -278,9 +277,8 @@ def _knob_int(name: str, default: int) -> int:
 class _Breaker:
     """Degraded-mode circuit breaker over the device path (the chaos
     tentpole's hardening half).  `threshold` CONSECUTIVE device-path
-    failures — dispatch raising, a flush waiter's collect dying, a
-    device hash_batch failing — open it; while open, verify_batch /
-    hash_batch route straight to the host oracle with NO device
+    failures — dispatch raising, a flush waiter's collect dying — open
+    it; while open, verify_batch routes straight to the host oracle, NO
     queuing, and every `probe_every`-th held verify call first sends a
     tiny probe batch through the device: a probe the DEVICE completes
     closes the breaker and traffic returns.  Knobs: constructor
@@ -328,7 +326,7 @@ class _Breaker:
                     self.metrics.breaker_trips.add()
                 _logger.warning(
                     "TPU circuit breaker OPEN after %d consecutive "
-                    "device failures; verify/hash routed to the host "
+                    "device failures; verify routed to the host "
                     "path (probe every %d calls)",
                     self._consecutive, self.probe_every,
                 )
@@ -676,7 +674,7 @@ class _FlushResult:
 
 
 class TPUCSP(CSP):
-    """Batched JAX/XLA crypto provider (ECDSA-P256 verify + SHA-256)."""
+    """Batched JAX/XLA crypto provider (ECDSA-P256 verify, Idemix)."""
 
     def __init__(
         self,
@@ -701,7 +699,7 @@ class TPUCSP(CSP):
             sw = SWCSP()
         self._sw = sw
         # degraded-mode circuit breaker: consecutive device failures
-        # flip every verify/hash to the host oracle (no device queuing)
+        # flip every verify to the host oracle (no device queuing)
         # until a periodic probe batch sees the device recover
         self._breaker = _Breaker(
             breaker_threshold, breaker_probe_every, metrics
@@ -782,8 +780,8 @@ class TPUCSP(CSP):
     @staticmethod
     def device_info() -> dict:
         """The accelerator as JAX reports it — every entry point that
-        claims a device run (chip_smoke.py, bench.py, `peer node start`
-        on this provider) prints this, so a host run cannot pass for
+        claims a device run (chip_smoke.py, benchmarks/run.py, `peer node
+        start` on this provider) prints this, so a host run cannot pass for
         one.  Initializes the backend; raises where JAX cannot."""
         import jax
 
@@ -852,7 +850,7 @@ class TPUCSP(CSP):
 
     @property
     def breaker_open(self) -> bool:
-        """True while verify/hash are served by the host oracle."""
+        """True while verify is served by the host oracle."""
         return self._breaker.open
 
     def health_checker(self):
@@ -865,7 +863,7 @@ class TPUCSP(CSP):
             if self._breaker.open:
                 raise RuntimeError(
                     "TPU degraded: circuit breaker open after "
-                    f"{self._breaker.trips} trip(s); verify/hash "
+                    f"{self._breaker.trips} trip(s); verify "
                     "served by the host oracle"
                 )
             return True
@@ -880,8 +878,8 @@ class TPUCSP(CSP):
         regression: a `tpu-flush-waiter` daemon thread still blocked in
         an XLA kernel at interpreter exit gets pthread-killed, the
         forced unwind crosses XLA's catch(...), and glibc aborts with
-        "FATAL: exception not rethrown".  Callers (bench.py, the
-        multichip dryrun, node shutdown) drain before exiting instead
+        "FATAL: exception not rethrown".  Callers (benchmarks/run.py,
+        the multichip dryrun, node shutdown) drain before exiting instead
         of papering over the abort with os._exit(0).
 
         Every in-flight flush is marked cancelled first so a wall
@@ -954,43 +952,8 @@ class TPUCSP(CSP):
         return hashlib.sha256(msg).digest()
 
     def hash_batch(self, msgs: Sequence[bytes]) -> list[bytes]:
-        if len(msgs) < self._min_device_batch:
-            return [hashlib.sha256(m).digest() for m in msgs]
-        if self._breaker_gate():
-            # open breaker: the host path IS the oracle for hashing —
-            # _breaker_gate already ran the periodic recovery probe, so
-            # hash-only workloads (snapshot exports) can close the
-            # breaker too, not just verify traffic
-            return [hashlib.sha256(m).digest() for m in msgs]
-        from fabric_tpu.csp.tpu import sha256 as dev_sha
-
-        try:
-            faultline.point("tpu.hash", n=len(msgs))
-            # Bucket by padded block count AND batch size to bound
-            # compiles.
-            nb = max((len(m) + 9 + 63) // 64 for m in msgs)
-            nb = 1 << (nb - 1).bit_length()
-            n = len(msgs)
-            bsz = _bucket(n, _HASH_BUCKETS)
-            out: list[bytes] = []
-            for off in range(0, n, bsz):
-                chunk = list(msgs[off : off + bsz])
-                pad = bsz - len(chunk)
-                chunk += [b""] * pad
-                digs = dev_sha.sha256_batch(chunk, n_blocks=nb)
-                out.extend(digs[: bsz - pad])
-        except Exception:
-            # device died mid-hash: the host answers, the breaker
-            # counts — loudly, so a swallowed correctness bug in the
-            # device path cannot hide as a silent perf regression
-            self._breaker.record(False)
-            _logger.warning(
-                "device hash_batch failed; served %d digests from the "
-                "host fallback", len(msgs), exc_info=True,
-            )
-            return [hashlib.sha256(m).digest() for m in msgs]
-        self._breaker.record(True)
-        return out
+        # the host at every size (README "Deliberate cuts")
+        return [hashlib.sha256(m).digest() for m in msgs]
 
     # -- verification ------------------------------------------------------
 

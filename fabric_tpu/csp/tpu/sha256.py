@@ -1,9 +1,12 @@
 """Batched SHA-256 on TPU.
 
-Replaces per-message `hashlib.sha256` host hashing on the validation hot
-path (reference: msp/identities.go:169-196 hashes each message before
-`bccsp.Verify`; bccsp/sw hash dispatch in bccsp/sw/impl.go) with one
-vectorized compression over all messages of a block.
+One vectorized compression over all messages of a batch (reference:
+msp/identities.go:169-196 hashes each message before `bccsp.Verify`;
+bccsp/sw hash dispatch in bccsp/sw/impl.go).  Nothing in the product
+routes to it: `TPUCSP.hash_batch` is hashlib, which was faster at every
+batch size measured.  It stays as the SHA-256 offload capability
+BASELINE.json names, compiled by `__graft_entry__.dryrun_multichip`
+and held to hashlib by tests/test_csp_tpu.py.
 
 TPU-first shape: every message is padded (standard SHA-256 Merkle–Damgård
 padding, done host-side in numpy) to the same static number of 64-byte

@@ -164,9 +164,10 @@ class KVLedger:
         # needs the ledger); commit() notifies it per committed block
         self.snapshots = None
         # Per-stage commit timing: cumulative wall seconds per pipeline
-        # stage (CommitMetrics.STAGES keys), always maintained (bench.py
-        # reads them); `metrics` (a common.metrics.CommitMetrics) also
-        # gets per-observation histograms for /metrics.
+        # stage (CommitMetrics.STAGES keys), always maintained
+        # (benchmarks/run.py reads them); `metrics` (a
+        # common.metrics.CommitMetrics) also gets per-observation
+        # histograms for /metrics.
         self._metrics = metrics
         # `ledger_metrics` (common.metrics.LedgerMetrics): the
         # per-channel height / durable_height gauges + block/tx
@@ -779,8 +780,7 @@ class LedgerProvider:
     """Opens/creates per-channel ledgers under one root (reference
     kv_ledger_provider.go + ledgermgmt).  `csp`/`metrics` feed the
     snapshot subsystem: per-file digests of generated snapshots go
-    through csp.hash_batch (TPU-batched when the node runs the tpu
-    provider, sw fallback otherwise); `snapshots_dir` defaults to
+    through csp.hash_batch; `snapshots_dir` defaults to
     <root>/snapshots."""
 
     def __init__(self, root_dir: str | None = None, csp=None, metrics=None,

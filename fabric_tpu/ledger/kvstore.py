@@ -152,8 +152,8 @@ class SqliteKVStore(KVStore):
     atomic batch commits (the recovery property blkstorage/kvledger rely
     on, reference blockfile checkpoints + leveldb atomicity).
 
-    Durability knobs (`python bench.py --sweep-sqlite` measures the
-    combos; the chaos crash matrix pins the default's safety):
+    Durability knobs (the chaos crash matrix pins the default's
+    safety):
     `synchronous`/`FABRIC_TPU_SQLITE_SYNC` and
     `wal_autocheckpoint`/`FABRIC_TPU_WAL_CHECKPOINT` — see
     _sqlite_sync_level/_sqlite_wal_checkpoint."""

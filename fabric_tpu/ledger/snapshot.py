@@ -20,9 +20,8 @@ directory of deterministic, ordered export files
                                per-file SHA-256 digests
 
 The per-file digests are computed through the CSP `hash_batch` seam
-(fabric_tpu/csp/api.py) — one batched call for all files — so snapshot
-integrity hashing rides the same TPU-batched path as block validation,
-with the sw provider as the host fallback.  `verify_snapshot` recomputes
+(fabric_tpu/csp/api.py) — one batched call for all files, which every
+provider in the tree answers with hashlib.  `verify_snapshot` recomputes
 the digests on import and refuses a tampered directory.
 
 Request lifecycle (reference snapshot_mgmt.go): requests are persisted
@@ -198,10 +197,9 @@ def load_metadata(snapshot_dir: str) -> dict:
 def _hash_files(snapshot_dir: str, names, csp=None, metrics=None,
                 channel: str = ""):
     """Per-file SHA-256 digests through the CSP hash_batch seam — ONE
-    batched call covers every file, so on the TPU provider the whole
-    snapshot is digested device-side; sw is the host fallback.  When the
-    csp package itself is unavailable (hosts without `cryptography`),
-    the common.hashing seam produces the identical digests."""
+    batched call covers every file.  When the csp package itself is
+    unavailable (hosts without `cryptography`), the common.hashing seam
+    produces the identical digests."""
     if csp is None:
         try:
             from fabric_tpu.csp.factory import get_default

@@ -4,8 +4,8 @@
 kernel (SURVEY.md §7 native-components policy).  The shared library is
 compiled on first use with the system g++ and cached next to the source;
 callers fall back to the pure-Python path when the build or load fails,
-and `load_error()` says why (measured paths — chip_smoke.py, bench.py —
-refuse to run on the fallback).
+and `load_error()` says why (measured paths — chip_smoke.py,
+benchmarks/run.py — refuse to run on the fallback).
 """
 
 from __future__ import annotations

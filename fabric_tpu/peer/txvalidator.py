@@ -249,7 +249,7 @@ class TxValidator:
         `metrics` (a common.metrics.ValidateMetrics) adds per-stage
         collect/verify_wait/policy histograms on /metrics; the
         cumulative splits are always kept in validate_stage_seconds
-        (bench.py reads them)."""
+        (benchmarks/run.py reads them)."""
         self.channel_id = channel_id
         self._ledger = ledger
         self._bundle = bundle
@@ -514,8 +514,8 @@ class TxValidator:
         # endorsement policy: each endorsement signs prp_bytes || endorser.
         # Digests are precomputed so policy prepare hits the plan cache
         # (and the device path skips host-side re-hashing) — and they go
-        # through the CSP seam as ONE hash_batch per tx, so a device
-        # provider batches them instead of the host hashing per lane.
+        # through the CSP seam (fabriclint's csp-seam rule) as ONE
+        # hash_batch per tx.
         msgs = [prp_bytes + e.endorser for e in cap.action.endorsements]
         digests = self._csp.hash_batch(msgs)
         p.signed = [

@@ -74,8 +74,8 @@ class RwsetFootprint:
 
 
 def parse_footprint(rwset_bytes: bytes | None) -> RwsetFootprint:
-    # Hot path: one call per tx per block (profile_host shows this
-    # function as the largest single collect cost), so the common shape
+    # Hot path: one call per tx per block (the largest single collect
+    # cost when it was profiled), so the common shape
     # — one namespace, a few public writes, no collections — runs on
     # list comprehensions and batch extends, not per-item loop bodies.
     touched: list = []

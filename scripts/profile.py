@@ -4,7 +4,7 @@ bench-style JSON line.
 
 Drives the faultfuzz commit workload (endorsed blocks -> validate ->
 commit over a fresh on-disk ledger) under an armed tracelens recorder
-and the profscope sampler, then prints one line in the bench.py shape:
+and the profscope sampler, then prints one line of JSON:
 the top hot frames (collapsed-stack leaf attribution), per-role lock
 wait totals, per-span CPU attribution (self_cpu_ms), workpool
 queue-wait vs run-time, and the speedscope artifact path.

@@ -10,7 +10,9 @@ import subprocess
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-CHILD_ONLY = {"leg_a_child"}  # the one function that may import jax
+# what only leg A's child runs: it alone may import jax, and its world
+# comes from `benchmarks/`, which the parent never puts on its path
+CHILD_ONLY = {"leg_a_child", "leg_a_world"}
 
 
 def _parent_side_imports() -> list[str]:
@@ -156,6 +158,39 @@ def test_native_load_error_is_recorded(monkeypatch):
     assert not native.available()
     assert "no such compiler" in native.load_error()
     assert native.marshal_batch(b"", b"", b"", b"", [0]) is None
+
+
+def test_the_smokes_x509_blocks_are_the_benchmarks_world_through_the_validator(tmp_path):
+    """Leg A's first step on the chip, here on the host at a small
+    size: the function the smoke builds its blocks with
+    (`benchlib.generator` over the deployment of `X509_CONFIG`) through
+    TxValidator over SWCSP into a ledger gives exactly the planted
+    flags (corrupted creator and endorsement signatures, conflicting
+    pairs) and the generator's expected state."""
+    import chip_smoke
+    from fabric_tpu.common.channelconfig import bundle_from_genesis
+    from fabric_tpu.csp import SWCSP
+    from fabric_tpu.ledger import LedgerProvider
+    from fabric_tpu.peer.committer import Committer
+    from fabric_tpu.peer.txvalidator import TxValidator
+    from fabric_tpu.protos.common import common_pb2
+
+    world = chip_smoke.leg_a_world(30, 2, block_txs=12)
+    assert [len(row) for row in world.planted] == [12, 12]
+    assert all(len(set(row)) == 4 for row in world.planted)  # every kind planted
+    sw = SWCSP()
+    ledger = LedgerProvider(str(tmp_path)).create(world.genesis)
+    committer = Committer(
+        TxValidator(world.channel, ledger,
+                    bundle_from_genesis(world.genesis, sw), sw), ledger)
+    flags = [
+        list(committer.store_block(common_pb2.Block.FromString(raw)))
+        for raw in world.blocks
+    ]
+    assert flags == world.planted
+    assert dict(ledger.get_state_range(world.namespaces[0], "", "")) == {
+        key: value for (_, key), (value, _) in world.expected_state().items()
+    }
 
 
 def test_the_smokes_idemix_block_is_the_benchmarks_world_through_the_validator(tmp_path):

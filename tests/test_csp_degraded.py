@@ -244,60 +244,6 @@ def test_dispatch_failure_counts_toward_breaker():
     assert sum(csp.lane_tally().values()) == len(items)
 
 
-def test_hash_batch_routes_host_while_open_and_on_failure():
-    """hash_batch: an injected device-hash failure falls back to
-    hashlib with correct digests; while the breaker is open the device
-    is not touched at all."""
-    csp = _csp(threshold=1)
-    msgs = [b"m%d" % i for i in range(48)]
-    want = [hashlib.sha256(m).digest() for m in msgs]
-    try:
-        with faultline.use_plan({"faults": [
-            {"point": "tpu.hash", "action": "raise",
-             "error": "DeviceUnavailable", "nth": 1},
-            # a second rule would fire if hash_batch touched the device
-            # again while open — it must not
-            {"point": "tpu.hash", "action": "raise",
-             "error": "RuntimeError", "nth": 2},
-        ]}):
-            assert csp.hash_batch(msgs) == want  # failure -> fallback
-            assert csp.breaker.open  # threshold 1
-            assert csp.hash_batch(msgs) == want  # host route, no device
-            assert len(faultline.trips()) == 1  # rule 2 never fired
-    finally:
-        csp.close()
-
-
-def test_hash_only_traffic_can_close_breaker():
-    """A breaker opened by hash-path failures must be closable by
-    hash-only traffic too: the gate runs the recovery probe on held
-    hash calls, so a hash-dominated node (snapshot exports) does not
-    stay on the host path forever after a transient device blip."""
-    prov = PrometheusProvider()
-    metrics = CSPMetrics(prov)
-    csp = _csp(metrics=metrics, threshold=1, probe_every=2)
-    msgs = [b"h%d" % i for i in range(32)]
-    want = [hashlib.sha256(m).digest() for m in msgs]
-    try:
-        with faultline.use_plan({"faults": [
-            {"point": "tpu.hash", "action": "raise",
-             "error": "DeviceUnavailable", "count": 1},
-        ]}):
-            assert csp.hash_batch(msgs) == want  # device dies -> opens
-            assert csp.breaker.open
-            assert csp.hash_batch(msgs) == want  # held 1: host route
-            assert csp.breaker.open
-            # held 2: probe due -> device recovered -> breaker closes
-            # and this call already hashes on the device again
-            assert csp.hash_batch(msgs) == want
-            assert not csp.breaker.open
-        assert 'csp_tpu_breaker_probes_total{result="ok"} 1' in (
-            prov.registry.expose()
-        )
-    finally:
-        csp.close()
-
-
 def test_probe_vector_is_device_valid():
     """The hardcoded probe vector really verifies on the device path —
     if it rotted, every probe would fail and an open breaker could

@@ -13,14 +13,31 @@ def test_factory_selects_tpu():
     init_factories("sw", force=True)
 
 
-def test_hash_batch_parity():
+def _hash_msgs():
     rng = random.Random(3)
-    csp = TPUCSP(min_device_batch=1)
     msgs = [bytes(rng.randrange(256) for _ in range(rng.randrange(0, 200))) for _ in range(37)]
-    msgs += [b"", b"a" * 55, b"a" * 56, b"a" * 64, b"a" * 119, b"a" * 120]
+    return msgs + [b"", b"a" * 55, b"a" * 56, b"a" * 64, b"a" * 119, b"a" * 120]
+
+
+def test_hash_batch_parity():
+    csp = TPUCSP(min_device_batch=1)
+    msgs = _hash_msgs()
     got = csp.hash_batch(msgs)
     want = [hashlib.sha256(m).digest() for m in msgs]
     assert got == want
+
+
+def test_sha256_kernel_parity():
+    """The device SHA-256 kernel has no product caller (hash_batch is
+    hashlib at every size); this is the guard that keeps it correct
+    for the dryrun's compile check, on the padding edges above."""
+    from fabric_tpu.csp.tpu import sha256 as dev_sha
+
+    msgs = _hash_msgs()
+    want = [hashlib.sha256(m).digest() for m in msgs]
+    assert dev_sha.sha256_batch(msgs) == want
+    # a static width wider than the longest message needs (bucketing)
+    assert dev_sha.sha256_batch(msgs, n_blocks=8) == want
 
 
 def test_verify_batch_parity_with_tampering():

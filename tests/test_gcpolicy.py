@@ -298,13 +298,18 @@ def test_the_frozen_heap_shows_on_the_process_metrics():
     pm = ProcessMetrics(prov)
     try:
         gc.freeze()
+        before = gc.get_freeze_count()
         pm.collect()
+        after = gc.get_freeze_count()
         text = prov.registry.expose()
         line = next(
             ln for ln in text.splitlines()
             if ln.startswith("process_gc_frozen_objects")
         )
-        assert float(line.split()[-1]) == gc.get_freeze_count() > 0
+        # a frozen object can still die by its reference count (another
+        # thread of the worker, a temporary of the call itself), so the
+        # count may fall between two reads and never rises
+        assert before >= float(line.split()[-1]) >= after > 0
     finally:
         gc.unfreeze()
         tracing._gc_keep = keep

@@ -498,8 +498,8 @@ def test_commit_stage_breakdown_and_metrics(tmp_path):
     ledger = provider.open("gc")
     for n in range(2):
         ledger.commit(_write_block(ledger, n, [("cc", f"k{n}", b"v")]))
-    # every pipeline stage accumulated wall time (bench.py's JSON line
-    # reports exactly these)
+    # every pipeline stage accumulated wall time (commit_ms_per_block of
+    # benchmarks/run.py sums exactly these)
     assert set(CommitMetrics.STAGES) <= set(ledger.commit_stage_seconds)
     assert all(v >= 0 for v in ledger.commit_stage_seconds.values())
     exposed = prov.registry.expose()

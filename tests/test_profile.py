@@ -152,8 +152,8 @@ def test_sampler_folds_spinning_thread_into_collapsed_stacks():
 
 def test_span_self_cpu_attribution_joins_critical_path():
     """Samples landing inside a live tracelens span are charged to it:
-    self_cpu_ms keys are span names that also appear in the trace's
-    critical path — busy-CPU read next to wall-gating per stage."""
+    self_cpu_ms keys are span names that also appear among the trace's
+    own stage events — busy-CPU read next to wall time per stage."""
     stop = threading.Event()
     started = threading.Event()
 
@@ -184,9 +184,12 @@ def test_span_self_cpu_attribution_joins_critical_path():
     assert row["wall_samples"] >= 1
     assert row["cpu_samples"] >= 1  # fresh frames each burn() => on-CPU
     assert row["self_cpu_ms"] == od["self_cpu_ms"]["hot.stage"]
-    # the join: every CPU-attributed span is a critical-path stage
-    cp = tracing.critical_path_ms(trace_doc["traceEvents"])
-    assert set(od["self_cpu_ms"]) <= set(cp)
+    # the join: every CPU-attributed span is a stage event of the trace
+    stages = {
+        ev["name"] for ev in trace_doc["traceEvents"]
+        if ev.get("cat") == "stage"
+    }
+    assert set(od["self_cpu_ms"]) <= stages
 
 
 # -- lock contention + workpool attribution ----------------------------------

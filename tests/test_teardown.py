@@ -5,12 +5,13 @@ passed every assertion, printed success, then ABORTED at interpreter
 teardown — a `tpu-flush-waiter` daemon thread was still inside an XLA
 kernel when Python exited, the runtime pthread-killed it, the forced
 unwind crossed XLA's catch(...), and glibc raised "FATAL: exception not
-rethrown".  bench.py papered the same abort over with os._exit(0).
+rethrown".  The old bench.py papered the same abort over with
+os._exit(0).
 
 The fix is a lifecycle, not a bigger hammer: TPUCSP.drain() joins every
-in-flight flush waiter (cancelling their EWMA feedback), bench.py and
-the dryrun call it on the way out, and threadwatch asserts the worker
-ledger is empty.  These tests pin the property: the dryrun subprocess
+in-flight flush waiter (cancelling their EWMA feedback), every entry
+point calls it on the way out (`node.quiesce`), and threadwatch asserts
+the worker ledger is empty.  These tests pin the property: the dryrun subprocess
 must exit rc=0 through NORMAL teardown, with no os._exit anywhere on
 the entry paths and nothing left in the threadwatch ledger."""
 
@@ -31,7 +32,7 @@ def test_no_os_exit_in_entry_points():
     # the next lifecycle regression instead of failing loudly
     import ast
 
-    for rel in ("bench.py", "__graft_entry__.py", "chip_smoke.py"):
+    for rel in ("__graft_entry__.py", "chip_smoke.py"):
         with open(os.path.join(ROOT, rel), "r", encoding="utf-8") as f:
             tree = ast.parse(f.read())
         calls = [

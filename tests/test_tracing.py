@@ -194,17 +194,6 @@ def test_virtual_clock_traces_are_byte_identical():
     assert a["dur"] == 10_000  # exactly the virtual 10ms, in µs
 
 
-def test_critical_path_over_stage_spans():
-    with clockskew.use_virtual(clockskew.VirtualClock(start=500.0)):
-        with tracing.scope() as rec:
-            _clocked_workload()
-            doc = tracing.export(rec)
-    cp = tracing.critical_path_ms(doc["traceEvents"])
-    # sequential stages: each contributes its full duration; the
-    # "root" span is cat=pipeline and must not appear
-    assert cp == {"stage.a": pytest.approx(10.0), "stage.b": pytest.approx(20.0)}
-
-
 # -- /traces endpoint --------------------------------------------------------
 
 
