@@ -568,6 +568,18 @@ class CSPMetrics:
                  "means forged credentials are being submitted.",
             statsd_format="%{stage}",
         ))
+        self.idemix_msm_terms = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="idemix",
+            name="msm_terms_total",
+            help="Point-and-scalar terms of the weighted G1 sums behind "
+                 "those checks, labeled by engine: bucket (one "
+                 "multi-scalar multiplication a sum) or window (a "
+                 "scalar multiplication a term: sums under the native "
+                 "threshold).  A block of n sound proofs adds 2n to "
+                 "bucket.",
+            statsd_format="%{engine}",
+        ))
         self.breaker_state.set(0)
 
 

@@ -74,6 +74,11 @@ FALLBACK_REASONS = (
 # csp_idemix_pairing_checks_total): a batch's combined check; after it
 # failed, a subset's in the bisection, or one item's own
 PAIRING_STAGES = ("combined", "subset", "item")
+# how the terms of a batch's weighted G1 sums were summed (the `engine`
+# label of csp_idemix_msm_terms_total): by the bucket method, one
+# multi-scalar multiplication a sum, or a windowed scalar multiplication
+# a term (a sum under the native threshold)
+MSM_ENGINES = ("bucket", "window")
 _RECENT_BATCHES = 16384
 
 
@@ -177,6 +182,7 @@ class IdemixCSP:
         self._fallbacks: dict = {}
         self._batches: dict = {}
         self._pairing_checks = dict.fromkeys(PAIRING_STAGES, 0)
+        self._msm_terms = dict.fromkeys(MSM_ENGINES, 0)
         self._recent: collections.deque = collections.deque(
             maxlen=_RECENT_BATCHES
         )
@@ -186,23 +192,26 @@ class IdemixCSP:
     def set_metrics(self, metrics) -> None:
         """Bind a common.metrics.CSPMetrics: csp_idemix_items_total,
         csp_idemix_fallbacks_total, csp_idemix_batches_total,
-        csp_idemix_pairing_checks_total."""
+        csp_idemix_pairing_checks_total, csp_idemix_msm_terms_total."""
         self._metrics = metrics
 
     def tally(self) -> dict:
         """From process start: `items` by "kind.path" (kind proof|nym,
         path pallas|xla|host), `fallbacks` by reason (FALLBACK_REASONS),
         `batches` by the bucket (padded lanes) a device launch ran at,
-        `pairing_checks` by stage (PAIRING_STAGES).  A peer whose Idemix
-        items all went through the Pallas kernel shows only
-        `proof.pallas` and `nym.pallas` and no fallback; one that has
-        met no forged credential shows only `combined` checks, one a
-        batch of proofs."""
+        `pairing_checks` by stage (PAIRING_STAGES), `msm_terms` by
+        engine (MSM_ENGINES).  A peer whose Idemix items all went
+        through the Pallas kernel shows only `proof.pallas` and
+        `nym.pallas` and no fallback; one that has met no forged
+        credential shows only `combined` checks, one a batch of proofs,
+        and its blocks' weighted sums under `bucket`, two terms a
+        surviving proof."""
         with self._lock:
             return {"items": dict(self._items),
                     "fallbacks": dict(self._fallbacks),
                     "batches": dict(self._batches),
-                    "pairing_checks": dict(self._pairing_checks)}
+                    "pairing_checks": dict(self._pairing_checks),
+                    "msm_terms": dict(self._msm_terms)}
 
     def recent_batches(self) -> list:
         """The last batches in order, each {"proofs", "nyms", "path",
@@ -217,20 +226,26 @@ class IdemixCSP:
             self._metrics.idemix_fallbacks.With("reason", reason).add()
 
     def _note_pairing(self, stats: dict) -> None:
-        """Count a batch's pairing checks (`signature._pairing_mask`'s
-        `stats`) by stage."""
+        """Count a batch's pairing checks by stage and the terms of its
+        weighted sums by engine (`signature._pairing_mask`'s `stats`)."""
         subset = stats.get("subset_checks", 0)
         item = stats.get("item_checks", 0)
         spent = {"combined": stats.get("checks", 0) - subset - item,
                  "subset": subset, "item": item}
+        summed = {"bucket": stats.get("msm_terms", 0),
+                  "window": stats.get("msm_window_terms", 0)}
         with self._lock:
             for stage, n in spent.items():
                 self._pairing_checks[stage] += n
+            for engine, n in summed.items():
+                self._msm_terms[engine] += n
         if self._metrics is not None:
             for stage, n in spent.items():
                 self._metrics.idemix_pairing_checks.With(
                     "stage", stage
                 ).add(n)
+            for engine, n in summed.items():
+                self._metrics.idemix_msm_terms.With("engine", engine).add(n)
 
     def _seal(self, items, mask, path: str, lanes: int, bucket: int) -> list:
         """Count a batch's items by kind and path; the mask as sealed."""
@@ -549,5 +564,5 @@ def for_csp(csp) -> IdemixCSP:
 
 __all__ = [
     "IdemixCSP", "IdemixVerifyItem", "IdemixNymItem", "FALLBACK_REASONS",
-    "PAIRING_STAGES", "for_csp",
+    "PAIRING_STAGES", "MSM_ENGINES", "for_csp",
 ]

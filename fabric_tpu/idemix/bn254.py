@@ -361,8 +361,10 @@ def g1_msm(terms):
     The verification hot path (Schnorr commitment recomputation, RLC
     accumulation in batched verify) — served by the native Montgomery
     implementation (native/bn254.cc) when available, else the affine
-    Python ladder.  The reference does the same per-base loop in AMCL
-    (fabric-amcl G1mul + add)."""
+    Python ladder.  The native sum picks its method from the number of
+    terms: the bucket method from `g1_msm_engine`'s threshold up, a
+    scalar multiplication a term below it, as the reference's per-base
+    loop in AMCL (fabric-amcl G1mul + add)."""
     nat = _native()
     if nat is not None:
         return nat.bn254_msm([t[0] for t in terms], [t[1] for t in terms])
@@ -372,6 +374,26 @@ def g1_msm(terms):
             continue
         out = g1_add(out, _g1_mul_py(pt, k))
     return out
+
+
+def g1_msm_sets(point_lists, scalars):
+    """[sum_i scalars[i]*points[i] for points in point_lists]: several
+    sums under one list of scalars (the two sides of a weighted pairing
+    check), as one native call."""
+    nat = _native()
+    if nat is not None:
+        return nat.bn254_msm_sets(point_lists, scalars)
+    return [g1_msm(list(zip(points, scalars))) for points in point_lists]
+
+
+def g1_msm_engine(n: int) -> str:
+    """How `g1_msm` / `g1_msm_sets` sum n terms: "bucket" (the native
+    bucket method, from its threshold up) or "window" (a windowed
+    scalar multiplication a term; the pure-Python ladder counts here)."""
+    nat = _native()
+    if nat is not None and n >= nat.bn254_msm_bucket_threshold():
+        return "bucket"
+    return "window"
 
 
 # --- G2 (affine over Fp2, on the twist) -------------------------------------

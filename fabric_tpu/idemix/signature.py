@@ -38,12 +38,14 @@ reference spends two FP256BN.Ate calls per signature
 (signature.go:290-291); the batch spends two per *block*.  Where the
 product is not 1 some item is forged, and `_isolate` finds which by
 bisection over the same weighted sums: two pairings a subset, 7 to 14
-subsets for one forgery among 125.
+subsets for one forgery among 125.  Every weighted sum, the batch's and
+a subset's, is ONE multi-scalar multiplication (`_weighted_sums`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import time
 
 from fabric_tpu.idemix import bn254 as bn
 from fabric_tpu.idemix import schnorr
@@ -310,6 +312,21 @@ def verify_batch(
     return _pairing_mask(sigs, ok, ipk, rng, stats=stats)
 
 
+def _weighted_sums(a_primes, a_bars, weights, seen: dict):
+    """(sum_i r_i*A'_i, sum_i r_i*Abar_i): the two sides of a weighted
+    check as one multi-scalar multiplication call (`bn.g1_msm_sets`:
+    the bucket method from its threshold up).  `seen` learns the terms
+    summed, by engine, and the wall spent."""
+    begun = time.perf_counter()
+    sums = bn.g1_msm_sets([a_primes, a_bars], weights)
+    seen["msm_ms"] += (time.perf_counter() - begun) * 1e3
+    engine = bn.g1_msm_engine(len(weights))
+    seen["msm_terms" if engine == "bucket" else "msm_window_terms"] += (
+        2 * len(weights)
+    )
+    return sums
+
+
 def _pairing_mask(sigs, ok: list[bool], ipk, rng=None,
                   stats: dict | None = None) -> list[bool]:
     """Combined two-pairing check over the Schnorr-surviving items with
@@ -319,33 +336,38 @@ def _pairing_mask(sigs, ok: list[bool], ipk, rng=None,
     a check an item took 125), and the result stays a per-signature
     mask.  `stats`, if given, learns `combined_ok`, how many items were
     `isolated` (the survivors of a batch whose combined check failed),
-    and the pairing `checks` of the batch (the combined one included: 1
-    on the passing path), `subset_checks` and `item_checks` among them."""
+    the pairing `checks` of the batch (the combined one included: 1
+    on the passing path), `subset_checks` and `item_checks` among them,
+    and what the weighted sums cost: `msm_terms` (point-and-scalar
+    terms, both sides, summed by the bucket method; 0 where every sum
+    was under its threshold), `msm_window_terms` (those summed a
+    multiplication a term) and `msm_ms` (the wall of the sums)."""
     live = [i for i, v in enumerate(ok) if v]
     seen = {"combined_ok": True, "isolated": 0, "checks": 0,
-            "subset_checks": 0, "item_checks": 0}
+            "subset_checks": 0, "item_checks": 0,
+            "msm_terms": 0, "msm_window_terms": 0, "msm_ms": 0.0}
     if live:
         weights = [bn.rand_zr(rng) for _ in live]
         a_primes = [sigs[i].a_prime for i in live]
         a_bars = [sigs[i].a_bar for i in live]
-        acc_ap = bn.g1_msm(list(zip(a_primes, weights)))
-        acc_ab = bn.g1_msm(list(zip(a_bars, weights)))
-        if not _balanced(acc_ap, acc_ab, ipk):
+        sums = _weighted_sums(a_primes, a_bars, weights, seen)
+        if not _balanced(*sums, ipk):
             # Rare path: at least one forged pairing.
             seen.update(combined_ok=False, isolated=len(live))
-            for i, v in zip(live, _isolate(a_primes, a_bars, weights, ipk,
-                                           seen)):
+            for i, v in zip(live, _isolate(a_primes, a_bars, weights, sums,
+                                           ipk, seen)):
                 ok[i] = v
         seen["checks"] = 1 + seen["subset_checks"] + seen["item_checks"]
+        seen["msm_ms"] = round(seen["msm_ms"], 3)
     if stats is not None:
         stats.update(seen)
     return ok
 
 
-def _isolate(a_primes, a_bars, weights, ipk, seen: dict) -> list[bool]:
-    """The verdicts of n items whose weighted combined check has failed,
-    each the one its own pairing check gives; counts what it spends into
-    `seen`.
+def _isolate(a_primes, a_bars, weights, sums, ipk, seen: dict) -> list[bool]:
+    """The verdicts of n items whose weighted combined check has failed
+    (`sums`: its two sides), each the one its own pairing check gives;
+    counts what it spends into `seen`.
 
     Bisection over the weights the combined check drew.  A subset's
     check balances its sums of r_i*A'_i and r_i*Abar_i.  A subset that
@@ -358,6 +380,11 @@ def _isolate(a_primes, a_bars, weights, ipk, seen: dict) -> list[bool]:
     in a group of prime order, so its weighted check fails exactly when
     its own does.  Nothing is sampled, and no half is accepted that was
     neither checked nor inferred.
+
+    A left half's sums are one multi-scalar multiplication over its
+    terms, the right half's the parent's less the left's: one forgery
+    costs about n terms a side over the whole bisection (n/2 + n/4 +
+    ...), and the sums of one level never more than n/2 a side.
 
     One forgery costs at most two checks a level, 2*ceil(log2 n).  So
     that a batch full of forgeries costs little more than a check an
@@ -373,37 +400,32 @@ def _isolate(a_primes, a_bars, weights, ipk, seen: dict) -> list[bool]:
 
     if n <= 3:
         return [item(j) for j in range(n)]
-    # prefix sums of the weighted points: a range's sum is one subtraction
-    sum_ap, sum_ab = [None], [None]
-    for w_ap, w_ab in zip(bn.g1_mul_many(a_primes, weights),
-                          bn.g1_mul_many(a_bars, weights)):
-        sum_ap.append(bn.g1_add(sum_ap[-1], w_ap))
-        sum_ab.append(bn.g1_add(sum_ab[-1], w_ab))
 
-    def subset(lo, hi):
+    def subset(sides):
         seen["subset_checks"] += 1
-        return _balanced(
-            bn.g1_add(sum_ap[hi], bn.g1_neg(sum_ap[lo])),
-            bn.g1_add(sum_ab[hi], bn.g1_neg(sum_ab[lo])), ipk,
-        )
+        return _balanced(*sides, ipk)
 
     verdicts = [True] * n
-    # (lo, hi, forged): a range whose verdicts are owed; `forged` when
-    # it is known to hold a forgery
-    owed = [(0, n, True)]
+    # (lo, hi, sums, forged): a range whose verdicts are owed, with its
+    # two weighted sums; `forged` when it is known to hold a forgery
+    owed = [(0, n, sums, True)]
     while owed:
-        lo, hi, forged = owed.pop()
+        lo, hi, sides, forged = owed.pop()
         if hi - lo == 1:
             verdicts[lo] = not forged and item(lo)
         elif seen["subset_checks"] >= n // 4:
             for j in range(lo, hi):
                 verdicts[j] = item(j)
-        elif forged or not subset(lo, hi):
+        elif forged or not subset(sides):
             mid = (lo + hi) // 2
-            if subset(lo, mid):
-                owed.append((mid, hi, True))
+            left = _weighted_sums(a_primes[lo:mid], a_bars[lo:mid],
+                                  weights[lo:mid], seen)
+            right = [bn.g1_add(whole, bn.g1_neg(part))
+                     for whole, part in zip(sides, left)]
+            if subset(left):
+                owed.append((mid, hi, right, True))
             else:
-                owed += [(mid, hi, False), (lo, mid, True)]
+                owed += [(mid, hi, right, False), (lo, mid, left, True)]
     return verdicts
 
 

@@ -80,6 +80,13 @@ def _load():
             msm = lib.bn254_g1_msm
             msm.restype = ctypes.c_int
             msm.argtypes = [ctypes.c_int] + [ctypes.c_char_p] * 3 + [u8p, u8p]
+            ms = lib.bn254_g1_msm_sets
+            ms.restype = ctypes.c_int
+            ms.argtypes = (
+                [ctypes.c_int] * 2 + [ctypes.c_char_p] * 3 + [u8p] * 3
+            )
+            lib.bn254_g1_msm_bucket_threshold.restype = ctypes.c_int
+            lib.bn254_g1_msm_bucket_threshold.argtypes = []
             mm = lib.bn254_g1_mul_many
             mm.restype = ctypes.c_int
             mm.argtypes = [ctypes.c_int] + [ctypes.c_char_p] * 3 + [u8p] * 3
@@ -267,66 +274,93 @@ def collect_block(env_bytes: bytes, env_off: np.ndarray,
         max_endos *= 4  # undersized endorsement arrays: retry larger
 
 
-def bn254_msm(points, scalars) -> tuple[int, int] | None:
-    """sum_i scalars[i] * points[i] over BN254 G1 (affine int coords;
-    None encodes a point at infinity, on input and output).  Raises
-    RuntimeError when the native library is unavailable — gate on
-    available() (idemix.bn254._native does)."""
+def _pack_g1(points) -> tuple[bytes, bytes]:
+    """32-byte big-endian x and y of each point, one after another;
+    None (infinity) as zeros."""
+    xs = bytearray(32 * len(points))
+    ys = bytearray(32 * len(points))
+    for i, pt in enumerate(points):
+        if pt is not None:
+            xs[32 * i:32 * i + 32] = pt[0].to_bytes(32, "big")
+            ys[32 * i:32 * i + 32] = pt[1].to_bytes(32, "big")
+    return bytes(xs), bytes(ys)
+
+
+def _pack_zr(scalars) -> bytes:
+    return b"".join((k % _BN254_R).to_bytes(32, "big") for k in scalars)
+
+
+def _unpack_g1(ox, oy, inf) -> list[tuple[int, int] | None]:
+    """The affine points of a native call's output buffers."""
+    b_ox, b_oy = ox.tobytes(), oy.tobytes()
+    return [
+        None if inf[i] else (
+            int.from_bytes(b_ox[32 * i:32 * i + 32], "big"),
+            int.from_bytes(b_oy[32 * i:32 * i + 32], "big"),
+        )
+        for i in range(len(inf))
+    ]
+
+
+def _bn254_lib():
     lib = _load()
     if lib is None:
         raise RuntimeError("native library unavailable")
-    n = len(points)
-    xs = bytearray(32 * n)
-    ys = bytearray(32 * n)
-    ss = bytearray(32 * n)
-    for i, (pt, k) in enumerate(zip(points, scalars)):
-        if pt is None:
-            continue  # (0,0) = infinity
-        xs[32 * i:32 * i + 32] = pt[0].to_bytes(32, "big")
-        ys[32 * i:32 * i + 32] = pt[1].to_bytes(32, "big")
-        ss[32 * i:32 * i + 32] = (k % _BN254_R).to_bytes(32, "big")
+    return lib
+
+
+def bn254_msm(points, scalars) -> tuple[int, int] | None:
+    """sum_i scalars[i] * points[i] over BN254 G1 (affine int coords;
+    None encodes a point at infinity, on input and output): by the
+    bucket method from `bn254_msm_bucket_threshold()` terms up, term by
+    term below.  Raises RuntimeError when the native library is
+    unavailable — gate on available() (idemix.bn254._native does)."""
+    lib = _bn254_lib()
+    n = min(len(points), len(scalars))
+    xs, ys = _pack_g1(points[:n])
     ox = np.zeros(32, np.uint8)
     oy = np.zeros(32, np.uint8)
-    rc = lib.bn254_g1_msm(n, bytes(xs), bytes(ys), bytes(ss), ox, oy)
-    if rc:
-        return None
-    return (
-        int.from_bytes(ox.tobytes(), "big"),
-        int.from_bytes(oy.tobytes(), "big"),
-    )
+    rc = lib.bn254_g1_msm(n, xs, ys, _pack_zr(scalars[:n]), ox, oy)
+    return _unpack_g1(ox, oy, [rc])[0]
+
+
+def bn254_msm_sets(point_lists, scalars) -> list[tuple[int, int] | None]:
+    """[sum_i scalars[i] * points[i] for points in point_lists]: several
+    sums under ONE list of scalars (every list as long as it) in one
+    native call, which recodes the scalars once and returns to the
+    interpreter once.  Raises RuntimeError when the native library is
+    unavailable."""
+    lib = _bn254_lib()
+    n, sets = len(scalars), len(point_lists)
+    if any(len(points) != n for points in point_lists):
+        raise ValueError("a list of points for every list of scalars")
+    xs, ys = _pack_g1([pt for points in point_lists for pt in points])
+    ox = np.zeros(32 * sets, np.uint8)
+    oy = np.zeros(32 * sets, np.uint8)
+    inf = np.zeros(sets, np.uint8)
+    lib.bn254_g1_msm_sets(n, sets, xs, ys, _pack_zr(scalars), ox, oy, inf)
+    return _unpack_g1(ox, oy, inf)
+
+
+def bn254_msm_bucket_threshold() -> int:
+    """The term count from which `bn254_msm` / `bn254_msm_sets` form a
+    sum by the bucket method and not term by term (a constant of
+    bn254.cc).  Raises RuntimeError when the native library is
+    unavailable."""
+    return _bn254_lib().bn254_g1_msm_bucket_threshold()
 
 
 def bn254_mul_many(points, scalars) -> list[tuple[int, int] | None]:
     """Independent scalars[i] * points[i]; one shared field inversion.
     Raises RuntimeError when the native library is unavailable."""
-    lib = _load()
-    if lib is None:
-        raise RuntimeError("native library unavailable")
-    n = len(points)
-    xs = bytearray(32 * n)
-    ys = bytearray(32 * n)
-    ss = bytearray(32 * n)
-    for i, (pt, k) in enumerate(zip(points, scalars)):
-        if pt is None:
-            continue
-        xs[32 * i:32 * i + 32] = pt[0].to_bytes(32, "big")
-        ys[32 * i:32 * i + 32] = pt[1].to_bytes(32, "big")
-        ss[32 * i:32 * i + 32] = (k % _BN254_R).to_bytes(32, "big")
+    lib = _bn254_lib()
+    n = min(len(points), len(scalars))
+    xs, ys = _pack_g1(points[:n])
     ox = np.zeros(32 * n, np.uint8)
     oy = np.zeros(32 * n, np.uint8)
     inf = np.zeros(n, np.uint8)
-    lib.bn254_g1_mul_many(n, bytes(xs), bytes(ys), bytes(ss), ox, oy, inf)
-    out: list = []
-    b_ox, b_oy = ox.tobytes(), oy.tobytes()
-    for i in range(n):
-        if inf[i]:
-            out.append(None)
-        else:
-            out.append((
-                int.from_bytes(b_ox[32 * i:32 * i + 32], "big"),
-                int.from_bytes(b_oy[32 * i:32 * i + 32], "big"),
-            ))
-    return out
+    lib.bn254_g1_mul_many(n, xs, ys, _pack_zr(scalars[:n]), ox, oy, inf)
+    return _unpack_g1(ox, oy, inf)
 
 
 _BN254_R = 0x30644e72e131a029b85045b68181585d2833e84879b9709143e1f593f0000001
@@ -356,5 +390,6 @@ def bn254_pairing_check(pairs) -> bool:
 
 __all__ = [
     "available", "load_error", "marshal_batch", "collect_block", "bn254_msm",
-    "bn254_mul_many", "bn254_pairing_check", "ecdsa_verify_host",
+    "bn254_msm_sets", "bn254_msm_bucket_threshold", "bn254_mul_many",
+    "bn254_pairing_check", "ecdsa_verify_host",
 ]

@@ -15,6 +15,7 @@
 
 #include <cstdint>
 #include <cstring>
+#include <vector>
 
 #include "fp254.h"
 
@@ -30,6 +31,7 @@ using fp254::fp_dbl;
 using fp254::fp_inv;
 using fp254::fp_is_zero;
 using fp254::fp_mul;
+using fp254::fp_neg;
 using fp254::fp_sqr;
 using fp254::from_mont;
 using fp254::load_fp_be;
@@ -176,38 +178,220 @@ void load_point(const u8* x_be, const u8* y_be, G1* out) {
   memcpy(out->z.v, ONE_M, sizeof(ONE_M));
 }
 
+// p + q where q is affine (q.z is ONE_M, as load_point leaves it):
+// madd-2007-bl, 7M + 4S where g1_add spends 11M + 5S.  P + P, P + (-P)
+// and infinity on either side come out as g1_add gives them.
+void g1_add_affine(const G1& p, const G1& q, G1* out) {
+  if (q.inf) {
+    *out = p;
+    return;
+  }
+  if (p.inf) {
+    *out = q;
+    return;
+  }
+  Fp z1z1, u2, s2, h, hh, i, j, rr, v, t;
+  fp_sqr(p.z, &z1z1);
+  fp_mul(q.x, z1z1, &u2);
+  fp_mul(q.y, p.z, &t);
+  fp_mul(t, z1z1, &s2);
+  fp_sub(u2, p.x, &h);
+  fp_sub(s2, p.y, &rr);
+  if (is_zero(h)) {
+    if (is_zero(rr)) {
+      g1_dbl(q, out);
+      return;
+    }
+    out->inf = true;
+    return;
+  }
+  fp_sqr(h, &hh);
+  fp_dbl(hh, &i);
+  fp_dbl(i, &i);                      // I = 4 HH
+  fp_mul(h, i, &j);
+  fp_dbl(rr, &rr);
+  fp_mul(p.x, i, &v);
+  G1 r;
+  r.inf = false;
+  fp_sqr(rr, &r.x);
+  fp_sub(r.x, j, &r.x);
+  fp_sub(r.x, v, &r.x);
+  fp_sub(r.x, v, &r.x);               // X3 = r^2 - J - 2V
+  fp_sub(v, r.x, &t);
+  fp_mul(rr, t, &r.y);
+  Fp y1j;
+  fp_mul(p.y, j, &y1j);
+  fp_dbl(y1j, &y1j);
+  fp_sub(r.y, y1j, &r.y);             // Y3 = r(V - X3) - 2 Y1 J
+  fp_add(p.z, h, &t);
+  fp_sqr(t, &t);
+  fp_sub(t, z1z1, &t);
+  fp_sub(t, hh, &r.z);                // Z3 = (Z1 + H)^2 - Z1Z1 - HH
+  *out = r;
+}
+
+// ---------------------------------------------------------------------------
+// Multi-scalar multiplication.
+// ---------------------------------------------------------------------------
+
+// A sum of this many terms or more is formed by the bucket method, a
+// shorter one term by term (g1_mul, then g1_add).  Both constants are
+// measured (PERF.md, PR 31: on the sandbox's and the chip's host the
+// two methods cross at 3 to 4 terms, few buckets of a window being in
+// use at few terms; 4-bit windows are level with 5-bit ones at the 127
+// terms of a block's combined check and ahead at every count below).
+constexpr int kBucketThreshold = 4;
+constexpr int kBucketWindow = 4;                    // bits a digit
+constexpr int kBuckets = 1 << (kBucketWindow - 1);  // signed digits
+
+// The signed base-2^c digits of n 32-byte big-endian scalars, least
+// significant first, each in [-2^(c-1), 2^(c-1)]; `nwin` digits a
+// scalar, one bit past the longest scalar so that no carry is lost.
+struct Digits {
+  int nwin;
+  std::vector<int8_t> d;  // n rows of nwin
+};
+
+void recode(int n, const u8* scalars, Digits* out) {
+  const int c = kBucketWindow;
+  int bits = 0;
+  for (int byte = 0; byte < 32 && !bits; ++byte) {
+    u8 any = 0;
+    for (int i = 0; i < n; ++i) any |= scalars[32 * i + byte];
+    for (int b = 7; b >= 0 && !bits; --b)
+      if ((any >> b) & 1) bits = 8 * (31 - byte) + b + 1;
+  }
+  out->nwin = bits / c + 1;
+  out->d.assign((size_t)n * out->nwin, 0);
+  for (int i = 0; i < n; ++i) {
+    u64 limb[5] = {0, 0, 0, 0, 0};
+    for (int k = 0; k < 4; ++k)
+      for (int j = 0; j < 8; ++j)
+        limb[k] = (limb[k] << 8) | scalars[32 * i + (3 - k) * 8 + j];
+    int8_t* row = &out->d[(size_t)i * out->nwin];
+    int carry = 0;
+    for (int w = 0; w < out->nwin; ++w) {
+      int pos = w * c, at = pos >> 6, off = pos & 63;
+      u64 raw = limb[at] >> off;      // limb[4] is 0: the carry's digit
+      if (off + c > 64) raw |= limb[at + 1] << (64 - off);
+      int digit = (int)(raw & ((1u << c) - 1)) + carry;
+      carry = digit > kBuckets;
+      row[w] = (int8_t)(carry ? digit - (1 << c) : digit);
+    }
+  }
+}
+
+// sum_i scalar_i * p_i by the bucket method (Pippenger): window by
+// window from the top, every point is added into the bucket of its
+// digit (negated under a negative one), the buckets are reduced by
+// running sums (sum_b b * bucket_b as a sum of suffix sums), and the
+// accumulator is doubled c times between windows.  `pts` are affine.
+void msm_bucket(int n, const G1* pts, const Digits& digits, G1* out) {
+  G1 acc;
+  acc.inf = true;
+  G1 bucket[kBuckets];
+  for (int w = digits.nwin - 1; w >= 0; --w) {
+    for (int k = 0; k < kBucketWindow; ++k) g1_dbl(acc, &acc);
+    for (int b = 0; b < kBuckets; ++b) bucket[b].inf = true;
+    for (int i = 0; i < n; ++i) {
+      int digit = digits.d[(size_t)i * digits.nwin + w];
+      if (digit > 0) {
+        g1_add_affine(bucket[digit - 1], pts[i], &bucket[digit - 1]);
+      } else if (digit < 0) {
+        G1 neg = pts[i];
+        fp_neg(pts[i].y, &neg.y);
+        g1_add_affine(bucket[-digit - 1], neg, &bucket[-digit - 1]);
+      }
+    }
+    G1 running, sum;
+    running.inf = sum.inf = true;
+    for (int b = kBuckets - 1; b >= 0; --b) {
+      g1_add(running, bucket[b], &running);
+      g1_add(sum, running, &sum);
+    }
+    g1_add(acc, sum, &acc);
+  }
+  *out = acc;
+}
+
+// sum_i scalar_i * (x_i, y_i) for each of `sets` lists of n points under
+// ONE list of n scalars (the lists lie one after another in xs and ys),
+// the method chosen from n.  The digits are the scalars' alone, so the
+// lists share them.
+void msm_sets(int n, int sets, const u8* xs, const u8* ys, const u8* scalars,
+              G1* out) {
+  const bool by_buckets = n >= kBucketThreshold;
+  Digits digits;
+  if (by_buckets) recode(n, scalars, &digits);
+  std::vector<G1> pts(by_buckets ? n : 0);
+  for (int s = 0; s < sets; ++s) {
+    const u8* sx = xs + (size_t)32 * n * s;
+    const u8* sy = ys + (size_t)32 * n * s;
+    if (by_buckets) {
+      for (int i = 0; i < n; ++i)
+        load_point(sx + 32 * i, sy + 32 * i, &pts[i]);
+      msm_bucket(n, pts.data(), digits, &out[s]);
+      continue;
+    }
+    G1 acc;
+    acc.inf = true;
+    for (int i = 0; i < n; ++i) {
+      G1 p, t;
+      load_point(sx + 32 * i, sy + 32 * i, &p);
+      if (p.inf) continue;
+      g1_mul(p, scalars + 32 * i, &t);
+      g1_add(acc, t, &acc);
+    }
+    out[s] = acc;
+  }
+}
+
+// The affine big-endian form of p; 1 (and zeros) when p is infinity.
+int store_point(const G1& p, u8* out_x, u8* out_y) {
+  if (p.inf) {
+    memset(out_x, 0, 32);
+    memset(out_y, 0, 32);
+    return 1;
+  }
+  Fp zinv, zinv2, zinv3, ax, ay;
+  fp_inv(p.z, &zinv);
+  fp_sqr(zinv, &zinv2);
+  fp_mul(zinv2, zinv, &zinv3);
+  fp_mul(p.x, zinv2, &ax);
+  fp_mul(p.y, zinv3, &ay);
+  from_mont(ax, &ax);
+  from_mont(ay, &ay);
+  store_fp_be(ax, out_x);
+  store_fp_be(ay, out_y);
+  return 0;
+}
+
 }  // namespace
 
 extern "C" {
+
+// The term count from which a sum is formed by the bucket method.
+int bn254_g1_msm_bucket_threshold() { return kBucketThreshold; }
 
 // out = sum_i scalar_i * (x_i, y_i).  Inputs/outputs 32-byte big-endian
 // affine; (0, 0) encodes infinity.  Returns 1 when the sum is infinity.
 int bn254_g1_msm(int n, const u8* xs, const u8* ys, const u8* scalars,
                  u8* out_x, u8* out_y) {
   G1 acc;
-  acc.inf = true;
-  for (int i = 0; i < n; ++i) {
-    G1 p, t;
-    load_point(xs + 32 * i, ys + 32 * i, &p);
-    if (p.inf) continue;
-    g1_mul(p, scalars + 32 * i, &t);
-    g1_add(acc, t, &acc);
-  }
-  if (acc.inf) {
-    memset(out_x, 0, 32);
-    memset(out_y, 0, 32);
-    return 1;
-  }
-  Fp zinv, zinv2, zinv3, ax, ay;
-  fp_inv(acc.z, &zinv);
-  fp_sqr(zinv, &zinv2);
-  fp_mul(zinv2, zinv, &zinv3);
-  fp_mul(acc.x, zinv2, &ax);
-  fp_mul(acc.y, zinv3, &ay);
-  from_mont(ax, &ax);
-  from_mont(ay, &ay);
-  store_fp_be(ax, out_x);
-  store_fp_be(ay, out_y);
+  msm_sets(n, 1, xs, ys, scalars, &acc);
+  return store_point(acc, out_x, out_y);
+}
+
+// out_s = sum_i scalar_i * (x_{s,i}, y_{s,i}) for s < sets: several sums
+// under the same n scalars in one call (xs, ys: sets * n coordinates,
+// list after list).  inf_flags[s] is set when sum s is infinity.
+int bn254_g1_msm_sets(int n, int sets, const u8* xs, const u8* ys,
+                      const u8* scalars, u8* out_xs, u8* out_ys,
+                      u8* inf_flags) {
+  std::vector<G1> acc(sets);
+  msm_sets(n, sets, xs, ys, scalars, acc.data());
+  for (int s = 0; s < sets; ++s)
+    inf_flags[s] = (u8)store_point(acc[s], out_xs + 32 * s, out_ys + 32 * s);
   return 0;
 }
 

@@ -382,6 +382,10 @@ class TestIdemixFlushSpans:
         assert pairing["combined_ok"] is True and pairing["isolated"] == 0
         assert pairing["checks"] == 1
         assert csp.tally()["pairing_checks"] == {"combined": 1, "subset": 0, "item": 0}
+        # three proofs: both weighted sums under the bucket method's threshold
+        assert (pairing["msm_terms"], pairing["msm_window_terms"]) == (0, 6)
+        assert pairing["msm_ms"] > 0
+        assert csp.tally()["msm_terms"] == {"bucket": 0, "window": 6}
 
     def test_a_failed_combined_check_shows_its_isolation(self, issuer, user):
         """A proof from a credential of a rogue issuer (the same bases,
@@ -408,6 +412,8 @@ class TestIdemixFlushSpans:
         assert pairing["args"]["isolated"] == 3
         assert pairing["args"]["checks"] == 4
         assert (pairing["args"]["subset_checks"], pairing["args"]["item_checks"]) == (0, 3)
+        # up to three survivors are checked one by one: no sum but the combined check's
+        assert (pairing["args"]["msm_terms"], pairing["args"]["msm_window_terms"]) == (0, 6)
 
     def test_disarmed_the_device_path_consults_nothing(self, issuer, user):
         from fabric_tpu.common import tracing
@@ -478,6 +484,104 @@ class TestIsolationByBisection:
             depth = (n - 1).bit_length()
             assert depth + 1 <= stats["checks"] <= 2 * depth + 1
             assert n != 125 or stats["checks"] <= 15
+
+    @pytest.mark.parametrize("forged", [
+        "at 0", "at 1", "at 30", "at 31", "at 61", "at 62", "at 63", "at 93",
+        "at 123", "at 124", "two", "thirteen", "all",
+    ])
+    def test_range_sums_give_verifys_mask(self, issuer, proofs, forged):
+        """The bisection over sums of ranges (a left half's one
+        multi-scalar multiplication, a right half's the parent's less
+        it): `signature.verify`'s verdicts wherever the forgery sits,
+        the checks inside PR 29's bounds, and no more terms summed than
+        a forgery's path down the halves holds."""
+        good, bad = proofs
+        n = self.N
+        places = {
+            "two": [17, 101], "thirteen": random.Random(13).sample(range(n), 13),
+            "all": list(range(n)),
+        }.get(forged) or [int(forged[3:])]
+        want = [i not in places for i in range(n)]
+        sigs = [good[i] if want[i] else bad[i] for i in range(n)]
+        stats: dict = {}
+        got = signature._pairing_mask(
+            sigs, [True] * n, issuer.ipk, random.Random(forged), stats=stats)
+        assert got == want
+        assert got == [signature._balanced(s.a_prime, s.a_bar, issuer.ipk) for s in sigs]
+        assert (stats["combined_ok"], stats["isolated"]) == (False, n)
+        assert stats["checks"] == 1 + stats["subset_checks"] + stats["item_checks"]
+        assert stats["subset_checks"] <= n // 4 + 1
+        assert stats["checks"] <= 1.25 * n + 8
+        summed = stats["msm_terms"] + stats["msm_window_terms"]
+        if len(places) == 1:
+            assert 8 <= stats["checks"] <= 15
+            # the combined check's 2n and, a side, one left half a level
+            assert 2 * n < summed <= 4 * n
+        # never more than the combined check and, for each level of
+        # halves, n/2 terms a side
+        assert summed <= 2 * n + n * (n - 1).bit_length()
+        assert stats["msm_terms"] >= 2 * n + 2 * (n // 2)
+        assert stats["msm_ms"] > 0
+
+    def test_the_operator_sees_the_sums_by_engine(self, issuer, proofs):
+        """`csp_idemix_msm_terms_total{engine}`, `tally()["msm_terms"]`
+        and the `stats` that `idemix.pairing` carries as attributes: a
+        block's 127 sound proofs are two sums by the bucket method, a
+        batch of three two sums by the term."""
+        from fabric_tpu.common.metrics import CSPMetrics, PrometheusProvider
+        from fabric_tpu.csp.idemix_provider import (
+            MSM_ENGINES, IdemixCSP, IdemixVerifyItem,
+        )
+
+        good, bad = proofs
+        assert bn.g1_msm_engine(127) == "bucket" and bn.g1_msm_engine(3) == "window"
+        prov = PrometheusProvider()
+        csp = IdemixCSP(rng=RNG, device=False, metrics=CSPMetrics(prov))
+        block = [IdemixVerifyItem(s, b"") for s in good + good[:2]]
+        assert csp.verify_batch(block, issuer.ipk) == [True] * 127
+        assert csp.tally()["msm_terms"] == {"bucket": 254, "window": 0}
+        text = prov.registry.expose()
+        for engine, n in zip(MSM_ENGINES, (254, 0)):
+            assert f'csp_idemix_msm_terms_total{{engine="{engine}"}} {n}' in text
+        assert csp.verify_batch(block[:3], issuer.ipk) == [True] * 3
+        assert csp.tally()["msm_terms"] == {"bucket": 254, "window": 6}
+        assert 'csp_idemix_msm_terms_total{engine="window"} 6' in prov.registry.expose()
+        # a forged proof adds the bisection's range sums to both engines
+        forged = block[:60] + [IdemixVerifyItem(bad[0], b"")] + block[60:124]
+        assert csp.verify_batch(forged, issuer.ipk) == [True] * 60 + [False] + [True] * 64
+        after = csp.tally()["msm_terms"]
+        assert after["bucket"] >= 254 + 250 + 2 * 62 and after["window"] > 6
+        assert csp.tally()["pairing_checks"]["combined"] == 3
+        # what the span carries: the same numbers
+        stats: dict = {}
+        signature._pairing_mask(
+            [i.sig for i in block], [True] * 127, issuer.ipk, RNG, stats=stats)
+        assert (stats["msm_terms"], stats["msm_window_terms"]) == (254, 0)
+        assert stats["checks"] == 1 and 0 < stats["msm_ms"] < 1000
+
+    def test_the_weights_are_rand_zrs_at_full_width(self, issuer, proofs, monkeypatch):
+        """One weight a surviving item, drawn by `bn.rand_zr` (uniform
+        over Zr, 254 bits), the same for both sums; the range sums draw
+        none of their own."""
+        good, bad = proofs
+        drawn, summed = [], []
+        rand_zr, msm_sets = bn.rand_zr, bn.g1_msm_sets
+        monkeypatch.setattr(bn, "rand_zr", lambda rng=None: drawn.append(rand_zr(rng)) or drawn[-1])
+        monkeypatch.setattr(
+            bn, "g1_msm_sets",
+            lambda lists, ks: summed.append((len(lists), list(ks))) or msm_sets(lists, ks))
+        sigs = good[:40] + [bad[0]] + good[40:80]
+        ok = [True] * 81
+        ok[7] = False
+        got = signature._pairing_mask(sigs, ok, issuer.ipk, random.Random(3))
+        assert got == [True] * 7 + [False] + [True] * 32 + [False] + [True] * 40
+        assert len(drawn) == 80 and max(drawn).bit_length() >= 250
+        assert all(0 < w < bn.R for w in drawn)
+        assert summed[0] == (2, drawn)
+        # every later sum is over a slice of the same weights
+        text = ",".join(map(str, drawn))
+        assert len(summed) > 4
+        assert all(sides == 2 and ",".join(map(str, ks)) in text for sides, ks in summed[1:])
 
     def test_up_to_three_survivors_get_a_check_each(self, issuer, proofs):
         good, bad = proofs
