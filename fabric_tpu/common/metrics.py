@@ -400,9 +400,11 @@ class ValidateMetrics:
     + policy prepare, possibly fanned out over the work pool), the wait
     on the device verify batch, and the host policy finish — the
     validate-side counterpart of CommitMetrics, so the /metrics reader
-    can see which side of the validate->commit pipeline owns the p99."""
+    can see which side of the validate->commit pipeline owns the p99.
+    `creators` lies inside `collect`: what of it went to deserialising
+    and validating the creators the block's memo did not hold."""
 
-    STAGES = ("collect", "verify_wait", "policy")
+    STAGES = ("collect", "creators", "verify_wait", "policy")
 
     def __init__(self, provider):
         self.stage_duration = provider.new_histogram(HistogramOpts(
@@ -410,7 +412,7 @@ class ValidateMetrics:
             subsystem="block",
             name="stage_duration",
             help="Seconds spent in one validate stage for one block "
-                 "(collect/verify_wait/policy).",
+                 "(collect, creators inside it, verify_wait, policy).",
             buckets=(
                 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                 0.1, 0.25, 0.5, 1.0, 2.5,
@@ -568,6 +570,18 @@ class CSPMetrics:
                  "means forged credentials are being submitted.",
             statsd_format="%{stage}",
         ))
+        self.keytable_flushes = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="tpu",
+            name="keytable_flushes_total",
+            help="Flushes by what became of their public keys: "
+                 "resident (every key already in the device's table), "
+                 "grown (new keys added), reset (table cleared and "
+                 "refilled), per_lane (more distinct keys than the "
+                 "table holds: 64 bytes of key a lane go to the "
+                 "per-lane-key kernel).",
+            statsd_format="%{outcome}",
+        ))
         self.idemix_msm_terms = provider.new_counter(CounterOpts(
             namespace="csp",
             subsystem="idemix",
@@ -581,6 +595,34 @@ class CSPMetrics:
             statsd_format="%{engine}",
         ))
         self.breaker_state.set(0)
+
+
+class MSPMetrics:
+    """The caching MSP's three LRUs (msp/cache.py, upstream
+    msp/cache/cache.go's sizes): who was asked for, and what fell out.
+    A channel with a handful of identities shows hits and no eviction;
+    one whose blocks carry more distinct creators than a cache holds
+    shows misses and evictions growing together, block after block."""
+
+    def __init__(self, provider):
+        self.cache_requests = provider.new_counter(CounterOpts(
+            namespace="msp",
+            subsystem="cache",
+            name="requests_total",
+            help="Lookups in the caching MSP, labeled by cache "
+                 "(deserialize, validate, principal) and outcome: hit, "
+                 "miss, or expired (a validate entry older than its "
+                 "60 s, checked again).",
+            statsd_format="%{cache}.%{outcome}",
+        ))
+        self.cache_evictions = provider.new_counter(CounterOpts(
+            namespace="msp",
+            subsystem="cache",
+            name="evictions_total",
+            help="Entries the caching MSP dropped because a cache was "
+                 "full, labeled by cache.",
+            statsd_format="%{cache}",
+        ))
 
 
 class WorkpoolMetrics:

@@ -47,6 +47,7 @@ class System:
         self._csp_metrics = None
         self._raft_metrics = None
         self._workpool_metrics = None
+        self._msp_metrics = None
         self._gossip_metrics = None
         self._deliver_metrics = None
         self._gateway_metrics = None
@@ -283,6 +284,18 @@ class System:
 
                 self._raft_metrics = RaftMetrics(self.metrics_provider)
             return self._raft_metrics
+
+    def msp_metrics(self):
+        """Lazily-built caching-MSP metrics (lookups by cache and
+        outcome, evictions) — hand the bundle to
+        ``msp.cache.set_metrics`` so a channel whose creators outnumber
+        the caches shows on /metrics."""
+        with self._lock:
+            if self._msp_metrics is None:
+                from fabric_tpu.common.metrics import MSPMetrics
+
+                self._msp_metrics = MSPMetrics(self.metrics_provider)
+            return self._msp_metrics
 
     def workpool_metrics(self):
         """Lazily-built shared-host-work-pool metrics (queue depth,

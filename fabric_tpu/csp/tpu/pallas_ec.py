@@ -938,11 +938,12 @@ def verify_packed(packed: dict, blk: int = BLK,
     return collect
 
 
-def dedup_keys(packed: dict) -> dict:
+def dedup_keys(packed: dict, seen: dict | None = None) -> dict:
     """Rewrite a packed dict into the deduplicated-key layout when the
     batch uses at most KEYTAB distinct public keys (typical blocks carry
     a handful of endorser identities); otherwise return it unchanged.
-    Saves 64B/signature of host->device transfer.
+    Saves 64B/signature of host->device transfer.  `seen`, where given,
+    is told how many `distinct` keys the batch held.
 
     The table shape is pinned to (8, KEYTAB): the kernel's one-hot is
     hard-wired to KEYTAB lanes, and an index outside it would select the
@@ -951,6 +952,8 @@ def dedup_keys(packed: dict) -> dict:
     qx, qy = packed["qx"], packed["qy"]
     cols = np.concatenate([qx, qy]).T  # (B, 16) words per key
     uniq, idx = np.unique(cols, axis=0, return_inverse=True)
+    if seen is not None:
+        seen["distinct"] = int(uniq.shape[0])
     if uniq.shape[0] > KEYTAB:
         return packed
     ktab = np.zeros((KEYTAB, 16), np.uint32)

@@ -151,6 +151,17 @@ class _KeyTable:
     the table resets to the current batch's keys; if a single batch
     holds more than KEYTAB distinct keys the caller falls back to the
     per-batch np.unique layout (which itself degrades to per-lane keys).
+
+    That suits a channel with a handful of clients.  Where every user
+    holds an enrolment certificate of its own, a two-block flush holds
+    some 900 distinct keys, and the table is all or nothing: `assign`
+    walks the lanes to the 257th key, clears the table, walks again,
+    fails, and `dedup_keys` then runs np.unique over the whole batch to
+    hand it over unchanged — three passes a flush to learn "a key a
+    lane", and the device's resident copy thrown away each time
+    (benchmarks/configs/manyclients-10k.json is the cell that times
+    it).  `last_outcome` says how the last batch came out: `resident`
+    (no new key), `grown`, `reset` (cleared and refilled) or `per_lane`.
     """
 
     def __init__(self):
@@ -162,6 +173,7 @@ class _KeyTable:
         self._ktaby = np.zeros((8, self.cap), np.uint32)
         self._dev: tuple | None = None
         self.uploads = 0  # device copies made (one per new key per device)
+        self.last_outcome = "resident"
 
     @staticmethod
     def _words(be32: bytes) -> np.ndarray:
@@ -181,7 +193,8 @@ class _KeyTable:
     def assign(self, keys) -> np.ndarray | None:
         """Per-lane table indexes for `keys`, or None when even a fresh
         table cannot hold this batch's distinct keys."""
-        for _attempt in (0, 1):
+        for attempt in (0, 1):
+            held = len(self._idx)
             kidx = np.empty(len(keys), np.uint32)
             ok = True
             for i, k in enumerate(keys):
@@ -193,12 +206,18 @@ class _KeyTable:
                         break
                 kidx[i] = j
             if ok:
+                self.last_outcome = (
+                    "reset" if attempt
+                    else "grown" if len(self._idx) > held
+                    else "resident"
+                )
                 return kidx
             # overflow: reset to this batch's working set and retry once
             self._idx.clear()
             self._ktabx[:] = 0
             self._ktaby[:] = 0
             self._dev = None
+        self.last_outcome = "per_lane"
         return None
 
     def device_tables(self, device=None):
@@ -834,7 +853,7 @@ class TPUCSP(CSP):
         if self._metrics is not None:
             self._metrics.dispatches.With("bucket", str(bucket)).add()
         with tracing.span(
-            "tpu.enqueue", lanes=lanes, bucket=bucket,
+            "tpu.enqueue", lanes=lanes, bucket=bucket, kernel=kernel,
             device=0 if dev is None else dev.id, cold=cold,
         ):
             yield
@@ -1259,8 +1278,20 @@ class TPUCSP(CSP):
                     kspan.annotate(
                         uploaded=self._key_table.uploads != uploads
                     )
+                    if tracing.enabled():
+                        kspan.annotate(distinct=int(np.count_nonzero(
+                            np.bincount(kidx, minlength=self._key_table.cap)
+                        )))
                 else:
-                    packed_all = pallas_ec.dedup_keys(packed_all)
+                    seen: dict = {}
+                    packed_all = pallas_ec.dedup_keys(packed_all, seen)
+                    kspan.annotate(**seen)
+                outcome = self._key_table.last_outcome
+                kspan.annotate(outcome=outcome)
+                if self._metrics is not None:
+                    self._metrics.keytable_flushes.With(
+                        "outcome", outcome
+                    ).add()
             shared = ("ktabx", "ktaby")
             kernel = (
                 "pallas_ec_p256_verify_ktab" if "kidx" in packed_all

@@ -6,6 +6,15 @@ Wraps any object with the MSP/MSPManager surface; safe because
 identities and principals are immutable once parsed and the underlying
 MSP config is fixed for a Bundle's lifetime (a config update builds a
 NEW bundle with fresh MSPs, so caches never go stale).
+
+The sizes are upstream's (msp/cache/cache.go: 100 / 100 / 100).  They
+suit a channel with a handful of identities.  On a channel whose
+blocks carry hundreds of distinct creators (one enrolment certificate
+a user) the tail evicts the head between two blocks and nearly every
+lookup of a creator misses; every lookup and every eviction is counted
+(`CachedMSP.tally()`, `msp_cache_requests_total{cache,outcome}` and
+`msp_cache_evictions_total{cache}` on /metrics) so that an operator
+can see which channel a peer serves.
 """
 
 from __future__ import annotations
@@ -40,25 +49,63 @@ def _template(exc: Exception) -> Exception | None:
         return None
 
 
+# an optional common.metrics.MSPMetrics (wired by operations.System
+# through the node): process-wide, as bundles and their caches come and
+# go with every config update
+_metrics = None
+
+
+def set_metrics(metrics) -> None:
+    """Attach a common.metrics.MSPMetrics: every lookup and eviction
+    of every CachedMSP then shows on /metrics."""
+    global _metrics
+    _metrics = metrics
+
+
+def _validated_lately(entry) -> bool:
+    """A validate entry, (stamp, outcome), younger than its time."""
+    return time.monotonic() - entry[0] < _VALIDATE_TTL_S
+
+
 class _LRU:
-    def __init__(self, cap: int):
+    def __init__(self, cap: int, name: str):
         self._cap = cap
+        self._name = name
         self._d: OrderedDict = OrderedDict()
         self._lock = threading.Lock()
+        self.requests = {"hit": 0, "miss": 0, "expired": 0}
+        self.evictions = 0
 
-    def get(self, key):
+    def get(self, key, fresh=None):
+        """(value, True) for an entry that is there and, where `fresh`
+        is given, still passes it; else (None, False), counted as a
+        miss or as expired."""
         with self._lock:
             if key not in self._d:
-                return None, False
-            self._d.move_to_end(key)
-            return self._d[key], True
+                outcome, found = "miss", (None, False)
+            elif fresh is not None and not fresh(self._d[key]):
+                outcome, found = "expired", (None, False)
+            else:
+                self._d.move_to_end(key)
+                outcome, found = "hit", (self._d[key], True)
+            self.requests[outcome] += 1
+        if _metrics is not None:
+            _metrics.cache_requests.With(
+                "cache", self._name, "outcome", outcome
+            ).add()
+        return found
 
     def put(self, key, value) -> None:
+        dropped = 0
         with self._lock:
             self._d[key] = value
             self._d.move_to_end(key)
             while len(self._d) > self._cap:
                 self._d.popitem(last=False)
+                dropped += 1
+            self.evictions += dropped
+        if dropped and _metrics is not None:
+            _metrics.cache_evictions.With("cache", self._name).add(dropped)
 
 
 class CachedMSP:
@@ -72,12 +119,21 @@ class CachedMSP:
         principal_cap: int = _PRINCIPAL_CACHE,
     ):
         self._inner = inner
-        self._deserialize = _LRU(deserialize_cap)
-        self._validate = _LRU(validate_cap)
-        self._principal = _LRU(principal_cap)
+        self._deserialize = _LRU(deserialize_cap, "deserialize")
+        self._validate = _LRU(validate_cap, "validate")
+        self._principal = _LRU(principal_cap, "principal")
 
     def __getattr__(self, name):
         return getattr(self._inner, name)
+
+    def tally(self) -> dict:
+        """Since this facade was built: lookups by cache and outcome
+        (`requests["validate"]["miss"]`) and evictions by cache."""
+        caches = (self._deserialize, self._validate, self._principal)
+        return {
+            "requests": {c._name: dict(c.requests) for c in caches},
+            "evictions": {c._name: c.evictions for c in caches},
+        }
 
     def deserialize_identity(self, serialized: bytes):
         ident, hit = self._deserialize.get(serialized)
@@ -109,13 +165,11 @@ class CachedMSP:
         if getattr(identity, "anonymous", False):
             return self._inner.validate(identity)  # single-use: no entry
         key = identity.serialize()
-        res, hit = self._validate.get(key)
+        res, hit = self._validate.get(key, fresh=_validated_lately)
         if hit:
-            stamp, outcome = res
-            if time.monotonic() - stamp < _VALIDATE_TTL_S:
-                if outcome is not None:
-                    raise copy.copy(outcome)
-                return
+            if res[1] is not None:
+                raise copy.copy(res[1])
+            return
         try:
             self._inner.validate(identity)
         except Exception as exc:
@@ -144,4 +198,4 @@ class CachedMSP:
         self._principal.put(key, None)
 
 
-__all__ = ["CachedMSP"]
+__all__ = ["CachedMSP", "set_metrics"]

@@ -86,9 +86,6 @@ class DevNode:
             else None
         )
         self._commit_events: queue.Queue = queue.Queue()
-        self.committer.add_commit_listener(
-            lambda blk, flags: self._commit_events.put((blk.header.number, flags))
-        )
 
         # orderer side
         oc = self.bundle.orderer_config
@@ -110,8 +107,12 @@ class DevNode:
     # in-process deliver: orderer block -> fresh copy -> commit pipeline
     def _deliver_to_peer(self, blk: common_pb2.Block) -> None:
         copy = common_pb2.Block.FromString(blk.SerializeToString())
-        self.committer.store_block(copy)
+        flags = self.committer.store_block(copy)
         self._maybe_adopt_config(copy)
+        # announced only now: whoever wakes on a config block's commit
+        # finds its bundle in force (a listener on the committer fired
+        # before the adoption, and a waiter could look in between)
+        self._commit_events.put((copy.header.number, flags))
 
     def _maybe_adopt_config(self, blk: common_pb2.Block) -> None:
         """After a VALID config tx commits, swap in the new channel
@@ -149,9 +150,6 @@ class DevNode:
                 if self._peer_signer is not None
                 else b""
             ),
-        )
-        self.committer.add_commit_listener(
-            lambda b, flags: self._commit_events.put((b.header.number, flags))
         )
         if self.endorser is not None:
             self.endorser = Endorser(

@@ -290,6 +290,11 @@ class PeerNode:
             self.operations.register_checker(
                 "workpool", workpool.health_checker()
             )
+            # the caching MSP's lookups and evictions: a channel whose
+            # blocks carry more creators than the caches hold shows here
+            from fabric_tpu.msp import cache as msp_cache
+
+            msp_cache.set_metrics(self.operations.msp_metrics())
             # profscope: route lock-contention samples to this node's
             # /metrics as lock_wait_seconds{role} when profiling is on
             from fabric_tpu.common import profile

@@ -28,6 +28,7 @@ the builtin plugin evaluates the channel/chaincode endorsement policy.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import os
 import threading
@@ -204,6 +205,22 @@ class _MaskWithIdemix(list):
     idemix: dict
 
 
+class _CreatorMemo(dict):
+    """A block's creators: serialized identity -> the identity, or None
+    for one the channel's MSPs refuse, and what filling it cost.  The
+    memo is per block, so `len()` is the block's distinct creators;
+    `validations` counts the identities deserialised and validated
+    afresh (the memo's misses: every creator once, and in faithful
+    mode every transaction) and `seconds` their wall."""
+
+    __slots__ = ("validations", "seconds")
+
+    def __init__(self):
+        super().__init__()
+        self.validations = 0
+        self.seconds = 0.0
+
+
 class TxValidator:
     """Reference TxValidator.Validate equivalent; `Validate` mutates the
     block's TRANSACTIONS_FILTER metadata like the reference does.
@@ -320,13 +337,19 @@ class TxValidator:
 
     # -- phase 1: per-tx syntactic validation + collection ----------------
 
-    def _creator_identity(self, creator_bytes: bytes, memo: dict,
+    def _creator_identity(self, creator_bytes: bytes, memo: "_CreatorMemo",
                           lock: threading.Lock | None = None):
         """Deserialize + channel-validate a creator, memoized per block —
-        a 1000-tx block typically carries a handful of distinct client
-        certs, and the per-call MSP cache still pays a lock + LRU
-        shuffle per tx.  Returns None when invalid.  Faithful mode
-        bypasses the memo (the reference pays this per tx).
+        where a channel has a handful of clients a 1000-tx block
+        carries a handful of distinct certs, and the per-call MSP cache
+        still pays a lock + LRU shuffle per tx.  Where it has thousands
+        (one Fabric CA enrolment certificate a user) a block carries
+        some 500 and the memo's misses are most of `collect`: a parse,
+        a chain signature and the validity, CRL and role checks each,
+        ~0.4 ms, which `CachedMSP`'s 100 entries do not save either
+        (benchmarks/configs/manyclients-10k.json; the memo counts them).
+        Returns None when invalid.  Faithful mode bypasses the memo
+        (the reference pays this per tx).
 
         `lock` guards the memo's WRITE when parallel collect workers
         share it; the hit-path read is deliberately lock-free (a dict
@@ -338,6 +361,7 @@ class TxValidator:
         identity) is unaffected."""
         if not self._faithful and creator_bytes in memo:
             return memo[creator_bytes]
+        t0 = time.perf_counter()
         mgr = self._bundle.msp_manager
         try:
             creator_of = getattr(mgr, "deserialize_creator", None)
@@ -352,11 +376,11 @@ class TxValidator:
                 mgr.validate(ident)
         except Exception:
             ident = None
-        if lock is not None:
-            with lock:
-                return memo.setdefault(creator_bytes, ident)
-        memo[creator_bytes] = ident
-        return ident
+        dt = time.perf_counter() - t0
+        with lock if lock is not None else contextlib.nullcontext():
+            memo.validations += 1
+            memo.seconds += dt
+            return memo.setdefault(creator_bytes, ident)
 
     def _collect_tx(self, env_bytes: bytes, seen_txids: set, sink: _ItemSink, work: _TxWork, memo: dict) -> int:
         """Serial per-tx collect: the pure parse half composed with the
@@ -688,7 +712,7 @@ class TxValidator:
     def _start_block_traced(self, block, seen_txids, bspan, num, t0):
         with tracing.attached(bspan.ctx), tracing.span(
             "collect", cat="stage", block=num,
-        ):
+        ) as cspan:
             envs = list(block.data.data)  # ONE materialization of the
             # envelope byte strings (each repeated-field access copies)
             n = len(envs)
@@ -696,7 +720,7 @@ class TxValidator:
             works = [_TxWork() for _ in range(n)]
             sink = _ItemSink(dedup=not self._faithful)
 
-            memo: dict = {}  # per-block creator-identity memo
+            memo = _CreatorMemo()  # per-block creator-identity memo
             self._policy_provider.begin_block()
             raw_meta = self._ns_meta
             if raw_meta is not None:
@@ -752,7 +776,16 @@ class TxValidator:
                 # verify_wait: device and host half of block n overlap
                 # collect of block n+1 and commit of block n-1
                 collect = _both(collect, sink.idemix.dispatch())
+            if tracing.enabled():
+                cspan.annotate(
+                    creators=len(memo),
+                    creator_validations=memo.validations,
+                    creator_ms=memo.seconds * 1e3,
+                )
         self._observe_stage("collect", time.perf_counter() - t0)
+        # inside collect, not beside it: what of the stage went to
+        # identities the block's memo did not hold
+        self._observe_stage("creators", memo.seconds)
         return block, flags, works, collect, envs, bspan
 
     def _collect_native(self, data, seen_txids, sink: _ItemSink, works, flags, memo: dict) -> bool:
@@ -847,10 +880,14 @@ class TxValidator:
         # order are byte-identical to the serial pass.  A failed parse
         # carries its flag code (int) in place of the footprint,
         # applied at the exact point _prepare_namespaces would have
-        # produced it.  (Creator identities are NOT prefetched: a block
-        # carries a handful of distinct creators, and per-lane memo
-        # locking costs more than the deserializations it would
-        # overlap.)
+        # produced it.  (Creator identities are NOT prefetched.  With a
+        # handful of distinct creators a block, per-lane memo locking
+        # costs more than the deserializations it would overlap.  With
+        # ~500, as on a channel of thousands of enrolled clients, they
+        # are ~200 ms a 1000-tx block of this loop, OpenSSL's share of
+        # which releases the interpreter's lock: the `creators` stage
+        # clock and `collect{creator_ms}` say what a prefetch, or the
+        # chain signature as a device lane, would have to win back.)
         prefetched: list | None = None
         width = self._collect_fanout(len(data), native=True)
         if width:

@@ -1,0 +1,386 @@
+"""A channel of thousands of enrolled clients (one Fabric CA enrolment
+certificate a user; `benchmarks/configs/manyclients-10k.json`): a
+block's creators outnumber the per-block memo's reuse, the MSP caches'
+100 entries and the provider's 256-key table.  Here, on the CPU at a
+small size: such a block validates to the serial host validator's
+flags on both collect paths; a creator whose only fault is its
+certificate is refused and its neighbours are not; a flush past the
+key table goes a key a lane and the next small one takes the table
+again; and what the tracing and the counters say of a block is what
+the block held.  The blocks are the benchmark's own
+(`benchmarks/worlds/x509-manyclients.py`)."""
+
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmarks")
+for p in (BENCH, ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+from fabric_tpu.common import tracing  # noqa: E402
+from fabric_tpu.csp import SWCSP  # noqa: E402
+from fabric_tpu.csp.api import VerifyBatchItem  # noqa: E402
+from fabric_tpu.csp.tpu import pallas_ec  # noqa: E402
+from fabric_tpu.csp.tpu.provider import TPUCSP, _KeyTable  # noqa: E402
+
+SEED = 2**31 + 32
+BAD_CREATOR, MVCC_CONFLICT, VALID = 4, 11, 0
+NO_FAULTS = {
+    "bad_creator_per_block": 0, "bad_endorsement_per_block": 0,
+    "conflict_pairs_per_block": 0, "rogue_ca_creators_per_block": 0,
+    "expired_creators_per_block": 0, "revoked_creators_per_block": 0,
+    "no_role_ou_creators_per_block": 0,
+}
+
+
+def _build(planted: dict, **deployment):
+    from benchlib.manifest import Manifest
+
+    man = Manifest(ROOT)
+    with open(os.path.join(BENCH, "configs", "manyclients-10k.json")) as f:
+        held = json.load(f)
+    dep = dict(held["deployment"], orgs=1, endorsers_per_tx=1, **deployment)
+    world = man.world(held)(SEED, dep, dict(held["planted"], **planted), 1)
+    return man, held, dep, world
+
+
+@pytest.fixture(scope="module")
+def crowd():
+    """One 560-tx block of a one-organisation channel whose creators
+    outnumber the key table, with every kind of fault planted."""
+    man, held, dep, world = _build({}, block_txs=560)
+    assert world.creators_per_block[0] > pallas_ec.KEYTAB
+    return man, held, dep, world
+
+
+def _validator(world, csp, python_collect=False, faithful=False):
+    from fabric_tpu.common.channelconfig import bundle_from_genesis
+    from fabric_tpu.ledger import LedgerProvider
+    from fabric_tpu.peer.txvalidator import TxValidator
+
+    ledger = LedgerProvider(None).create(world.genesis)
+    v = TxValidator(world.channel, ledger, bundle_from_genesis(world.genesis, csp), csp,
+                    faithful=faithful)
+    if python_collect:
+        v._collect_native = lambda *a, **k: False
+    return v
+
+
+def _block(world, b=0):
+    from fabric_tpu.protos.common import common_pb2
+
+    return common_pb2.Block.FromString(world.blocks[b])
+
+
+@pytest.fixture(scope="module")
+def serial_flags(crowd):
+    """The judge: the reference's cost model (no memo, no interning, a
+    verify a signature) over `SWCSP`."""
+    world = crowd[3]
+    return list(_validator(world, SWCSP(), faithful=True).validate(_block(world)))
+
+
+@pytest.mark.parametrize("python_collect", [False, True], ids=["native", "python"])
+def test_a_block_of_more_creators_than_the_key_table_validates_as_the_serial_validator_does(
+        crowd, serial_flags, python_collect):
+    from fabric_tpu import native
+
+    if not python_collect and not native.available():
+        pytest.skip(f"no native collector: {native.load_error()}")
+    world = crowd[3]
+    v = _validator(world, SWCSP(), python_collect=python_collect)
+    got = list(v.validate(_block(world)))
+    assert got == serial_flags
+    # the validator does not run MVCC: the planted conflicts are the ledger's
+    assert got == [VALID if f == MVCC_CONFLICT else f for f in world.planted[0]]
+    assert got.count(BAD_CREATOR) == 3 + 2 + 1 + 1 + 1
+
+
+def test_the_plain_reference_reads_the_crowded_block_as_planted(crowd):
+    man, held, dep, world = crowd
+    flags, states = man.reference(held)(world.public, dep, world.blocks)
+    assert [list(f) for f in flags] == [list(p) for p in world.planted]
+    assert states[-1] == world.expected_state()
+
+
+@pytest.mark.parametrize("fault", [
+    "rogue_ca_creators_per_block", "expired_creators_per_block",
+    "revoked_creators_per_block", "no_role_ou_creators_per_block",
+])
+@pytest.mark.parametrize("python_collect", [False, True], ids=["native", "python"])
+def test_a_creator_whose_only_fault_is_its_certificate_is_refused_alone(fault, python_collect):
+    man, held, dep, world = _build(dict(NO_FAULTS, **{fault: 1}), block_txs=8)
+    want = list(world.planted[0])
+    assert sorted(want) == [VALID] * 7 + [BAD_CREATOR]
+    got = list(_validator(world, SWCSP(), python_collect=python_collect).validate(_block(world)))
+    assert got == want
+    flags, _states = man.reference(held)(world.public, dep, world.blocks)
+    assert list(flags[0]) == want
+    # the same identities pass an MSP that validates nothing: the fault
+    # is the certificate's, and the signature is sound
+    from fabric_tpu.msp.msp import MSP
+
+    real = MSP.validate
+    try:
+        MSP.validate = lambda self, identity: None
+        lax = list(_validator(world, SWCSP(), python_collect=python_collect)
+                   .validate(_block(world)))
+    finally:
+        MSP.validate = real
+    assert lax == [VALID] * 8
+
+
+# -- the key table and the per-lane layout ---------------------------------
+
+
+def _signed(n_keys: int, n_items: int, csp=None):
+    csp = csp or SWCSP()
+    keys = [csp.key_gen() for _ in range(n_keys)]
+    items = []
+    for i in range(n_items):
+        key = keys[i % n_keys]
+        digest = csp.hash(b"manyclients-%d" % i)
+        sig = csp.sign(key, digest)
+        if i % 7 == 3:
+            sig = sig[:-1] + bytes([sig[-1] ^ 1])
+        items.append(VerifyBatchItem(key.public_key(), digest, sig))
+    return items
+
+
+def _key_words(items) -> np.ndarray:
+    """(16, B): the words of every lane's public key, x then y."""
+    return np.concatenate([
+        np.stack([_KeyTable._words(it.key.x_bytes) for it in items], axis=1),
+        np.stack([_KeyTable._words(it.key.y_bytes) for it in items], axis=1),
+    ])
+
+
+def _lane_keys(layout: dict) -> np.ndarray:
+    """The keys a layout hands the kernel, a lane at a time."""
+    if "kidx" in layout:
+        idx = np.asarray(layout["kidx"])
+        return np.concatenate([np.asarray(layout["ktabx"])[:, idx],
+                               np.asarray(layout["ktaby"])[:, idx]])
+    return np.concatenate([np.asarray(layout["qx"]), np.asarray(layout["qy"])])
+
+
+def test_a_flush_past_the_table_goes_a_key_a_lane_and_the_next_small_one_takes_the_table():
+    sw = SWCSP()
+    crowded = _signed(pallas_ec.KEYTAB + 44, 330, sw)
+    small = _signed(5, 40, sw)
+    table = _KeyTable()
+    assert table.last_outcome == "resident"
+
+    assert table.assign([it.key for it in small]) is not None
+    assert table.last_outcome == "grown"
+    assert table.assign([it.key for it in small]) is not None
+    assert table.last_outcome == "resident"
+
+    # past the table: no index a lane, and dedup_keys hands the packed
+    # batch over as it came, having counted its keys
+    assert table.assign([it.key for it in crowded]) is None
+    assert table.last_outcome == "per_lane"
+    packed = TPUCSP._marshal_native(crowded)
+    if packed is None:
+        packed = pallas_ec.prepare_packed(
+            next(iter(TPUCSP(min_device_batch=1)._tuple_chunks(crowded)))[0])
+    seen: dict = {}
+    handed = pallas_ec.dedup_keys(packed, seen)
+    assert handed is packed and "kidx" not in handed
+    assert seen == {"distinct": pallas_ec.KEYTAB + 44}
+    assert (_lane_keys(handed)[:, :len(crowded)] == _key_words(crowded)).all()
+
+    # the next small flush takes the table again, which the crowd left empty
+    kidx = table.assign([it.key for it in small])
+    assert kidx is not None and table.last_outcome == "grown"
+    layout = {"kidx": kidx, "ktabx": table._ktabx, "ktaby": table._ktaby}
+    assert (_lane_keys(layout) == _key_words(small)).all()
+    assert len(set(kidx.tolist())) == 5
+    assert table.assign([it.key for it in small]) is not None
+    assert table.last_outcome == "resident"
+    # a batch that fits the table but not beside what it holds: cleared, refilled
+    wide = crowded[:pallas_ec.KEYTAB - 2]
+    kidx = table.assign([it.key for it in wide])
+    assert kidx is not None and table.last_outcome == "reset"
+    layout = {"kidx": kidx, "ktabx": table._ktabx, "ktaby": table._ktaby}
+    assert (_lane_keys(layout) == _key_words(wide)).all()
+
+    # the same keys a lane either way, so the same verdicts: the two
+    # kernels' parity on one layout each is tests/test_pallas_ec.py's
+
+
+class _NoKernel:
+    """`pallas_ec.verify_packed` for a backend without Mosaic: keeps
+    the layout it was handed and seals every lane true."""
+
+    def __init__(self):
+        self.layouts = []
+
+    def __call__(self, packed):
+        self.layouts.append(packed)
+        b = (packed["kidx"] if "kidx" in packed else packed["qx"]).shape[-1]
+        return lambda: np.ones(b, bool)
+
+
+def test_the_dispatch_says_which_kernel_took_a_flush_and_how_its_keys_came_out(monkeypatch):
+    """The TPU branch of `_dispatch` up to the kernel call, on this
+    backend: `tpu.keytable{outcome, distinct}`, `tpu.enqueue{kernel}`,
+    `csp_tpu_keytable_flushes_total{outcome}`."""
+    import jax
+
+    from fabric_tpu import native
+    from fabric_tpu.common.metrics import CSPMetrics, PrometheusProvider
+
+    if not native.available():
+        pytest.skip(f"no native marshaller: {native.load_error()}")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kernel = _NoKernel()
+    monkeypatch.setattr(pallas_ec, "verify_packed", kernel)
+    prov = PrometheusProvider()
+    csp = TPUCSP(min_device_batch=1, stall_factor=None, metrics=CSPMetrics(prov))
+    small, crowded = _signed(5, 40), _signed(pallas_ec.KEYTAB + 4, 300)
+    wide = crowded[:pallas_ec.KEYTAB - 2]
+    flushes = (small, small, crowded, small, wide)
+    try:
+        with tracing.scope() as rec:
+            for items in flushes:
+                assert csp.verify_batch_async(items)() == [True] * len(items)
+            events = tracing.export(rec)["traceEvents"]
+    finally:
+        csp.close()
+    tables = [e["args"] for e in events if e.get("name") == "tpu.keytable"]
+    assert [(a["outcome"], a["distinct"]) for a in tables] == [
+        ("grown", 5), ("resident", 5), ("per_lane", pallas_ec.KEYTAB + 4),
+        ("grown", 5), ("reset", pallas_ec.KEYTAB - 2)]
+    table, lane = "pallas_ec_p256_verify_ktab", "pallas_ec_p256_verify"
+    enq = [e["args"] for e in events if e.get("name") == "tpu.enqueue"]
+    assert [(a["kernel"], a["lanes"]) for a in enq] == [
+        (table, 40), (table, 40), (lane, 300), (table, 40), (table, len(wide))]
+    assert ["kidx" in layout for layout in kernel.layouts] == [True, True, False, True, True]
+    for layout, items in zip(kernel.layouts, flushes):
+        assert (_lane_keys(layout)[:, :len(items)] == _key_words(items)).all()
+    text = prov.registry.expose()
+    for outcome, n in (("grown", 2), ("resident", 1), ("per_lane", 1), ("reset", 1)):
+        assert f'csp_tpu_keytable_flushes_total{{outcome="{outcome}"}} {n}' in text
+
+
+# -- what the tracing and the counters say of a block ----------------------
+
+
+@pytest.mark.parametrize("python_collect", [False, True], ids=["native", "python"])
+def test_the_collect_span_and_the_stage_clock_count_the_blocks_creators(crowd, python_collect):
+    from fabric_tpu import native
+
+    if not python_collect and not native.available():
+        pytest.skip(f"no native collector: {native.load_error()}")
+    world = crowd[3]
+    v = _validator(world, SWCSP(), python_collect=python_collect)
+    with tracing.scope() as rec:
+        v.validate(_block(world))
+        events = tracing.export(rec)["traceEvents"]
+    (collect,) = [e for e in events if e.get("name") == "collect"]
+    distinct = world.creators_per_block[0]
+    assert collect["args"]["creators"] == distinct
+    # a block's memo: every creator deserialised and validated once
+    assert collect["args"]["creator_validations"] == distinct
+    stage = v.validate_stage_seconds
+    assert collect["args"]["creator_ms"] == pytest.approx(1e3 * stage["creators"])
+    assert 0 < stage["creators"] < stage["collect"]
+    assert collect["args"]["creator_ms"] <= collect["dur"] / 1e3 + 1.0
+    # the MSP caches behind the memo: a miss a creator and a peer (the
+    # policy's principal check validates the endorser), and with more
+    # creators than entries the tail has pushed the head out
+    tally = v._bundle.msp_manager.tally()
+    for cache in ("deserialize", "validate"):
+        assert tally["requests"][cache]["miss"] >= distinct
+        assert tally["requests"][cache]["hit"] <= 2
+        assert tally["evictions"][cache] >= distinct - 100 - 5
+    assert tally["requests"]["validate"]["expired"] == 0
+    # the refused creators never reached validate's cache as successes,
+    # and a second block of the same creators finds a hundred at most
+    before = tally["requests"]["deserialize"]["hit"]
+    v2 = _validator(world, SWCSP(), python_collect=python_collect)
+    v2._bundle = v._bundle
+    v2.validate(_block(world))
+    assert v._bundle.msp_manager.tally()["requests"]["deserialize"]["hit"] - before <= 100
+
+
+def test_faithful_mode_validates_a_creator_a_transaction(crowd):
+    world = crowd[3]
+    v = _validator(world, SWCSP(), faithful=True)
+    with tracing.scope() as rec:
+        v.validate(_block(world))
+        events = tracing.export(rec)["traceEvents"]
+    (collect,) = [e for e in events if e.get("name") == "collect"]
+    assert collect["args"]["creators"] == world.creators_per_block[0]
+    assert collect["args"]["creator_validations"] == len(world.planted[0])
+
+
+def test_the_msp_caches_count_on_the_metrics_page():
+    from fabric_tpu.common.metrics import MSPMetrics, PrometheusProvider
+    from fabric_tpu.msp import cache as msp_cache
+
+    man, held, dep, world = _build(NO_FAULTS, block_txs=8)
+    prov = PrometheusProvider()
+    msp_cache.set_metrics(MSPMetrics(prov))
+    try:
+        v = _validator(world, SWCSP())
+        v.validate(_block(world))
+        v.validate(_block(world))
+    finally:
+        msp_cache.set_metrics(None)
+    tally = v._bundle.msp_manager.tally()
+    text = prov.registry.expose()
+    for cache, outcomes in tally["requests"].items():
+        for outcome, n in outcomes.items():
+            if n:
+                line = f'msp_cache_requests_total{{cache="{cache}",outcome="{outcome}"}} {n}'
+                assert line in text, (line, text)
+    creators = world.creators_per_block[0]
+    assert tally["requests"]["deserialize"]["miss"] >= creators
+    assert tally["requests"]["deserialize"]["hit"] >= creators    # the second block
+    assert tally["evictions"] == {"deserialize": 0, "validate": 0, "principal": 0}
+    assert "msp_cache_evictions_total{" not in text
+
+
+def test_a_validate_entry_past_its_time_counts_as_expired(monkeypatch):
+    from fabric_tpu.msp import cache as msp_cache
+
+    class Inner:
+        validated = 0
+
+        def validate(self, identity):
+            Inner.validated += 1
+
+    class Ident:
+        def serialize(self):
+            return b"one"
+
+    cached = msp_cache.CachedMSP(Inner())
+    cached.validate(Ident())
+    cached.validate(Ident())
+    monkeypatch.setattr(msp_cache, "_VALIDATE_TTL_S", 0.0)
+    cached.validate(Ident())
+    assert Inner.validated == 2
+    assert cached.tally()["requests"]["validate"] == {"hit": 1, "miss": 1, "expired": 1}
+
+
+def test_disarmed_the_new_sites_consult_nothing(crowd):
+    """Off, a site is a global load and an `is None` test: the armed
+    path's counter stays where it was through a whole crowded block on
+    either collect path, and the stage clock still runs.  (The
+    provider's dispatch: `test_tracing.py`'s pin over the commit path.)"""
+    world = crowd[3]
+    assert not tracing.enabled()
+    before = tracing.lookup_count()
+    for python_collect in (False, True):
+        v = _validator(world, SWCSP(), python_collect=python_collect)
+        v.validate(_block(world))
+        assert v.validate_stage_seconds["creators"] > 0
+    assert tracing.lookup_count() == before

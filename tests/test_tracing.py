@@ -707,6 +707,7 @@ def test_flush_anatomy_and_queue_waits_are_spans(tpu_world):
     for e in _by_name(doc, "tpu.enqueue"):
         assert e["args"]["bucket"] == 32 and 0 < e["args"]["lanes"] <= 32
         assert e["args"]["device"] == 0 and isinstance(e["args"]["cold"], bool)
+        assert e["args"]["kernel"] == "xla_p256_verify"     # this backend's
     waits = _by_name(doc, "tpu.device_wait")
     assert len(waits) == len(flushes)
     assert all(w["tid"] == "tpu-flush-waiter" for w in waits)
@@ -735,6 +736,10 @@ def test_flush_anatomy_and_queue_waits_are_spans(tpu_world):
             assert e["args"]["parent"] == root["args"]["span"], (name, e["args"])
     assert {e["args"]["parent"] for e in back} == {
         roots[1]["args"]["span"], roots[2]["args"]["span"]}
+    # a block's creators on its collect span: one client signed all three
+    for e in _by_name(doc, "collect"):
+        assert (e["args"]["creators"], e["args"]["creator_validations"]) == (1, 1)
+        assert 0 < e["args"]["creator_ms"] <= e["dur"] / 1e3 + 1.0
 
 
 def test_a_flush_past_its_deadline_carries_raced(tpu_world):
