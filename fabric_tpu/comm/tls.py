@@ -20,11 +20,17 @@ import dataclasses
 import os
 import ssl
 import tempfile
+import threading
 
 from fabric_tpu.common.hashing import sha256 as _sha256
 
 from cryptography import x509
 from cryptography.hazmat.primitives.serialization import Encoding
+
+
+# one lock for all credentials objects: each makes its directory once,
+# a few times a process
+_MATERIALIZE_LOCK = threading.Lock()
 
 
 @dataclasses.dataclass
@@ -56,16 +62,24 @@ class TLSCredentials:
     def _materialize(self) -> tuple[str, str]:
         """Write cert/key to a private temp dir (ssl.load_cert_chain is
         path-only); reused across contexts for this object's lifetime."""
-        if self._tmpdir is None:
-            self._tmpdir = tempfile.TemporaryDirectory(prefix="fabric-tls-")
-            os.chmod(self._tmpdir.name, 0o700)
-            cp = os.path.join(self._tmpdir.name, "cert.pem")
-            kp = os.path.join(self._tmpdir.name, "key.pem")
-            with open(cp, "wb") as f:
-                f.write(self.cert_pem)
-            with open(kp, "wb") as f:
-                f.write(self.key_pem)
-            os.chmod(kp, 0o600)
+        with _MATERIALIZE_LOCK:
+            # published only when both files are written: a restarted
+            # peer reopens its channels, whose deliver clients build
+            # their contexts on threads of their own, before it builds
+            # its server's, and a second caller that found the
+            # directory named but still empty failed `load_cert_chain`
+            # and took the peer down (tests/test_nwo.py, PR 33)
+            if self._tmpdir is None:
+                tmpdir = tempfile.TemporaryDirectory(prefix="fabric-tls-")
+                os.chmod(tmpdir.name, 0o700)
+                cp = os.path.join(tmpdir.name, "cert.pem")
+                kp = os.path.join(tmpdir.name, "key.pem")
+                with open(cp, "wb") as f:
+                    f.write(self.cert_pem)
+                with open(kp, "wb") as f:
+                    f.write(self.key_pem)
+                os.chmod(kp, 0o600)
+                self._tmpdir = tmpdir
         return (
             os.path.join(self._tmpdir.name, "cert.pem"),
             os.path.join(self._tmpdir.name, "key.pem"),

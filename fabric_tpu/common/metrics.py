@@ -602,7 +602,10 @@ class MSPMetrics:
     msp/cache/cache.go's sizes): who was asked for, and what fell out.
     A channel with a handful of identities shows hits and no eviction;
     one whose blocks carry more distinct creators than a cache holds
-    shows misses and evictions growing together, block after block."""
+    shows misses and evictions growing together, block after block.
+    And the chain signatures behind the misses (msp/msp.py): `batch`
+    where a block's creators were decided in one native call, `single`
+    where OpenSSL checked one in place."""
 
     def __init__(self, provider):
         self.cache_requests = provider.new_counter(CounterOpts(
@@ -622,6 +625,17 @@ class MSPMetrics:
             help="Entries the caching MSP dropped because a cache was "
                  "full, labeled by cache.",
             statsd_format="%{cache}",
+        ))
+        self.chain_signatures = provider.new_counter(CounterOpts(
+            namespace="msp",
+            name="chain_signatures_total",
+            help="Certificate chain signatures the X.509 MSPs checked, "
+                 "labeled by path: batch (a block's creators, one "
+                 "native call without the interpreter's lock) or "
+                 "single (one OpenSSL call in place: another curve or "
+                 "algorithm, several issuer candidates, an "
+                 "intermediate's own hop, an identity validated alone).",
+            statsd_format="%{path}",
         ))
 
 

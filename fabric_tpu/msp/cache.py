@@ -24,6 +24,8 @@ import threading
 import time
 from collections import OrderedDict
 
+from fabric_tpu.msp import msp as _msp
+
 _DESERIALIZE_CACHE = 100
 _VALIDATE_CACHE = 100
 _PRINCIPAL_CACHE = 100
@@ -57,9 +59,11 @@ _metrics = None
 
 def set_metrics(metrics) -> None:
     """Attach a common.metrics.MSPMetrics: every lookup and eviction
-    of every CachedMSP then shows on /metrics."""
+    of every CachedMSP then shows on /metrics, and every chain
+    signature of the X.509 MSPs behind them, by path."""
     global _metrics
     _metrics = metrics
+    _msp.chain_signatures = None if metrics is None else metrics.chain_signatures
 
 
 def _validated_lately(entry) -> bool:
@@ -151,25 +155,75 @@ class CachedMSP:
         batched verify, and never enters the LRUs: it would not be
         asked for again, and a block of them would flush every X.509
         identity out."""
-        deferred = getattr(self._inner, "deserialize_deferred", None)
-        if deferred is not None:
-            ident = deferred(serialized)
-            if ident is not None:
-                self._inner.validate(ident)
-                return ident
-        ident = self.deserialize_identity(serialized)
-        self.validate(ident)
+        ident = self._anonymous_creator(serialized)
+        if ident is None:
+            ident = self.deserialize_identity(serialized)
+            self.validate(ident)
         return ident
+
+    def _anonymous_creator(self, serialized: bytes):
+        """The creator through its MSP's own door (`deserialize_
+        deferred`), validated; None where its MSP has no such door."""
+        deferred = getattr(self._inner, "deserialize_deferred", None)
+        ident = None if deferred is None else deferred(serialized)
+        if ident is not None:
+            self._inner.validate(ident)
+        return ident
+
+    def deserialize_creators(self, creators) -> tuple[list, int]:
+        """A block's distinct creators in one pass: for each what
+        `deserialize_creator` gives it, or None where that raises, by
+        the same lookups in the same caches; and how many chain
+        signatures one native call decided on the way.  The
+        X.509 identities that have to be validated afresh have their
+        chain signatures checked together first (`prove_chains` of the
+        MSP or manager behind this facade), so the `validate` of each
+        finds its verdict waiting; where nothing was decided ahead,
+        each `validate` checks its own."""
+        idents: list = [None] * len(creators)
+        owing = []
+        for i, serialized in enumerate(creators):
+            try:
+                ident = self._anonymous_creator(serialized)
+                if ident is not None:
+                    idents[i] = ident
+                    continue
+                ident = self.deserialize_identity(serialized)
+                key = ident.serialize()
+                if self._validated(key):
+                    idents[i] = ident
+                else:
+                    owing.append((i, key, ident))
+            except Exception:
+                pass
+        decided = 0
+        prove = getattr(self._inner, "prove_chains", None)
+        if owing and prove is not None:
+            decided = prove([ident for _, _, ident in owing])
+        for i, key, ident in owing:
+            try:
+                self._validate_afresh(key, ident)
+                idents[i] = ident
+            except Exception:
+                pass
+        return idents, decided
 
     def validate(self, identity) -> None:
         if getattr(identity, "anonymous", False):
             return self._inner.validate(identity)  # single-use: no entry
         key = identity.serialize()
+        if not self._validated(key):
+            self._validate_afresh(key, identity)
+
+    def _validated(self, key) -> bool:
+        """True for an identity validated lately; raises what refused
+        it lately; False: validate it afresh."""
         res, hit = self._validate.get(key, fresh=_validated_lately)
-        if hit:
-            if res[1] is not None:
-                raise copy.copy(res[1])
-            return
+        if hit and res[1] is not None:
+            raise copy.copy(res[1])
+        return hit
+
+    def _validate_afresh(self, key, identity) -> None:
         try:
             self._inner.validate(identity)
         except Exception as exc:

@@ -8,6 +8,8 @@ the whole block's items go to one `CSP.verify_batch` call (SURVEY.md §3.4).
 
 from __future__ import annotations
 
+import functools
+
 from cryptography import x509
 from cryptography.hazmat.primitives import serialization
 from cryptography.x509.oid import NameOID
@@ -19,10 +21,9 @@ from fabric_tpu.protos.msp import identities_pb2
 
 
 def cert_pubkey(cert: x509.Certificate) -> ECDSAP256PublicKey:
-    der = cert.public_key().public_bytes(
-        serialization.Encoding.DER, serialization.PublicFormat.SubjectPublicKeyInfo
-    )
-    return ECDSAP256PublicKey.from_der(der)
+    """The certificate's key as the provider's marshal reads it, taken
+    from the certificate once: no export to DER and parse back."""
+    return ECDSAP256PublicKey(cert.public_key())
 
 
 def cert_ous(cert: x509.Certificate) -> list[str]:
@@ -35,16 +36,26 @@ def cert_ous(cert: x509.Certificate) -> list[str]:
 class Identity:
     """A deserialized, not-necessarily-valid identity bound to its MSP."""
 
+    # (issuer bytes, the MSP's trusted certificate with that subject,
+    # the verdict): this certificate's signature under its one issuer
+    # candidate, decided ahead in a block's native batch
+    # (`msp.prove_chains`) for the `MSP.validate` that follows, which
+    # takes it once; None: validate checks the signature itself
+    chain_verdict = None
+
     def __init__(self, mspid: str, cert: x509.Certificate, csp):
         self.mspid = mspid
         self.cert = cert
         self._csp = csp
         self.public_key = cert_pubkey(cert)
-        der = cert.public_bytes(serialization.Encoding.DER)
+        self.ous = cert_ous(cert)
+
+    @functools.cached_property
+    def id(self) -> tuple[str, str]:
         # IdentityIdentifier: (mspid, hash of the raw cert) — reference
         # msp/mspimpl.go getIdentityFromConf.
-        self.id = (mspid, _sha256(der).hex())
-        self.ous = cert_ous(cert)
+        der = self.cert.public_bytes(serialization.Encoding.DER)
+        return (self.mspid, _sha256(der).hex())
 
     def serialize(self) -> bytes:
         # memoized: the hot path (policy evaluation, cache keys) calls

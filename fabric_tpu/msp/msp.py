@@ -8,6 +8,14 @@ Differences from the reference are deliberate simplifications recorded
 here: chain building walks issuer->subject with signature checks per hop
 (cryptography exposes no full RFC 5280 path builder); OU certifier
 identifiers compare against the chain's root/intermediate certs' hashes.
+
+What chain building asks of the MSP's own certificates is the same for
+every identity and is prepared once, in `_setup` (`_Trusted`, the index
+by subject bytes).  The signature of an identity's certificate under
+its issuer is checked either here, one OpenSSL call through the Python
+wrapper, or ahead of `validate` for many identities in one native call
+that holds no interpreter lock (`prove_chains`): the verdict is the
+same, and `validate` is the one place that accepts or refuses.
 """
 
 from __future__ import annotations
@@ -17,8 +25,18 @@ import datetime
 from cryptography import x509
 from cryptography.hazmat.primitives import serialization
 from cryptography.hazmat.primitives.asymmetric import ec
+from cryptography.x509.oid import SignatureAlgorithmOID
 
+from fabric_tpu.common.hashing import sha256 as _sha256
 from fabric_tpu.csp import factory as csp_factory
+from fabric_tpu.csp.api import (
+    P256_N,
+    ECDSAP256PublicKey,
+    VerifyBatchItem,
+    is_low_s,
+    marshal_ecdsa_signature,
+    unmarshal_ecdsa_signature,
+)
 from fabric_tpu.msp.identity import Identity, SigningIdentity
 from fabric_tpu.protos.msp import msp_principal_pb2
 from fabric_tpu.protos.msp import identities_pb2, msp_config_pb2
@@ -38,18 +56,105 @@ def _load_pem_cert(pem: bytes) -> x509.Certificate:
     return certs[0]
 
 
-def _verify_issued(issuer: x509.Certificate, cert: x509.Certificate) -> bool:
-    if cert.issuer != issuer.subject:
-        return False
-    pub = issuer.public_key()
+# under this many chain signatures the native call's fixed cost (every
+# issuer key set up anew a call, ~0.15 ms; the arrays) outweighs what
+# it saves a signature, so OpenSSL checks in place: on the chip's host
+# a call of 1 costs 207 us, of 4 106 each, of 16 80, level with the
+# wrapper's 78 (PERF.md, PR 33).  What the threshold is worth: a block
+# with one creator, as five of the benchmark's six cells have, pays
+# 0.08 ms for its chain signature and not 0.21
+_NATIVE_BATCH_MIN = 16
+
+
+class _Trusted:
+    """A root or intermediate certificate of an MSP with what chain
+    building reads of it an identity, taken from it once: its key, as
+    the OpenSSL object for the single check and as the coordinates the
+    native verifier takes (None: not P-256), its validity window, its
+    serial number, and for an intermediate its own way up to a root."""
+
+    __slots__ = ("cert", "root", "public_key", "p256", "not_before",
+                 "not_after", "serial", "path")
+
+    def __init__(self, cert: x509.Certificate, root: bool):
+        self.cert = cert
+        self.root = root
+        try:
+            self.public_key = cert.public_key()
+        except Exception:
+            self.public_key = None  # a key OpenSSL cannot load signs nothing
+        try:
+            self.p256 = ECDSAP256PublicKey(self.public_key)
+        except Exception:
+            self.p256 = None
+        self.not_before = cert.not_valid_before_utc
+        self.not_after = cert.not_valid_after_utc
+        self.serial = cert.serial_number
+        # [self, ..., root], () where no trusted way leads up from
+        # here, None until an identity first asks (`MSP._path`)
+        self.path: list | tuple | None = None
+
+
+# common.metrics.MSPMetrics.chain_signatures or None, process-wide:
+# `msp.cache.set_metrics` binds it with the caches' counters
+chain_signatures = None
+
+
+def _count_chain_signatures(path: str, n: int = 1) -> None:
+    counter = chain_signatures
+    if counter is not None:
+        counter.With("path", path).add(n)
+
+
+def _signed_by(ca: _Trusted, cert: x509.Certificate) -> bool:
+    """One chain signature, by OpenSSL through the Python wrapper.
+    The caller found `ca` by the certificate's issuer bytes."""
+    _count_chain_signatures("single")
     try:
-        pub.verify(
+        ca.public_key.verify(
             cert.signature, cert.tbs_certificate_bytes,
             ec.ECDSA(cert.signature_hash_algorithm),
         )
         return True
     except Exception:
         return False
+
+
+def prove_chains(pairs) -> int:
+    """For (MSP, identity) pairs about to be validated: the chain
+    signature of every identity that qualifies (`MSP._chain_item`),
+    all in ONE `native.ecdsa_verify_host` call, which holds no
+    interpreter lock while it runs; each verdict is left on its
+    identity for the `MSP.validate` that follows.  Returns how many
+    the call decided: 0 where fewer than `_NATIVE_BATCH_MIN` qualify
+    or the native library or libcrypto is missing, and `validate`
+    then checks each signature itself, as it does for every identity
+    that did not qualify."""
+    owing = []
+    for msp, identity in pairs:
+        try:
+            item = msp._chain_item(identity)
+        except Exception:
+            # a field of the certificate that does not decode (they are
+            # parsed when first read): `validate` meets it again, in
+            # its caller's guard, and refuses this identity alone
+            item = None
+        if item is not None:
+            owing.append((identity, item))
+    if len(owing) < _NATIVE_BATCH_MIN:
+        return 0
+    from fabric_tpu import native
+
+    try:
+        mask = native.ecdsa_verify_host([item[2] for _, item in owing])
+    except Exception:
+        mask = None  # nothing decided: each validate checks its own
+    if mask is None:
+        return 0
+    for (identity, (issuer, ca, _)), ok in zip(owing, mask):
+        identity.chain_verdict = (issuer, ca, ok)
+    _count_chain_signatures("batch", len(owing))
+    return len(owing)
 
 
 class MSP:
@@ -65,6 +170,9 @@ class MSP:
         self.node_ous_enabled = False
         self.ou_roles: dict[str, str] = {}  # OU string -> role name
         self.signer: SigningIdentity | None = None
+        # subject bytes -> the roots, then the intermediates, with that
+        # subject (`_setup`; a config update builds new MSPs)
+        self._issuers: dict[bytes, list[_Trusted]] = {}
 
     # -- setup (reference mspimplsetup.go) --------------------------------
 
@@ -88,6 +196,14 @@ class MSP:
             _load_pem_cert(c).public_bytes(serialization.Encoding.DER)
             for c in fconf.admins
         ]
+        self._issuers = {}
+        for certs, root in (
+            (self.root_certs, True), (self.intermediate_certs, False),
+        ):
+            for c in certs:
+                self._issuers.setdefault(
+                    c.subject.public_bytes(), []
+                ).append(_Trusted(c, root))
         self.crls = [x509.load_pem_x509_crl(c) for c in fconf.revocation_list]
         if fconf.HasField("fabric_node_ous") and fconf.fabric_node_ous.enable:
             self.node_ous_enabled = True
@@ -124,51 +240,113 @@ class MSP:
 
     # -- validation (reference mspimplvalidate.go) ------------------------
 
-    def _chain(self, cert: x509.Certificate) -> list[x509.Certificate]:
-        """Build [leaf, intermediates..., root]; raises if no trusted path."""
-        by_subject: dict[bytes, list[x509.Certificate]] = {}
-        for c in self.intermediate_certs:
-            by_subject.setdefault(c.subject.public_bytes(), []).append(c)
-        roots_by_subject: dict[bytes, list[x509.Certificate]] = {}
-        for c in self.root_certs:
-            roots_by_subject.setdefault(c.subject.public_bytes(), []).append(c)
-
-        chain = [cert]
-        current = cert
-        for _ in range(10):  # path length bound
-            issuer_key = current.issuer.public_bytes()
-            for root in roots_by_subject.get(issuer_key, []):
-                if _verify_issued(root, current):
-                    chain.append(root)
-                    return chain
-            advanced = False
-            for inter in by_subject.get(issuer_key, []):
-                if inter in chain:
-                    continue
-                if _verify_issued(inter, current):
-                    chain.append(inter)
-                    current = inter
-                    advanced = True
+    def _path(self, ca: _Trusted) -> list | tuple:
+        """[ca, ..., root] for an intermediate: its own signature under
+        its issuer and every hop above, checked once an MSP and then
+        known; () where no trusted way leads up."""
+        if ca.path is None:
+            path, current = [ca], ca
+            for _ in range(9):  # path length bound: ten hops with the leaf's
+                advanced = False
+                for up in self._issuers.get(current.cert.issuer.public_bytes(), ()):
+                    if not up.root and any(up.cert == c.cert for c in path):
+                        continue
+                    if _signed_by(up, current.cert):
+                        path.append(up)
+                        current, advanced = up, True
+                        break
+                if not advanced or current.root:
                     break
-            if not advanced:
-                break
+            ca.path = path if current.root else ()
+        return ca.path
+
+    def _chain(self, identity: Identity) -> list[_Trusted]:
+        """The trusted certificates above the identity's, [intermediates
+        ..., root]; raises if no trusted path.  The issuer is found by
+        the raw issuer bytes, roots before intermediates, as the
+        reference compares RawIssuer with RawSubject."""
+        cert = identity.cert
+        ahead = identity.chain_verdict
+        if ahead is None:
+            issuer = cert.issuer.public_bytes()
+        else:
+            issuer, identity.chain_verdict = ahead[0], None
+        for ca in self._issuers.get(issuer, ()):
+            if not ca.root and ca.cert == cert:
+                continue
+            if ahead is not None and ahead[1] is ca:
+                signed = ahead[2]
+            else:
+                signed = _signed_by(ca, cert)
+            if not signed:
+                continue
+            if ca.root:
+                return [ca]
+            path = self._path(ca)
+            if path:
+                return path
+            break
         raise MSPError("could not build certification chain to a trusted root")
+
+    def _chain_item(self, identity: Identity):
+        """(issuer bytes, the issuer, the native verifier's item) where
+        this identity's chain signature is one the native batch decides
+        exactly as `_signed_by` would: one issuer candidate, holding a
+        P-256 key, ECDSA with SHA-256, (r, s) in range and in strict
+        DER.  None otherwise, and `_chain` checks it in place.  A
+        certificate's signature is valid with either S (Go's
+        x509.CheckSignature and OpenSSL accept both) while the native
+        verifier holds every signature to Fabric's low-S rule for
+        transactions, so it is handed (r, min(s, n - s)), which
+        verifies exactly when (r, s) does."""
+        cert = identity.cert
+        issuer = cert.issuer.public_bytes()
+        candidates = self._issuers.get(issuer, ())
+        if len(candidates) != 1:
+            return None
+        ca = candidates[0]
+        if (
+            ca.p256 is None
+            or cert.signature_algorithm_oid
+            != SignatureAlgorithmOID.ECDSA_WITH_SHA256
+            or (not ca.root and ca.cert == cert)
+        ):
+            return None
+        sig = cert.signature
+        try:
+            r, s = unmarshal_ecdsa_signature(sig)  # strict DER
+        except ValueError:
+            return None
+        if r >= P256_N or s >= P256_N:
+            return None
+        if not is_low_s(s):
+            sig = marshal_ecdsa_signature(r, P256_N - s)
+        return issuer, ca, VerifyBatchItem(
+            ca.p256, _sha256(cert.tbs_certificate_bytes), sig
+        )
+
+    def prove_chains(self, identities) -> int:
+        return prove_chains([(self, i) for i in identities])
 
     def validate(self, identity: Identity) -> None:
         """Raises MSPError when invalid: untrusted chain, expired, revoked,
         or (with NodeOUs) not classifiable into exactly one role."""
-        chain = self._chain(identity.cert)
+        chain = self._chain(identity)
+        cert = identity.cert
         now = datetime.datetime.now(datetime.timezone.utc)
-        for c in chain:
-            if now < c.not_valid_before_utc or now > c.not_valid_after_utc:
+        if now < cert.not_valid_before_utc or now > cert.not_valid_after_utc:
+            raise MSPError("certificate outside its validity period")
+        for ca in chain:
+            if now < ca.not_before or now > ca.not_after:
                 raise MSPError("certificate outside its validity period")
         # CRL check: any cert of the chain revoked by a CRL signed by its
         # issuer invalidates the identity (reference validateCertAgainstChain)
-        for crl in self.crls:
-            for c in chain[:-1]:
-                entry = crl.get_revoked_certificate_by_serial_number(c.serial_number)
-                if entry is not None:
-                    raise MSPError("certificate has been revoked")
+        if self.crls:
+            serials = [cert.serial_number] + [ca.serial for ca in chain[:-1]]
+            for crl in self.crls:
+                for serial in serials:
+                    if crl.get_revoked_certificate_by_serial_number(serial) is not None:
+                        raise MSPError("certificate has been revoked")
         if self.node_ous_enabled:
             roles = {self.ou_roles[ou] for ou in identity.ous if ou in self.ou_roles}
             if len(roles) != 1:
@@ -311,6 +489,12 @@ class MSPManager:
 
     def validate(self, identity) -> None:
         self.get_msp(identity.mspid).validate(identity)
+
+    def prove_chains(self, identities) -> int:
+        """The chain signatures of identities of any of the channel's
+        MSPs, ahead of their `validate`, in one native call."""
+        pairs = [(self._msps.get(i.mspid), i) for i in identities]
+        return prove_chains([p for p in pairs if isinstance(p[0], MSP)])
 
 
 __all__ = [

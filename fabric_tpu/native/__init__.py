@@ -166,7 +166,9 @@ def ecdsa_verify_host(items) -> list[bool] | None:
     """Batched host ECDSA-P256 verification through libcrypto
     (ecverify.cc): the TPU provider's chip-stall fallback — OpenSSL's
     nistz256 verify is a multiple of the python-wrapped rate, which
-    directly bounds the p99 cost of a stalled flush.  Verdicts match
+    directly bounds the p99 cost of a stalled flush; and the X.509
+    MSP's batch of a block's chain signatures (msp/msp.py
+    prove_chains).  The call holds no interpreter lock.  Verdicts match
     csp/sw.py _verify_one (strict DER, low-S).  Returns None when the
     native library or libcrypto is unavailable."""
     lib = _load()

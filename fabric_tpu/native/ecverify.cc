@@ -10,6 +10,11 @@
 // the python-per-signature engine: it models the reference's serial
 // cost structure (bccsp/sw/ecdsa.go:41) and is not wired to this.
 //
+// Second caller since PR 33: the X.509 MSP checks the chain signatures
+// of a block's distinct creators here in one call (msp/msp.py
+// prove_chains), handing over (r, min(s, n - s)) because a
+// certificate's signature is valid with either S.
+//
 // Semantics mirror csp/sw.py _verify_one exactly: DER-strict parse,
 // r,s in [1, n-1], LOW-S enforced, then curve verification.
 

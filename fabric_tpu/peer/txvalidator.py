@@ -211,14 +211,17 @@ class _CreatorMemo(dict):
     memo is per block, so `len()` is the block's distinct creators;
     `validations` counts the identities deserialised and validated
     afresh (the memo's misses: every creator once, and in faithful
-    mode every transaction) and `seconds` their wall."""
+    mode every transaction), `seconds` their wall, and `chain_batch`
+    those whose chain signature the block's one native call decided
+    (`_creators_ahead`; 0 where each was checked in place)."""
 
-    __slots__ = ("validations", "seconds")
+    __slots__ = ("validations", "seconds", "chain_batch")
 
     def __init__(self):
         super().__init__()
         self.validations = 0
         self.seconds = 0.0
+        self.chain_batch = 0
 
 
 class TxValidator:
@@ -344,10 +347,13 @@ class TxValidator:
         carries a handful of distinct certs, and the per-call MSP cache
         still pays a lock + LRU shuffle per tx.  Where it has thousands
         (one Fabric CA enrolment certificate a user) a block carries
-        some 500 and the memo's misses are most of `collect`: a parse,
-        a chain signature and the validity, CRL and role checks each,
-        ~0.4 ms, which `CachedMSP`'s 100 entries do not save either
-        (benchmarks/configs/manyclients-10k.json; the memo counts them).
+        some 500, which `CachedMSP`'s 100 entries do not save either
+        (benchmarks/configs/manyclients-10k.json; the memo counts
+        them): the native-walker collect fills the memo for the whole
+        block first (`_creators_ahead`) and this only hits; a miss
+        here, one identity at a time (a parse, one OpenSSL chain
+        signature, the validity, CRL and role checks), is the Python
+        collector's, a lane the walker handed back, or faithful mode.
         Returns None when invalid.  Faithful mode bypasses the memo
         (the reference pays this per tx).
 
@@ -381,6 +387,27 @@ class TxValidator:
             memo.validations += 1
             memo.seconds += dt
             return memo.setdefault(creator_bytes, ident)
+
+    def _creators_ahead(self, creators, memo: "_CreatorMemo") -> None:
+        """Fill the block's memo with its distinct creators (first-seen
+        order) in ONE call on the channel's MSPs: each deserialised and
+        validated as `_creator_identity` would, one identity at a time,
+        but with all their chain signatures checked in one native call
+        that holds no interpreter lock (the committer's thread gets it
+        meanwhile).  An anonymous (Idemix) creator goes through its own
+        door inside that call, by its MSP.  Which transaction is
+        refused for which reason stays the per-tx loop's: this only
+        decides who a creator is."""
+        batch = getattr(self._bundle.msp_manager, "deserialize_creators", None)
+        if batch is None:
+            return
+        t0 = time.perf_counter()
+        distinct = list(dict.fromkeys(creators))
+        idents, decided = batch(distinct)
+        memo.update(zip(distinct, idents))
+        memo.validations += len(distinct)
+        memo.chain_batch += decided
+        memo.seconds += time.perf_counter() - t0
 
     def _collect_tx(self, env_bytes: bytes, seen_txids: set, sink: _ItemSink, work: _TxWork, memo: dict) -> int:
         """Serial per-tx collect: the pure parse half composed with the
@@ -781,6 +808,7 @@ class TxValidator:
                     creators=len(memo),
                     creator_validations=memo.validations,
                     creator_ms=memo.seconds * 1e3,
+                    creator_chain_batch=memo.chain_batch,
                 )
         self._observe_stage("collect", time.perf_counter() - t0)
         # inside collect, not beside it: what of the stage went to
@@ -854,8 +882,18 @@ class TxValidator:
         else:
             txid_known = self._ledger.tx_id_exists
         ident_intern: dict = {}  # endorser cert slice -> canonical object
-        creator_off_l = co["creator_off"].tolist()
-        creator_len_l = co["creator_len"].tolist()
+        creator_l = [
+            sl(off, ln) for off, ln in zip(
+                co["creator_off"].tolist(), co["creator_len"].tolist()
+            )
+        ]
+        if not self._faithful:
+            # the creators of the lanes the walker accepted, known
+            # before the glue loop starts: validated as one batch, so
+            # the loop's _creator_identity only hits
+            self._creators_ahead(
+                [c for c, st in zip(creator_l, status_l) if st >= 0], memo
+            )
         sig_off_l = co["sig_off"].tolist()
         sig_len_l = co["sig_len"].tolist()
         txid_off_l = txid_off_pre
@@ -880,14 +918,12 @@ class TxValidator:
         # order are byte-identical to the serial pass.  A failed parse
         # carries its flag code (int) in place of the footprint,
         # applied at the exact point _prepare_namespaces would have
-        # produced it.  (Creator identities are NOT prefetched.  With a
-        # handful of distinct creators a block, per-lane memo locking
-        # costs more than the deserializations it would overlap.  With
-        # ~500, as on a channel of thousands of enrolled clients, they
-        # are ~200 ms a 1000-tx block of this loop, OpenSSL's share of
-        # which releases the interpreter's lock: the `creators` stage
-        # clock and `collect{creator_ms}` say what a prefetch, or the
-        # chain signature as a device lane, would have to win back.)
+        # produced it.  (Creator identities are not prefetched by the
+        # pool: they were validated above, as one batch on this thread,
+        # whose chain signatures run in one native call without the
+        # interpreter's lock.  The `creators` stage clock and
+        # `collect{creator_ms, creator_chain_batch}` say what a block's
+        # identities cost and how many signatures that call decided.)
         prefetched: list | None = None
         width = self._collect_fanout(len(data), native=True)
         if width:
@@ -948,8 +984,7 @@ class TxValidator:
                 continue
             # creator deserialize + validate (reference flag precedence:
             # BAD_CREATOR_SIGNATURE wins over later-stage failures)
-            creator_bytes = sl(creator_off_l[i], creator_len_l[i])
-            creator = self._creator_identity(creator_bytes, memo)
+            creator = self._creator_identity(creator_l[i], memo)
             if creator is None:
                 flags[i] = V.BAD_CREATOR_SIGNATURE
                 continue

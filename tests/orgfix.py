@@ -31,3 +31,26 @@ def make_org(mspid: str = "Org1MSP", node_ous: bool = True, admins=None) -> Org:
     conf = msp_config_from_ca(ca, mspid, node_ous=node_ous, admins=admins or [])
     msp = MSP.from_config(conf, csp)
     return Org(mspid, ca, msp, csp)
+
+
+def undecodable_issuer(creator: bytes) -> bytes:
+    """The serialized identity with the last byte of its certificate's
+    issuer name made a byte no string type decodes.  Such a certificate
+    loads (cryptography parses a Name when it is first read) and raises
+    ValueError from `.issuer` ever after."""
+    from cryptography import x509
+    from cryptography.hazmat.primitives.serialization import Encoding
+
+    from fabric_tpu.protos.msp import identities_pb2
+
+    sid = identities_pb2.SerializedIdentity.FromString(creator)
+    cert = x509.load_pem_x509_certificate(sid.id_bytes)
+    der, issuer = cert.public_bytes(Encoding.DER), cert.issuer.public_bytes()
+    end = der.index(issuer) + len(issuer)
+    broken = x509.load_der_x509_certificate(der[:end - 1] + b"\xff" + der[end:])
+    try:
+        broken.issuer
+    except ValueError:
+        sid.id_bytes = broken.public_bytes(Encoding.PEM)
+        return sid.SerializeToString()
+    raise AssertionError("the issuer still decodes")

@@ -136,6 +136,52 @@ def test_a_creator_whose_only_fault_is_its_certificate_is_refused_alone(fault, p
     assert lax == [VALID] * 8
 
 
+@pytest.mark.parametrize("python_collect", [False, True], ids=["native", "python"])
+def test_a_creator_whose_issuer_name_does_not_decode_is_refused_alone_in_the_crowded_block(
+        crowd, serial_flags, python_collect):
+    """A certificate whose issuer raises when first read (it loads:
+    cryptography parses a Name on access) costs its own transaction
+    flag 4 and the block nothing: the batch ahead of the loop leaves it
+    to its `validate`, whose guard refuses it alone."""
+    from fabric_tpu import native
+    from fabric_tpu.protos.common import common_pb2
+    from fabric_tpu.protoutil.common import compute_tx_id
+    from orgfix import undecodable_issuer
+
+    if not python_collect and not native.available():
+        pytest.skip(f"no native collector: {native.load_error()}")
+    world = crowd[3]
+    block = _block(world)
+    at = next(i for i in range(100, len(serial_flags))
+              if serial_flags[i - 1:i + 2] == [VALID] * 3)
+    env = common_pb2.Envelope.FromString(block.data.data[at])
+    payload = common_pb2.Payload.FromString(env.payload)
+    shdr = common_pb2.SignatureHeader.FromString(payload.header.signature_header)
+    chdr = common_pb2.ChannelHeader.FromString(payload.header.channel_header)
+    shdr.creator = undecodable_issuer(shdr.creator)
+    chdr.tx_id = compute_tx_id(shdr.nonce, shdr.creator)
+    payload.header.signature_header = shdr.SerializeToString()
+    payload.header.channel_header = chdr.SerializeToString()
+    env.payload = payload.SerializeToString()
+    block.data.data[at] = env.SerializeToString()
+    want = list(serial_flags)
+    want[at] = BAD_CREATOR
+    serial = common_pb2.Block.FromString(block.SerializeToString())
+    assert list(_validator(world, SWCSP(), faithful=True).validate(serial)) == want
+    v = _validator(world, SWCSP(), python_collect=python_collect)
+    with tracing.scope() as rec:
+        got = list(v.validate(block))
+        events = tracing.export(rec)["traceEvents"]
+    assert got == want
+    (collect,) = [e for e in events if e.get("name") == "collect"]
+    distinct = world.creators_per_block[0] + 1
+    assert collect["args"]["creators"] == distinct
+    assert collect["args"]["creator_validations"] == distinct
+    if not python_collect and native.ecdsa_verify_host([]) is not None:
+        # every creator's chain signature but the one that cannot be read
+        assert collect["args"]["creator_chain_batch"] == distinct - 1
+
+
 # -- the key table and the per-lane layout ---------------------------------
 
 
@@ -384,3 +430,140 @@ def test_disarmed_the_new_sites_consult_nothing(crowd):
         v.validate(_block(world))
         assert v.validate_stage_seconds["creators"] > 0
     assert tracing.lookup_count() == before
+
+
+# -- a block's creators as one batch ----------------------------------------
+
+
+@pytest.mark.parametrize("python_collect", [False, True], ids=["native", "python"])
+def test_the_collect_span_says_how_many_chain_signatures_one_native_call_decided(
+        crowd, serial_flags, python_collect, monkeypatch):
+    """The native-walker collect validates the block's distinct creators
+    ahead as one batch: every X.509 creator of this channel qualifies
+    (one P-256 root an organisation), the refused ones too, and the
+    Python OpenSSL check is left to the endorsers' handful.  The Python
+    collector learns a creator a transaction and checks each in place."""
+    from fabric_tpu import native
+    from fabric_tpu.msp import msp as msp_mod
+
+    if native.ecdsa_verify_host([]) is None:
+        pytest.skip(f"no native verifier: {native.load_error()}")
+    world = crowd[3]
+    singles, built = [], []
+    real_signed_by, real_trusted = msp_mod._signed_by, msp_mod._Trusted.__init__
+    monkeypatch.setattr(msp_mod, "_signed_by",
+                        lambda ca, cert: singles.append(cert) or real_signed_by(ca, cert))
+    monkeypatch.setattr(msp_mod._Trusted, "__init__",
+                        lambda self, cert, root: built.append(cert) or real_trusted(self, cert, root))
+    v = _validator(world, SWCSP(), python_collect=python_collect)
+    trusted = len(built)
+    with tracing.scope() as rec:
+        got = list(v.validate(_block(world)))
+        events = tracing.export(rec)["traceEvents"]
+    assert got == serial_flags
+    (collect,) = [e for e in events if e.get("name") == "collect"]
+    distinct = world.creators_per_block[0]
+    assert collect["args"]["creators"] == distinct
+    assert collect["args"]["creator_validations"] == distinct
+    # the MSPs' trust index: built with the bundle, a certificate a CA,
+    # and not touched again by any of the block's identities
+    assert 0 < trusted <= 4 and len(built) == trusted
+    if python_collect:
+        assert collect["args"]["creator_chain_batch"] == 0
+        assert len(singles) >= distinct
+    else:
+        assert collect["args"]["creator_chain_batch"] == distinct
+        assert len(singles) <= 4    # the endorsing peer, the orderer
+
+
+def test_without_the_native_verifier_every_creator_is_checked_in_place(crowd, serial_flags, monkeypatch):
+    from fabric_tpu import native
+
+    if not native.available():
+        pytest.skip(f"no native collector: {native.load_error()}")
+    monkeypatch.setattr(native, "ecdsa_verify_host", lambda items: None)
+    world = crowd[3]
+    v = _validator(world, SWCSP())
+    with tracing.scope() as rec:
+        got = list(v.validate(_block(world)))
+        events = tracing.export(rec)["traceEvents"]
+    assert got == serial_flags
+    (collect,) = [e for e in events if e.get("name") == "collect"]
+    assert collect["args"]["creator_chain_batch"] == 0
+    assert collect["args"]["creator_validations"] == world.creators_per_block[0]
+
+
+def test_the_chain_signatures_count_on_the_metrics_page_by_path(crowd):
+    from fabric_tpu import native
+    from fabric_tpu.common.metrics import MSPMetrics, PrometheusProvider
+    from fabric_tpu.msp import cache as msp_cache
+
+    if native.ecdsa_verify_host([]) is None:
+        pytest.skip(f"no native verifier: {native.load_error()}")
+    world = crowd[3]
+    prov = PrometheusProvider()
+    msp_cache.set_metrics(MSPMetrics(prov))
+    try:
+        _validator(world, SWCSP()).validate(_block(world))
+        text = prov.registry.expose()
+        assert (f'msp_chain_signatures_total{{path="batch"}} '
+                f'{world.creators_per_block[0]}') in text
+        _validator(world, SWCSP(), python_collect=True).validate(_block(world))
+        text = prov.registry.expose()
+    finally:
+        msp_cache.set_metrics(None)
+    (single,) = [ln for ln in text.splitlines()
+                 if ln.startswith('msp_chain_signatures_total{path="single"}')]
+    assert int(float(single.split()[-1])) >= world.creators_per_block[0]
+
+
+@pytest.mark.parametrize("python_collect", [False, True], ids=["native", "python"])
+def test_a_transaction_signed_with_high_s_is_still_refused(python_collect):
+    """A certificate's signature is valid with either S; a
+    transaction's is not, and the batch of chain signatures does not
+    loosen that: the creator's own signature still goes the block's
+    way, low-S enforced."""
+    from cryptography.hazmat.primitives.asymmetric.utils import (
+        decode_dss_signature, encode_dss_signature)
+
+    from fabric_tpu import native
+    from fabric_tpu.csp.api import P256_N as _P256_N
+    from fabric_tpu.protos.common import common_pb2
+
+    man, held, dep, world = _build(NO_FAULTS, block_txs=8)
+    block = _block(world)
+    env = common_pb2.Envelope.FromString(block.data.data[3])
+    r, s = decode_dss_signature(env.signature)
+    assert 2 * s <= _P256_N
+    env.signature = encode_dss_signature(r, _P256_N - s)
+    block.data.data[3] = env.SerializeToString()
+    got = list(_validator(world, SWCSP(), python_collect=python_collect).validate(block))
+    assert got == [VALID] * 3 + [BAD_CREATOR] + [VALID] * 4
+    # and the native verifier itself, which the chain signatures share
+    # with the provider's host fallback, holds what it is handed to low S
+    sw = SWCSP()
+    key, digest = sw.key_gen(), sw.hash(b"manyclients")
+    low = sw.sign(key, digest)
+    r, s = decode_dss_signature(low)
+    high = encode_dss_signature(r, _P256_N - s)
+    pub = key.public_key()
+    assert not sw.verify(pub, high, digest)
+    assert native.ecdsa_verify_host(
+        [VerifyBatchItem(pub, digest, low), VerifyBatchItem(pub, digest, high)]
+    ) in ([True, False], None)
+
+
+@pytest.mark.parametrize("lanes", [1, 15, 16, 17, 63, 64, 128, 129, 257, 330])
+def test_a_native_batch_of_any_size_gives_each_lane_its_own_verdict(lanes):
+    """`ecverify.cc` keeps a context a key for the length of a call,
+    and a block's chain signatures come to it as one batch of any size
+    from `_NATIVE_BATCH_MIN` up: the verdicts are the lanes' own."""
+    from fabric_tpu import native
+
+    sw = SWCSP()
+    items = _signed(7, lanes, sw)
+    got = native.ecdsa_verify_host(items)
+    if got is None:
+        pytest.skip(f"no native verifier: {native.load_error()}")
+    assert got == [sw.verify(it.key, it.signature, it.digest) for it in items]
+    assert got.count(False) == len([i for i in range(lanes) if i % 7 == 3])

@@ -300,19 +300,32 @@ def test_jsonl_and_html_artifacts(tmp_path, ops_system):
 # ---------------------------------------------------------------------------
 
 
+def _wait_for(cond, what: str, timeout_s: float = 60.0) -> None:
+    deadline = time.monotonic() + timeout_s
+    while not cond():
+        assert time.monotonic() < deadline, f"timed out before {what}"
+        time.sleep(0.05)
+
+
 def test_wedged_peer_flagged_in_verdict_survivors_green(tmp_path):
     """A per-node faultline plan wedges one peer's block ingestion
     (deliver connect + the gossip.state.payload funnel — the silent
     deliver-client-wedge class PR 11 caught by luck).  The victim is
-    chosen as the gossip election NON-leader so the survivors keep
-    committing; netscope must flag exactly the victim in the verdict
-    while the invariants oracle stays green on every node."""
+    the gossip election NON-leader, so the survivors keep committing:
+    the survivor is started first and leads before the victim exists.
+    (A standing leader is not contested, whoever's pki-id is smaller;
+    with every node spawned at once the victim led whenever it declared
+    first, and the org took no block at all: this test's unsteadiness
+    beside busy xdist workers, until PR 33.)  netscope must flag
+    exactly the victim in the verdict while the invariants oracle stays
+    green on every node."""
     from fabric_tpu.common.hashing import sha256
 
     peers = ["org1-peer0", "org1-peer1"]
-    # gossip leadership: smallest pki-id (sha256(name)[:16]) wins and
-    # runs the deliver client for the org — wedge the OTHER peer
+    # should the two ever contest, the smallest pki-id
+    # (sha256(name)[:16]) wins: wedge the OTHER peer
     victim = max(peers, key=lambda n: sha256(n.encode())[:16])
+    survivor = next(p for p in peers if p != victim)
     plan = {"seed": 1, "faults": [
         {"point": "gossip.state.payload", "action": "raise",
          "error": "RuntimeError", "every": 1, "count": 10 ** 9},
@@ -324,11 +337,32 @@ def test_wedged_peer_flagged_in_verdict_survivors_green(tmp_path):
         faultline={victim: plan},
     )
     with nh.Network(str(tmp_path / "net"), topo) as net:
-        net.start()
+        early = [n for n in net.nodes if n != victim]
+        for name in early:
+            net.spawn(name)
+        for name in early:
+            net.wait_ready(name)
+        _wait_for(
+            lambda: net.status(survivor)["election_leader"],
+            "the survivor leads",
+        )
+        net.spawn(victim)
+        net.wait_ready(victim)
+        # the detector needs scrape windows in which orderer AND
+        # survivor advance: one block through first, so the stream
+        # starts on a deliver client that is connected
+        net.broadcast(netident.make_tx(
+            topo.channel, "prime", b"v", orgs=topo.orgs, cc="netcc",
+        ))
+        _wait_for(
+            lambda: net.status(survivor)["height"] >= 2,
+            "the survivor commits a block",
+        )
         scope = nh.attach_netscope(net, interval_s=0.15)
         try:
+            # a second or so of stream: several 4-round stall windows
             result = nh.run_stream(
-                net, txs=60, settle_timeout_s=20, scope=scope,
+                net, txs=200, settle_timeout_s=20, scope=scope,
             )
         finally:
             scope.stop()
@@ -339,7 +373,6 @@ def test_wedged_peer_flagged_in_verdict_survivors_green(tmp_path):
     # invariants green EVERYWHERE: the victim's ledger is consistent
     # (just short), the survivors committed the stream
     assert result["violations"] == {}
-    survivor = next(p for p in peers if p != victim)
     assert result["heights"][survivor] > result["heights"][victim]
     # the stall episode carries its evidence window, and the episode
     # (evidence included) rides the jsonl artifact beside a repro

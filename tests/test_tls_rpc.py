@@ -34,6 +34,45 @@ def test_mutual_tls_roundtrip(cas):
         srv.stop()
 
 
+def test_two_threads_building_contexts_of_one_credentials_object(cas, monkeypatch):
+    """A restarted peer's deliver clients build their client contexts
+    on their own threads while the main thread builds the server's: the
+    second to ask must not be shown the key directory before the files
+    are in it (the peer died of `load_cert_chain`'s FileNotFoundError;
+    `tests/test_nwo.py`'s restart under load, until PR 33)."""
+    import os
+    import threading
+    import time
+
+    from fabric_tpu.comm import tls as tls_mod
+
+    named = threading.Event()
+    real_chmod = os.chmod
+
+    def slow_chmod(path, mode):
+        real_chmod(path, mode)
+        if mode == 0o700:       # the directory exists, the files do not
+            named.set()
+            time.sleep(0.3)
+
+    monkeypatch.setattr(tls_mod.os, "chmod", slow_chmod)
+    creds = credentials_from_ca(cas[0], "peer0.org1")
+    failed = []
+
+    def first():
+        try:
+            creds.client_context()
+        except Exception as exc:
+            failed.append(exc)
+
+    t = threading.Thread(target=first)
+    t.start()
+    assert named.wait(5)
+    creds.server_context()
+    t.join()
+    assert failed == []
+
+
 def test_client_without_cert_rejected(cas):
     ca, _ = cas
     srv = _server(credentials_from_ca(ca, "server.org1"))
