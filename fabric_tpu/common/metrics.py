@@ -594,6 +594,26 @@ class CSPMetrics:
                  "bucket.",
             statsd_format="%{engine}",
         ))
+        self.flush_segments = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="tpu",
+            name="flush_segments_total",
+            help="Batches (verify_batch_async calls: a block each while "
+                 "a peer streams blocks) that device flushes took in.  "
+                 "Over the sum of dispatches_total it is blocks a "
+                 "flush: near 1 on a peer that keeps up, 2-3 on one "
+                 "catching up.",
+        ))
+        self.small_batches = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="tpu",
+            name="small_batches_total",
+            help="Batches under bccsp.tpu.minDeviceBatch, verified on "
+                 "the host on the caller's thread to the same rule; "
+                 "lanes_total{sealed_by=\"small\"} counts their lanes.  "
+                 "Growth means blocks of a handful of transactions: a "
+                 "lightly loaded channel cut by BatchTimeout.",
+        ))
         self.breaker_state.set(0)
 
 

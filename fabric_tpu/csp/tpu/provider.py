@@ -994,8 +994,14 @@ class TPUCSP(CSP):
         after the flush, so pipelined callers keep their host/device
         overlap while paying the fixed cost once per ~2 blocks."""
         if len(items) < self._min_device_batch:
-            result = self._sw.verify_batch(items)
+            # too small for the device: verified here, on the caller's
+            # thread (the validator's `collect`), to the same rule; the
+            # span says so, for without it the time is `collect`'s
+            with tracing.span("tpu.small", lanes=len(items)):
+                result = self._sw.verify_batch(items)
             self._note_sealed("small", len(items))
+            if self._metrics is not None:
+                self._metrics.small_batches.add()
             return lambda: result
         if self._breaker_gate():
             # degraded mode: the device is failing, so serve from the
@@ -1065,17 +1071,25 @@ class TPUCSP(CSP):
         advance the generation.  Caller holds _pend_lock."""
         guarded(self, "_pend_batches", by="csp.tpu.pend")
         items: list = []
-        for b in self._pend_batches:
+        segments = self._pend_batches
+        for b in segments:
             items.extend(b)
         self._pend_batches = []
         self._pend_lanes = 0
         gen = self._gen
         self._gen += 1
+        if self._metrics is not None:
+            self._metrics.flush_segments.add(len(segments))
         # dispatch begun -> mask sealed, ended by whoever seals it; it
-        # shares `batch` with tpu.dispatch and the segments' tpu.collect
+        # shares `batch` with tpu.dispatch and the segments' tpu.collect.
+        # `segments`: the verify_batch_async batches it took in (a block
+        # each under store_stream), `segment_lanes` their lanes in order
         fspan = tracing.begin(
             "tpu.flush", detach=True, batch=gen, lanes=len(items),
+            segments=len(segments),
         )
+        if tracing.enabled():
+            fspan.annotate(segment_lanes=[len(b) for b in segments])
         try:
             with tracing.span(
                 "tpu.dispatch", parent=fspan.ctx, batch=gen,

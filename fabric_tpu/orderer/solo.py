@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import queue
 import threading
+import time
 
 from fabric_tpu.devtools.lockwatch import spawn_thread
 
@@ -69,14 +70,27 @@ class SoloChain:
         self._on_block(blk)
 
     def _run(self) -> None:
-        timer_armed = False
+        # the batch timer, as upstream's solo main loop keeps it: armed
+        # by the message that enters an EMPTY batch, left alone by the
+        # messages that follow it, disarmed by a cut that leaves
+        # nothing pending.  `deadline` is when it fires (None: not
+        # armed).  A per-`get` timeout would restart it with every
+        # message, and a channel whose messages come less than
+        # BatchTimeout apart would be cut by count alone.
+        deadline: float | None = None
         while not self._halted.is_set():
             try:
-                item = self._q.get(timeout=self._timeout if timer_armed else None)
+                if deadline is None:
+                    item = self._q.get()
+                else:
+                    wait = deadline - time.monotonic()
+                    if wait <= 0:
+                        raise queue.Empty  # due: before the next message
+                    item = self._q.get(timeout=wait)
             except queue.Empty:
                 # batch timer fired
                 self._emit(self._cutter.cut())
-                timer_armed = False
+                deadline = None
                 continue
             if item is None:
                 break
@@ -85,12 +99,15 @@ class SoloChain:
                 # config messages are isolated into their own block
                 self._emit(self._cutter.cut())
                 self._emit([raw], is_config=True)
-                timer_armed = self._cutter.pending
+                deadline = None
                 continue
             batches, pending = self._cutter.ordered(raw)
             for batch in batches:
                 self._emit(batch)
-            timer_armed = pending
+            if not pending:
+                deadline = None
+            elif deadline is None:
+                deadline = time.monotonic() + self._timeout
         # drain on halt
         self._emit(self._cutter.cut())
 

@@ -804,6 +804,9 @@ class TxValidator:
                 # collect of block n+1 and commit of block n-1
                 collect = _both(collect, sink.idemix.dispatch())
             if tracing.enabled():
+                # the root says how large the block was: a reader can
+                # then tell a 3-tx block's cost from a 500-tx block's
+                bspan.annotate(txs=n)
                 cspan.annotate(
                     creators=len(memo),
                     creator_validations=memo.validations,

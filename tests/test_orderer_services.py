@@ -412,3 +412,79 @@ def test_maintenance_filter_unit_rules(tmp_path):
         cs.bundle.orderer_config = oc
     finally:
         w.registrar.halt_all()
+
+
+# -- the solo consenter's batch timer (upstream orderer/consensus/solo) ------
+
+
+class _ListWriter:
+    """Stands where the BlockWriter stands: keeps each batch and when
+    it was cut."""
+
+    def __init__(self):
+        self.cuts: list = []          # (monotonic time, batch)
+
+    def create_next_block(self, batch):
+        return list(batch)
+
+    def write_block(self, blk, is_config=False):
+        self.cuts.append((time.monotonic(), blk))
+
+
+def test_solo_batch_timer_runs_from_the_first_message_of_a_batch():
+    """The timer is armed by the message that enters an empty batch
+    and is NOT restarted by the messages that follow: a channel whose
+    messages come closer together than BatchTimeout still gets a block
+    every BatchTimeout.  (A timeout counted from the LAST message cut
+    nothing here until the messages stopped.)  A count cut that leaves
+    nothing pending disarms it, and the next message arms it afresh."""
+    from fabric_tpu.orderer.blockcutter import BlockCutter
+    from fabric_tpu.orderer.solo import SoloChain
+
+    timeout, gap, n = 0.3, 0.05, 30
+    writer = _ListWriter()
+    chain = SoloChain(BlockCutter(max_message_count=1000), writer, batch_timeout_s=timeout)
+    chain.start()
+    try:
+        t_first = time.monotonic()
+        for i in range(n):
+            chain.order(common_pb2.Envelope(payload=b"m%d" % i))
+            time.sleep(gap)
+        t_last = time.monotonic()
+        cuts_while_flowing = [c for c in writer.cuts if c[0] < t_last]
+        time.sleep(2 * timeout)
+    finally:
+        chain.halt()
+    # 1.5 s of messages 50 ms apart under a 0.3 s timeout: blocks were
+    # cut while they flowed, the first no sooner than a timeout after
+    # the first message and holding a part of them only
+    assert len(cuts_while_flowing) >= 2
+    assert cuts_while_flowing[0][0] - t_first >= timeout * 0.9
+    assert 1 <= len(cuts_while_flowing[0][1]) < n
+    # nothing lost, nothing twice, order kept; no empty block
+    assert [m for _t, b in writer.cuts for m in b] == [
+        common_pb2.Envelope(payload=b"m%d" % i).SerializeToString() for i in range(n)]
+    assert all(b for _t, b in writer.cuts)
+
+
+def test_solo_count_cut_disarms_the_timer_and_the_next_message_arms_it_afresh():
+    from fabric_tpu.orderer.blockcutter import BlockCutter
+    from fabric_tpu.orderer.solo import SoloChain
+
+    timeout = 0.4
+    writer = _ListWriter()
+    chain = SoloChain(BlockCutter(max_message_count=3), writer, batch_timeout_s=timeout)
+    chain.start()
+    try:
+        for i in range(3):                       # a full batch: cut by count
+            chain.order(common_pb2.Envelope(payload=b"a%d" % i))
+        time.sleep(0.75 * timeout)               # the old timer, were it still armed,
+        t_lone = time.monotonic()                # would fire 0.25 timeouts from here
+        chain.order(common_pb2.Envelope(payload=b"lone"))
+        deadline = time.monotonic() + 10 * timeout
+        while len(writer.cuts) < 2 and time.monotonic() < deadline:
+            time.sleep(0.01)
+    finally:
+        chain.halt()
+    assert [len(b) for _t, b in writer.cuts] == [3, 1]
+    assert writer.cuts[1][0] - t_lone >= timeout * 0.9
