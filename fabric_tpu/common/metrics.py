@@ -926,6 +926,16 @@ class LedgerMetrics:
             help="Blocks committed since process start, per channel.",
             statsd_format="%{channel}",
         ))
+        self.commit_assist = provider.new_counter(CounterOpts(
+            namespace="ledger",
+            name="commit_assist_total",
+            help="Blocks committed by what came with them: full = the "
+                 "validator's txids and envelope bytes (no envelope is "
+                 "decoded again), none = the ledger parsed the block "
+                 "itself (genesis, recovery, a caller without a "
+                 "validator).",
+            statsd_format="%{channel}.%{assist}",
+        ))
         self.transactions = provider.new_counter(CounterOpts(
             namespace="ledger",
             name="transactions_total",

@@ -20,6 +20,7 @@ import time
 
 from fabric_tpu.common import gcpolicy
 from fabric_tpu.common.hashing import sha256 as _sha256
+from fabric_tpu.peer.committer import validate_for_commit
 from fabric_tpu.protos.common import common_pb2
 from fabric_tpu.protos.gossip import message_pb2 as gpb
 from fabric_tpu.protos.ledger.rwset import rwset_pb2
@@ -328,7 +329,7 @@ class PrivDataCoordinator:
         return self._ledger.get_block_by_number(num)
 
     def store_block(self, block) -> list[int]:
-        self._validator.validate(block)
+        assist = validate_for_commit(self._validator, block)
         flags = list(protoutil.tx_filter(block))
         reqs = block_pvt_requirements(block)
         pvt_data: dict[int, bytes] = {}
@@ -389,7 +390,7 @@ class PrivDataCoordinator:
             # The ledger persists block + pvt data + missing records
             # together (kvledger owns the pvt store so restart recovery
             # replays cleartext writes).
-            self._ledger.commit(block, pvt_data, missing)
+            self._ledger.commit(block, pvt_data, missing, assist=assist)
         self._transient.purge_by_txids(txids)
         if block.header.number % self._retention == 0:
             floor = max(0, block.header.number - self._retention)

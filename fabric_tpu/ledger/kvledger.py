@@ -49,7 +49,13 @@ class CommitAssist:
     block-store index), and the materialized envelope byte list (the
     store splice-serializes the block from these instead of re-encoding
     the whole message).  The reference re-unmarshals at every one of
-    those stages (validator.go, validateAndPrepareBatch, blockindex.go)."""
+    those stages (validator.go, validateAndPrepareBatch, blockindex.go).
+
+    Both of the committer's entry points hand one over with every block:
+    Committer.store_stream (from TxValidator.validate_pipeline) and
+    Committer.store_block (from TxValidator.validate, as the
+    private-data coordinator's store_block does).  A position the
+    validator could not parse holds None and is parsed here."""
 
     rwsets: list  # per-tx marshaled TxReadWriteSet | None
     footprints: list  # per-tx RwsetFootprint | None
@@ -426,7 +432,13 @@ class KVLedger:
             txids = assist.txids
             env_bytes = assist.env_bytes
         if rwsets is None or len(rwsets) != len(flags):
+            # only a caller without a validator comes this far (the
+            # genesis block, recovery replay, devtools): a walk of every
+            # envelope that no stage clock or span below covers
             rwsets = extract_rwsets(block)
+        # the block store is handed the block's txids and envelope bytes
+        # (it parses only a position whose txid is None)
+        assisted = txids is not None and env_bytes is not None
         num = block.header.number
         t0 = t()
         # group.mvcc reads through the collector overlay, so a block
@@ -446,7 +458,9 @@ class KVLedger:
             # tests/test_chaos_commit.py drives every one of these)
             faultline.point("commit.stage", stage="mvcc", block=num)
         t1 = t()
-        with tracing.span("block_append", cat="stage", block=num):
+        with tracing.span(
+            "block_append", cat="stage", block=num, assisted=assisted,
+        ):
             file_idx = self._blocks.add_block(
                 block, txids=txids, env_bytes=env_bytes,
                 into=group.collector, sync=False,
@@ -489,6 +503,10 @@ class KVLedger:
                 self._blocks.height
             )
             lm.blocks_committed.With("channel", self.ledger_id).add()
+            lm.commit_assist.With(
+                "channel", self.ledger_id,
+                "assist", "full" if assisted else "none",
+            ).add()
             lm.transactions.With("channel", self.ledger_id).add(
                 sum(1 for f in flags if f == 0)  # VALID
             )

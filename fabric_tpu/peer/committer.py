@@ -23,6 +23,17 @@ from fabric_tpu.common import gcpolicy, tracing
 from fabric_tpu.devtools.lockwatch import spawn_thread
 
 
+def validate_for_commit(validator, block):
+    """Validate a lone block (sets its sig/policy flags) and hand back
+    what the validation learned of it: the CommitAssist that
+    store_stream's pipeline hands KVLedger.commit with every block, so
+    a lone block's commit re-decodes no envelope either.  None from a
+    validator that keeps none; the ledger then parses for itself."""
+    validator.validate(block)
+    take = getattr(validator, "take_assist", None)
+    return None if take is None else take()
+
+
 class Committer:
     def __init__(self, validator, ledger, metrics=None):
         self._validator = validator
@@ -47,14 +58,18 @@ class Committer:
     def store_block(self, block) -> list[int]:
         """The per-block pipeline; returns final validation flags."""
         t0 = time.perf_counter()
-        self._validator.validate(block)  # sets sig/policy flags
+        assist = validate_for_commit(self._validator, block)
         t_validate = time.perf_counter() - t0
-        # the commit stages join the block's trace, as they do through
-        # CommitAssist.trace_ctx in store_stream
+        # the commit stages join the block's trace through
+        # CommitAssist.trace_ctx, as they do in store_stream
         with self._lock, tracing.attached(
-            getattr(self._validator, "last_block_trace", None)
+            getattr(assist, "trace_ctx", None)
         ):
-            self._ledger.commit(block)  # MVCC + persist (updates flags again)
+            # MVCC + persist (updates flags again)
+            self._ledger.commit(block, assist=assist)
+        # nothing of the block's parse is in hand when pipeline_empty()
+        # collects below (held, a full collection walks its footprints)
+        del assist
         if self.metrics is not None:
             self.metrics.observe(
                 "validate_duration", t_validate, channel=self._validator.channel_id
@@ -223,4 +238,4 @@ class Committer:
         return getattr(self._ledger, "durable_height", self._ledger.height)
 
 
-__all__ = ["Committer"]
+__all__ = ["Committer", "validate_for_commit"]

@@ -158,6 +158,28 @@ class _TxWork:
     # VALID, later in-block txs touching them are invalidated
 
 
+def _commit_assist(works: list, envs: list, bspan):
+    """ONE per-block assist bundle for KVLedger.commit, from either
+    entry point (validate, validate_pipeline): the marshaled rwsets,
+    the already-decoded footprints (MVCC + history reuse), the txids
+    (block-store index), and the envelope bytes (the store
+    splice-serializes instead of re-encoding 1-2 MB).  A lane the
+    collect could not parse leaves None at its position, and the ledger
+    falls back to its own parse there."""
+    from fabric_tpu.ledger.kvledger import CommitAssist
+
+    return CommitAssist(
+        rwsets=[w.rwset for w in works],
+        footprints=[w.footprint for w in works],
+        txids=[w.txid for w in works],
+        env_bytes=envs,
+        # carries the block's trace root to whoever commits (the
+        # committer thread in store_stream) so the commit stages join
+        # the same per-block trace; None while tracing is disarmed
+        trace_ctx=bspan.ctx,
+    )
+
+
 @dataclasses.dataclass
 class _ParsedTx:
     """The shared-state-free half of one tx's collect, produced by
@@ -324,8 +346,9 @@ class TxValidator:
         # blocks whose collect actually fanned out (the tier-1 smoke
         # asserts the parallel path ran, not just that flags matched)
         self.parallel_collect_blocks = 0
-        # trace root of the block validate() saw last (tracing armed)
-        self.last_block_trace = None
+        # the CommitAssist of the block validate() saw last, until
+        # take_assist() hands it over
+        self._assist = None
 
     def _committed_metadata(self, ns: str, key: str) -> dict[str, bytes]:
         return self._ledger.get_state_metadata(ns, key)
@@ -626,13 +649,21 @@ class TxValidator:
     # -- the three-phase validate -----------------------------------------
 
     def validate(self, block: common_pb2.Block) -> list[int]:
-        block, flags, works, collect, _envs, bspan = self._start_block(
+        self._assist = None  # never an earlier block's, if this raises
+        block, flags, works, collect, envs, bspan = self._start_block(
             block, set()
         )
-        # Committer.store_block attaches the commit stages to it (None
-        # while tracing is disarmed)
-        self.last_block_trace = bspan.ctx
-        return self._finish_block(block, flags, works, collect, bspan)
+        flags = self._finish_block(block, flags, works, collect, bspan)
+        self._assist = _commit_assist(works, envs, bspan)
+        return flags
+
+    def take_assist(self):
+        """What validate() learned of the block it saw last, as the
+        CommitAssist validate_pipeline hands out a block; handed over
+        once (Committer.store_block passes it to KVLedger.commit), so
+        nothing of a committed block stays alive here."""
+        assist, self._assist = self._assist, None
+        return assist
 
     def validate_pipeline(self, blocks, depth: int = 2, release=None,
                           rwsets_out=None):
@@ -666,24 +697,7 @@ class TxValidator:
             block, flags, works, collect, envs, bspan, txids = started
             flags = self._finish_block(block, flags, works, collect, bspan)
             if rwsets_out is not None:
-                # ONE per-block assist bundle: the marshaled rwsets, the
-                # already-decoded footprints (MVCC + history reuse), the
-                # txids (block-store index), and the envelope bytes (the
-                # store splice-serializes instead of re-encoding 1-2 MB)
-                from fabric_tpu.ledger.kvledger import CommitAssist
-
-                rwsets_out(
-                    CommitAssist(
-                        rwsets=[w.rwset for w in works],
-                        footprints=[w.footprint for w in works],
-                        txids=[w.txid for w in works],
-                        env_bytes=envs,
-                        # carries the block's trace root onto the
-                        # committer thread so the commit stages join
-                        # the same per-block trace
-                        trace_ctx=bspan.ctx,
-                    )
-                )
+                rwsets_out(_commit_assist(works, envs, bspan))
             if release is None:
                 seen_txids.difference_update(txids)  # close the window
             else:

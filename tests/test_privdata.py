@@ -132,7 +132,7 @@ class _FakeValidator:
 
 class _FakeLedger:
     """Ledger stand-in with a real PvtDataStore (the coordinator and
-    reconciler contract: commit(block, pvt, missing), pvt_store,
+    reconciler contract: commit(block, pvt, missing, assist), pvt_store,
     get_block_by_number, commit_old_pvt_data)."""
 
     def __init__(self, btl_policy=None):
@@ -141,7 +141,7 @@ class _FakeLedger:
         self.blocks = {}
         self.pvt_store = PvtDataStore(MemKVStore(), "ch", btl_policy)
 
-    def commit(self, block, pvt_data=None, missing_pvt=None):
+    def commit(self, block, pvt_data=None, missing_pvt=None, assist=None):
         self.committed.append((block.header.number, dict(pvt_data or {})))
         self.blocks[block.header.number] = block
         self.pvt_store.commit(
