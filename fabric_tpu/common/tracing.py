@@ -35,6 +35,23 @@ keeps every event in ``obs["spans"]`` for the per-layer readers
 (``benchmarks/layer_metrics/``) and lays the ``stage`` and ``bench``
 spans over the device's idle gaps.
 
+A span's ``dur`` is wall time, and the pipeline's threads share one
+interpreter lock, so an armed span also says how long its thread was
+on a CPU: ``tts``/``tdur`` (the trace-event format's thread clock and
+thread duration, microseconds of ``time.thread_time_ns()``) on every
+span that begins and ends on one thread, and ``args.proc_cpu_us``
+(``time.process_time_ns()``, every thread of the process counted) on
+the roots that bound a piece of work: a detached root, which has no
+thread of its own (a peer's ``block``, an orderer's ``raft.block``), and
+the ``cat="bench"`` roots a harness times with.  No other span pays for
+the second clock.
+``dur - tdur`` is the time the thread waited: for the lock, a queue,
+the disk or the scheduler.  Spans that follow each other within
+microseconds share one reading of the thread's clock where a reading
+is dear (``_thread_cpu_ns``): their ``tdur`` tile, and a single span is
+off by a few reads' worth of wall at most.  Under a virtual clock the
+fields are left out, so seeded documents stay byte-identical.
+
 Generation-2 collections stop every thread, so an armed process also
 records them: :func:`arm` installs the process's one ``gc.callbacks``
 entry and each long collection becomes a ``gc.pause`` span on the
@@ -171,6 +188,33 @@ _tls = threading.local()  # .stack: list[Span | _Remote]
 _stacks_by_thread: dict[int, list] = {}
 
 
+# A read of the thread's CPU clock is a system call: 0.4 us on Linux,
+# 6 us on a sandboxed kernel (whose clock then ticks at 10 ms), where
+# the wall clock costs 0.09.  Stages follow each other within
+# microseconds, so a span that begins or ends within a few reads' worth
+# of wall after its thread's last reading shares that reading: where the
+# clock is cheap next to nothing is shared and every span is exact,
+# where it is dear neighbours tile (one's end IS the next one's start),
+# nothing is counted twice, and a span is off by the window at most.
+_CPU_REUSE_READS = 8
+_cpu_reuse_s: float | None = None     # the window, measured at first use
+
+
+def _thread_cpu_ns(wall: float) -> int:
+    global _cpu_reuse_s
+    if _cpu_reuse_s is None:
+        t0 = time.perf_counter()
+        for _ in range(16):
+            time.thread_time_ns()
+        _cpu_reuse_s = _CPU_REUSE_READS * (time.perf_counter() - t0) / 16
+    last = getattr(_tls, "cpu", None)
+    if last is not None and 0 <= wall - last[0] < _cpu_reuse_s:
+        return last[1]
+    now = time.thread_time_ns()
+    _tls.cpu = (wall, now)
+    return now
+
+
 def _stack() -> list:
     s = getattr(_tls, "stack", None)
     if s is None:
@@ -220,7 +264,7 @@ class Span:
 
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "attrs", "cat",
-        "start", "_tid", "_detached", "_ended",
+        "start", "_tid", "_detached", "_ended", "_tcpu", "_pcpu",
     )
 
     def __init__(self, name: str, trace_id: int, span_id: int,
@@ -232,7 +276,21 @@ class Span:
         self.parent_id = parent_id
         self.cat = cat
         self.attrs = attrs
+        # CPU clocks (module docstring), real clock only: the thread's
+        # unless detached, the process's on a root that is detached or
+        # a harness's.  Read so that the process's interval contains
+        # the wall's and the thread's; the thread's passes the wall's
+        # by one read at most.
+        real = clockskew.installed() is None
+        self._pcpu = (
+            time.process_time_ns()
+            if real and parent_id is None and (detached or cat == "bench")
+            else None
+        )
         self.start = clockskew.monotonic()
+        self._tcpu = (
+            _thread_cpu_ns(self.start) if real and not detached else None
+        )
         self._tid = threading.current_thread().name
         self._detached = detached
         self._ended = False
@@ -256,6 +314,8 @@ class Span:
             return
         rec = _recorder
         end_ts = clockskew.monotonic()
+        tcpu = _thread_cpu_ns(end_ts) if self._tcpu is not None else None
+        pcpu = time.process_time_ns() if self._pcpu is not None else None
         if not self._detached:
             stack = _stack()
             # repair: close any child an exception left open above us
@@ -267,12 +327,14 @@ class Span:
                     top._ended = True
                     top.attrs["abandoned"] = True
                     if rec is not None:
+                        # closed at an end that is not its own: no CPU
                         rec.record(top._event(end_ts))
         self._ended = True
         if rec is not None:
-            rec.record(self._event(end_ts))
+            rec.record(self._event(end_ts, tcpu, pcpu))
 
-    def _event(self, end_ts: float) -> dict:
+    def _event(self, end_ts: float, tcpu: int | None = None,
+               pcpu: int | None = None) -> dict:
         args = {
             "trace": f"{self.trace_id:x}",
             "span": f"{self.span_id:x}",
@@ -280,10 +342,12 @@ class Span:
         if self.parent_id is not None:
             args["parent"] = f"{self.parent_id:x}"
         args.update(self.attrs)
+        if pcpu is not None:
+            args["proc_cpu_us"] = max(0, round((pcpu - self._pcpu) / 1e3))
         # round, not truncate: 0.01s on a virtual clock must be exactly
         # 10000µs, or determinism tests chase float dust
         ts = round(self.start * 1e6)
-        return {
+        event = {
             "ph": "X",
             "name": self.name,
             "cat": self.cat,
@@ -293,6 +357,13 @@ class Span:
             "tid": self._tid,
             "args": args,
         }
+        if tcpu is not None:
+            # the trace-event format's own thread clock: Perfetto and
+            # chrome://tracing show it as the slice's CPU duration
+            tts = round(self._tcpu / 1e3)
+            event["tts"] = tts
+            event["tdur"] = max(0, round(tcpu / 1e3) - tts)
+        return event
 
 
 class _Noop:
@@ -539,13 +610,17 @@ def _on_gc(phase: str, info: dict) -> None:
     if gen < 1 and dt < GC_SPAN_MIN_S:
         return
     ts = round(t0 * 1e6)
+    dur = max(0, round((t0 + dt) * 1e6) - ts)
     _gc_pending.append({
         "ph": "X",
         "name": "gc.pause",
         # "stage": the benchmark lays these over the device's idle gaps
         "cat": "stage",
         "ts": ts,
-        "dur": max(0, round((t0 + dt) * 1e6) - ts),
+        "dur": dur,
+        # the collector runs on the thread that tripped it: all CPU
+        "tts": max(0, round(time.thread_time_ns() / 1e3) - dur),
+        "tdur": dur,
         "pid": 0,
         "tid": threading.current_thread().name,
         "args": {

@@ -47,11 +47,12 @@ _pool = None
 _pool_lock = threading.Lock()
 
 # observability: an optional WorkpoolMetrics bundle (queue depth /
-# in-flight / saturation gauges, wired by operations.System) plus
-# always-on cheap counters for the bench JSON line; all under one lock
+# in-flight / saturation gauges, wired by operations.System) and the
+# count of chunks in flight that it and the /healthz checker read; both
+# under one lock
 _metrics = None
 _stats_lock = threading.Lock()
-_stats = {"chunks": 0, "in_flight": 0, "max_in_flight": 0}
+_in_flight = 0
 
 
 def set_metrics(metrics) -> None:
@@ -62,27 +63,12 @@ def set_metrics(metrics) -> None:
         _metrics = metrics
 
 
-def stats() -> dict:
-    """Always-on fan-out counters (chunks submitted, peak concurrent
-    chunks)."""
-    with _stats_lock:
-        return {k: v for k, v in _stats.items() if k != "in_flight"}
-
-
-def reset_stats() -> None:
-    with _stats_lock:
-        _stats["chunks"] = 0
-        _stats["max_in_flight"] = 0
-
-
 def _note_submit(pool, n_chunks: int) -> None:
+    global _in_flight
     with _stats_lock:
-        _stats["chunks"] += n_chunks
-        _stats["in_flight"] += n_chunks
-        if _stats["in_flight"] > _stats["max_in_flight"]:
-            _stats["max_in_flight"] = _stats["in_flight"]
+        _in_flight += n_chunks
         m = _metrics
-        inflight = _stats["in_flight"]
+        inflight = _in_flight
     if m is not None:
         m.in_flight.set(inflight)
         q = getattr(pool, "_work_queue", None)
@@ -93,10 +79,11 @@ def _note_submit(pool, n_chunks: int) -> None:
 
 
 def _note_done(n_chunks: int) -> None:
+    global _in_flight
     with _stats_lock:
-        _stats["in_flight"] = max(0, _stats["in_flight"] - n_chunks)
+        _in_flight = max(0, _in_flight - n_chunks)
         m = _metrics
-        inflight = _stats["in_flight"]
+        inflight = _in_flight
     if m is not None:
         m.in_flight.set(inflight)
 
@@ -160,7 +147,7 @@ def saturation() -> tuple[int, int, int]:
     q = getattr(pool, "_work_queue", None)
     depth = q.qsize() if q is not None else 0
     with _stats_lock:
-        inflight = _stats["in_flight"]
+        inflight = _in_flight
     return inflight, workers, depth
 
 
@@ -299,8 +286,6 @@ __all__ = [
     "stage_width",
     "run_chunked",
     "set_metrics",
-    "stats",
-    "reset_stats",
     "saturation",
     "health_checker",
 ]

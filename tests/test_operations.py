@@ -193,12 +193,12 @@ class TestOperationsServer:
             _work_queue = _FakeQueue(3)
 
         monkeypatch.setattr(workpool, "_pool", _FakePool())
-        monkeypatch.setitem(workpool._stats, "in_flight", 5)
+        monkeypatch.setattr(workpool, "_in_flight", 5)
         with pytest.raises(RuntimeError, match="saturated"):
             check()
         # full utilization with an empty queue is NOT unhealthy
         _FakePool._work_queue = _FakeQueue(0)
-        monkeypatch.setitem(workpool._stats, "in_flight", 2)
+        monkeypatch.setattr(workpool, "_in_flight", 2)
         assert check() is True
 
     def test_tpu_breaker_checker(self):

@@ -9,6 +9,8 @@ from __future__ import annotations
 
 import json
 import logging
+import threading
+import time
 import urllib.request
 
 import pytest
@@ -194,6 +196,222 @@ def test_virtual_clock_traces_are_byte_identical():
     assert a["dur"] == 10_000  # exactly the virtual 10ms, in µs
 
 
+# -- CPU time: how long a span's thread was on a CPU -------------------------
+
+
+def _spin_cpu(ns: int) -> None:
+    t0 = time.thread_time_ns()
+    while time.thread_time_ns() - t0 < ns:
+        pass
+
+
+def _armed_doc(work) -> dict:
+    tracing.arm(256)
+    try:
+        work()
+        return tracing.export()
+    finally:
+        tracing.disarm()
+        tracing.reset_ids()     # as scope() leaves the disarmed world
+
+
+def _thread_clock_step_us() -> int:
+    """The largest step this host's thread CPU clock takes, seen over a
+    short spin: under a microsecond on Linux, a whole 10 ms tick on a
+    sandboxed kernel that credits CPU time by timer."""
+    step, last = 0, time.thread_time_ns()
+    until = time.monotonic() + 0.03
+    while time.monotonic() < until:
+        now = time.thread_time_ns()
+        if now != last:
+            step, last = max(step, now - last), now
+    return step // 1000 + 1
+
+
+def test_a_spinning_span_is_all_cpu_and_a_sleeping_one_none():
+    """`tdur` is the thread's CPU between begin and end, `tts` the
+    thread clock at the start: a spin reads within a tenth of its wall,
+    a sleep under a twentieth, each with one step of the host's clock
+    to spare (a single span is quantised by it) and in the best of a
+    few tries (the suite's other workers share the machine's cores)."""
+    step = _thread_clock_step_us()
+
+    def work():
+        with tracing.span("block", cat="pipeline"):
+            with tracing.span("spin", cat="stage"):
+                _spin_cpu(40_000_000)
+            with tracing.span("sleep", cat="stage"):
+                time.sleep(0.06 + 20 * step / 1e6)
+
+    for _ in range(6):
+        before = time.thread_time_ns() / 1e3
+        doc = _armed_doc(work)
+        (spin,) = _by_name(doc, "spin")
+        (sleep,) = _by_name(doc, "sleep")
+        if (0.9 * spin["dur"] - step <= spin["tdur"] <= spin["dur"] + step
+                and spin["tdur"] >= 40_000 - step
+                and sleep["tdur"] < sleep["dur"] / 20):
+            break
+    else:
+        pytest.fail(f"no try read a spin as CPU and a sleep as none: {spin} {sleep}")
+    assert before - step <= spin["tts"] <= sleep["tts"] - spin["tdur"] + step
+    assert sleep["dur"] >= 60_000
+    for ev in (spin, sleep):        # children: the thread's clock alone
+        assert "proc_cpu_us" not in ev["args"]
+
+
+def test_spans_that_follow_each_other_share_a_reading_and_tile(monkeypatch):
+    """A read of the thread's clock is a system call, and stages follow
+    each other within microseconds: within a few reads' worth of wall
+    (here 20 us) after the thread's last reading a span's begin or end
+    shares it, so
+    neighbours tile (one's end is the next one's start) and no CPU is
+    counted twice; one span is off by that much at most.  On clocks of the test's own: the wall steps 7 us a read,
+    the thread's clock 3 us a read."""
+    wall, cpu = [1000.0], [7_000_000]
+
+    def fake_wall():
+        wall[0] += 7e-6
+        return wall[0]
+
+    def fake_cpu():
+        cpu[0] += 3_000
+        return cpu[0]
+
+    monkeypatch.setattr(clockskew, "monotonic", fake_wall)
+    monkeypatch.setattr(time, "thread_time_ns", fake_cpu)
+    monkeypatch.setattr(tracing, "_cpu_reuse_s", 20e-6)
+
+    def work():
+        for name in "abcde":
+            with tracing.span(name, cat="stage"):
+                pass
+        wall[0] += 1.0                      # a pause: nothing is shared over it
+        with tracing.span("later", cat="stage"):
+            wall[0] += 0.5
+
+    reads0 = cpu[0]
+    events = [e for e in _armed_doc(work)["traceEvents"] if e["ph"] == "X"]
+    # ten begins and ends 7 us apart: a fresh reading every third, then
+    # both ends of the late span
+    assert (cpu[0] - reads0) // 3_000 == 4 + 2
+    five, later = events[:5], events[5]
+    t0 = five[0]["tts"]
+    # a, c and d lie between two shared readings; b and e span a fresh one
+    assert [(e["name"], e["tts"] - t0, e["tdur"]) for e in five] == [
+        ("a", 0, 0), ("b", 0, 3), ("c", 3, 0), ("d", 6, 0), ("e", 6, 3)]
+    assert (later["tts"] - t0, later["tdur"]) == (12, 3)
+
+
+def test_detached_spans_and_roots_carry_the_process_cpu():
+    """A detached span has no thread of its own: no `tdur`.  The roots
+    that bound a piece of work read the process's clock: a detached
+    root (a peer's `block`) that alone, a harness's root
+    (`cat="bench"`) beside its thread's.  The process's clock counts
+    every thread, so a reading holds a helper thread's spin that the
+    root's own `tdur` does not.  No other span pays for the second
+    clock, a detached span under a root among them."""
+    def spin_beside():
+        th = threading.Thread(target=_spin_cpu, args=(30_000_000,))
+        th.start()
+        th.join()
+
+    def work():
+        block = tracing.begin("block", detach=True, cat="pipeline")
+        spin_beside()
+        block.end()
+        with tracing.span("root", cat="bench"):
+            det = tracing.begin("det", detach=True)
+            spin_beside()
+            det.end()
+            with tracing.span("child"):
+                pass
+
+    step = _thread_clock_step_us()
+    doc = _armed_doc(work)
+    (block,) = _by_name(doc, "block")
+    (root,) = _by_name(doc, "root")
+    (det,) = _by_name(doc, "det")
+    (child,) = _by_name(doc, "child")
+    for ev in (block, det):
+        assert "tts" not in ev and "tdur" not in ev
+    assert block["args"]["proc_cpu_us"] >= 29_000 - step
+    assert root["args"]["proc_cpu_us"] >= 29_000 - step
+    assert isinstance(root["tts"], int) and isinstance(root["tdur"], int)
+    # it joined, did not spin
+    assert root["tdur"] < root["args"]["proc_cpu_us"] - 20_000 + step
+    assert "proc_cpu_us" not in det["args"]
+    assert "tdur" in child and "proc_cpu_us" not in child["args"]
+    # a span under a context carried from another thread is no root
+    def hop():
+        with tracing.span("origin") as sp:
+            ctx = sp.ctx
+        with tracing.attached(ctx), tracing.span("hopped"):
+            pass
+
+    (hopped,) = _by_name(_armed_doc(hop), "hopped")
+    assert "tdur" in hopped and "proc_cpu_us" not in hopped["args"]
+    # nor does a root of another category read the process's clock
+    def waits():
+        with tracing.span("commit.idle", cat="stage"):
+            pass
+
+    (idle,) = _by_name(_armed_doc(waits), "commit.idle")
+    assert "parent" not in idle["args"]
+    assert "tdur" in idle and "proc_cpu_us" not in idle["args"]
+
+
+def test_an_abandoned_child_has_no_cpu_time_of_its_own():
+    def work():
+        outer = tracing.begin("outer", cat="bench")
+        tracing.begin("inner")        # a crash path never ends it
+        outer.end()
+
+    doc = _armed_doc(work)
+    (inner,) = _by_name(doc, "inner")
+    (outer,) = _by_name(doc, "outer")
+    assert inner["args"]["abandoned"] is True
+    assert "tts" not in inner and "tdur" not in inner
+    assert "tdur" in outer and "proc_cpu_us" in outer["args"]
+
+
+def test_a_virtual_clock_document_carries_no_cpu_time():
+    """Seeded documents stay byte-identical: under an installed virtual
+    clock no event has `tts`, `tdur` or `proc_cpu_us`, roots and
+    detached spans among them."""
+    def run():
+        with clockskew.use_virtual(clockskew.VirtualClock(start=500.0)):
+            with tracing.scope() as rec:
+                _clocked_workload()
+                det = tracing.begin("det", detach=True, cat="pipeline")
+                clockskew.sleep(0.005)
+                det.end()
+                return tracing.export(rec)
+
+    a, b = run(), run()
+    assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
+    assert len(a["traceEvents"]) == 5
+    for ev in a["traceEvents"]:
+        assert "tts" not in ev and "tdur" not in ev
+        assert "proc_cpu_us" not in ev["args"]
+    # what a stage event holds is what it held before the CPU clocks
+    (stage,) = _by_name(a, "stage.a")
+    assert set(stage) == {"ph", "name", "cat", "ts", "dur", "pid", "tid", "args", "id"}
+
+
+def test_gc_pause_events_count_as_cpu():
+    import gc
+
+    tracing.arm(256)
+    try:
+        gc.collect(2)
+        (pause,) = _by_name(tracing.export(), "gc.pause")
+    finally:
+        tracing.disarm()
+        tracing.reset_ids()
+    assert pause["tdur"] == pause["dur"] and pause["tts"] >= 0
+
+
 # -- /traces endpoint --------------------------------------------------------
 
 
@@ -239,6 +457,11 @@ def test_traces_endpoint_serves_flight_recorder():
         (probe,) = _by_name(doc, "ops.probe")
         assert probe["ph"] == "X"
         assert probe["args"]["block"] == 7
+        # every span here began and ended on one thread: Perfetto reads
+        # a thread duration on each
+        spans = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+        assert len(spans) >= 5
+        assert all(isinstance(e["tts"], int) and 0 <= e["tdur"] for e in spans)
         # RPC hop: serve nests under call nests under ops.probe
         (serve,) = _by_name(doc, "rpc.serve")
         (call,) = _by_name(doc, "rpc.call")
@@ -408,6 +631,28 @@ def test_traced_commit_stream_is_byte_identical_to_untraced(tmp_path):
         provider.close()
 
 
+def test_span_sequence_of_a_traced_commit_is_what_it_was(tmp_path):
+    """The CPU clocks ride on the events and stay out of the
+    determinism view: a commit traced on the real clock (its spans
+    carry `tdur`) and the same commit under a virtual clock (none does)
+    give one `span_sequence()`, the four-tuples it always gave."""
+    with tracing.scope() as rec:
+        _run_commit_workload(str(tmp_path / "real"))
+        real = tracing.export(rec)
+    with clockskew.use_virtual(clockskew.VirtualClock(start=10.0, auto_step=1e-4)):
+        with tracing.scope() as rec:
+            _run_commit_workload(str(tmp_path / "virtual"))
+            virtual = tracing.export(rec)
+    assert any("tdur" in e for e in real["traceEvents"])
+    assert not any("tdur" in e for e in virtual["traceEvents"])
+    seq = tracing.span_sequence(real)
+    assert seq == tracing.span_sequence(virtual)
+    assert seq and all(len(t) == 4 for t in seq)
+    names = [t[0] for t in seq]
+    for stage in ("mvcc", "block_append", "state", "history", "kv_txn"):
+        assert names.count(stage) == 5, stage
+
+
 # -- satellites: log correlation + workpool metrics --------------------------
 
 
@@ -426,11 +671,10 @@ def test_flogging_emits_trace_ids_when_armed():
     assert fmt.format(record) == "hello"
 
 
-def test_workpool_metrics_gauges_and_stats():
+def test_workpool_metrics_gauges():
     from fabric_tpu.common.metrics import PrometheusProvider, WorkpoolMetrics
 
     prov = PrometheusProvider()
-    workpool.reset_stats()
     workpool.set_metrics(WorkpoolMetrics(prov))
     try:
         with workpool.scoped_pool(2) as pool:
@@ -439,16 +683,14 @@ def test_workpool_metrics_gauges_and_stats():
                 list(range(20)), 4,
             )
         assert out == [v + 1 for v in range(20)]
-        stats = workpool.stats()
-        assert stats["chunks"] == 4
-        assert 1 <= stats["max_in_flight"] <= 4
         exposed = prov.registry.expose()
+        # four chunks went out and all came back
         assert "workpool_in_flight_chunks 0" in exposed
-        assert "workpool_worker_saturation" in exposed
+        assert "workpool_worker_saturation 1" in exposed
         assert "workpool_queue_depth" in exposed
+        assert workpool.saturation()[0] == 0
     finally:
         workpool.set_metrics(None)
-        workpool.reset_stats()
 
 
 def test_operations_system_builds_workpool_metrics_lazily():
