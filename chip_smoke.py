@@ -261,9 +261,9 @@ def leg_a_child(seed: int) -> int:
             self.flushes: list[tuple[int, tuple]] = []
             self.last_items: list = []
 
-        def verify_batch_async(self, items):
+        def verify_batch_async(self, items, flush=False):
             self.submitted += len(items)
-            return super().verify_batch_async(items)
+            return super().verify_batch_async(items, flush=flush)
 
         def _dispatch(self, items):
             res = super()._dispatch(items)

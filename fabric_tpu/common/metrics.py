@@ -614,6 +614,16 @@ class CSPMetrics:
                  "Growth means blocks of a handful of transactions: a "
                  "lightly loaded channel cut by BatchTimeout.",
         ))
+        self.early_flushes = provider.new_counter(CounterOpts(
+            namespace="csp",
+            subsystem="tpu",
+            name="early_flushes_total",
+            help="Device flushes a caller asked for while it still "
+                 "collected the rest of its batch: the first 2,048 "
+                 "lanes of a block validated alone that holds more.  "
+                 "One a block on a peer that keeps up with a channel "
+                 "of full blocks, none while it streams a backlog.",
+        ))
         self.breaker_state.set(0)
 
 

@@ -306,16 +306,26 @@ class CSP(abc.ABC):
     @abc.abstractmethod
     def verify_batch(self, items: Sequence[VerifyBatchItem]) -> list[bool]: ...
 
-    def verify_batch_async(self, items: Sequence[VerifyBatchItem]):
+    def verify_batch_async(self, items: Sequence[VerifyBatchItem],
+                           flush: bool = False):
         """Dispatch a batch verify and return a zero-arg collector.
 
         Device providers override this to return BEFORE the device
         finishes, so callers can overlap host work for the next batch
         with the device's current one (the block-pipeline mode of the
         txvalidator).  The default computes eagerly — correct for host
-        providers, which have nothing to overlap."""
+        providers, which have nothing to overlap.  `flush=True` asks a
+        provider that buffers batches to dispatch now; the default has
+        buffered nothing."""
         result = self.verify_batch(items)
         return lambda: result
+
+    def early_chunk(self, lanes: int) -> int | None:
+        """How many lanes of a batch of `lanes` that is collected alone
+        the provider wants first (handed over with `flush=True` while
+        the caller collects the rest), or None: it takes the batch
+        whole.  A host provider overlaps nothing, so None."""
+        return None
 
 
 __all__ = [

@@ -282,8 +282,13 @@ class CustodyCSP(CSP):
     def verify_batch(self, items) -> list[bool]:
         return self._local.verify_batch(self._publicized(items))
 
-    def verify_batch_async(self, items):
-        return self._local.verify_batch_async(self._publicized(items))
+    def verify_batch_async(self, items, flush: bool = False):
+        return self._local.verify_batch_async(
+            self._publicized(items), flush=flush
+        )
+
+    def early_chunk(self, lanes: int) -> int | None:
+        return self._local.early_chunk(lanes)
 
     def close(self) -> None:
         """Quiesce the local verify provider (a TPUCSP joins its flush

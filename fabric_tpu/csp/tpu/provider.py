@@ -58,8 +58,12 @@ except ModuleNotFoundError as _exc:  # pragma: no cover - minimal hosts
 
 _BATCH_BUCKETS = (32, 128, 512, 2048, 4096, 8192, 32768)  # single dispatch
 # for big batches: per-call overhead beats chunk-pipelining wins
-# (4096 matters: a 1000-tx block at 3-of-5 is 4000 sigs)
 _MAX_CHUNK = 8192  # largest single kernel execution
+# a flush of more than one and at most two of these runs as chunks of
+# this size (a 1000-tx block at 3-of-5 is 4000 sigs: 2048 + 1952), so
+# that whoever collects such a batch alone can hand the first chunk to
+# the device while it collects the rest (`TPUCSP.early_chunk`)
+_EARLY_CHUNK = 2048
 
 # Who can seal a verified lane's mask (TPUCSP.lane_tally keys, the
 # `sealed_by` label of csp_tpu_lanes_total): the device; the host race
@@ -126,10 +130,17 @@ def _chunk_plan(
 ) -> list[tuple[int, int]]:
     """(lanes, padded_bucket) per kernel execution.  Full chunks run at
     max_chunk; the tail pads to its own bucket instead of inflating the
-    whole batch to the next power of two.  min_bucket floors the pad
-    size — the Pallas paths pass the kernel block (256) so every chunk
-    is a whole number of grid blocks and device placement never falls
-    back to a host-side pad."""
+    whole batch to the next power of two.  A flush of 2,049-4,096 lanes
+    is cut at _EARLY_CHUNK instead, whoever sends it and however it is
+    dispatched: [(2048, 2048), (n - 2048, its own bucket)], so the 4096
+    bucket is named for no n up to max_chunk and a process that hands
+    the first chunk over early (`TPUCSP.early_chunk`) uses the shapes
+    of one that does not.  min_bucket floors the pad size — the Pallas
+    paths pass the kernel block (256) so every chunk is a whole number
+    of grid blocks and device placement never falls back to a
+    host-side pad."""
+    if _EARLY_CHUNK < n <= 2 * _EARLY_CHUNK:
+        max_chunk = min(max_chunk, _EARLY_CHUNK)
     out = []
     left = n
     while left > 0:
@@ -982,7 +993,28 @@ class TPUCSP(CSP):
     def verify_batch(self, items: Sequence[VerifyBatchItem]) -> list[bool]:
         return self.verify_batch_async(items)()
 
-    def verify_batch_async(self, items: Sequence[VerifyBatchItem]):
+    def early_chunk(self, lanes: int) -> int | None:
+        """Where a batch of `lanes` that is collected alone is cut: the
+        lanes of the chunk plan's first chunk when the plan has more
+        than one, else None.  The validator of a lone block hands that
+        many over with `flush=True` as soon as it holds them, and the
+        rest at the end of its collect, so the device runs the first
+        chunk while the host collects the second (a flush that was one
+        dispatch of these same chunks at the end).  None too while this
+        process has not enqueued the first chunk's bucket yet: that
+        enqueue traces and lowers the kernel shape for seconds, which
+        is no time to take out of the middle of a collect, so the first
+        such batch goes out whole and warms the shape at its end."""
+        plan = _chunk_plan(lanes, self._max_chunk, min_bucket=256)
+        if len(plan) < 2:
+            return None
+        take, bucket = plan[0]
+        if not any(b == bucket for _kernel, b in _enqueued):
+            return None
+        return take
+
+    def verify_batch_async(self, items: Sequence[VerifyBatchItem],
+                           flush: bool = False):
         """Enqueue a batch, return its collector.
 
         Batches are COALESCED across calls: every kernel execution pays
@@ -992,7 +1024,13 @@ class TPUCSP(CSP):
         call — when `coalesce_lanes` lanes are pending, or at the first
         collector invocation.  The device still executes asynchronously
         after the flush, so pipelined callers keep their host/device
-        overlap while paying the fixed cost once per ~2 blocks."""
+        overlap while paying the fixed cost once per ~2 blocks.
+
+        `flush=True` dispatches what is pending, this batch included,
+        before it returns: for a caller with nothing behind it to hide
+        the flush (a lone block's first `early_chunk`), which goes on
+        collecting while the device runs.  Each flush is a generation
+        of its own, and a collector returns its own segment's mask."""
         if len(items) < self._min_device_batch:
             # too small for the device: verified here, on the caller's
             # thread (the validator's `collect`), to the same rule; the
@@ -1015,8 +1053,8 @@ class TPUCSP(CSP):
             seg_start = self._pend_lanes
             self._pend_batches.append(items)
             self._pend_lanes += len(items)
-            if self._pend_lanes >= self._coalesce:
-                self._flush_locked()
+            if flush or self._pend_lanes >= self._coalesce:
+                self._flush_locked(early=flush)
         n = len(items)
 
         memo: list = []
@@ -1066,9 +1104,11 @@ class TPUCSP(CSP):
 
         return collector
 
-    def _flush_locked(self) -> None:
+    def _flush_locked(self, early: bool = False) -> None:
         """Dispatch every pending batch as one chunked device call and
-        advance the generation.  Caller holds _pend_lock."""
+        advance the generation.  Caller holds _pend_lock.  `early`: a
+        caller asked for it (`verify_batch_async(flush=True)`) while it
+        still collects what the next flush will carry."""
         guarded(self, "_pend_batches", by="csp.tpu.pend")
         items: list = []
         segments = self._pend_batches
@@ -1080,13 +1120,15 @@ class TPUCSP(CSP):
         self._gen += 1
         if self._metrics is not None:
             self._metrics.flush_segments.add(len(segments))
+            if early:
+                self._metrics.early_flushes.add()
         # dispatch begun -> mask sealed, ended by whoever seals it; it
         # shares `batch` with tpu.dispatch and the segments' tpu.collect.
         # `segments`: the verify_batch_async batches it took in (a block
         # each under store_stream), `segment_lanes` their lanes in order
         fspan = tracing.begin(
             "tpu.flush", detach=True, batch=gen, lanes=len(items),
-            segments=len(segments),
+            segments=len(segments), early=early,
         )
         if tracing.enabled():
             fspan.annotate(segment_lanes=[len(b) for b in segments])

@@ -75,6 +75,10 @@ class _ItemSink:
         self.items: list = []
         self._index: dict = {}
         self._dedup = dedup
+        # a lone block's first provider chunk, once handed over: its
+        # lanes (items[:early_lanes]) and their collector
+        self.early_lanes = 0
+        self._early = None
         # the other kind of lane: anonymous creators' deferred items
         self.idemix = _IdemixSink()
 
@@ -92,6 +96,35 @@ class _ItemSink:
 
     def add_many(self, items) -> list[int]:
         return [self.add(it) for it in items]
+
+    def hand_early(self, csp, lanes: int) -> None:
+        """Hand the provider the first `lanes` items now, to be
+        dispatched before this returns; the sink goes on filling.  Its
+        indices stay what they were: the sink only appends, and a later
+        duplicate of an item handed early gets the early index."""
+        self._early = csp.verify_batch_async(self.items[:lanes], flush=True)
+        self.early_lanes = lanes
+
+    def hand_over(self, csp):
+        """The collector of the block's mask, over every item in sink
+        order: one batch, or after `hand_early` the early chunk's mask
+        and the rest's joined."""
+        early, rest = self._early, self.items
+        if early is None:
+            return csp.verify_batch_async(rest) if rest else (lambda: [])
+        rest = rest[self.early_lanes:]
+        if not rest:
+            return early
+        tail = csp.verify_batch_async(rest)
+
+        def collect():
+            # the tail first: its first collector is what dispatches it
+            # (the early chunk's flush is out already), and the device
+            # runs the two in order
+            mask = tail()
+            return early() + mask
+
+        return collect
 
 
 class _IdemixSink:
@@ -346,6 +379,9 @@ class TxValidator:
         # blocks whose collect actually fanned out (the tier-1 smoke
         # asserts the parallel path ran, not just that flags matched)
         self.parallel_collect_blocks = 0
+        # lone blocks whose first provider chunk went to the device
+        # while the rest was still collected (`_ItemSink.hand_early`)
+        self.early_flush_blocks = 0
         # the CommitAssist of the block validate() saw last, until
         # take_assist() hands it over
         self._assist = None
@@ -650,8 +686,10 @@ class TxValidator:
 
     def validate(self, block: common_pb2.Block) -> list[int]:
         self._assist = None  # never an earlier block's, if this raises
+        # one block and nothing behind it: no next block's collect will
+        # hide its flush, so it may hand its first chunk over early
         block, flags, works, collect, envs, bspan = self._start_block(
-            block, set()
+            block, set(), lone=True
         )
         flags = self._finish_block(block, flags, works, collect, bspan)
         self._assist = _commit_assist(works, envs, bspan)
@@ -725,8 +763,11 @@ class TxValidator:
             return 0
         return min(width, n)
 
-    def _start_block(self, block: common_pb2.Block, seen_txids: set):
-        """Phases 1+2: collect every tx, dispatch the device verify."""
+    def _start_block(self, block: common_pb2.Block, seen_txids: set,
+                     lone: bool = False):
+        """Phases 1+2: collect every tx, dispatch the device verify.
+        `lone`: the caller validates this block alone (`validate`), so
+        the flush overlaps nothing unless the collect itself does."""
         t0 = time.perf_counter()
         num = block.header.number
         # detached per-block root: its children (collect here,
@@ -739,7 +780,7 @@ class TxValidator:
         )
         try:
             return self._start_block_traced(
-                block, seen_txids, bspan, num, t0
+                block, seen_txids, bspan, num, t0, lone
             )
         except BaseException:
             # detached roots are off the stack-repair path: end the
@@ -750,7 +791,7 @@ class TxValidator:
             bspan.end()
             raise
 
-    def _start_block_traced(self, block, seen_txids, bspan, num, t0):
+    def _start_block_traced(self, block, seen_txids, bspan, num, t0, lone):
         with tracing.attached(bspan.ctx), tracing.span(
             "collect", cat="stage", block=num,
         ) as cspan:
@@ -777,7 +818,7 @@ class TxValidator:
             else:
                 self._ns_meta_block = None
             native = self._collect_native(
-                envs, seen_txids, sink, works, flags, memo
+                envs, seen_txids, sink, works, flags, memo, lone
             )
             if not native:
                 width = self._collect_fanout(n)
@@ -806,11 +847,9 @@ class TxValidator:
                             envs[i], seen_txids, sink, works[i], memo
                         )
 
-            collect = (
-                self._csp.verify_batch_async(sink.items)
-                if sink.items
-                else (lambda: [])
-            )
+            collect = sink.hand_over(self._csp)
+            if sink.early_lanes:
+                self.early_flush_blocks += 1
             if sink.idemix.by_msp:
                 # the block's Idemix items go out here too, as ONE
                 # asynchronous batched verify (an MSP), before
@@ -826,6 +865,7 @@ class TxValidator:
                     creator_validations=memo.validations,
                     creator_ms=memo.seconds * 1e3,
                     creator_chain_batch=memo.chain_batch,
+                    early_lanes=sink.early_lanes,
                 )
         self._observe_stage("collect", time.perf_counter() - t0)
         # inside collect, not beside it: what of the stage went to
@@ -833,7 +873,8 @@ class TxValidator:
         self._observe_stage("creators", memo.seconds)
         return block, flags, works, collect, envs, bspan
 
-    def _collect_native(self, data, seen_txids, sink: _ItemSink, works, flags, memo: dict) -> bool:
+    def _collect_native(self, data, seen_txids, sink: _ItemSink, works, flags, memo: dict,
+                        lone: bool = False) -> bool:
         """Native-assisted collect: one C++ pass walks every envelope's
         wire format (syntactic checks + SHA-256 digests, collect.cc),
         then this glue does only identity/policy work per tx.  `data` is
@@ -858,7 +899,14 @@ class TxValidator:
         signature joins the ECDSA sink over the walker's payload digest,
         an anonymous (Idemix) creator's credential proof and pseudonym
         signature join the block's Idemix sink (`_IdemixSink`), exactly
-        as the Python half (`_parse_tx` / `_integrate_tx`) does."""
+        as the Python half (`_parse_tx` / `_integrate_tx`) does.
+
+        A `lone` block (nothing behind it whose collect would hide its
+        flush) asks the provider where its lanes would be cut
+        (`early_chunk`, of the walker's count: a creator and the
+        endorsements of every lane it accepted) and hands exactly that
+        many to the device as soon as the sink holds them, so the first
+        chunk's kernel runs under the rest of this loop."""
         from fabric_tpu import native
         from fabric_tpu.csp.api import VerifyBatchItem
 
@@ -992,7 +1040,20 @@ class TxValidator:
                 prefetched[i] = fp
             self.parallel_collect_blocks += 1
 
+        cut = None
+        if lone and not self._faithful:
+            early_chunk = getattr(self._csp, "early_chunk", None)
+            if early_chunk is not None:
+                ok = co["status"] >= 0
+                cut = early_chunk(
+                    int(ok.sum()) + int(co["endo_count"][ok].sum())
+                )
+        items = sink.items
+
         for i in range(len(data)):
+            if cut is not None and len(items) >= cut:
+                sink.hand_early(self._csp, cut)
+                cut = None
             st = status_l[i]
             if st < 0:  # python re-derives every non-valid lane
                 flags[i] = self._collect_tx(
