@@ -83,7 +83,9 @@ class _Ledgers:
             provider = LedgerProvider(path)
             ledger = provider.create(self._world.genesis)
             bundle = self._bundle()
-            validator = TxValidator(self._world.channel, ledger, bundle, self._csp)
+            validator = TxValidator(
+                self._world.channel, ledger, bundle, self._csp,
+                definition_provider=getattr(self._world, "definition_provider", None))
             self.cur = (provider, ledger, validator, Committer(validator, ledger), path)
         return self.cur
 

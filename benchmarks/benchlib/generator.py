@@ -1,6 +1,7 @@
-"""Seeded world and block generator: the benchmark's own copy of
-`scripts/bench_pipeline.py` `_build_world`/`_make_blocks`, made seeded
-and quick.  `benchmarks/worlds/x509-majority.py` gives it to the
+"""Seeded world and block generator, the one X.509 generator of the
+tree (its original, `_build_world`/`_make_blocks` of a script that
+PR 30 deleted, drove the endorser; this is that made seeded and
+quick).  `benchmarks/worlds/x509-majority.py` gives it to the
 harness under the name the configurations use; its helpers (seeded
 keys and CAs, `Org`) are there for a later world to build on.
 

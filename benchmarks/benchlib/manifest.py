@@ -38,6 +38,15 @@ n_blocks)`; what comes back has
                       the reference as it is
     lanes_per_block   signatures a block carries (printed only)
 
+and, if its chaincodes are not all under the channel's default
+endorsement policy,
+
+    definition_provider   what a peer's lifecycle gives its validator:
+                      `validation_info(namespace)` -> (plugin name,
+                      ApplicationPolicy bytes) or None for the default;
+                      handed to every `TxValidator` the engine builds.
+                      A world without the attribute gets none.
+
 The same seed gives the same world.  A world module touches no JAX: the
 engine builds the world while the device initialises.
 

@@ -12,10 +12,10 @@ def apply():
 
     inner = TPUCSP.verify_batch_async
 
-    def verify_batch_async(self, items):
+    def verify_batch_async(self, items, flush=False):
         if len(items) < self._min_device_batch:
             self._note_sealed("small", len(items))
             return lambda: [True] * len(items)
-        return inner(self, items)
+        return inner(self, items, flush)
 
     TPUCSP.verify_batch_async = verify_batch_async

@@ -1,8 +1,11 @@
 """The cells' kernel shapes compile for a described v5e, without the
 chip (on-chip-measurement guide, section 2): `pallas_ec`'s key-table
-kernel at the three buckets the benchmark's cells dispatch.  What the
-chip's compiler refuses here costs no chip time.  Nothing runs, so
-this says nothing about results or times.
+kernel at the two buckets the full-block X.509 cells dispatch (since
+PR 38 no cell dispatches 4096: a flush of 2,049-4,096 lanes runs as
+chunks of 2,048), and its per-lane-key kernel at the 8192 bucket of
+`manyclients-10k.catchup`, whose two-block flush holds more keys than
+the table.  What the chip's compiler refuses here costs no chip time.
+Nothing runs, so this says nothing about results or times.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU's library, and every test worker imports
@@ -13,7 +16,9 @@ import os
 
 import pytest
 
-BUCKETS = (2048, 4096, 8192)   # solo1 lone/run, majority5 lone, majority5 pair
+# (kernel, bucket): solo1's flushes and majority5's lone block in chunks;
+# majority5's pair of blocks; manyclients-10k's pair, a key a lane
+SHAPES = (("ktab", 2048), ("ktab", 8192), ("per_lane", 8192))
 
 
 @pytest.fixture(scope="module")
@@ -29,8 +34,8 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-@pytest.mark.parametrize("bucket", BUCKETS)
-def test_pallas_ec_compiles_for_v5e_at_the_cells_buckets(one_chip, bucket):
+@pytest.mark.parametrize("kernel, bucket", SHAPES)
+def test_pallas_ec_compiles_for_v5e_at_the_cells_buckets(one_chip, kernel, bucket):
     import jax
     import jax.numpy as jnp
     from jax.experimental.compilation_cache import compilation_cache
@@ -45,8 +50,13 @@ def test_pallas_ec_compiles_for_v5e_at_the_cells_buckets(one_chip, bucket):
         c["solmat"], c["bias"], c["r256"], c["r512"], c["sub_c"],
         c["p_limbs"], c["n_limbs"], c["gx"][:, :, 0], c["gy"][:, :, 0],
     ]
-    args = [
-        shape(8, pallas_ec.KEYTAB), shape(8, pallas_ec.KEYTAB), shape(1, bucket),
+    if kernel == "ktab":      # the key table and an index a lane
+        keys = [shape(8, pallas_ec.KEYTAB), shape(8, pallas_ec.KEYTAB), shape(1, bucket)]
+        build = pallas_ec._build_call_dedup
+    else:                     # qx, qy a lane
+        keys = [shape(8, bucket), shape(8, bucket)]
+        build = pallas_ec._build_call
+    args = keys + [
         shape(8, bucket), shape(8, bucket), shape(8, bucket), shape(2, bucket),
     ] + [
         jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip) for a in consts
@@ -57,9 +67,7 @@ def test_pallas_ec_compiles_for_v5e_at_the_cells_buckets(one_chip, bucket):
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        call = pallas_ec._build_call_dedup.__wrapped__(
-            bucket // pallas_ec.BLK, pallas_ec.BLK, False
-        )
+        call = build.__wrapped__(bucket // pallas_ec.BLK, pallas_ec.BLK, False)
         compiled = call.lower(*args).compile()
     finally:
         jax.config.update("jax_enable_compilation_cache", was)

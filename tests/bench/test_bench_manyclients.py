@@ -142,11 +142,10 @@ def test_a_rehearsal_agrees_with_its_reference_to_the_flag_and_the_state_entry(s
 
 
 def test_a_traced_rehearsal_reports_the_metrics_the_host_can_read(sound, man, held):
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        doc = json.load(f)
-    due = {m["name"] for m in doc["per_layer"]
-           if CELL in m.get("workloads", ()) or "workloads" not in m}
-    assert len(due) == 23 and set(sound["metrics"]) <= due
+    # due by the harness's own rule: an entry without `workloads` is due
+    # where the end-to-end metric it moves is reported, not everywhere
+    due = {m["name"] for m in man.metrics("per_layer", CELL)}
+    assert set(sound["metrics"]) <= due
     assert {"collect_ms_per_block.catchup", "verify_wait_ms_per_block.catchup",
             "commit_ms_per_block.catchup", "lanes_per_flush.catchup",
             "creator_validate_ms_per_block.catchup", "creator_miss_share.catchup",
