@@ -402,9 +402,20 @@ class ValidateMetrics:
     validate-side counterpart of CommitMetrics, so the /metrics reader
     can see which side of the validate->commit pipeline owns the p99.
     `creators` lies inside `collect`: what of it went to deserialising
-    and validating the creators the block's memo did not hold."""
+    and validating the creators the block's memo did not hold.
+    `await_commit` stands between `verify_wait` and `policy`, and only
+    for a pipelined block some of whose key-level endorsement decisions
+    depend on an earlier block's commit: how long it waited for that
+    commit to land (peer/txvalidator.py `_KeyWindow`).
 
-    STAGES = ("collect", "creators", "verify_wait", "policy")
+    Key-level (state-based) endorsement has three counters beside
+    them: the committed state-metadata lookups the validator made, the
+    decisions it deferred to the policy stage because a block in flight
+    could still change a key's VALIDATION_PARAMETER, and the
+    endorsement-plan cache's outcomes (`cleared`: the cache ran over
+    its cap and was emptied)."""
+
+    STAGES = ("collect", "creators", "verify_wait", "await_commit", "policy")
 
     def __init__(self, provider):
         self.stage_duration = provider.new_histogram(HistogramOpts(
@@ -412,12 +423,43 @@ class ValidateMetrics:
             subsystem="block",
             name="stage_duration",
             help="Seconds spent in one validate stage for one block "
-                 "(collect, creators inside it, verify_wait, policy).",
+                 "(collect, creators inside it, verify_wait, "
+                 "await_commit, policy).",
             buckets=(
                 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05,
                 0.1, 0.25, 0.5, 1.0, 2.5,
             ),
             statsd_format="%{channel}.%{stage}",
+        ))
+        self.keylevel_lookups = provider.new_counter(CounterOpts(
+            namespace="validator",
+            subsystem="keylevel",
+            name="lookups_total",
+            help="Committed state-metadata lookups made to find a "
+                 "written key's VALIDATION_PARAMETER.",
+            label_names=("channel",),
+            statsd_format="%{channel}",
+        ))
+        self.keylevel_deferred = provider.new_counter(CounterOpts(
+            namespace="validator",
+            subsystem="keylevel",
+            name="deferred_total",
+            help="Transactions whose endorsement policies were resolved "
+                 "only once an earlier block's commit had landed, "
+                 "because that block could change a written key's "
+                 "VALIDATION_PARAMETER.",
+            label_names=("channel",),
+            statsd_format="%{channel}",
+        ))
+        self.plan_cache = provider.new_counter(CounterOpts(
+            namespace="validator",
+            subsystem="plan",
+            name="cache_total",
+            help="Endorsement-plan cache outcomes: hit, miss (a plan "
+                 "built), cleared (the cache ran over its cap and was "
+                 "emptied).",
+            label_names=("outcome",),
+            statsd_format="%{outcome}",
         ))
 
 

@@ -798,6 +798,10 @@ class TPUCSP(CSP):
         self._metrics = metrics
         self._tally_lock = threading.Lock()
         self._lane_tally = dict.fromkeys(LANE_SEALERS, 0)
+        # flushes so far, the batches (a block each under store_stream)
+        # they took in, and the flushes that took in one alone; written
+        # under _pend_lock by _flush_locked (flush_tally)
+        self._flush_tally = {"flushes": 0, "segments": 0, "lone": 0}
         # the second kernel's provider (BN254: a channel's Idemix
         # credential proofs and pseudonym signatures); an IdemixMSP
         # built with this CSP verifies through it, and drain() and
@@ -839,6 +843,17 @@ class TPUCSP(CSP):
         "device"."""
         with self._tally_lock:
             return dict(self._lane_tally)
+
+    def flush_tally(self) -> dict[str, int]:
+        """Device flushes so far: `flushes`, the `segments` they took
+        in (verify_batch_async batches: a block each while a peer
+        streams blocks) and the `lone` flushes that took in one batch
+        alone.  segments / flushes is blocks a flush, as
+        csp_tpu_flush_segments_total over csp_tpu_dispatches_total is
+        on /metrics; a stream whose flushes are mostly `lone` overlaps
+        no block's verification with another's."""
+        with self._pend_lock:
+            return dict(self._flush_tally)
 
     def _note_sealed(self, kind: str, lanes: int) -> None:
         with self._tally_lock:
@@ -1118,6 +1133,11 @@ class TPUCSP(CSP):
         self._pend_lanes = 0
         gen = self._gen
         self._gen += 1
+        tally = self._flush_tally
+        tally["flushes"] += 1
+        tally["segments"] += len(segments)
+        if len(segments) == 1:
+            tally["lone"] += 1
         if self._metrics is not None:
             self._metrics.flush_segments.add(len(segments))
             if early:

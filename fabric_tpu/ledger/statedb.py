@@ -24,6 +24,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import struct
+import threading
 
 from fabric_tpu.ledger.kvstore import KVStore, NamedDB
 
@@ -208,6 +209,11 @@ class VersionedDB:
         self._db = NamedDB(store, name)
         self._indexes: dict[str, set[str]] | None = None  # lazy-loaded
         self._meta_ns: set[str] | bool | None = None  # lazy; True = unknown
+        # loading the set (a store read, then the assignment) against
+        # dropping it: a validator's thread reads it while a committer's
+        # flushes a group underneath, and a load that straddled the
+        # flush must not leave the older set cached behind it
+        self._meta_lock = threading.Lock()
 
     def rebased(self, base: KVStore) -> "VersionedDB":
         """The same versioned namespace over a different base store —
@@ -222,6 +228,7 @@ class VersionedDB:
         c._db = self._db.rebase(base)
         c._indexes = self._load_indexes()
         c._meta_ns = None
+        c._meta_lock = threading.Lock()
         return c
 
     # -- metadata presence fast path ---------------------------------------
@@ -234,15 +241,24 @@ class VersionedDB:
         without touching the store.  Monotone (never un-flagged), so it
         can only over-report, never under-report.  Legacy DBs written
         before this key existed stay permanently conservative."""
-        if self._meta_ns is None:
-            raw = self._db.get(_META_NS_KEY)
-            if raw is not None:
-                self._meta_ns = set(json.loads(raw.decode()))
-            elif self._db.get(_SAVEPOINT_KEY) is not None:
-                self._meta_ns = True  # pre-existing DB: unknown history
-            else:
-                self._meta_ns = set()
-        return self._meta_ns
+        m = self._meta_ns
+        if m is None:
+            with self._meta_lock:
+                m = self._meta_ns
+                if m is None:
+                    raw = self._db.get(_META_NS_KEY)
+                    if raw is not None:
+                        m = set(json.loads(raw.decode()))
+                    elif self._db.get(_SAVEPOINT_KEY) is not None:
+                        m = True  # pre-existing DB: unknown history
+                    else:
+                        m = set()
+                    self._meta_ns = m
+        return m
+
+    def _drop_meta_ns(self) -> None:
+        with self._meta_lock:
+            self._meta_ns = None
 
     def invalidate_caches(self) -> None:
         """Drop caches derived from the backing store — call after the
@@ -250,7 +266,7 @@ class VersionedDB:
         from a commit group, an out-of-band writer).  Index DEFINITIONS
         are deliberately kept: they only ever grow, and group commits
         never add them."""
-        self._meta_ns = None
+        self._drop_meta_ns()
 
     def may_have_metadata(self, ns: str) -> bool:
         """False guarantees no key under `ns` carries metadata.
@@ -259,8 +275,9 @@ class VersionedDB:
         every apply_updates (see there), so metadata written through a
         DIFFERENT VersionedDB over the same backing store (offline
         repair tooling) becomes visible at the next commit boundary.
-        Between commits the answer may lag by at most one block — the
-        same adjacency relaxation the pipelined validator documents.
+        What THIS ledger's commits flag is visible as soon as the
+        commit (a group: its flush) has landed: the pipelined validator
+        counts on that for the first metadata a namespace ever gets.
         Hot callers (the per-tx key-level endorsement fast path) should
         memoize per block, as TxValidator does."""
         m = self._load_meta_ns()
@@ -437,7 +454,7 @@ class VersionedDB:
         # write_batch and drop a freshly-added flag; the re-read narrows
         # that window, it does not close it.  Concurrent committers
         # would need the merge under the store's write lock.
-        self._meta_ns = None
+        self._drop_meta_ns()
         meta_ns = self._load_meta_ns()
         for ns, kvs in batch.items():
             for key, vv in kvs.items():
@@ -463,7 +480,7 @@ class VersionedDB:
         # drop the metadata-namespace cache so the next reader re-loads
         # it from the store: one cheap get per commit buys visibility of
         # out-of-band writers (a second VersionedDB over this store)
-        self._meta_ns = None
+        self._drop_meta_ns()
 
     def savepoint(self) -> Height | None:
         raw = self._db.get(_SAVEPOINT_KEY)
@@ -518,7 +535,7 @@ class VersionedDB:
         ).encode()
         puts[_SAVEPOINT_KEY] = savepoint.pack()
         self._db.write_batch(puts, [])
-        self._meta_ns = None
+        self._drop_meta_ns()
         return count
 
 

@@ -93,9 +93,12 @@ class Committer:
 
         Three overlapped stages: host collect (validator), device
         verify (CSP async), and MVCC+persist (this method's committer
-        thread).  Same documented relaxation as validate_pipeline: SBE
-        metadata reads for block k+1 may precede block k's commit;
-        depth=1 restores strict adjacency.
+        thread).  The flags are those of `store_block`, one block at a
+        time, at any depth: where a transaction's key-level
+        endorsement policy depends on an earlier block of the stream,
+        the validator decides it once that block's commit has landed
+        here (`validate_pipeline`; the release below tells it), and a
+        commit that fails ends its wait with the failure.
 
         Group commit: the committer thread buffers up to `depth` blocks
         into one CommitGroup (one shared KV transaction + unsynced
@@ -120,7 +123,29 @@ class Committer:
         commit_q: queue.Queue = queue.Queue(maxsize=depth)
         done_q: queue.Queue = queue.Queue()
 
+        # the newest release the validator handed out: through it the
+        # committer thread tells a validator that waits for a commit
+        # (key-level endorsement) that the commit will not come
+        newest: list = [None]
+
+        def give_up(exc):
+            abort = getattr(newest[0], "abort", None)
+            if abort is not None:
+                abort(exc)
+
         def commit_loop():
+            try:
+                commit_blocks()
+            except BaseException as e:
+                # whatever ends this thread ends the stream: neither the
+                # consumer (on done_q) nor the validator (on a commit)
+                # is left waiting, and the consumer raises what it was
+                died = RuntimeError(f"the committer thread died: {e!r}")
+                died.__cause__ = e
+                give_up(died)
+                done_q.put(died)
+
+        def commit_blocks():
             failed = False
             group = self._ledger.begin_commit_group()
             grouped: list = []  # (block, release_txids) awaiting flush
@@ -156,6 +181,7 @@ class Committer:
                                 self._ledger.commit_group_flush(group)
                             announce()
                         except Exception as e:
+                            give_up(e)
                             done_q.put(e)
                     return
                 if failed:
@@ -186,6 +212,7 @@ class Committer:
                     # blocked on done_q); nothing further commits onto
                     # suspect state
                     failed = True
+                    give_up(e)
                     done_q.put(e)
 
         th = spawn_thread(
@@ -199,14 +226,13 @@ class Committer:
                 rwsets_out=rwsets_q.append,
             ):
                 assist = rwsets_q.popleft()
+                newest[0] = landed = releases.popleft()
                 with tracing.span(
                     "commit.backpressure", cat="stage",
                     parent=getattr(assist, "trace_ctx", None),
                     depth=commit_q.qsize(),
                 ):
-                    commit_q.put(
-                        (pending.popleft(), releases.popleft(), assist)
-                    )
+                    commit_q.put((pending.popleft(), landed, assist))
                 n_in += 1
                 while not done_q.empty():
                     r = done_q.get()

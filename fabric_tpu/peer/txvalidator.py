@@ -28,6 +28,7 @@ the builtin plugin evaluates the channel/chaincode endorsement policy.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import dataclasses
 import os
@@ -36,6 +37,7 @@ import time
 
 from fabric_tpu.common import tracing, workpool
 from fabric_tpu.devtools import faultline, knob_registry
+from fabric_tpu.ledger.txmgmt import VALIDATION_PARAMETER
 from fabric_tpu.peer.validation_plugins import (
     IllegalWritesetError,
     PluginRegistry,
@@ -189,6 +191,167 @@ class _TxWork:
     meta_keys: frozenset = frozenset()
     # keys whose VALIDATION_PARAMETER this tx rewrites; once the tx is
     # VALID, later in-block txs touching them are invalidated
+    deferred: bool = False
+    # one of its pendings is a DeferredValidation: its policies are
+    # resolved in the policy stage, once the block it waits on landed
+
+
+class _BlockWorks(list):
+    """A block's `_TxWork`s in tx order, and beside them what of the
+    block's key-level decisions waits for an earlier block's commit
+    (`_Deferral`; None for a block that defers nothing: every block of
+    a channel without key-level policies, and every lone block)."""
+
+    __slots__ = ("deferral",)
+
+    def __init__(self, n: int):
+        super().__init__([_TxWork() for _ in range(n)])
+        self.deferral = None
+
+
+@dataclasses.dataclass
+class _Deferral:
+    window: "_KeyWindow"
+    waits_on: int  # the newest block a deferred decision depends on
+    txs: int  # the block's transactions whose decision is deferred
+
+
+class _KeyLevelCount:
+    """One stage's state-metadata lookups (`_committed_metadata`):
+    how many, their wall, and the distinct parameters they met."""
+
+    __slots__ = ("reads", "seconds", "params")
+
+    def __init__(self):
+        self.reads = 0
+        self.seconds = 0.0
+        self.params: set = set()
+
+
+class _KeyWindow:
+    """The keys whose VALIDATION_PARAMETER the blocks in flight in ONE
+    pipeline may still change: block number -> its (ns_or_hashns, key)
+    pairs, from the end of the block's collect until its commit has
+    landed (durable and readable: `_Landed`).  Only a block that may
+    change a parameter (a metadata write, or a delete, of a transaction
+    its collect did not refuse) is ever in it, so on a channel without
+    key-level policies it stays empty and nobody takes its lock.
+
+    Upstream validates block k+1 after it committed block k; here k+1
+    is collected while k is verified or committed.  A transaction of
+    k+1 that writes a key in this window is therefore DECIDED only once
+    the newest block that may change the key's parameter has landed
+    (its signatures go to the device with everybody else's), and reads
+    the parameter then; every other transaction reads what is
+    committed, which no block in flight changes."""
+
+    def __init__(self):
+        self._cond = threading.Condition()
+        self._writes: dict[int, frozenset] = {}
+        self._failed: BaseException | None = None
+
+    def pending(self) -> dict | None:
+        """(ns, key) -> the newest block in flight that may change its
+        parameter, as of now; None while no block in flight changes
+        any.  A block takes this ONCE, before it reads anything of the
+        state: what has left the window by then is committed and
+        readable, what is still in it stays pending for the whole
+        block."""
+        if not self._writes:
+            return None
+        with self._cond:
+            out: dict = {}
+            for num in sorted(self._writes):
+                out.update(dict.fromkeys(self._writes[num], num))
+        return out or None
+
+    def add(self, num: int, keys: frozenset) -> None:
+        with self._cond:
+            self._writes[num] = keys
+
+    def land(self, num: int) -> None:
+        if num in self._writes:
+            with self._cond:
+                self._writes.pop(num, None)
+                self._cond.notify_all()
+
+    def abort(self, exc: BaseException) -> None:
+        """The commits this window waits for will not come."""
+        with self._cond:
+            self._failed = exc
+            self._cond.notify_all()
+
+    def await_landed(self, num: int) -> None:
+        with self._cond:
+            while num in self._writes:
+                if self._failed is not None:
+                    raise self._failed
+                self._cond.wait()
+
+
+class _Landed:
+    """What `validate_pipeline` hands its `release` for a yielded
+    block: the caller runs it once the block's commit has landed, and
+    the block's txids leave the duplicate window (the ledger's index
+    holds them) and its keys the key window (the state holds their
+    parameters).  `abort(exc)` is for a caller whose commit failed: a
+    validator that waits for a commit in this pipeline wakes with
+    `exc` instead of waiting for ever."""
+
+    __slots__ = ("_seen", "_txids", "_window", "_num")
+
+    def __init__(self, seen: set, txids: set, window: _KeyWindow, num: int):
+        self._seen, self._txids = seen, txids
+        self._window, self._num = window, num
+
+    def __call__(self) -> None:
+        self._seen.difference_update(self._txids)
+        self._window.land(self._num)
+
+    def abort(self, exc: BaseException) -> None:
+        self._window.abort(exc)
+
+
+class KeyLevelTally:
+    """The process's key-level (state-based) endorsement work, from its
+    start: `lookups` of committed state metadata, decisions `deferred`
+    to the policy stage, the `waits` for a commit those took, and of
+    the last RECENT blocks that went through a pipeline their number
+    and their deferred decisions, oldest first.  A benchmark's
+    condition reads it (benchmarks/conditions/keylevel-shape.py); an
+    operator reads the same on /metrics
+    (validator_keylevel_lookups_total, validator_keylevel_deferred_total)."""
+
+    RECENT = 16384
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.lookups = 0
+        self.deferred = 0
+        self.waits = 0
+        self._recent: collections.deque = collections.deque(maxlen=self.RECENT)
+
+    def note(self, lookups: int = 0, deferred: int = 0, waits: int = 0) -> None:
+        with self._lock:
+            self.lookups += lookups
+            self.deferred += deferred
+            self.waits += waits
+
+    def block_done(self, num: int, deferred: int) -> None:
+        self._recent.append((num, deferred))  # a deque's append is atomic
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"lookups": self.lookups, "deferred": self.deferred,
+                    "waits": self.waits, "recent_blocks": list(self._recent)}
+
+
+_KEYLEVEL = KeyLevelTally()
+
+
+def keylevel_tally() -> dict:
+    """See KeyLevelTally."""
+    return _KEYLEVEL.snapshot()
 
 
 def _commit_assist(works: list, envs: list, bspan):
@@ -343,6 +506,14 @@ class TxValidator:
             else getattr(ledger, "may_have_state_metadata", None)
         )
         self._ns_meta_block = None  # per-block memoized wrapper
+        # key-level endorsement, per block (see _KeyWindow): what the
+        # blocks in flight may still change, as the block in hand found
+        # it when its collect began (`dict.get`; None: nothing), the
+        # keys whose parameter the block in hand may change itself, and
+        # the count of the stage's state-metadata lookups
+        self._pending_block = None
+        self._param_keys: set = set()
+        self._keylevel = _KeyLevelCount()
         self._registry = plugin_registry or PluginRegistry(plans=not faithful)
         self._policy_provider = PolicyProvider(
             bundle.policy_manager, bundle.msp_manager, definition_provider
@@ -387,7 +558,47 @@ class TxValidator:
         self._assist = None
 
     def _committed_metadata(self, ns: str, key: str) -> dict[str, bytes]:
-        return self._ledger.get_state_metadata(ns, key)
+        t0 = time.perf_counter()
+        entries = self._ledger.get_state_metadata(ns, key)
+        count = self._keylevel
+        count.reads += 1
+        count.seconds += time.perf_counter() - t0
+        raw = entries.get(VALIDATION_PARAMETER)
+        if raw:
+            count.params.add(raw)
+        return entries
+
+    def _plan_counts(self) -> tuple:
+        """The builtin plugin's plan-cache outcomes so far (hits,
+        misses, clears); zeros where another plugin stands under its
+        name."""
+        plugin = self._registry.plugin("vscc")
+        return (getattr(plugin, "plan_hits", 0),
+                getattr(plugin, "plan_misses", 0),
+                getattr(plugin, "plan_clears", 0))
+
+    def _count_keylevel(self, lookups: int = 0, deferred: int = 0,
+                        waits: int = 0) -> None:
+        """A block's key-level work onto the process's tally and the
+        peer's /metrics; called only by a block that did any."""
+        _KEYLEVEL.note(lookups, deferred, waits)
+        m = self._metrics
+        if m is None:
+            return
+        if lookups:
+            m.keylevel_lookups.With("channel", self.channel_id).add(lookups)
+        if deferred:
+            m.keylevel_deferred.With("channel", self.channel_id).add(deferred)
+
+    def _count_plans(self, before: tuple) -> tuple:
+        """The plan cache's outcomes since `before`, onto /metrics."""
+        delta = tuple(a - b for a, b in zip(self._plan_counts(), before))
+        m = self._metrics
+        if m is not None:
+            for outcome, n in zip(("hit", "miss", "cleared"), delta):
+                if n:
+                    m.plan_cache.With("outcome", outcome).add(n)
+        return delta
 
     def _plugin_for(self, namespace: str):
         name = "vscc"
@@ -717,39 +928,58 @@ class TxValidator:
         that commit each block before pulling the next flags.  A caller
         that commits asynchronously (Committer.store_stream) passes
         `release`: for every yielded block it receives a zero-arg
-        callable and the txid window stays open until that callable
-        runs (after the commit lands, when ledger.tx_id_exists takes
-        over detection — no gap either way).
-        Documented relaxation vs strict serial validation: key-level
-        endorsement-policy (SBE) metadata reads for block k+1 see the
-        state committed BEFORE block k (k is not committed while k+1
-        collects).  Cross-block SBE updates this close together are
-        race-y in the reference's deliver pipeline too; deployments that
-        need strict adjacency can use depth=1."""
-        import collections
+        callable (a `_Landed`) and the txid window stays open until
+        that callable runs (after the commit lands, when
+        ledger.tx_id_exists takes over detection — no gap either way).
 
+        Key-level (state-based) endorsement is decided as a validator
+        that commits every block before it validates the next decides
+        it, at any depth (`_KeyWindow`): a transaction that writes a
+        key whose VALIDATION_PARAMETER a block still in flight may
+        change sends its signatures to the device with its block and
+        has its policies resolved in the policy stage, once that
+        block's commit has landed — when the generator is resumed
+        after the block's flags, or when `release`'s callable has run.
+        A caller whose commit fails calls that callable's `abort(exc)`,
+        and a validator waiting here raises `exc`.  Every other
+        transaction is decided as it always was, and a stream whose
+        blocks change no parameter never waits."""
         q: collections.deque = collections.deque()
         seen_txids: set[str] = set()
+        window = _KeyWindow()
 
         def finish(started):
             block, flags, works, collect, envs, bspan, txids = started
+            num = block.header.number
             flags = self._finish_block(block, flags, works, collect, bspan)
+            deferral = works.deferral
+            _KEYLEVEL.block_done(num, 0 if deferral is None else deferral.txs)
             if rwsets_out is not None:
                 rwsets_out(_commit_assist(works, envs, bspan))
             if release is None:
                 seen_txids.difference_update(txids)  # close the window
             else:
-                release(lambda: seen_txids.difference_update(txids))
-            return flags
+                release(_Landed(seen_txids, txids, window, num))
+            return flags, num
+
+        def land(num):
+            # the caller took the flags and came back for more: it has
+            # committed the block (see above)
+            if release is None:
+                window.land(num)
 
         for block in blocks:
             before = set(seen_txids)
-            started = self._start_block(block, seen_txids)
+            started = self._start_block(block, seen_txids, window=window)
             q.append(started + (seen_txids - before,))
             if len(q) >= depth:
-                yield finish(q.popleft())
+                flags, num = finish(q.popleft())
+                yield flags
+                land(num)
         while q:
-            yield finish(q.popleft())
+            flags, num = finish(q.popleft())
+            yield flags
+            land(num)
 
     def _collect_fanout(self, n: int, native: bool = False) -> int:
         """Chunk count for this block's parallel collect; 0/1 = serial.
@@ -764,10 +994,13 @@ class TxValidator:
         return min(width, n)
 
     def _start_block(self, block: common_pb2.Block, seen_txids: set,
-                     lone: bool = False):
+                     lone: bool = False, window: "_KeyWindow | None" = None):
         """Phases 1+2: collect every tx, dispatch the device verify.
         `lone`: the caller validates this block alone (`validate`), so
-        the flush overlaps nothing unless the collect itself does."""
+        the flush overlaps nothing unless the collect itself does.
+        `window`: the pipeline's record of the key-level parameters its
+        blocks in flight may still change; None where every earlier
+        block is committed (`validate`)."""
         t0 = time.perf_counter()
         num = block.header.number
         # detached per-block root: its children (collect here,
@@ -780,7 +1013,7 @@ class TxValidator:
         )
         try:
             return self._start_block_traced(
-                block, seen_txids, bspan, num, t0, lone
+                block, seen_txids, bspan, num, t0, lone, window
             )
         except BaseException:
             # detached roots are off the stack-repair path: end the
@@ -791,7 +1024,8 @@ class TxValidator:
             bspan.end()
             raise
 
-    def _start_block_traced(self, block, seen_txids, bspan, num, t0, lone):
+    def _start_block_traced(self, block, seen_txids, bspan, num, t0, lone,
+                            window):
         with tracing.attached(bspan.ctx), tracing.span(
             "collect", cat="stage", block=num,
         ) as cspan:
@@ -799,11 +1033,18 @@ class TxValidator:
             # envelope byte strings (each repeated-field access copies)
             n = len(envs)
             flags = [V.NOT_VALIDATED] * n
-            works = [_TxWork() for _ in range(n)]
+            works = _BlockWorks(n)
             sink = _ItemSink(dedup=not self._faithful)
 
             memo = _CreatorMemo()  # per-block creator-identity memo
             self._policy_provider.begin_block()
+            # BEFORE anything of the state is read (the namespace memo
+            # below included): see _KeyWindow.pending
+            pending = None if window is None else window.pending()
+            self._pending_block = None if pending is None else pending.get
+            self._param_keys = set()
+            count = self._keylevel = _KeyLevelCount()
+            plans0 = self._plan_counts()
             raw_meta = self._ns_meta
             if raw_meta is not None:
                 meta_memo: dict = {}
@@ -850,6 +1091,13 @@ class TxValidator:
             collect = sink.hand_over(self._csp)
             if sink.early_lanes:
                 self.early_flush_blocks += 1
+            if window is not None and self._param_keys:
+                window.add(num, frozenset(self._param_keys))
+            if pending is not None:
+                self._defer(works, window)
+            if count.reads:
+                self._count_keylevel(lookups=count.reads)
+            plans = self._count_plans(plans0)
             if sink.idemix.by_msp:
                 # the block's Idemix items go out here too, as ONE
                 # asynchronous batched verify (an MSP), before
@@ -866,12 +1114,32 @@ class TxValidator:
                     creator_ms=memo.seconds * 1e3,
                     creator_chain_batch=memo.chain_batch,
                     early_lanes=sink.early_lanes,
+                    keylevel_reads=count.reads,
+                    keylevel_ms=count.seconds * 1e3,
+                    keylevel_policies=len(count.params),
+                    plan_hits=plans[0], plan_misses=plans[1],
+                    plan_clears=plans[2],
                 )
         self._observe_stage("collect", time.perf_counter() - t0)
         # inside collect, not beside it: what of the stage went to
         # identities the block's memo did not hold
         self._observe_stage("creators", memo.seconds)
         return block, flags, works, collect, envs, bspan
+
+    @staticmethod
+    def _defer(works: "_BlockWorks", window: "_KeyWindow") -> None:
+        """Note on the block's works which of its transactions wait for
+        an earlier block's commit, and for which block."""
+        txs, waits_on = 0, -1
+        for w in works:
+            if w.deferred:
+                txs += 1
+                waits_on = max(waits_on, max(
+                    p.waits_on for p, _idxs in w.pendings
+                    if p.waits_on is not None
+                ))
+        if txs:
+            works.deferral = _Deferral(window, waits_on, txs)
 
     def _collect_native(self, data, seen_txids, sink: _ItemSink, works, flags, memo: dict,
                         lone: bool = False) -> bool:
@@ -1172,16 +1440,25 @@ class TxValidator:
                 state_metadata=self._committed_metadata,
                 footprint=footprint,
                 ns_has_metadata=self._ns_meta_block,
+                pending=self._pending_block,
             )
             try:
                 pending = self._plugin_for(ns).prepare(ctx)
             except Exception:
                 return V.INVALID_OTHER_REASON
             w.pendings.append((pending, sink.add_many(pending.items)))
+            if ctx.pending is not None and getattr(
+                pending, "waits_on", None
+            ) is not None:
+                w.deferred = True
         w.touched_keys = footprint.touched
         w.rwset = rwset_bytes
         w.footprint = footprint
         w.meta_keys = frozenset(footprint.meta_writes)
+        if w.meta_keys or footprint.deletes:
+            # what a later block has to find committed before it can
+            # decide a transaction that writes one of these (_KeyWindow)
+            self._param_keys.update(w.meta_keys, footprint.deletes)
         return V.VALID
 
     def _observe_stage(self, stage: str, dt: float) -> None:
@@ -1222,6 +1499,21 @@ class TxValidator:
         t1 = time.perf_counter()
         self._observe_stage("verify_wait", t1 - t0)
 
+        # key-level decisions that depend on an earlier block's commit:
+        # the lanes are verified (nothing above waited for a commit),
+        # the decision now waits until that commit has landed
+        deferral = getattr(works, "deferral", None)
+        if deferral is not None:
+            with tracing.attached(ctx), tracing.span(
+                "policy.await_commit", cat="stage", block=num,
+                waits_on=deferral.waits_on, txs=deferral.txs,
+            ):
+                deferral.window.await_landed(deferral.waits_on)
+            t1, t_wait = time.perf_counter(), t1
+            self._observe_stage("await_commit", t1 - t_wait)
+            count = self._keylevel = _KeyLevelCount()
+            plans0 = self._plan_counts()
+
         # phase 3: in-order finish.  All policy evaluations read the
         # COMMITTED (pre-block) metadata — the reference does the same,
         # since GetValidationParameterForKey fetches from the ledger
@@ -1234,7 +1526,7 @@ class TxValidator:
         updated: set[tuple[str, str]] = set()
         with tracing.attached(ctx), tracing.span(
             "policy", cat="stage", block=num,
-        ):
+        ) as pspan:
             for i in range(n):
                 if flags[i] != V.VALID:
                     continue
@@ -1254,16 +1546,37 @@ class TxValidator:
                 if w.touched_keys & updated:
                     flags[i] = V.ENDORSEMENT_POLICY_FAILURE
                     continue
-                ok = all(
-                    p.finish([mask[j] for j in idxs])
-                    for p, idxs in w.pendings
-                )
+                try:
+                    ok = all(
+                        p.finish([mask[j] for j in idxs])
+                        for p, idxs in w.pendings
+                    )
+                except Exception:
+                    if not w.deferred:
+                        raise
+                    # policies resolved here and not in collect: what
+                    # `prepare` raising gives there (_prepare_namespaces)
+                    flags[i] = V.INVALID_OTHER_REASON
+                    continue
                 if not ok:
                     flags[i] = V.ENDORSEMENT_POLICY_FAILURE
                     continue
                 updated.update(w.meta_keys)
 
             protoutil.set_tx_filter(block, bytes(flags))
+            if deferral is not None:
+                self._count_keylevel(
+                    lookups=count.reads, deferred=deferral.txs, waits=1
+                )
+                plans = self._count_plans(plans0)
+                pspan.annotate(
+                    deferred=deferral.txs, deferred_reads=count.reads,
+                    deferred_ms=count.seconds * 1e3,
+                    plan_hits=plans[0], plan_misses=plans[1],
+                    plan_clears=plans[2],
+                )
+            else:
+                pspan.annotate(deferred=0)
         self._observe_stage("policy", time.perf_counter() - t1)
         return flags
 

@@ -60,6 +60,9 @@ class RwsetFootprint:
 
     touched: frozenset  # {(ns_or_hashns, key)} the tx writes or re-metas
     meta_writes: dict  # {(ns_or_hashns, key): {entry: value}}
+    deletes: list  # [(ns_or_hashns, key)] the tx deletes: a key's
+    #                metadata goes with it, so a delete changes the key's
+    #                VALIDATION_PARAMETER as a metadata write does
     per_ns: dict  # ns -> {"pub": [key], "meta": [key],
     #                      "coll": [(coll, hashns, hkey)],
     #                      "coll_meta": [(coll, hashns, hkey)],
@@ -79,6 +82,7 @@ def parse_footprint(rwset_bytes: bytes | None) -> RwsetFootprint:
     # — one namespace, a few public writes, no collections — runs on
     # list comprehensions and batch extends, not per-item loop bodies.
     touched: list = []
+    deletes: list = []
     meta: dict[tuple[str, str], dict[str, bytes]] = {}
     per_ns: dict[str, dict] = {}
     parsed: list = []
@@ -93,7 +97,13 @@ def parse_footprint(rwset_bytes: bytes | None) -> RwsetFootprint:
             kvrw = kv_rwset_pb2.KVRWSet.FromString(nsrw.rwset)
             colls: list = []
             parsed.append((ns, kvrw, colls))
-            pub = [w.key for w in kvrw.writes]
+            # ONE walk of the writes for their keys and their deletes: a
+            # second iteration makes every KVWrite's wrapper again
+            pub = []
+            for w in kvrw.writes:
+                pub.append(w.key)
+                if w.is_delete:
+                    deletes.append((ns, w.key))
             mkeys = [mw.key for mw in kvrw.metadata_writes]
             entry = per_ns[ns] = {
                 "pub": pub, "meta": mkeys, "coll": [], "coll_meta": [],
@@ -121,7 +131,12 @@ def parse_footprint(rwset_bytes: bytes | None) -> RwsetFootprint:
                 hns = hash_ns(ns, cname)
                 hrw = kv_rwset_pb2.HashedRWSet.FromString(ch.hashed_rwset)
                 colls.append((cname, hrw, bytes(ch.pvt_rwset_hash)))
-                hkeys = [bytes(hw.key_hash).hex() for hw in hrw.hashed_writes]
+                hkeys = []
+                for hw in hrw.hashed_writes:
+                    hkey = bytes(hw.key_hash).hex()
+                    hkeys.append(hkey)
+                    if hw.is_delete:
+                        deletes.append((hns, hkey))
                 if hkeys:
                     touched.extend((hns, k) for k in hkeys)
                     entry["coll"].extend((cname, hns, k) for k in hkeys)
@@ -134,7 +149,7 @@ def parse_footprint(rwset_bytes: bytes | None) -> RwsetFootprint:
                     meta[(hns, hkey)] = {
                         e.name: bytes(e.value) for e in mw.entries
                     }
-    return RwsetFootprint(frozenset(touched), meta, per_ns, parsed)
+    return RwsetFootprint(frozenset(touched), meta, deletes, per_ns, parsed)
 
 
 @dataclasses.dataclass
@@ -156,11 +171,25 @@ class ValidationContext:
     # VALIDATION_PARAMETER lookups wholesale (the reference pays a
     # GetStateMetadata fetch per written key per tx,
     # statebased/vpmanagerimpl.go:293); None = unknown, look keys up
+    pending: Callable[[tuple], int | None] | None = None
+    # the pipelined validator's: the (ns_or_hashns, key) pair -> the
+    # newest block still in flight (collected, its commit not yet
+    # readable) that may change the key's VALIDATION_PARAMETER, None for
+    # a key no such block touches.  The committed metadata of such a key
+    # is not yet what a validator that commits every block before it
+    # validates the next would read, so an action that writes one is
+    # not decided in `prepare`: see DeferredValidation.  None = nothing
+    # in flight changes any parameter (a lone block; every block of a
+    # channel without key-level policies)
 
 
 class PendingValidation:
     """Two-phase result: `items` join the block batch; `finish(mask)`
     returns True when the action validates."""
+
+    waits_on: int | None = None
+    # the block whose commit `finish` has to find landed: only a
+    # DeferredValidation has one
 
     def __init__(self, pendings: list, items: list):
         self._pendings = pendings  # [(PendingEvaluation, (start, end))]
@@ -399,6 +428,38 @@ class _PlanPending(PendingValidation):
         return self._plan.decide(tuple(bits))
 
 
+class DeferredValidation(PendingValidation):
+    """An action that writes a key whose VALIDATION_PARAMETER a block
+    still in flight may change (`ValidationContext.pending`): WHICH
+    policies decide it is not known while its block is collected.  Its
+    signatures do not wait for that: `items` are a lane for each
+    distinct endorser that deserializes, as an EndorsementPlan makes
+    them whatever its policies turn out to be, and they join the
+    block's batch with everybody else's.  `finish` resolves the
+    policies against the committed state, so the validator calls it
+    only once block `waits_on` has landed (its commit durable and
+    readable); the verdict is then the plan's, from the same mask."""
+
+    def __init__(self, plugin: "BuiltinV20Plugin", ctx: ValidationContext,
+                 waits_on: int, endorsers: tuple, lanes: list, items: list):
+        self._plugin = plugin
+        self._ctx = ctx
+        self.waits_on = waits_on
+        self._endorsers = endorsers  # distinct endorser identities, in order
+        self._lanes = lanes  # endorser index per item position
+        self.items = items
+
+    def finish(self, mask) -> bool:
+        ctx = self._ctx
+        policies = self._plugin._policies(ctx)
+        if isinstance(policies, _FailPending):
+            return False
+        plan = self._plugin._plan(
+            policies, self._endorsers, ctx.policy_provider.deserializer
+        )
+        return _PlanPending(plan, self._lanes, self.items).finish(mask)
+
+
 class BuiltinV20Plugin:
     """The default endorsement-policy plugin ("vscc"), key-level aware.
     Evaluates the single namespace in `ctx.namespace`; the validator
@@ -410,6 +471,32 @@ class BuiltinV20Plugin:
     def __init__(self, plans: bool = True):
         self._use_plans = plans
         self._plans: dict[tuple, EndorsementPlan] = {}
+        # the plan cache's outcomes since this plugin was built: a plan
+        # found, a plan built, and the times the cache ran over
+        # _PLAN_CAP and was emptied (the validator reads the three a
+        # block: collect{plan_hits, plan_misses, plan_clears},
+        # validator_plan_cache_total{outcome})
+        self.plan_hits = 0
+        self.plan_misses = 0
+        self.plan_clears = 0
+
+    def _plan(self, policies, endorsers: tuple, deserializer) -> EndorsementPlan:
+        """The plan of (policies, distinct endorsers), from the cache
+        where plans are kept; a plan that cannot be built raises."""
+        if not self._use_plans:
+            return EndorsementPlan(policies, endorsers, deserializer)
+        key = (tuple(policies), endorsers)
+        plan = self._plans.get(key)
+        if plan is not None:
+            self.plan_hits += 1
+            return plan
+        plan = EndorsementPlan(policies, endorsers, deserializer)
+        self.plan_misses += 1
+        if len(self._plans) >= self._PLAN_CAP:
+            self._plans.clear()
+            self.plan_clears += 1
+        self._plans[key] = plan
+        return plan
 
     def _plan_pending(self, ctx: ValidationContext, policies) -> PendingValidation | None:
         """Plan-cached fast path; None when an endorsement lacks a
@@ -423,12 +510,15 @@ class BuiltinV20Plugin:
                 return None
             if sd.identity not in uniq:
                 uniq[sd.identity] = sd
-        key = (tuple(policies), tuple(uniq))
-        plan = self._plans.get(key)
-        if plan is None:
+        endorsers = tuple(uniq)
+        # the hit, a transaction of every block, without a call
+        plan = self._plans.get((tuple(policies), endorsers))
+        if plan is not None:
+            self.plan_hits += 1
+        else:
             try:
-                plan = EndorsementPlan(
-                    policies, tuple(uniq), ctx.policy_provider.deserializer
+                plan = self._plan(
+                    policies, endorsers, ctx.policy_provider.deserializer
                 )
             except Exception as exc:
                 # fall back to the per-tx generic path; the plan build
@@ -439,9 +529,6 @@ class BuiltinV20Plugin:
                     "to per-tx evaluation): %s", ctx.namespace, exc,
                 )
                 return None
-            if len(self._plans) >= self._PLAN_CAP:
-                self._plans.clear()
-            self._plans[key] = plan
         lanes, items = [], []
         for j, sd in enumerate(uniq.values()):
             ident = plan.identities[j]
@@ -452,7 +539,62 @@ class BuiltinV20Plugin:
                 )
         return _PlanPending(plan, lanes, items)
 
+    def _defer(self, ctx: ValidationContext, waits_on: int) -> DeferredValidation:
+        """The action's lanes from its endorsements alone; its policies
+        once block `waits_on` has landed."""
+        uniq: dict[bytes, SignedData] = {}
+        for sd in ctx.endorsements:
+            if sd.identity not in uniq:
+                uniq[sd.identity] = sd
+        deserializer = ctx.policy_provider.deserializer
+        lanes, items = [], []
+        for j, sd in enumerate(uniq.values()):
+            try:
+                ident = deserializer.deserialize_identity(sd.identity)
+            except Exception:
+                # fabriclint: allow[exception-discipline] no lane: the
+                # plan holds None for this endorser and its bit stays False
+                continue
+            lanes.append(j)
+            if sd.digest is not None:
+                items.append(
+                    VerifyBatchItem(ident.public_key, sd.digest, sd.signature)
+                )
+            else:
+                items.append(ident.verification_item(sd.data, sd.signature))
+        return DeferredValidation(
+            self,
+            # at `finish` the block's own memo of the namespaces that
+            # hold metadata is as stale as its window: look keys up
+            dataclasses.replace(ctx, pending=None, ns_has_metadata=None),
+            waits_on, tuple(uniq), lanes, items,
+        )
+
     def prepare(self, ctx: ValidationContext) -> PendingValidation:
+        policies = self._policies(ctx)
+        if not isinstance(policies, list):
+            return policies  # a _FailPending, or a DeferredValidation
+
+        planned = self._plan_pending(ctx, policies)
+        if planned is not None:
+            return planned
+
+        items: list = []
+        pendings = []
+        for pol in policies:
+            pending = pol.prepare(ctx.endorsements)
+            start = len(items)
+            items.extend(pending.items)
+            pendings.append((pending, (start, len(items))))
+        return PendingValidation(pendings, items)
+
+    def _policies(self, ctx: ValidationContext):
+        """The policies that decide the action, each once: its keys'
+        VALIDATION_PARAMETERs as committed, and the fallbacks of the
+        keys without one.  A _FailPending where the action can never
+        validate; a DeferredValidation where a key's parameter is not
+        yet what it will be when the blocks before this one have
+        landed."""
         try:
             fp = ctx.footprint or parse_footprint(ctx.rwset_bytes)
         except Exception as exc:
@@ -469,6 +611,15 @@ class BuiltinV20Plugin:
         # metadata-written; identical key-level policies evaluated once.
         pub_keys = set(entry["pub"]) | set(entry["meta"])
         coll_keys = set(entry["coll"]) | set(entry["coll_meta"])
+
+        pending = ctx.pending
+        if pending is not None:
+            ns0 = ctx.namespace
+            waits = [pending((ns0, k)) for k in pub_keys]
+            waits.extend(pending((ns, key)) for _c, ns, key in coll_keys)
+            waits = [b for b in waits if b is not None]
+            if waits:
+                return self._defer(ctx, max(waits))
 
         policies_by_bytes: dict[bytes, object] = {}
         fallbacks: dict[str, object] = {}  # "" = ccEP, else collection
@@ -536,19 +687,7 @@ class BuiltinV20Plugin:
             policies.append(
                 ctx.policy_provider.chaincode_policy(ctx.namespace)
             )
-
-        planned = self._plan_pending(ctx, policies)
-        if planned is not None:
-            return planned
-
-        items: list = []
-        pendings = []
-        for pol in policies:
-            pending = pol.prepare(ctx.endorsements)
-            start = len(items)
-            items.extend(pending.items)
-            pendings.append((pending, (start, len(items))))
-        return PendingValidation(pendings, items)
+        return policies
 
 
 class PluginRegistry:
@@ -574,6 +713,7 @@ __all__ = [
     "IllegalWritesetError",
     "parse_footprint",
     "PendingValidation",
+    "DeferredValidation",
     "PolicyProvider",
     "BuiltinV20Plugin",
     "PluginRegistry",
