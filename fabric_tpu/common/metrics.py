@@ -440,6 +440,16 @@ class ValidateMetrics:
             label_names=("channel",),
             statsd_format="%{channel}",
         ))
+        self.keylevel_point_reads = provider.new_counter(CounterOpts(
+            namespace="validator",
+            subsystem="keylevel",
+            name="point_reads_total",
+            help="Of those lookups, the ones a block's bulk read of its "
+                 "written keys' state metadata did not cover: each went "
+                 "to the ledger by itself.",
+            label_names=("channel",),
+            statsd_format="%{channel}",
+        ))
         self.keylevel_deferred = provider.new_counter(CounterOpts(
             namespace="validator",
             subsystem="keylevel",

@@ -716,6 +716,12 @@ class KVLedger:
         endorsement fast path."""
         return self._state.may_have_metadata(ns)
 
+    def holds_state_metadata(self) -> bool:
+        """False guarantees that NO namespace of the committed state
+        carries metadata: `may_have_state_metadata` is False for every
+        one (the validator asks this once a block)."""
+        return self._state.holds_metadata()
+
     def define_index(self, ns: str, field: str) -> None:
         """Create (and backfill) a rich-query index on a dotted JSON
         field of a namespace — the statecouchdb index-definition
@@ -745,6 +751,9 @@ class KVLedger:
 
     def get_state_metadata(self, ns: str, key: str) -> dict[str, bytes]:
         return self.new_query_executor().get_state_metadata(ns, key)
+
+    def get_state_metadata_many(self, pairs) -> dict:
+        return self.new_query_executor().get_state_metadata_many(pairs)
 
     def get_history_for_key(self, ns: str, key: str):
         return self._history.get_history_for_key(ns, key)
@@ -789,6 +798,38 @@ class QueryExecutor:
             return {}  # namespace never stored metadata: skip the store
         vv = self._state.get_state(ns, key)
         return decode_metadata(vv.metadata) if vv else {}
+
+    def get_state_metadata_many(self, pairs) -> dict:
+        """`get_state_metadata` of every (ns, key) pair, as ONE read of
+        the same committed view: {(ns, key): entries}, with an entry for
+        every pair asked for ({} for a key that is absent or carries
+        none).  Pairs of a namespace that never stored metadata are
+        answered without the store, the rest in one `get_state_many`;
+        equal metadata is decoded once, so its keys share one dict:
+        the answer is for reading."""
+        from fabric_tpu.ledger.txmgmt import decode_metadata
+
+        out: dict = {}
+        may: dict[str, bool] = {}
+        ask: list = []
+        for pair in pairs:
+            ns = pair[0]
+            m = may.get(ns)
+            if m is None:
+                m = may[ns] = self._state.may_have_metadata(ns)
+            if m:
+                ask.append(pair)
+            else:
+                out[pair] = {}
+        if ask:
+            decoded: dict[bytes, dict] = {}
+            for pair, vv in self._state.get_state_many(ask).items():
+                raw = vv.metadata if vv else b""
+                entries = decoded.get(raw)
+                if entries is None:
+                    entries = decoded[raw] = decode_metadata(raw)
+                out[pair] = entries
+        return out
 
     def done(self) -> None:
         pass

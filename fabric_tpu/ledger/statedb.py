@@ -283,6 +283,11 @@ class VersionedDB:
         m = self._load_meta_ns()
         return True if m is True else ns in m
 
+    def holds_metadata(self) -> bool:
+        """False guarantees `may_have_metadata` is False for every
+        namespace: no key of the state carries metadata."""
+        return bool(self._load_meta_ns())
+
     # -- index definitions -------------------------------------------------
 
     def _load_indexes(self) -> dict[str, set[str]]:

@@ -200,13 +200,17 @@ class _BlockWorks(list):
     """A block's `_TxWork`s in tx order, and beside them what of the
     block's key-level decisions waits for an earlier block's commit
     (`_Deferral`; None for a block that defers nothing: every block of
-    a channel without key-level policies, and every lone block)."""
+    a channel without key-level policies, and every lone block) and
+    the committed state metadata its plugins look up (`_KeyLevelMemo`):
+    both stages of THIS block read it, whatever blocks are collected
+    between them."""
 
-    __slots__ = ("deferral",)
+    __slots__ = ("deferral", "keylevel")
 
-    def __init__(self, n: int):
+    def __init__(self, n: int, keylevel: "_KeyLevelMemo"):
         super().__init__([_TxWork() for _ in range(n)])
         self.deferral = None
+        self.keylevel = keylevel
 
 
 @dataclasses.dataclass
@@ -217,15 +221,118 @@ class _Deferral:
 
 
 class _KeyLevelCount:
-    """One stage's state-metadata lookups (`_committed_metadata`):
-    how many, their wall, and the distinct parameters they met."""
+    """One stage's state-metadata lookups (`_KeyLevelMemo.lookup`): how
+    many the plugins asked, how many of them went to the ledger one by
+    one, the pairs the stage's bulk read fetched, the wall of both
+    kinds of read, and the distinct parameters the lookups met."""
 
-    __slots__ = ("reads", "seconds", "params")
+    __slots__ = ("reads", "point_reads", "bulk_keys", "seconds", "params")
 
     def __init__(self):
         self.reads = 0
+        self.point_reads = 0
+        self.bulk_keys = 0
         self.seconds = 0.0
         self.params: set = set()
+
+
+class _KeyLevelMemo:
+    """ONE block's view of the committed state metadata: what its
+    plugins' `ValidationContext.state_metadata` answers from.  A stage
+    fills it with one bulk read of the pairs its transactions will ask
+    for (`fill`), and `lookup` answers from memory; a pair it does not
+    hold (a lane parsed inline, the Python collector, a custom plugin's
+    own key) is read from the ledger as it always was, and remembered.
+    Flags never depend on what a bulk read covered.
+
+    `pending` is the block's `_KeyWindow.pending` view: a pair it
+    names is neither fetched nor remembered while the block is
+    collected, since the commit that decides its parameter has not
+    landed.  `landed()` opens the policy stage, which the validator
+    enters only after that commit: from then on every pair is read.
+    `remember=False` (faithful mode) keeps a ledger read a lookup."""
+
+    __slots__ = ("_ledger", "_held", "_pending", "count")
+
+    def __init__(self, ledger, pending, remember: bool):
+        self._ledger = ledger
+        self._held: dict | None = {} if remember else None
+        self._pending = pending
+        self.count = _KeyLevelCount()
+
+    def lookup(self, ns: str, key: str) -> dict[str, bytes]:
+        count = self.count
+        count.reads += 1
+        held = self._held
+        pair = (ns, key)
+        entries = None if held is None else held.get(pair)
+        if entries is None:
+            t0 = time.perf_counter()
+            entries = self._ledger.get_state_metadata(ns, key)
+            count.point_reads += 1
+            count.seconds += time.perf_counter() - t0
+            if held is not None and self._settled(pair):
+                held[pair] = entries
+        raw = entries.get(VALIDATION_PARAMETER)
+        if raw:
+            count.params.add(raw)
+        return entries
+
+    def _settled(self, pair: tuple) -> bool:
+        pending = self._pending
+        return pending is None or pending(pair) is None
+
+    def fill(self, pairs) -> None:
+        """One bulk read of those of `pairs` that are settled and not
+        held yet."""
+        held = self._held
+        if held is None:
+            return
+        want = [p for p in pairs if p not in held and self._settled(p)]
+        if not want:
+            return
+        t0 = time.perf_counter()
+        got = self._ledger.get_state_metadata_many(want)
+        held.update(got)
+        self.count.bulk_keys += len(got)
+        self.count.seconds += time.perf_counter() - t0
+
+    def landed(self) -> _KeyLevelCount:
+        """The commit the block's deferred decisions waited for has
+        landed: nothing is pending any more, and the policy stage
+        counts its own lookups."""
+        self._pending = None
+        self.count = _KeyLevelCount()
+        return self.count
+
+
+def _no_metadata(ns: str) -> bool:
+    """`ValidationContext.ns_has_metadata` of a block whose collect
+    found the committed state without any."""
+    return False
+
+
+def _metadata_pairs(footprints, may_hold=None) -> dict:
+    """The (ns_or_hashns, key) pairs whose committed metadata the
+    builtin plugin looks up for these footprints (`_policies`: every
+    key a namespace's action writes or re-metas, public or of a
+    collection), first seen first; `may_hold` leaves out the
+    namespaces whose lookups the plugin skips."""
+    pairs: dict = {}
+    for fp in footprints:
+        for ns, entry in fp.per_ns.items():
+            if not entry["writes"]:
+                continue
+            if may_hold is None or may_hold(ns):
+                for k in entry["pub"]:
+                    pairs[(ns, k)] = None
+                for k in entry["meta"]:
+                    pairs[(ns, k)] = None
+            for hashed in (entry["coll"], entry["coll_meta"]):
+                for _coll, hns, k in hashed:
+                    if may_hold is None or may_hold(hns):
+                        pairs[(hns, k)] = None
+    return pairs
 
 
 class _KeyWindow:
@@ -506,14 +613,26 @@ class TxValidator:
             else getattr(ledger, "may_have_state_metadata", None)
         )
         self._ns_meta_block = None  # per-block memoized wrapper
+        # whether ANY namespace of the committed state holds metadata,
+        # asked once a block: only then is a block's collect worth a
+        # pass over its footprints and a bulk read before the plugins
+        # run (`_KeyLevelMemo.fill`).  Faithful mode reads a key a
+        # lookup, as upstream's GetStateMetadata does.
+        self._holds_meta = (
+            None
+            if faithful
+            else getattr(ledger, "holds_state_metadata", None)
+        )
         # key-level endorsement, per block (see _KeyWindow): what the
         # blocks in flight may still change, as the block in hand found
         # it when its collect began (`dict.get`; None: nothing), the
         # keys whose parameter the block in hand may change itself, and
-        # the count of the stage's state-metadata lookups
+        # the committed-metadata lookup of the block in hand's memo
+        # (its `ValidationContext`s keep it: the block's policy stage
+        # runs after later blocks' collects have replaced it here)
         self._pending_block = None
         self._param_keys: set = set()
-        self._keylevel = _KeyLevelCount()
+        self._metadata_block = None
         self._registry = plugin_registry or PluginRegistry(plans=not faithful)
         self._policy_provider = PolicyProvider(
             bundle.policy_manager, bundle.msp_manager, definition_provider
@@ -557,17 +676,6 @@ class TxValidator:
         # take_assist() hands it over
         self._assist = None
 
-    def _committed_metadata(self, ns: str, key: str) -> dict[str, bytes]:
-        t0 = time.perf_counter()
-        entries = self._ledger.get_state_metadata(ns, key)
-        count = self._keylevel
-        count.reads += 1
-        count.seconds += time.perf_counter() - t0
-        raw = entries.get(VALIDATION_PARAMETER)
-        if raw:
-            count.params.add(raw)
-        return entries
-
     def _plan_counts(self) -> tuple:
         """The builtin plugin's plan-cache outcomes so far (hits,
         misses, clears); zeros where another plugin stands under its
@@ -577,16 +685,20 @@ class TxValidator:
                 getattr(plugin, "plan_misses", 0),
                 getattr(plugin, "plan_clears", 0))
 
-    def _count_keylevel(self, lookups: int = 0, deferred: int = 0,
+    def _count_keylevel(self, count: _KeyLevelCount, deferred: int = 0,
                         waits: int = 0) -> None:
-        """A block's key-level work onto the process's tally and the
+        """A stage's key-level work onto the process's tally and the
         peer's /metrics; called only by a block that did any."""
-        _KEYLEVEL.note(lookups, deferred, waits)
+        _KEYLEVEL.note(count.reads, deferred, waits)
         m = self._metrics
         if m is None:
             return
-        if lookups:
-            m.keylevel_lookups.With("channel", self.channel_id).add(lookups)
+        if count.reads:
+            m.keylevel_lookups.With("channel", self.channel_id).add(count.reads)
+        if count.point_reads:
+            m.keylevel_point_reads.With(
+                "channel", self.channel_id
+            ).add(count.point_reads)
         if deferred:
             m.keylevel_deferred.With("channel", self.channel_id).add(deferred)
 
@@ -1033,7 +1145,6 @@ class TxValidator:
             # envelope byte strings (each repeated-field access copies)
             n = len(envs)
             flags = [V.NOT_VALIDATED] * n
-            works = _BlockWorks(n)
             sink = _ItemSink(dedup=not self._faithful)
 
             memo = _CreatorMemo()  # per-block creator-identity memo
@@ -1043,10 +1154,26 @@ class TxValidator:
             pending = None if window is None else window.pending()
             self._pending_block = None if pending is None else pending.get
             self._param_keys = set()
-            count = self._keylevel = _KeyLevelCount()
+            keylevel = _KeyLevelMemo(
+                self._ledger, self._pending_block, not self._faithful
+            )
+            self._metadata_block = keylevel.lookup
+            works = _BlockWorks(n, keylevel)
+            count = keylevel.count
             plans0 = self._plan_counts()
+            # ONE check a block of what the ledger already knows, after
+            # the window was read: a state without metadata (every
+            # block of a channel without key-level policies) takes no
+            # pass over footprints, no read and no question a
+            # namespace.  What lands while the block is collected was
+            # in the window, so its keys are pending and decided in
+            # the policy stage: no other key has a parameter.
+            holds = self._holds_meta
+            ahead = holds is not None and holds()
             raw_meta = self._ns_meta
-            if raw_meta is not None:
+            if holds is not None and not ahead:
+                self._ns_meta_block = _no_metadata
+            elif raw_meta is not None:
                 meta_memo: dict = {}
 
                 def ns_meta(ns, _memo=meta_memo, _raw=raw_meta):
@@ -1059,7 +1186,7 @@ class TxValidator:
             else:
                 self._ns_meta_block = None
             native = self._collect_native(
-                envs, seen_txids, sink, works, flags, memo, lone
+                envs, seen_txids, sink, works, flags, memo, lone, ahead
             )
             if not native:
                 width = self._collect_fanout(n)
@@ -1096,7 +1223,7 @@ class TxValidator:
             if pending is not None:
                 self._defer(works, window)
             if count.reads:
-                self._count_keylevel(lookups=count.reads)
+                self._count_keylevel(count)
             plans = self._count_plans(plans0)
             if sink.idemix.by_msp:
                 # the block's Idemix items go out here too, as ONE
@@ -1117,6 +1244,8 @@ class TxValidator:
                     keylevel_reads=count.reads,
                     keylevel_ms=count.seconds * 1e3,
                     keylevel_policies=len(count.params),
+                    keylevel_bulk_keys=count.bulk_keys,
+                    keylevel_point_reads=count.point_reads,
                     plan_hits=plans[0], plan_misses=plans[1],
                     plan_clears=plans[2],
                 )
@@ -1142,7 +1271,7 @@ class TxValidator:
             works.deferral = _Deferral(window, waits_on, txs)
 
     def _collect_native(self, data, seen_txids, sink: _ItemSink, works, flags, memo: dict,
-                        lone: bool = False) -> bool:
+                        lone: bool = False, ahead: bool = False) -> bool:
         """Native-assisted collect: one C++ pass walks every envelope's
         wire format (syntactic checks + SHA-256 digests, collect.cc),
         then this glue does only identity/policy work per tx.  `data` is
@@ -1174,7 +1303,13 @@ class TxValidator:
         (`early_chunk`, of the walker's count: a creator and the
         endorsements of every lane it accepted) and hands exactly that
         many to the device as soon as the sink holds them, so the first
-        chunk's kernel runs under the rest of this loop."""
+        chunk's kernel runs under the rest of this loop.
+
+        `ahead`: the state holds metadata, so the plugins will look up
+        the written keys' VALIDATION_PARAMETERs: the footprints are
+        parsed before the glue loop and the block's memo fetches those
+        keys' metadata in ONE read (`_KeyLevelMemo.fill`), where the
+        loop would make a ledger read a key."""
         from fabric_tpu import native
         from fabric_tpu.csp.api import VerifyBatchItem
 
@@ -1244,11 +1379,13 @@ class TxValidator:
         es_off = co["e_sig_off"].tolist()
         es_len = co["e_sig_len"].tolist()
 
-        # parallel prefetch over the walker-validated endorser lanes:
-        # the rwset footprint decode — the glue loop's largest per-tx
-        # cost — fans out in deterministic chunks; the glue loop below
-        # then runs unchanged with footprints in hand, so flags/sink
-        # order are byte-identical to the serial pass.  A failed parse
+        # prefetch over the walker-validated endorser lanes: the rwset
+        # footprint decode — the glue loop's largest per-tx cost — fans
+        # out in deterministic chunks (a chosen width), or runs here
+        # ahead of the loop (`ahead`: the same parses in the same
+        # order, none twice); the glue loop below then runs unchanged
+        # with footprints in hand, so flags/sink order are
+        # byte-identical to the serial pass.  A failed parse
         # carries its flag code (int) in place of the footprint,
         # applied at the exact point _prepare_namespaces would have
         # produced it.  (Creator identities are not prefetched by the
@@ -1259,7 +1396,7 @@ class TxValidator:
         # identities cost and how many signatures that call decided.)
         prefetched: list | None = None
         width = self._collect_fanout(len(data), native=True)
-        if width:
+        if width or ahead:
             def _prefetch(off, lanes):
                 out = []
                 for i in lanes:
@@ -1299,14 +1436,22 @@ class TxValidator:
                     if t in seen_txids or txid_known(t):
                         continue
                 lanes.append(i)
-            got = workpool.run_chunked(
-                self._collect_pool or workpool.default_pool(),
-                _prefetch, lanes, width,
-            )
+            if width:
+                got = workpool.run_chunked(
+                    self._collect_pool or workpool.default_pool(),
+                    _prefetch, lanes, width,
+                )
+                self.parallel_collect_blocks += 1
+            else:
+                got = _prefetch(0, lanes)
             prefetched = [None] * len(data)
             for i, fp in zip(lanes, got):
                 prefetched[i] = fp
-            self.parallel_collect_blocks += 1
+            if ahead:
+                works.keylevel.fill(_metadata_pairs(
+                    (fp for fp in got if not isinstance(fp, int)),
+                    self._ns_meta_block,
+                ))
 
         cut = None
         if lone and not self._faithful:
@@ -1437,7 +1582,7 @@ class TxValidator:
                 endorsements=signed,
                 rwset_bytes=rwset_bytes,
                 policy_provider=self._policy_provider,
-                state_metadata=self._committed_metadata,
+                state_metadata=self._metadata_block,
                 footprint=footprint,
                 ns_has_metadata=self._ns_meta_block,
                 pending=self._pending_block,
@@ -1511,7 +1656,7 @@ class TxValidator:
                 deferral.window.await_landed(deferral.waits_on)
             t1, t_wait = time.perf_counter(), t1
             self._observe_stage("await_commit", t1 - t_wait)
-            count = self._keylevel = _KeyLevelCount()
+            count = works.keylevel.landed()
             plans0 = self._plan_counts()
 
         # phase 3: in-order finish.  All policy evaluations read the
@@ -1527,6 +1672,14 @@ class TxValidator:
         with tracing.attached(ctx), tracing.span(
             "policy", cat="stage", block=num,
         ) as pspan:
+            if deferral is not None:
+                # what the deferred transactions' policies will look up
+                # and the memo does not hold yet (their pending keys,
+                # above all), in one read of the state as it landed
+                works.keylevel.fill(_metadata_pairs(
+                    w.footprint for i, w in enumerate(works)
+                    if w.deferred and flags[i] == V.VALID
+                ))
             for i in range(n):
                 if flags[i] != V.VALID:
                     continue
@@ -1565,13 +1718,13 @@ class TxValidator:
 
             protoutil.set_tx_filter(block, bytes(flags))
             if deferral is not None:
-                self._count_keylevel(
-                    lookups=count.reads, deferred=deferral.txs, waits=1
-                )
+                self._count_keylevel(count, deferred=deferral.txs, waits=1)
                 plans = self._count_plans(plans0)
                 pspan.annotate(
                     deferred=deferral.txs, deferred_reads=count.reads,
                     deferred_ms=count.seconds * 1e3,
+                    deferred_bulk_keys=count.bulk_keys,
+                    deferred_point_reads=count.point_reads,
                     plan_hits=plans[0], plan_misses=plans[1],
                     plan_clears=plans[2],
                 )

@@ -560,6 +560,16 @@ def test_the_spans_and_the_counters_say_what_the_stream_held(chain, serial):
     lookups = sum(a["keylevel_reads"] for a in collects.values()) \
         + sum(a.get("deferred_reads", 0) for a in policies.values())
     assert lookups > 0 and all(a["keylevel_ms"] >= 0.0 for a in collects.values())
+    # one bulk read a stage fetched what they asked for; a lookup it did
+    # not cover went to the ledger by itself (none, with the native walker)
+    stages = [(a["keylevel_reads"], a["keylevel_bulk_keys"], a["keylevel_point_reads"])
+              for a in collects.values()]
+    stages += [(a["deferred_reads"], a["deferred_bulk_keys"], a["deferred_point_reads"])
+               for a in policies.values() if a["deferred"]]
+    assert all(0 <= point <= reads and (bulk or point or not reads)
+               for reads, bulk, point in stages)
+    point_reads = sum(point for _reads, _bulk, point in stages)
+    assert sum(bulk for _reads, bulk, _point in stages) > 0 or point_reads == lookups
     assert max(a["keylevel_policies"] for a in collects.values()) >= 2
     assert sum(a["plan_misses"] for a in collects.values()) >= 2
     assert sum(a["plan_hits"] for a in collects.values()) > 0
@@ -569,10 +579,13 @@ def test_the_spans_and_the_counters_say_what_the_stream_held(chain, serial):
     assert after["waits"] - before["waits"] == len(waits)
     assert after["recent_blocks"][-N_BLOCKS:] == sorted(deferred.items())
     for name in ("validator_keylevel_lookups_total", "validator_keylevel_deferred_total",
-                 "validator_plan_cache_total"):
+                 "validator_keylevel_point_reads_total", "validator_plan_cache_total"):
         assert name in text                       # on the page from the start
     text = ops.metrics_provider.registry.expose()
     assert f'validator_keylevel_lookups_total{{channel="benchch"}} {lookups}' in text
+    if point_reads:
+        assert (f'validator_keylevel_point_reads_total{{channel="benchch"}} '
+                f'{point_reads}') in text
     assert (f'validator_keylevel_deferred_total{{channel="benchch"}} '
             f'{sum(deferred.values())}') in text
     assert 'validator_plan_cache_total{outcome="hit"}' in text
