@@ -57,12 +57,17 @@ def say(tag: str, record) -> None:
 
 class _Ledgers:
     """Fresh on-disk ledgers (block files + sqlite WAL), one at a time,
-    with the validator and committer a peer would hold for each."""
+    with the validator and committer a peer would hold for each.  Where
+    the world has `setup_blocks`, `populate()` commits them once and
+    every ledger after that starts as a copy of what they left."""
 
     def __init__(self, root: str, world, csp):
         self._root, self._world, self._csp = root, world, csp
         self._n = 0
         self._bundle_cached = None
+        self._template = None     # a closed ledger's directory holding the world's setup_blocks
+        self.base = 1             # the height of a ledger that took none of the world's `blocks`
+        self.copy_s: list = []    # what each copy of the template took
         self.validate_s: dict = collections.Counter()
         self.commit_s: dict = collections.Counter()
         self.blocks_counted = 0
@@ -80,14 +85,47 @@ class _Ledgers:
             self.retire()
             self._n += 1
             path = os.path.join(self._root, f"ledger{self._n}")
-            provider = LedgerProvider(path)
-            ledger = provider.create(self._world.genesis)
+            if self._template is None:
+                provider = LedgerProvider(path)
+                ledger = provider.create(self._world.genesis)
+            else:
+                t0 = time.perf_counter()
+                shutil.copytree(self._template, path)
+                self.copy_s.append(time.perf_counter() - t0)
+                provider = LedgerProvider(path)
+                ledger = provider.open(self._world.channel)
             bundle = self._bundle()
             validator = TxValidator(
                 self._world.channel, ledger, bundle, self._csp,
                 definition_provider=getattr(self._world, "definition_provider", None))
             self.cur = (provider, ledger, validator, Committer(validator, ledger), path)
         return self.cur
+
+    def populate(self) -> dict | None:
+        """The world's `setup_blocks` (numbers 1..m), committed once
+        through `store_stream` as a pass commits its blocks, into a
+        ledger that is then closed, so that its WAL is checkpointed,
+        and kept as the template: what is in the state got there by
+        commits.  What it cost, or None for a world without any."""
+        raw = getattr(self._world, "setup_blocks", None)
+        if not raw:
+            return None
+        from fabric_tpu.protos.common import common_pb2
+
+        t0 = time.perf_counter()
+        provider, ledger, _v, committer, path = self.fresh()
+        for _flags in committer.store_stream(common_pb2.Block.FromString(b) for b in raw):
+            pass
+        if ledger.height != 1 + len(raw):
+            raise RuntimeError(f"ledger height {ledger.height} after {len(raw)} setup blocks")
+        rows = sum(1 for ns in self._world.namespaces
+                   for _row in ledger._state.get_state_range(ns, "", ""))
+        provider.close()
+        self.cur = None
+        self._template, self.base = path, 1 + len(raw)
+        return {"blocks": len(raw), "rows": rows, "seconds": time.perf_counter() - t0,
+                "bytes_on_disk": sum(os.path.getsize(os.path.join(d, f))
+                                     for d, _dirs, files in os.walk(path) for f in files)}
 
     def _bundle(self):
         if self._bundle_cached is None:
@@ -103,14 +141,14 @@ class _Ledgers:
         if self.cur is None:
             return
         _prov, ledger, validator, _c, _path = self.cur
-        if ledger.height <= 1:
+        if ledger.height <= self.base:
             self._drop(self.cur)
             self.cur = None
             return
         if self.counting:
             self.validate_s.update(validator.validate_stage_seconds)
             self.commit_s.update(ledger.commit_stage_seconds)
-            self.blocks_counted += ledger.height - 1
+            self.blocks_counted += ledger.height - self.base
         self._drop(self.prev)
         self.prev, self.cur = self.cur, None
 
@@ -122,7 +160,7 @@ class _Ledgers:
 
     def last_with_blocks(self):
         for entry in (self.cur, self.prev):
-            if entry is not None and entry[1].height > 1:
+            if entry is not None and entry[1].height > self.base:
                 return entry
         return None
 
@@ -258,7 +296,9 @@ class Cell:
         bucket, the key table and both entry points have run.  A steady
         cell also takes one backlog pass first: how many blocks share a
         flush there depends on timing, and a bucket first met inside
-        the window would compile there."""
+        the window would compile there.  A world's `setup_blocks` are
+        committed first, once: the template of every ledger from here on."""
+        populated = self.ledgers.populate()
         t0 = time.perf_counter()
         self._backlog_pass(timed=False)
         if self.mode == "open_loop":
@@ -266,6 +306,9 @@ class Cell:
         self.csp.drain()
         self.warm_mark = self.buckets.mark()
         self.first_block_s = self.buckets.first_wall_s
+        if populated:
+            copies = self.ledgers.copy_s
+            say("populate", dict(populated, copy_s_per_pass=sum(copies) / len(copies)))
         say("warm_up", {
             "seconds": time.perf_counter() - t0,
             "first_dispatch_s": self.first_block_s,
@@ -312,7 +355,7 @@ class Cell:
                     self._flags_out(bno, flags)
         wall = time.perf_counter() - t0
         self.gc.timed = False
-        if ledger.height != 1 + len(blocks):
+        if ledger.height != self.ledgers.base + len(blocks):
             raise RuntimeError(f"ledger height {ledger.height} after {len(blocks)} blocks")
         if timed:
             self.pass_walls.append(wall)
@@ -476,6 +519,34 @@ class Cell:
 
     # -- the check against the plain reference ---------------------------
 
+    def _reference(self):
+        """The plain reference's flags, a block of the world's `blocks`
+        each, and its state after the k-th of them (k >= 1, asked in
+        rising order).  Of a populated world it replays the
+        `setup_blocks` too and answers with the base they left and, a
+        block, the rows that changed: folded here, in place."""
+        run = self.manifest.reference(self.config)
+        setup_blocks = getattr(self.world, "setup_blocks", None)
+        if not setup_blocks:
+            flags, states = run(self.world.public, self.deployment, self.world.blocks)
+            return flags, lambda k: states[k - 1]
+        flags, state, changes = run(self.world.public, self.deployment, self.world.blocks,
+                                    setup_blocks)
+        folded = 0
+
+        def state_after(k):
+            nonlocal folded
+            for changed in changes[folded:k]:
+                for row, held in changed.items():
+                    if held is None:
+                        state.pop(row, None)
+                    else:
+                        state[row] = held
+            folded = max(folded, k)
+            return state
+
+        return flags, state_after
+
     def check(self) -> dict:
         """Every flag list the window yielded against the reference's,
         the last ledger's state against the reference's map, and the
@@ -483,26 +554,29 @@ class Cell:
         engine's own, due in every cell, and those the configuration
         names."""
         t0 = time.perf_counter()
-        ref_flags, ref_states = self.manifest.reference(self.config)(
-            self.world.public, self.deployment, self.world.blocks
-        )
+        ref_flags, state_after = self._reference()
+        t_ref = time.perf_counter()
         ref_flags = [bytes(f) for f in ref_flags]
-        generator_agrees = (
-            [bytes(f) for f in self.world.planted] == ref_flags
-            and self.world.expected_state() == ref_states[-1]
-        )
         bad_blocks = sum(1 for bno, flags in self.yielded if flags != ref_flags[bno])
         entry = self.ledgers.last_with_blocks()
         state_diff = -1
+        rows = same = 0
         if entry is not None:
             ledger = entry[1]
-            got = {
-                (ns, key): (vv.value, (vv.version.block_num, vv.version.tx_num))
-                for ns in self.world.namespaces
-                for key, vv in ledger._state.get_state_range(ns, "", "")
-            }
-            want = ref_states[ledger.height - 2]
-            state_diff = len(set(got.items()) ^ set(want.items()))
+            want = state_after(ledger.height - self.ledgers.base)
+            for ns in self.world.namespaces:
+                for key, vv in ledger._state.get_state_range(ns, "", ""):
+                    rows += 1
+                    same += want.get((ns, key)) == (
+                        vv.value, (vv.version.block_num, vv.version.tx_num))
+            # the rows either side has and the other has not, or has otherwise
+            state_diff = (rows - same) + (len(want) - same)
+        generator_agrees = (
+            [bytes(f) for f in self.world.planted] == ref_flags
+            and self.world.expected_state() == state_after(len(self.world.blocks))
+        )
+        say("check", {"reference_s": t_ref - t0, "state_rows": rows,
+                      "state_and_generator_s": time.perf_counter() - t_ref})
         tally = self.csp.lane_tally()
         lanes = {k: tally[k] - self.tally0.get(k, 0) for k in tally}
         win = self.buckets
@@ -587,6 +661,7 @@ class Cell:
             "pass_gen2_collections": [n for n, _s in self.pass_gc2],
             "pass_gen2_pause_s": [s for _n, s in self.pass_gc2],
             "pass_stage_seconds": self.pass_stages,
+            "ledger_copy_s": self.ledgers.copy_s,
             "block_latencies_ms": [x * 1e3 for x in self.latencies_s],
             "arrival_lateness_ms": [x * 1e3 for x in self.lateness_s],
             "blocks_per_store_call": dict(collections.Counter(self.run_sizes)),

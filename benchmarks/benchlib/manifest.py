@@ -47,12 +47,43 @@ endorsement policy,
                       handed to every `TxValidator` the engine builds.
                       A world without the attribute gets none.
 
+and, if its ledgers are to start populated (a deployment whose state is
+larger than a pass's blocks can write),
+
+    setup_blocks      serialized Block bytes, numbers 1..m: ordinary
+                      blocks, valid under the channel's policies, that
+                      write what a ledger holds before the first
+                      measured block (a few fat transactions a block
+                      is fine; the orderer's `AbsoluteMaxBytes` bounds
+                      a block).  `blocks` are then numbers m+1..m+n,
+                      and `expected_state()` holds the populated rows
+                      too.  The engine commits them once, in set-up,
+                      through `Committer.store_stream` into a ledger
+                      of `LedgerProvider.create`, closes it and keeps
+                      its directory as the template; every pass's
+                      ledger is a copy of the template, opened by
+                      `LedgerProvider.open`, outside the timed part as
+                      a ledger's creation is.  What is in the state
+                      got there by commits: the benchmark writes
+                      nothing into the program's tables.  The template
+                      is paid for by `setup_s`, and the line
+                      `# populate` says what it cost (blocks, rows,
+                      seconds, bytes on disk, the copy's seconds a
+                      pass).  A world without the attribute gets what
+                      it got: a ledger of the genesis block alone.
+
 The same seed gives the same world.  A world module touches no JAX: the
 engine builds the world while the device initialises.
 
 `reference/<name>.py` gives `run(public, deployment, blocks)` ->
 (per block the flags, per block the (namespace, key) -> (value,
-(block, tx)) state after it), of a fresh chain.  It is independent of
+(block, tx)) state after it), of a fresh chain.  The reference of a
+world with `setup_blocks` is called `run(public, deployment, blocks,
+setup_blocks)`, replays both, and answers (per measured block the
+flags, the state after the last of `setup_blocks`, per measured block
+the rows that changed: (namespace, key) -> (value, (block, tx)), or
+None for a row deleted); the engine folds them, and compares every row
+of the world's namespaces, the populated ones too.  It is independent of
 the code under test: of `fabric_tpu` it imports `fabric_tpu.protos`
 and nothing else, and it takes nothing the program has made.
 
@@ -68,7 +99,8 @@ in `tests/bench/test_bench_rehearsal.py` holds the engine to them):
     lanes_window   the provider's `lane_tally()`, window only: lanes by who sealed them
     new_buckets    kernel buckets first used inside the window
     csp            the provider as the peer built it (`lane_tally()`, `breaker`)
-    world          what the cell's world module built
+    world          what the cell's world module built (its `setup_blocks`, where
+                   a condition wants the populated rows, among it)
     deployment     the configuration's numbers as they were run
 
 What the program counts elsewhere (a tally another provider keeps, a

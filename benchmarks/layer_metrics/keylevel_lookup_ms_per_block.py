@@ -1,6 +1,8 @@
 """Validator (`peer/txvalidator.py`): the wall of the committed
 state-metadata lookups that find a written key's VALIDATION_PARAMETER,
-over blocks: `keylevel_ms` summed over the window's `collect` spans
+over blocks (since PR 41 the bulk read a stage plus any point reads, as
+`keylevel_bulk_hit_share` counts them): `keylevel_ms` summed over the
+window's `collect` spans
 (the transactions decided while their block is collected) and
 `deferred_ms` over its `policy` spans (those decided once an earlier
 block's commit had landed).  A program whose `collect` spans lack

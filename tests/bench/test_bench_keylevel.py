@@ -80,12 +80,13 @@ def test_the_configuration_states_its_source_its_shapes_and_its_guarantees(held)
     (cell,) = [w for w in doc["workloads"] if w["name"] == CELL]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (CONFIG, "catchup", 1)
     assert Manifest(ROOT).traffic(cell)["blocks_per_pass"] == 16
-    # the three metrics stand at the end of `per_layer`, for this cell alone
-    assert [m["name"] for m in doc["per_layer"][-3:]] == [
-        "keylevel_commit_wait_ms_per_block.catchup", "keylevel_lookup_ms_per_block.catchup",
-        "keylevel_deferred_tx_share.catchup"]
-    assert all(m["workloads"] == [CELL] and m["moves"] == "committed_tx_per_s"
-               and m["layer"] == "validator (peer/txvalidator.py)" for m in doc["per_layer"][-3:])
+    # the cell's three metrics, wherever they stand in `per_layer`, for this cell alone
+    declared = {m["name"]: m for m in doc["per_layer"]}
+    for name in ("keylevel_commit_wait_ms_per_block.catchup",
+                 "keylevel_lookup_ms_per_block.catchup", "keylevel_deferred_tx_share.catchup"):
+        m = declared[name]
+        assert m["workloads"] == [CELL] and m["moves"] == "committed_tx_per_s"
+        assert m["layer"] == "validator (peer/txvalidator.py)"
 
 
 def test_the_world_plants_what_the_configuration_says_and_counts_its_neighbours(man, held, kl):

@@ -10,10 +10,10 @@ manifest appends the entry PR 41 proposes:
      "source": "program_span", "layer": "validator (peer/txvalidator.py)",
      "moves": "committed_tx_per_s", "workloads": ["keylevel-5org-1000tx.catchup"]}
 
-PR 41 leaves `BENCHMARK.json` without it: an accepted test pins the
-last three entries of `per_layer` (`test_bench_keylevel.py`), and only
-a `benchmark` PR may edit an accepted file.  Once declared, the tests
-here hold the declared entry to this one.
+PR 41 had to leave `BENCHMARK.json` without it (an accepted test pinned
+the last three entries of `per_layer`); PR 43 took the pin out and
+appended the entry, and the tests here hold the declared entry to this
+one.
 
 No number of a CPU run is a device number: the tests read counts and
 shares of counts, never a time."""

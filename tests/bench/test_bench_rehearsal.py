@@ -5,10 +5,12 @@ broken underneath (a control of `benchmarks/controls/` patches the
 program's own classes), which must come out as not correct.  Then a
 new KIND of deployment, added to a copy of the benchmark by new files
 and entries alone: a toy world, reference, condition and control; a
-per-layer metric appended to that copy with its reader, and the copy
-held to the manifest's own tests; and a toy world of two chaincodes
-under a policy each, which come to the validator through the world's
-`definition_provider`.
+per-layer metric appended to that copy with its reader; a copy with an
+entry appended to each of the manifest's four lists, held to every
+test file of `tests/bench` that does not run the engine; a toy world of
+two chaincodes under a policy each, which come to the validator through
+the world's `definition_provider`; and a toy world whose ledgers start
+populated from the `setup_blocks` it carries.
 
 Every flush is 20 lanes, so one kernel shape is built in this process.
 No number of a CPU run is a device number: the test reads counts,
@@ -227,9 +229,9 @@ def _add_cell(root, name, like, **changed):
 
 
 @pytest.fixture
-def toy_root(tmp_path):
-    """A copy of the benchmark with the toy kind added as new files and
-    new entries; what was there is checked byte for byte on the way out."""
+def copy_root(tmp_path):
+    """A copy of the benchmark to add new files and new entries to;
+    what was there is checked byte for byte on the way out."""
     root = str(tmp_path)
     b = os.path.join(root, "benchmarks")
     shutil.copytree(os.path.join(ROOT, "benchmarks"), b,
@@ -241,12 +243,6 @@ def toy_root(tmp_path):
             with open(os.path.join(d, f), "rb") as fh:
                 before[os.path.join(d, f)] = fh.read()
     shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root)
-    solo = _held(root, "solo1-500tx")
-    _add_cell(root, "toy", "solo1-500tx", world="toy-world", reference="toy-reference",
-              conditions=["toy-condition"],
-              deployment=dict(solo["deployment"], toy_unsigned_tx=0),
-              planted=dict(solo["planted"], bad_creator_per_block=1,
-                           bad_endorsement_per_block=1))
 
     def write(kind, name, text):
         # a kind's directory comes with its first file: git carries no
@@ -255,14 +251,27 @@ def toy_root(tmp_path):
         with open(os.path.join(b, kind, name + ".py"), "w") as f:
             f.write(text)
 
-    write("worlds", "toy-world", TOY_WORLD)
-    write("reference", "toy-reference", TOY_REFERENCE % {"flip": False})
-    write("conditions", "toy-condition", TOY_CONDITION % {"limit": 1000})
-    write("controls", "toy-control", TOY_CONTROL)
     yield root, write
     for p, data in before.items():
         with open(p, "rb") as fh:
             assert fh.read() == data, p
+
+
+@pytest.fixture
+def toy_root(copy_root):
+    """The copy with the toy kind added."""
+    root, write = copy_root
+    solo = _held(root, "solo1-500tx")
+    _add_cell(root, "toy", "solo1-500tx", world="toy-world", reference="toy-reference",
+              conditions=["toy-condition"],
+              deployment=dict(solo["deployment"], toy_unsigned_tx=0),
+              planted=dict(solo["planted"], bad_creator_per_block=1,
+                           bad_endorsement_per_block=1))
+    write("worlds", "toy-world", TOY_WORLD)
+    write("reference", "toy-reference", TOY_REFERENCE % {"flip": False})
+    write("conditions", "toy-condition", TOY_CONDITION % {"limit": 1000})
+    write("controls", "toy-control", TOY_CONTROL)
+    return root, write
 
 
 def _toy_run(root, capsys, cell="toy.catchup", size=TINY):
@@ -315,9 +324,9 @@ def test_a_new_kind_of_deployment_is_added_by_files_only(sound, unpatched, toy_r
 # PR 37 built seven metrics that no PR could declare: three accepted
 # tests pinned the END of `per_layer` and the count of metrics due in
 # two cells.  What holds "appended, nothing moved" now is data
-# (`data/accepted_*.json`, `test_bench_manifest.py`), and this case is
-# the one that would have caught the pins: one more entry with its
-# reader, and every test of the manifest run against the copy.
+# (`data/accepted_*.json`, `test_bench_manifest.py`).  Here: one more
+# entry with its reader is due where it says and a traced run reports
+# it; the hold further down runs every test file against such a copy.
 
 TOY_READER = '''
 """Toy: the transactions of a block, a number the engine already gives."""
@@ -328,28 +337,7 @@ def read(obs):
 '''
 
 
-def _every_test_of(module, **fixtures):
-    """Call each `test_*` of `module` with `fixtures` standing in for
-    pytest's, one call a parametrised case; the names that ran."""
-    ran = []
-    for name, test in sorted(vars(module).items()):
-        if not (name.startswith("test_") and inspect.isfunction(test)):
-            continue
-        cases = [{}]
-        for mark in getattr(test, "pytestmark", ()):
-            if mark.name == "parametrize":
-                names = [n.strip() for n in mark.args[0].split(",")]
-                cases = [dict(case, **dict(zip(names, v if len(names) > 1 else (v,))))
-                         for case in cases for v in mark.args[1]]
-        for case in cases:
-            test(**{p: case[p] if p in case else fixtures[p]()
-                    for p in inspect.signature(test).parameters})
-            ran.append(name)
-    return ran
-
-
-def test_a_per_layer_metric_is_appended_and_the_manifests_tests_pass_unedited(
-        sound, toy_root, tmp_path_factory):
+def test_a_per_layer_metric_is_appended_and_a_traced_run_reports_it(sound, toy_root):
     root, write = toy_root
     write("layer_metrics", "toy_txs_per_block", TOY_READER)
     with open(os.path.join(root, "BENCHMARK.json")) as f:
@@ -362,13 +350,8 @@ def test_a_per_layer_metric_is_appended_and_the_manifests_tests_pass_unedited(
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(doc, f)
 
-    ran = _every_test_of(contract, root=lambda: root, doc=lambda: doc,
-                         tmp_path=lambda: tmp_path_factory.mktemp("a_copy_of_the_copy"))
-    assert len(ran) >= 19 and {
-        "test_top_level_keys_and_sizes", "test_configs", "test_workloads", "test_metrics",
-        "test_what_was_accepted_stands_first_in_its_order_and_as_it_was",
-        "test_a_config_a_mix_a_cell_and_a_metric_are_added_as_new_files_only"} <= set(ran)
-    # the same tests do refuse an entry that is put BEFORE an accepted one
+    # the manifest's test refuses an entry that is put BEFORE an accepted one (a
+    # copy that appends passes every test of every file: the hold further down)
     moved = dict(doc, per_layer=doc["per_layer"][-1:] + doc["per_layer"][:-1])
     with pytest.raises(AssertionError):
         contract.test_what_was_accepted_stands_first_in_its_order_and_as_it_was(moved, "per_layer")
@@ -385,6 +368,176 @@ def test_a_per_layer_metric_is_appended_and_the_manifests_tests_pass_unedited(
     assert line["metrics"]["toy_txs_per_block.catchup"] == {"value": 10.0, "unit": "tx"}
     assert {"collect_ms_per_block.catchup", "policy_ms_per_block.catchup",
             "collect_cpu_ms_per_block.catchup", "host_cores_busy.catchup"} <= set(line["metrics"])
+
+
+# -- every list takes an appended entry, under every test of the benchmark ----
+#
+# PR 39's guard held a copy with one more `per_layer` entry to
+# `test_bench_manifest.py` alone, and one PR later a cell's own test
+# pinned the end of `per_layer` again (`test_bench_keylevel.py`, PR 40;
+# PR 41 could not declare its metric).  So: a copy with one more entry at
+# the end of EACH of the four lists, and every test under `tests/bench`
+# held to it that does not run the engine, start a process or call the
+# chip's compiler: the configuration-and-entries tests of each cell's
+# file, the readers' and the worlds'.  A test that counts or indexes a
+# list by position now fails in the PR that writes it.
+
+# what a test (or a fixture it uses) says that is not held to the copy
+NOT_HELD = ("run_cell", "engine.Cell(", "subprocess", "get_topology_desc")
+
+
+def _is_fixture(obj) -> bool:
+    return hasattr(obj, "_fixture_function_marker") or hasattr(obj, "_pytestfixturefunction")
+
+
+def _hold(path, root, builtin, monkeypatch):
+    """Load the test file at `path` afresh with the copy at `root` as
+    its checkout, and run each of its tests that `NOT_HELD` does not
+    exclude, the module's own fixtures resolved by name and `builtin`
+    standing in for pytest's.  The names that ran."""
+    import importlib.util
+
+    import conftest
+
+    monkeypatch.setattr(conftest, "ROOT", root)
+    monkeypatch.setattr(conftest, "BENCH", os.path.join(root, "benchmarks"))
+    stem = os.path.splitext(os.path.basename(path))[0]
+    spec = importlib.util.spec_from_file_location("held_" + stem, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    fixtures = {name: getattr(obj, "__wrapped__", obj)
+                for name, obj in vars(module).items() if _is_fixture(obj)}
+
+    def says(fn, seen):
+        """The source of `fn`, of the module's fixtures under it and of
+        the module's own functions it names."""
+        text = inspect.getsource(fn)
+        for p in list(inspect.signature(fn).parameters) + list(fn.__code__.co_names):
+            under = fixtures.get(p) or vars(module).get(p)
+            if inspect.isfunction(under) and under.__module__ == module.__name__ \
+                    and p not in seen:
+                seen.add(p)
+                text += says(under, seen)
+        return text
+
+    made, open_generators = {}, []
+
+    def value(name, case):
+        if name in case:
+            return case[name]
+        if name not in fixtures:
+            return builtin[name]()
+        if name not in made:
+            fn = fixtures[name]
+            got = fn(**{p: value(p, case) for p in inspect.signature(fn).parameters})
+            if inspect.isgenerator(got):
+                open_generators.append(got)
+                got = next(got)
+            made[name] = got
+        return made[name]
+
+    ran = []
+    for name, test in sorted(vars(module).items()):
+        if not (name.startswith("test_") and inspect.isfunction(test)):
+            continue
+        if any(word in says(test, set()) for word in NOT_HELD):
+            continue
+        cases = [{}]
+        for mark in getattr(test, "pytestmark", ()):
+            if mark.name == "parametrize":
+                names = [n.strip() for n in mark.args[0].split(",")]
+                values = [getattr(v, "values", v) for v in mark.args[1]]     # a pytest.param
+                cases = [dict(case, **dict(zip(names, v if len(names) > 1 else (v,))))
+                         for case in cases for v in values]
+        for case in cases:
+            with pytest.MonkeyPatch.context() as own:      # undone after every test, as pytest's is
+                case = dict(case, monkeypatch=own)
+                try:
+                    test(**{p: value(p, case) for p in inspect.signature(test).parameters})
+                except pytest.skip.Exception:
+                    continue
+            ran.append(f"{stem}::{name}")
+    for gen in open_generators:
+        next(gen, None)
+    return ran
+
+
+OLD_PIN = '''
+import json
+import os
+
+from conftest import ROOT
+
+
+def test_the_cells_metric_stands_at_the_end_of_per_layer():
+    """As `test_bench_keylevel.py:84-88` had it from PR 40 to PR 43."""
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    assert doc["per_layer"][-1]["name"] == %(last)r
+'''
+
+
+def test_an_entry_appended_to_each_list_passes_every_test_that_reads_the_manifest(
+        copy_root, tmp_path_factory, capsys, monkeypatch):
+    root, write = copy_root
+    # a configuration of an accepted kind (a toy reference need not be
+    # independent of the program; an accepted one is held to it), its
+    # cell, the cell's name at the end of the `workloads` list of every
+    # metric a catch-up cell reports, and a per-layer entry of its own
+    _add_cell(root, "toy", "solo1-500tx")
+    write("layer_metrics", "toy_txs_per_block", TOY_READER)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    doc["per_layer"].append({"name": "toy_txs_per_block.catchup", "unit": "tx", "better": "higher",
+                             "source": "program_counter", "layer": "harness (benchmarks/)",
+                             "moves": "committed_tx_per_s", "workloads": ["toy.catchup"]})
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(doc, f)
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        accepted = json.load(f)
+    for name in ("configs", "workloads", "per_layer"):
+        assert doc[name][:-1] == accepted[name] or name == "per_layer"
+        assert len(doc[name]) == len(accepted[name]) + 1
+    grown = [m["name"] for was, m in zip(accepted["end_to_end"], doc["end_to_end"])
+             if m.get("workloads", [])[len(was.get("workloads", [])):] == ["toy.catchup"]]
+    assert grown == ["committed_tx_per_s"]
+    # what the tests read beside the benchmark: the program and their own data
+    for beside in ("fabric_tpu", "tests"):
+        os.symlink(os.path.join(ROOT, beside), os.path.join(root, beside))
+
+    builtin = {"tmp_path": lambda: tmp_path_factory.mktemp("held"),
+               "tmp_path_factory": lambda: tmp_path_factory,
+               "capsys": lambda: capsys}
+    here = os.path.dirname(os.path.abspath(__file__))
+    ran = []
+    for file in sorted(os.listdir(here)):
+        if file.startswith("test_bench_") and file.endswith(".py") \
+                and file != os.path.basename(__file__):
+            ran += _hold(os.path.join(here, file), root, builtin, monkeypatch)
+    assert {name.split("::")[0] for name in ran} >= {
+        "test_bench_manifest", "test_bench_keylevel", "test_bench_worlds",
+        "test_bench_commit_assist", "test_bench_cpu_spans", "test_bench_timeoutcut",
+        "test_bench_manyclients", "test_bench_policy_reader", "test_bench_span_readers",
+        "test_bench_keylevel_bulk_reader"}
+    assert len(ran) >= 150 and {
+        "test_bench_keylevel::test_the_configuration_states_its_source_its_shapes_and_its_guarantees",
+        "test_bench_manifest::test_what_was_accepted_stands_first_in_its_order_and_as_it_was",
+        "test_bench_worlds::test_every_configuration_names_a_world_and_a_reference_that_keep_the_contract",
+        "test_bench_keylevel_bulk_reader::test_the_entry_stands_beside_the_cells_other_key_level_metrics",
+    } <= set(ran)
+    # no rehearsal came along: the engine is not run here
+    assert not {"test_bench_keylevel::test_a_traced_rehearsal_reports_the_three_metrics",
+                "test_bench_idemix::test_skipping_the_pseudonym_signature_is_wrong_in_every_block",
+                "test_bench_refusal::test_no_tpu_no_result"} & set(ran)
+
+    # and a pin of the kind PR 40 wrote, which the checkout as it is
+    # passes, is refused by the same hold
+    pinned = os.path.join(str(tmp_path_factory.mktemp("pinned")), "test_bench_pinned.py")
+    with open(pinned, "w") as f:
+        f.write(OLD_PIN % {"last": accepted["per_layer"][-1]["name"]})
+    assert len(_hold(pinned, ROOT, builtin, monkeypatch)) == 1
+    with pytest.raises(AssertionError):
+        _hold(pinned, root, builtin, monkeypatch)
 
 
 # -- two chaincodes, a policy each ------------------------------------------
@@ -564,6 +717,247 @@ def test_two_chaincodes_under_two_policies_come_in_through_the_worlds_definition
     assert line["correct"] is False and line["failed"] == line["attempted"] > 0
     assert compared["generator_disagrees_with_reference"][0] == 0
     assert compared["state_entries_differing_from_reference"][0] > 0
+
+
+# -- a ledger that starts populated -------------------------------------------
+#
+# The door: a world may carry `setup_blocks`, which the engine commits
+# once in set-up through `store_stream`, keeps as a template, and copies
+# for every pass.  The toy world's two set-up blocks write a hundred rows
+# in four fat transactions; its measured blocks read those rows at the
+# versions the set-up left and overwrite them, so that every flag and
+# every row depends on what the ledger held before the first measured
+# block.  Planted: a read of a populated row at a version it never had
+# (MVCC_READ_CONFLICT), a read of a row no block wrote (valid: absent is
+# what the ledger says too), and a delete of a populated row.
+
+TOY_POPULATED_WORLD = '''
+"""Toy: a hundred rows committed before the first measured block."""
+import dataclasses
+import random
+
+from benchlib import generator
+
+ROWS_PER_SETUP_TX, SETUP_TXS_PER_BLOCK, SETUP_BLOCKS = 25, 2, 2
+NS = generator.CHAINCODE
+
+
+@dataclasses.dataclass
+class PopulatedWorld:
+    channel: str
+    genesis: object
+    blocks: list
+    planted: list
+    namespaces: tuple
+    public: dict
+    lanes_per_block: int
+    state: dict
+    setup_blocks: list
+
+    def expected_state(self):
+        return self.state
+
+
+def build_world(seed, deployment, planted, n_blocks):
+    from fabric_tpu import protoutil
+    from fabric_tpu.protos.common import common_pb2
+    from fabric_tpu.protos.ledger.rwset import rwset_pb2
+    from fabric_tpu.protos.ledger.rwset.kvrwset import kv_rwset_pb2
+    from fabric_tpu.protos.peer import chaincode_pb2, proposal_pb2
+
+    n_txs, endorsers = int(deployment["block_txs"]), int(deployment["endorsers_per_tx"])
+    net = generator.build_world(seed, deployment, planted, 0)     # the channel, no block
+    rng = random.Random(f"toy-populated:{int(seed)}")
+    client = net.orgs[0].signer(rng, "client", "client")
+    peers = [o.signer(rng, f"peer{i}", "peer") for i, o in enumerate(net.orgs[:endorsers])]
+    ok = proposal_pb2.Response(status=200)
+
+    def envelope(reads, writes):
+        """reads: (key, version or None); writes: (key, value or None for a delete)."""
+        kv = kv_rwset_pb2.KVRWSet()
+        for key, version in reads:
+            read = kv.reads.add(key=key)
+            if version is not None:
+                read.version.block_num, read.version.tx_num = version
+        for key, value in writes:
+            if value is None:
+                kv.writes.add(key=key, is_delete=True)
+            else:
+                kv.writes.add(key=key, value=value)
+        results = rwset_pb2.TxReadWriteSet(data_model=rwset_pb2.TxReadWriteSet.KV)
+        results.ns_rwset.add(namespace=NS, rwset=kv.SerializeToString())
+        prop, _txid = protoutil.create_chaincode_proposal(
+            client.serialize(), net.channel, NS, [b"toy"], nonce=rng.randbytes(24))
+        resps = [protoutil.create_proposal_response(
+            prop, results=results.SerializeToString(), events=b"", response=ok,
+            chaincode_id=chaincode_pb2.ChaincodeID(name=NS), endorser_signer=p) for p in peers]
+        return protoutil.create_signed_tx(prop, client, resps).SerializeToString()
+
+    def block(number, envelopes):
+        blk = common_pb2.Block()
+        blk.header.number = number
+        blk.data.data.extend(envelopes)
+        while len(blk.metadata.metadata) < 3:
+            blk.metadata.metadata.append(b"")
+        return blk.SerializeToString()
+
+    state, setup_blocks, row = {}, [], 0
+    for b in range(SETUP_BLOCKS):
+        envelopes = []
+        for t in range(SETUP_TXS_PER_BLOCK):
+            writes = [(f"acct{row + j:03d}", rng.randbytes(10)) for j in range(ROWS_PER_SETUP_TX)]
+            row += ROWS_PER_SETUP_TX
+            envelopes.append(envelope((), writes))
+            for key, value in writes:
+                state[NS, key] = (value, (1 + b, t))
+        setup_blocks.append(block(1 + b, envelopes))
+
+    blocks, flags = [], []
+    for b in range(n_blocks):
+        number, envelopes, want = 1 + SETUP_BLOCKS + b, [], []
+        for i in range(n_txs):
+            key = f"acct{(7 * (b * n_txs + i)) % row:03d}"
+            held = state.get((NS, key))
+            version = None if held is None else held[1]
+            value = rng.randbytes(10)
+            if b == 0 and i == 3:
+                # a version the populated row never had
+                envelopes.append(envelope([(key, (SETUP_BLOCKS, 9))], [(key, value)]))
+                want.append(generator.MVCC_READ_CONFLICT)
+                continue
+            if b == 0 and i == 5:
+                # a row no block wrote: read absent, written here
+                envelopes.append(envelope([("nobody", None)], [("nobody", value)]))
+                state[NS, "nobody"] = (value, (number, i))
+            elif b == 0 and i == 7:
+                envelopes.append(envelope([(key, version)], [(key, None)]))
+                state.pop((NS, key))
+            else:
+                envelopes.append(envelope([(key, version)], [(key, value)]))
+                state[NS, key] = (value, (number, i))
+            want.append(generator.VALID)
+        blocks.append(block(number, envelopes))
+        flags.append(want)
+    return PopulatedWorld(net.channel, net.genesis, blocks, flags, (NS,), net.public,
+                          n_txs * (1 + endorsers), state, setup_blocks)
+'''
+
+TOY_POPULATED_REFERENCE = '''
+"""Toy: the x509 reference over both lists of blocks, answering as the
+reference of a populated world answers: flags, base, changes."""
+import importlib.util
+import os
+
+WITHHELD = %(withheld)r     # the test's switch: the set-up blocks kept from the reference
+
+_spec = importlib.util.spec_from_file_location(
+    "toy_populated_x509_reference", os.path.join(os.path.dirname(__file__), "x509-majority.py"))
+_x509 = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_x509)
+
+
+def run(public, deployment, blocks, setup_blocks):
+    ref = _x509.Reference(public["ca_certs_pem"], int(deployment["orgs"]))
+    for b in () if WITHHELD else setup_blocks:
+        assert set(ref.apply_block(b)) == {_x509.VALID}
+    base = dict(ref.state)
+    flags, changes = [], []
+    for b in blocks:
+        before = dict(ref.state)
+        flags.append(ref.apply_block(b))
+        changes.append({row: ref.state.get(row) for row in set(before) | set(ref.state)
+                        if before.get(row) != ref.state.get(row)})
+    return flags, base, changes
+'''
+
+
+@pytest.fixture
+def populated_root(toy_root):
+    root, write = toy_root
+    _add_cell(root, "toy-populated", "solo1-500tx", world="toy-populated-world",
+              reference="toy-populated-reference")
+    write("worlds", "toy-populated-world", TOY_POPULATED_WORLD)
+    write("reference", "toy-populated-reference", TOY_POPULATED_REFERENCE % {"withheld": False})
+    return root, write
+
+
+# ten transactions of two lanes a block, two blocks a pass: a flush is 20
+# or 40 lanes, the one kernel shape this process has compiled
+POPULATED = engine.Rehearsal(block_txs=10, blocks_per_pass=2)
+POPULATED_CELL = "toy-populated.catchup"
+
+
+def test_a_world_may_start_every_passes_ledger_populated(sound, populated_root, capsys,
+                                                         monkeypatch):
+    root, _write = populated_root
+    passes = []
+    flags_out = engine.Cell._flags_out
+
+    def kept(self, bno, flags):
+        if bno == 0:
+            passes.append([])
+        passes[-1].append(bytes(flags))
+        flags_out(self, bno, flags)
+        if len(passes) == 2 and bno == 1:
+            self.seconds = 0.0          # two passes, however long the CPU's kernel takes
+
+    monkeypatch.setattr(engine.Cell, "_flags_out", kept)
+    line = engine.run_cell(root, POPULATED_CELL, 2**31 + 99, 3600.0, False, rehearsal=POPULATED)
+    said = {}
+    for out_line in capsys.readouterr().out.splitlines():
+        tag, _, rest = out_line.partition(": ")
+        if tag in ("# populate", "# check", "# records"):
+            said[tag[2:]] = json.loads(rest)
+    assert line["correct"] is True and line["failed"] == 0
+    assert all(c["value"] == 0 for c in line["compared"].values())
+    assert set(line["metrics"]) == {"committed_tx_per_s", "setup_s"}
+    # committed once, in set-up; copied for the warm-up's pass and for each of the window's
+    pop = said["populate"]
+    assert (pop["blocks"], pop["rows"]) == (2, 100) and pop["bytes_on_disk"] > 0
+    assert set(pop) == {"blocks", "rows", "seconds", "bytes_on_disk", "copy_s_per_pass"}
+    assert len(said["records"]["ledger_copy_s"]) == 1 + len(passes) == 3
+    # every row compared, the populated ones too: a hundred, one deleted, one new
+    assert said["check"]["state_rows"] == 100
+    # a pass is the world's two blocks, and the stage clocks count those alone
+    assert said["records"]["blocks"] == 2 * len(passes) == line["attempted"]
+    # a pass after the first starts from the template again: the same
+    # reads at the same versions get the same flags
+    assert all(p == passes[0] for p in passes) and len(passes[0]) == 2
+    assert list(passes[0][0]) == [0, 0, 0, 11, 0, 0, 0, 0, 0, 0] and set(passes[0][1]) == {0}
+
+
+def test_a_populated_world_is_checked_against_what_its_setup_blocks_left(
+        sound, populated_root, capsys, monkeypatch):
+    root, write = populated_root
+    # the set-up blocks kept from the reference: to it the chain starts
+    # empty, every read of a populated row conflicts and no such row is there
+    write("reference", "toy-populated-reference", TOY_POPULATED_REFERENCE % {"withheld": True})
+    line, compared = _toy_run(root, capsys, POPULATED_CELL, POPULATED)
+    assert line["correct"] is False and line["failed"] == line["attempted"] > 0
+    assert compared["state_entries_differing_from_reference"][0] >= 90
+    assert compared["generator_disagrees_with_reference"][0] == 1
+    write("reference", "toy-populated-reference", TOY_POPULATED_REFERENCE % {"withheld": False})
+
+    # a template that lost a row nobody reads: every flag is as the
+    # reference has it, and the state check still misses the row
+    populate = engine._Ledgers.populate
+
+    def lossy(self):
+        import sqlite3
+
+        cost = populate(self)
+        db = sqlite3.connect(os.path.join(self._template, "index.sqlite"))
+        with db:
+            lost = db.execute("DELETE FROM kv WHERE instr(k, 'statedb') AND instr(k, 'acct099')")
+        assert lost.rowcount == 1
+        db.close()
+        return cost
+
+    monkeypatch.setattr(engine._Ledgers, "populate", lossy)
+    line, compared = _toy_run(root, capsys, POPULATED_CELL, POPULATED)
+    assert line["correct"] is False and line["failed"] == 0
+    assert compared.pop("state_entries_differing_from_reference") == (1, 0)
+    assert all(value == 0 for value, _limit in compared.values())
 
 
 # -- a control takes what the provider takes --------------------------------

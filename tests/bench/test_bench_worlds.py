@@ -46,6 +46,11 @@ GOLDEN = {
 DEFAULT_POLICY_ALONE = ("majority5-1000tx", "solo1-500tx", "idemix-nym128",
                         "manyclients-10k", "timeoutcut-2s")
 
+# the configurations accepted before a world could start its ledgers
+# populated (PR 43): their worlds have no `setup_blocks`, so every pass's
+# ledger is made from the genesis block alone, as it was
+STARTS_AT_GENESIS = DEFAULT_POLICY_ALONE + ("keylevel-5org-1000tx",)
+
 
 def blocks_digest(world) -> str:
     """Planted flags and, of every transaction, channel, nonce, number
@@ -124,7 +129,15 @@ def test_every_configuration_names_a_world_and_a_reference_that_keep_the_contrac
 
     assert isinstance(world.channel, str) and world.channel
     assert isinstance(world.genesis, common_pb2.Block) and world.genesis.header.number == 0
-    assert [common_pb2.Block.FromString(b).header.number for b in world.blocks] == [1, 2]
+    # the second optional part: blocks 1..m that populate every ledger
+    # before the first measured block, which is then number m + 1
+    setup_blocks = getattr(world, "setup_blocks", None)
+    if name in STARTS_AT_GENESIS:
+        assert setup_blocks is None
+    m = len(setup_blocks or ())
+    assert [common_pb2.Block.FromString(b).header.number for b in setup_blocks or ()] \
+        == list(range(1, m + 1))
+    assert [common_pb2.Block.FromString(b).header.number for b in world.blocks] == [m + 1, m + 2]
     assert [len(p) for p in world.planted] == \
         [len(common_pb2.Block.FromString(b).data.data) for b in world.blocks]
     assert world.namespaces and all(isinstance(ns, str) for ns in world.namespaces)
@@ -139,11 +152,22 @@ def test_every_configuration_names_a_world_and_a_reference_that_keep_the_contrac
         assert definitions is None
     state = world.expected_state()
     assert state and all(ns in world.namespaces for ns, _key in state)
-    assert all(1 <= blk <= n and isinstance(value, bytes)
+    assert all(1 <= blk <= m + n and isinstance(value, bytes)
                for value, (blk, _tx) in state.values())
     # the reference gives flags and a state after every block, from
-    # `public`, the deployment's numbers and the blocks alone
-    flags, states = man.reference(held)(world.public, held["deployment"], world.blocks)
+    # `public`, the deployment's numbers and the blocks alone; of a
+    # populated world the state the set-up blocks left and, a block, the
+    # rows that changed
+    if setup_blocks:
+        flags, base, changes = man.reference(held)(
+            world.public, held["deployment"], world.blocks, setup_blocks)
+        assert base and all(1 <= blk <= m for _value, (blk, _tx) in base.values())
+        states = []
+        for changed in changes:
+            base = {row: v for row, v in {**base, **changed}.items() if v is not None}
+            states.append(base)
+    else:
+        flags, states = man.reference(held)(world.public, held["deployment"], world.blocks)
     assert [list(f) for f in flags] == [list(p) for p in world.planted]
     assert len(states) == n and states[-1] == state and states[0] != states[1]
 
@@ -246,14 +270,16 @@ def test_a_reference_runs_with_none_of_the_program_loaded(man, name, file, tmp_p
                             held["planted"], 2)
     fed = os.path.join(str(tmp_path), "fed.pickle")
     with open(fed, "wb") as f:
-        pickle.dump((held, world.public, world.blocks), f)
+        pickle.dump((held, world.public, world.blocks,
+                     getattr(world, "setup_blocks", None)), f)
     code = (
         "import json, os, pickle, sys\n"
         f"root = {ROOT!r}\n"
         "sys.path[:0] = [os.path.join(root, 'benchmarks'), root]\n"
         "from benchlib.manifest import Manifest\n"
-        f"held, public, blocks = pickle.load(open({fed!r}, 'rb'))\n"
-        "flags, states = Manifest(root).reference(held)(public, held['deployment'], blocks)\n"
+        f"held, public, blocks, setup_blocks = pickle.load(open({fed!r}, 'rb'))\n"
+        "more = (setup_blocks,) if setup_blocks else ()\n"
+        "flags = Manifest(root).reference(held)(public, held['deployment'], blocks, *more)[0]\n"
         "ours = sorted(m for m in sys.modules if m.split('.')[0] in ('fabric_tpu', 'jax', 'jaxlib')\n"
         "              and m != 'fabric_tpu' and not m.startswith('fabric_tpu.protos'))\n"
         "print(json.dumps([[list(f) for f in flags], ours]))\n"
