@@ -1005,6 +1005,31 @@ class LedgerMetrics:
                  "per channel.",
             statsd_format="%{channel}",
         ))
+        self.mvcc_invalidated = provider.new_counter(CounterOpts(
+            namespace="ledger",
+            name="mvcc_invalidated_total",
+            help="Transactions that came to MVCC valid and were "
+                 "invalidated there, by reason: read = a read key's "
+                 "committed version differs, phantom = a range query's "
+                 "result does.",
+            statsd_format="%{channel}.%{reason}",
+        ))
+        self.preload_rows = provider.new_counter(CounterOpts(
+            namespace="ledger",
+            name="preload_rows_total",
+            help="Distinct keys a block's one MVCC bulk read asked the "
+                 "state for, by outcome: found = the row is there, "
+                 "missing = the state holds no such row.",
+            statsd_format="%{channel}.%{outcome}",
+        ))
+        self.kv_txn_rows = provider.new_counter(CounterOpts(
+            namespace="ledger",
+            name="kv_txn_rows_total",
+            help="Rows (puts and deletes of index, state, history and "
+                 "private data together) written by the commit groups' "
+                 "KV transactions, per channel.",
+            statsd_format="%{channel}",
+        ))
 
 
 class LockMetrics:

@@ -447,10 +447,16 @@ class KVLedger:
         # committer thread attached the CommitAssist context; the
         # stage-boundary fault points stay INSIDE each span so injected
         # trips annotate the stage they landed in.
-        with tracing.span("mvcc", cat="stage", block=num):
+        with tracing.span("mvcc", cat="stage", block=num) as msp:
             batch = group.mvcc.validate_and_prepare(
                 num, rwsets, flags, pvt_data,
                 footprints=footprints,
+            )
+            counts = group.mvcc.last_counts
+            msp.annotate(
+                valid_in=counts["valid_in"],
+                read_conflicts=counts["read_conflicts"],
+                phantom_conflicts=counts["phantom_conflicts"],
             )
             protoutil.set_tx_filter(block, flags)
             # stage-boundary fault points: an injected crash lands AFTER
@@ -510,6 +516,19 @@ class KVLedger:
             lm.transactions.With("channel", self.ledger_id).add(
                 sum(1 for f in flags if f == 0)  # VALID
             )
+            for reason in ("read", "phantom"):
+                n = counts[reason + "_conflicts"]
+                if n:
+                    lm.mvcc_invalidated.With(
+                        "channel", self.ledger_id, "reason", reason
+                    ).add(n)
+            found = counts["rows_found"]
+            for outcome, n in (("found", found),
+                               ("missing", counts["keys_asked"] - found)):
+                if n:
+                    lm.preload_rows.With(
+                        "channel", self.ledger_id, "outcome", outcome
+                    ).add(n)
         if self.snapshots is not None and self.snapshots.has_pending_request(
             block.header.number
         ):
@@ -547,9 +566,10 @@ class KVLedger:
                     self._blocks.sync_files(group.dirty_files)
                     faultline.point("commit.stage", stage="fsync")
                 t1 = time.perf_counter()
+                rows = group.collector.pending
                 with tracing.span(
                     "kv_txn", cat="stage", block=boundary,
-                    blocks=group.blocks,
+                    blocks=group.blocks, rows=rows,
                 ):
                     group.collector.flush()
                     faultline.point("commit.stage", stage="kv_txn")
@@ -580,6 +600,10 @@ class KVLedger:
                 self._metrics.blocks_per_sync.With(
                     "channel", self.ledger_id
                 ).observe(group.blocks)
+            if self._lmetrics is not None:
+                self._lmetrics.kv_txn_rows.With(
+                    "channel", self.ledger_id
+                ).add(rows)
             # the base store changed under the main view's caches
             self._state.invalidate_caches()
             self._durable_height = self._blocks.height

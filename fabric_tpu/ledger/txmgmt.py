@@ -13,10 +13,12 @@ MVCC, all signature checks already ran as one batch.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+import threading
 import time
 
-from fabric_tpu.common import workpool
+from fabric_tpu.common import tracing, workpool
 from fabric_tpu.common.hashing import sha256 as _sha256
 from fabric_tpu.devtools import faultline
 from fabric_tpu.ledger.kvstore import shard_of_namespace, store_shards
@@ -381,6 +383,53 @@ class _TxUpdates:
 _PARALLEL_MIN_WRITES = 32
 
 
+# what MVCCValidator counts of every block it validates, in this order
+MVCC_COUNTS = ("keys_asked", "rows_found", "valid_in",
+               "read_conflicts", "phantom_conflicts")
+
+
+class MvccTally:
+    """What MVCC asked of the state and what it refused, from process
+    start: the sums of `MVCC_COUNTS` over every block an MVCCValidator
+    validated (`keys_asked`: the distinct (namespace, key) pairs the
+    block's one bulk preload asked the store for; `rows_found`: those
+    that are rows; `valid_in`: the transactions that came in VALID with
+    a read-write set; `read_conflicts` / `phantom_conflicts`: those MVCC
+    invalidated, by kind), and of the last RECENT blocks their number
+    and their five counts, oldest first, so that whoever knows how many
+    blocks a stretch committed (a benchmark's window:
+    benchmarks/conditions/smallbank-shape.py) reads that stretch alone.
+    An operator reads the same on /metrics (ledger_preload_rows_total,
+    ledger_mvcc_invalidated_total)."""
+
+    RECENT = 16384
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._totals = [0] * len(MVCC_COUNTS)
+        self._blocks = 0
+        self._recent: collections.deque = collections.deque(maxlen=self.RECENT)
+
+    def block_done(self, num: int, counts: tuple) -> None:
+        with self._lock:
+            self._blocks += 1
+            self._totals = [a + b for a, b in zip(self._totals, counts)]
+            self._recent.append((num, *counts))
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            return {"blocks": self._blocks, **dict(zip(MVCC_COUNTS, self._totals)),
+                    "recent_blocks": list(self._recent)}
+
+
+_MVCC = MvccTally()
+
+
+def mvcc_tally() -> dict:
+    """See MvccTally."""
+    return _MVCC.snapshot()
+
+
 class MVCCValidator:
     """Block-level MVCC validation building the state update batch
     (reference validation/validator.go:82 validateAndPrepareBatch).
@@ -416,6 +465,9 @@ class MVCCValidator:
         # ledger folds these into commit_stage_seconds/os /metrics as
         # mvcc_preload/mvcc_check/mvcc_prepare
         self.last_stage_seconds: dict[str, float] = {}
+        # the last block's MVCC_COUNTS by name: the ledger puts them on
+        # its `mvcc` span and on /metrics (MvccTally has the sums)
+        self.last_counts: dict[str, int] = {}
         # blocks whose prepare actually fanned out (smoke-test probe)
         self.parallel_prepare_blocks = 0
 
@@ -573,8 +625,14 @@ class MVCCValidator:
                 flags[tx_num] = BAD_RWSET
         t = time.perf_counter
         t0 = t()
-        cache = self._preload(parsed_per_tx)
+        # the one bulk read of the rows the block names: a span of its
+        # own, so that a state past the store's cache shows where it costs
+        with tracing.span("mvcc.preload", cat="stage", block=block_num) as psp:
+            cache = self._preload(parsed_per_tx)
+            found = len(cache) - list(cache.values()).count(None)
+            psp.annotate(keys_asked=len(cache), rows_found=found)
         t1 = t()
+        valid_in = read_conflicts = phantom_conflicts = 0
 
         # -- pass 1: serial conflict checks + version bookkeeping -------
         # updated_versions carries every in-block write's version (None
@@ -614,6 +672,7 @@ class MVCCValidator:
         for tx_num, parsed in enumerate(parsed_per_tx):
             if parsed is None or flags[tx_num] != VALID:
                 continue
+            valid_in += 1
             code = VALID
             for ns, kvrw, colls in parsed:
                 for read in kvrw.reads:
@@ -653,6 +712,10 @@ class MVCCValidator:
                     break
             flags[tx_num] = code
             if code != VALID:
+                if code == PHANTOM_READ_CONFLICT:
+                    phantom_conflicts += 1
+                else:
+                    read_conflicts += 1
                 continue
             h = Height(block_num, tx_num)
             pvt_by_coll = self._parse_pvt(pvt_data.get(tx_num))
@@ -742,6 +805,10 @@ class MVCCValidator:
         self.last_stage_seconds = {
             "preload": t1 - t0, "check": t2 - t1, "prepare": t() - t2,
         }
+        counts = (len(cache), found, valid_in, read_conflicts,
+                  phantom_conflicts)
+        self.last_counts = dict(zip(MVCC_COUNTS, counts))
+        _MVCC.block_done(block_num, counts)
         return batch
 
     def _prepare_groups(self, groups: list, cache: dict) -> list[dict]:
