@@ -570,6 +570,8 @@ class KVLedger:
                 with tracing.span(
                     "kv_txn", cat="stage", block=boundary,
                     blocks=group.blocks, rows=rows,
+                    clustered=self._kv.clustered,
+                    mmap_bytes=self._kv.mmap_bytes,
                 ):
                     group.collector.flush()
                     faultline.point("commit.stage", stage="kv_txn")

@@ -19,6 +19,7 @@ import time
 import zlib
 from typing import Iterator
 
+from fabric_tpu.common.flogging import must_get_logger
 from fabric_tpu.devtools import faultline, knob_registry
 from fabric_tpu.devtools.lockwatch import guarded, named_lock, named_rlock
 
@@ -26,6 +27,11 @@ from fabric_tpu.devtools.lockwatch import guarded, named_lock, named_rlock
 class KVStore:
     """Ordered byte-key store. Iteration is over a half-open [start, end)
     range in lexicographic key order, like leveldb iterators."""
+
+    # what a paged backend found at open (SqliteKVStore); a store
+    # without pages says neither
+    clustered = False
+    mmap_bytes = 0
 
     def get(self, key: bytes) -> bytes | None:
         raise NotImplementedError
@@ -103,6 +109,12 @@ class MemKVStore(KVStore):
 
 _SQLITE_SYNC_LEVELS = ("OFF", "NORMAL", "FULL", "EXTRA")
 
+# what PRAGMA mmap_size is asked for: above any build's
+# SQLITE_MAX_MMAP_SIZE, so what is granted is that build's ceiling
+_MMAP_ASK = 1 << 62
+
+_logger = must_get_logger("ledger.kvstore")
+
 
 def _sqlite_sync_level(override: str | None) -> str:
     """PRAGMA synchronous level: ctor override, else
@@ -152,6 +164,27 @@ class SqliteKVStore(KVStore):
     atomic batch commits (the recovery property blkstorage/kvledger rely
     on, reference blockfile checkpoints + leveldb atomicity).
 
+    The page path (what a row costs in pages, what a page costs to
+    reach; sqlite's 2 MB page cache is left as it is):
+
+    - a fresh file's `kv` is `WITHOUT ROWID`: a row is one entry of the
+      key's own B-tree, not a rowid-table row plus an autoindex entry
+      that repeats the (long, prefixed) key — half the file, half the
+      leaves a write dirties, one descent a read.  A file an older
+      build made keeps the rowid layout it has and keeps working (every
+      statement here runs on either; `clustered` says which was found);
+    - reads go through mapped memory (`PRAGMA mmap_size`, asked at the
+      build's ceiling; `mmap_bytes` is what was granted, 0 = plain
+      `pread`): a miss of the page cache is a memory read, not a system
+      call and a copy.  sqlite never WRITES through the map, so the
+      WAL, `synchronous` and checkpoint paths are what they were.  The
+      one operational consequence: an I/O error on a mapped page ends
+      the process with a signal (SIGBUS) where a `pread` would have
+      returned an error — a process death like any other to the
+      block-file-first recovery;
+    - a transaction's rows reach sqlite in key order, so neighbouring
+      keys land on a leaf while the cache still holds it.
+
     Durability knobs (the chaos crash matrix pins the default's
     safety):
     `synchronous`/`FABRIC_TPU_SQLITE_SYNC` and
@@ -170,9 +203,25 @@ class SqliteKVStore(KVStore):
             f"PRAGMA wal_autocheckpoint={self.wal_autocheckpoint:d}"
         )
         self._conn.execute(
-            "CREATE TABLE IF NOT EXISTS kv (k BLOB PRIMARY KEY, v BLOB NOT NULL)"
+            "CREATE TABLE IF NOT EXISTS kv "
+            "(k BLOB PRIMARY KEY, v BLOB NOT NULL) WITHOUT ROWID"
         )
         self._conn.commit()
+        # observed, not configured: a rowid table carries its key in
+        # sqlite_autoindex_kv_1, a WITHOUT ROWID table has no such index
+        self.clustered = self._conn.execute(
+            "SELECT 1 FROM sqlite_master WHERE name = 'sqlite_autoindex_kv_1'"
+        ).fetchone() is None
+        # the pragma answers with what it granted (no row at all from a
+        # build without mmap); address space, not memory
+        granted = self._conn.execute(
+            f"PRAGMA mmap_size={_MMAP_ASK:d}"
+        ).fetchone()
+        self.mmap_bytes = granted[0] if granted else 0
+        _logger.info(
+            "kvstore %s: clustered=%s mmap_bytes=%d",
+            path, self.clustered, self.mmap_bytes,
+        )
         self._lock = threading.RLock()
 
     def get(self, key: bytes) -> bytes | None:
@@ -204,20 +253,20 @@ class SqliteKVStore(KVStore):
                 self._conn.executemany(
                     "INSERT INTO kv(k, v) VALUES(?, ?) "
                     "ON CONFLICT(k) DO UPDATE SET v = excluded.v",
-                    [(k, v) for k, v in puts.items()],
+                    sorted(puts.items()),
                 )
                 self._conn.executemany(
                     "DELETE FROM kv WHERE k = ?", [(k,) for k in deletes]
                 )
 
     def write_batch_if_absent(self, puts) -> None:
-        # first occurrence wins WITHIN the batch too: sqlite executes
-        # the rows in order and ignores every later conflicting insert
+        # a dict's keys are unique, so key order changes no winner: the
+        # one conflict left is with a row the table already holds
         with self._lock:
             with self._conn:
                 self._conn.executemany(
                     "INSERT OR IGNORE INTO kv(k, v) VALUES(?, ?)",
-                    list(puts.items()),
+                    sorted(puts.items()),
                 )
 
     def iterate(self, start: bytes = b"", end: bytes | None = None):
@@ -594,6 +643,10 @@ class ShardedKVStore(KVStore):
             )
             for i in range(n)
         ]
+        # the layout every file has: one older file makes the answer no
+        files = [self._coord, *self._stores]
+        self.clustered = all(f.clustered for f in files)
+        self.mmap_bytes = min(f.mmap_bytes for f in files)
         # serializes two-phase flushes and guards the epoch counter
         self._lock = named_lock("kvstore.shard_flush")
         # per-phase wall splits of the LAST two-phase flush; kvledger

@@ -3,7 +3,8 @@
 bulk preload asked the state for and how many rows it found (on the
 `mvcc.preload` span, a stage of its own under `mvcc`), how many
 transactions came in valid and how many it invalidated, by kind (on the
-`mvcc` span), `rows` on `kv_txn`, three counters on /metrics and
+`mvcc` span), `rows` (and since PR 48 `clustered` / `mmap_bytes`, what
+the store found at open) on `kv_txn`, three counters on /metrics and
 `mvcc_tally()` from process start.  Hand-made blocks through `KVLedger.commit`: reads that hit,
 reads of absent keys, an in-block conflict, a conflict with the block
 before, a phantom, a transaction that came in refused.  No behaviour
@@ -121,6 +122,9 @@ def test_the_spans_the_counters_and_the_tally_say_what_mvcc_made_of_each_block(t
     kv = {e["args"]["block"]: e["args"] for e in events if e["name"] == "kv_txn"}
     assert [kv[n]["blocks"] for n in (0, 1, 2)] == [1, 1, 1]
     assert kv[0]["rows"] >= 4 and kv[1]["rows"] >= 4 and kv[2]["rows"] >= 1
+    # and, since PR 48, the layout of the store it wrote them to: a
+    # fresh file is clustered and its reads are mapped
+    assert all(kv[n]["clustered"] is True and kv[n]["mmap_bytes"] > 0 for n in kv)
     text = metrics.registry.expose()
     assert f'ledger_mvcc_invalidated_total{{channel="{CHANNEL}",reason="read"}} 2' in text
     assert f'ledger_mvcc_invalidated_total{{channel="{CHANNEL}",reason="phantom"}} 1' in text
