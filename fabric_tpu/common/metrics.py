@@ -687,7 +687,8 @@ class MSPMetrics:
     shows misses and evictions growing together, block after block.
     And the chain signatures behind the misses (msp/msp.py): `batch`
     where a block's creators were decided in one native call, `single`
-    where OpenSSL checked one in place."""
+    where OpenSSL checked one in place; and who read the certificates
+    of a block's creators that missed."""
 
     def __init__(self, provider):
         self.cache_requests = provider.new_counter(CounterOpts(
@@ -717,6 +718,17 @@ class MSPMetrics:
                  "single (one OpenSSL call in place: another curve or "
                  "algorithm, several issuer candidates, an "
                  "intermediate's own hop, an identity validated alone).",
+            statsd_format="%{path}",
+        ))
+        self.creator_parses = provider.new_counter(CounterOpts(
+            namespace="msp",
+            name="creator_parses_total",
+            help="Certificates of block creators the caching MSP's batch "
+                 "door read because its deserialize cache did not hold "
+                 "them, labeled by path: native (one call without the "
+                 "interpreter's lock reads a crowded block's) or python "
+                 "(one at a time: a small block, a certificate the "
+                 "native reader handed back, no native library).",
             statsd_format="%{path}",
         ))
 

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import abc
 import dataclasses
+import functools
 import hashlib
 import typing
 from typing import Sequence
@@ -77,11 +78,10 @@ class Key(abc.ABC):
         raise NotImplementedError
 
 
-def _point_ski(x: int, y: int) -> bytes:
+def _point_ski(x_bytes: bytes, y_bytes: bytes) -> bytes:
     # Reference computes SKI = SHA-256 over the uncompressed marshaled point
     # (bccsp/sw/keys.go ecdsaPublicKey.SKI / elliptic.Marshal).
-    raw = b"\x04" + x.to_bytes(32, "big") + y.to_bytes(32, "big")
-    return hashlib.sha256(raw).digest()
+    return hashlib.sha256(b"\x04" + x_bytes + y_bytes).digest()
 
 
 class ECDSAP256PublicKey(Key):
@@ -97,7 +97,31 @@ class ECDSAP256PublicKey(Key):
         # marshaller consumes these per verify item on the hot path
         self.x_bytes: bytes = self.x.to_bytes(32, "big")
         self.y_bytes: bytes = self.y.to_bytes(32, "big")
-        self._ski = _point_ski(self.x, self.y)
+        self._ski = _point_ski(self.x_bytes, self.y_bytes)
+
+    @classmethod
+    def from_coordinates(cls, x_bytes: bytes, y_bytes: bytes) -> "ECDSAP256PublicKey":
+        """The key of a point the caller has checked to lie on P-256
+        (the native certificate reader has: native/x509.cc), from its
+        32-byte big-endian coordinates.  What the marshal and the key
+        table read (`x_bytes`, `y_bytes`, `ski()`) is there at once;
+        the `cryptography` object is built when `crypto_key`, `der`,
+        `pem` or the sw provider's verify first asks."""
+        self = cls.__new__(cls)
+        self.x_bytes, self.y_bytes = x_bytes, y_bytes
+        self.x = int.from_bytes(x_bytes, "big")
+        self.y = int.from_bytes(y_bytes, "big")
+        self._ski = _point_ski(x_bytes, y_bytes)
+        return self
+
+    @functools.cached_property
+    def _key(self) -> "ec.EllipticCurvePublicKey":
+        # only a key `from_coordinates` made comes here: `__init__`
+        # holds the object it was given
+        _require_crypto()
+        return ec.EllipticCurvePublicNumbers(
+            self.x, self.y, ec.SECP256R1()
+        ).public_key()
 
     def ski(self) -> bytes:
         return self._ski

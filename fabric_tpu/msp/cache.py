@@ -99,6 +99,12 @@ class _LRU:
             ).add()
         return found
 
+    def absent(self, keys) -> list:
+        """Those of `keys` that are not there now.  Counts nothing and
+        moves nothing: a look ahead, not a lookup."""
+        with self._lock:
+            return [k for k in keys if k not in self._d]
+
     def put(self, key, value) -> None:
         dropped = 0
         with self._lock:
@@ -126,24 +132,32 @@ class CachedMSP:
         self._deserialize = _LRU(deserialize_cap, "deserialize")
         self._validate = _LRU(validate_cap, "validate")
         self._principal = _LRU(principal_cap, "principal")
+        # certificates the batch door read because the deserialize
+        # cache did not hold them, by who read them
+        self._creator_parses = {"native": 0, "python": 0}
 
     def __getattr__(self, name):
         return getattr(self._inner, name)
 
     def tally(self) -> dict:
         """Since this facade was built: lookups by cache and outcome
-        (`requests["validate"]["miss"]`) and evictions by cache."""
+        (`requests["validate"]["miss"]`), evictions by cache, and the
+        certificates `deserialize_creators` read by path."""
         caches = (self._deserialize, self._validate, self._principal)
         return {
             "requests": {c._name: dict(c.requests) for c in caches},
             "evictions": {c._name: c.evictions for c in caches},
+            "creator_parses": dict(self._creator_parses),
         }
 
     def deserialize_identity(self, serialized: bytes):
+        return self._deserialized(serialized, self._inner.deserialize_identity)
+
+    def _deserialized(self, serialized: bytes, parse):
         ident, hit = self._deserialize.get(serialized)
         if hit:
             return ident
-        ident = self._inner.deserialize_identity(serialized)
+        ident = parse(serialized)
         if not getattr(ident, "anonymous", False):
             self._deserialize.put(bytes(serialized), ident)
         return ident
@@ -173,14 +187,30 @@ class CachedMSP:
     def deserialize_creators(self, creators) -> tuple[list, int]:
         """A block's distinct creators in one pass: for each what
         `deserialize_creator` gives it, or None where that raises, by
-        the same lookups in the same caches; and how many chain
-        signatures one native call decided on the way.  The
-        X.509 identities that have to be validated afresh have their
-        chain signatures checked together first (`prove_chains` of the
-        MSP or manager behind this facade), so the `validate` of each
-        finds its verdict waiting; where nothing was decided ahead,
-        each `validate` checks its own."""
+        the same lookups in the same caches in the same order; and how
+        many chain signatures one native call decided on the way.
+        Where the block is crowded, the certificates the deserialize
+        cache does not hold are read together first, in one native call
+        (`read_identities` of the MSP or manager behind this facade:
+        `tally()["creator_parses"]` says how many it read and how many
+        went one at a time).  The X.509 identities that have to be
+        validated afresh then have their chain signatures checked
+        together (`prove_chains`), so the `validate` of each finds its
+        verdict waiting; where nothing was decided ahead, each
+        `validate` checks its own."""
         idents: list = [None] * len(creators)
+        read = self._read_ahead(creators)
+
+        def parse(serialized):
+            ident = read.get(serialized)
+            path = "python" if ident is None else "native"
+            self._creator_parses[path] += 1
+            if _metrics is not None:
+                _metrics.creator_parses.With("path", path).add()
+            if ident is None:
+                ident = self._inner.deserialize_identity(serialized)
+            return ident
+
         owing = []
         for i, serialized in enumerate(creators):
             try:
@@ -188,7 +218,7 @@ class CachedMSP:
                 if ident is not None:
                     idents[i] = ident
                     continue
-                ident = self.deserialize_identity(serialized)
+                ident = self._deserialized(serialized, parse)
                 key = ident.serialize()
                 if self._validated(key):
                     idents[i] = ident
@@ -207,6 +237,22 @@ class CachedMSP:
             except Exception:
                 pass
         return idents, decided
+
+    def _read_ahead(self, creators) -> dict:
+        """serialized -> identity for the creators the deserialize cache
+        does not hold now and the native reader qualified (none in a
+        small block: `msp.read_identities`).  A look ahead only: the
+        lookups that count, and every entry, are the pass's own, so a
+        creator the cache holds now and drops before its turn is read
+        then, one at a time."""
+        read = getattr(self._inner, "read_identities", None)
+        if read is None:
+            return {}
+        absent = self._deserialize.absent(creators)
+        return {
+            c: ident for c, ident in zip(absent, read(absent))
+            if ident is not None
+        }
 
     def validate(self, identity) -> None:
         if getattr(identity, "anonymous", False):

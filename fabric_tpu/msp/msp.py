@@ -15,7 +15,12 @@ by subject bytes).  The signature of an identity's certificate under
 its issuer is checked either here, one OpenSSL call through the Python
 wrapper, or ahead of `validate` for many identities in one native call
 that holds no interpreter lock (`prove_chains`): the verdict is the
-same, and `validate` is the one place that accepts or refuses.
+same, and `validate` is the one place that accepts or refuses.  The
+certificates of a crowded block's creators are read the same way, in
+one native call beside that one (`read_identities`): the reader
+qualifies a certificate or hands it back to `deserialize_identity`, and
+what it qualified reaches `validate` as fields in place of a
+`cryptography` object.
 """
 
 from __future__ import annotations
@@ -71,13 +76,16 @@ class _Trusted:
     building reads of it an identity, taken from it once: its key, as
     the OpenSSL object for the single check and as the coordinates the
     native verifier takes (None: not P-256), its validity window, its
-    serial number, and for an intermediate its own way up to a root."""
+    serial number, its DER (an identity that IS this certificate does
+    not chain through it), and for an intermediate its own way up to a
+    root."""
 
-    __slots__ = ("cert", "root", "public_key", "p256", "not_before",
+    __slots__ = ("cert", "der", "root", "public_key", "p256", "not_before",
                  "not_after", "serial", "path")
 
     def __init__(self, cert: x509.Certificate, root: bool):
         self.cert = cert
+        self.der = cert.public_bytes(serialization.Encoding.DER)
         self.root = root
         try:
             self.public_key = cert.public_key()
@@ -157,6 +165,39 @@ def prove_chains(pairs) -> int:
     return len(owing)
 
 
+def read_identities(msps: dict, serialized) -> list:
+    """For each serialized identity of a crowded block (at least
+    `_NATIVE_BATCH_MIN` of them, as for the chain batch) the `Identity`
+    of its MSP in `msps` (MSP id -> MSP), built from the fields ONE
+    `native.x509_read` call read of them all without the interpreter's
+    lock; or None: the reader handed the certificate back (another
+    curve, algorithm or encoding: native/x509.cc has the list), its MSP
+    is not an X.509 one of these, the block is small, or the native
+    library or libcrypto is missing.  The caller takes a None through
+    `deserialize_identity`, which accepts or refuses it as ever.
+    Nothing is decided here: a qualified certificate is one that door
+    would have read to the same fields."""
+    idents: list = [None] * len(serialized)
+    if len(serialized) < _NATIVE_BATCH_MIN:
+        return idents
+    from fabric_tpu import native
+
+    try:
+        read = native.x509_read(serialized, wrapped=True)
+    except Exception:
+        read = None  # nothing read: each goes through its own door
+    for i, fields in enumerate(read or ()):
+        if isinstance(fields, int):
+            continue  # handed back: the status says by which rule
+        mspid = fields.mspid.decode()  # printable ASCII, by the reader's rule
+        msp = msps.get(mspid)
+        if isinstance(msp, MSP):
+            idents[i] = Identity.from_fields(
+                mspid, fields, bytes(serialized[i]), msp.csp
+            )
+    return idents
+
+
 class MSP:
     """One organization's membership rules (an X.509 trust domain)."""
 
@@ -233,6 +274,9 @@ class MSP:
         cert = _load_pem_cert(sid.id_bytes)
         return Identity(self.mspid, cert, self.csp)
 
+    def read_identities(self, serialized) -> list:
+        return read_identities({self.mspid: self}, serialized)
+
     def get_default_signing_identity(self) -> SigningIdentity:
         if self.signer is None:
             raise MSPError(f"MSP {self.mspid} has no signing identity")
@@ -265,19 +309,18 @@ class MSP:
         ..., root]; raises if no trusted path.  The issuer is found by
         the raw issuer bytes, roots before intermediates, as the
         reference compares RawIssuer with RawSubject."""
-        cert = identity.cert
         ahead = identity.chain_verdict
         if ahead is None:
-            issuer = cert.issuer.public_bytes()
+            issuer = identity.issuer_bytes
         else:
             issuer, identity.chain_verdict = ahead[0], None
         for ca in self._issuers.get(issuer, ()):
-            if not ca.root and ca.cert == cert:
+            if not ca.root and ca.der == identity.der:
                 continue
             if ahead is not None and ahead[1] is ca:
                 signed = ahead[2]
             else:
-                signed = _signed_by(ca, cert)
+                signed = _signed_by(ca, identity.cert)
             if not signed:
                 continue
             if ca.root:
@@ -299,18 +342,20 @@ class MSP:
         verifier holds every signature to Fabric's low-S rule for
         transactions, so it is handed (r, min(s, n - s)), which
         verifies exactly when (r, s) does."""
-        cert = identity.cert
-        issuer = cert.issuer.public_bytes()
+        issuer = identity.issuer_bytes
         candidates = self._issuers.get(issuer, ())
         if len(candidates) != 1:
             return None
         ca = candidates[0]
-        if (
-            ca.p256 is None
-            or cert.signature_algorithm_oid
-            != SignatureAlgorithmOID.ECDSA_WITH_SHA256
-            or (not ca.root and ca.cert == cert)
-        ):
+        if ca.p256 is None or (not ca.root and ca.der == identity.der):
+            return None
+        laid_out = identity.chain_signature
+        if laid_out is not None:
+            # the native reader qualified this certificate by the rules
+            # below and laid (digest, (r, min(s, n - s))) out
+            return issuer, ca, VerifyBatchItem(ca.p256, *laid_out)
+        cert = identity.cert
+        if cert.signature_algorithm_oid != SignatureAlgorithmOID.ECDSA_WITH_SHA256:
             return None
         sig = cert.signature
         try:
@@ -332,9 +377,8 @@ class MSP:
         """Raises MSPError when invalid: untrusted chain, expired, revoked,
         or (with NodeOUs) not classifiable into exactly one role."""
         chain = self._chain(identity)
-        cert = identity.cert
         now = datetime.datetime.now(datetime.timezone.utc)
-        if now < cert.not_valid_before_utc or now > cert.not_valid_after_utc:
+        if now < identity.not_before or now > identity.not_after:
             raise MSPError("certificate outside its validity period")
         for ca in chain:
             if now < ca.not_before or now > ca.not_after:
@@ -342,7 +386,7 @@ class MSP:
         # CRL check: any cert of the chain revoked by a CRL signed by its
         # issuer invalidates the identity (reference validateCertAgainstChain)
         if self.crls:
-            serials = [cert.serial_number] + [ca.serial for ca in chain[:-1]]
+            serials = [identity.serial] + [ca.serial for ca in chain[:-1]]
             for crl in self.crls:
                 for serial in serials:
                     if crl.get_revoked_certificate_by_serial_number(serial) is not None:
@@ -367,8 +411,7 @@ class MSP:
         return next(iter(roles)) if len(roles) == 1 else None
 
     def _is_admin(self, identity: Identity) -> bool:
-        der = identity.cert.public_bytes(serialization.Encoding.DER)
-        if der in self.admins:
+        if identity.der in self.admins:
             return True
         return self.node_ous_enabled and self._role_of(identity) == "admin"
 
@@ -468,6 +511,12 @@ class MSPManager:
     def deserialize_identity(self, serialized: bytes) -> Identity:
         sid = identities_pb2.SerializedIdentity.FromString(serialized)
         return self.get_msp(sid.mspid).deserialize_identity(serialized)
+
+    def read_identities(self, serialized) -> list:
+        """A crowded block's identities of any of the channel's X.509
+        MSPs, their certificates read in one native call; None for
+        each that goes through `deserialize_identity` as ever."""
+        return read_identities(self._msps, serialized)
 
     def deserialize_deferred(self, serialized: bytes):
         """An identity of an MSP that can leave its expensive proof

@@ -14,13 +14,14 @@ import ctypes
 import os
 import subprocess
 import threading
+import typing
 
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRCS = [os.path.join(_DIR, "marshal.cc"), os.path.join(_DIR, "collect.cc"),
          os.path.join(_DIR, "bn254.cc"), os.path.join(_DIR, "pairing.cc"),
-         os.path.join(_DIR, "ecverify.cc")]
+         os.path.join(_DIR, "ecverify.cc"), os.path.join(_DIR, "x509.cc")]
 _LIB = os.path.join(_DIR, "libfabricmarshal.so")
 
 _lock = threading.Lock()
@@ -99,6 +100,14 @@ def _load():
                 ctypes.c_int, ctypes.c_char_p, ctypes.c_char_p,
                 ctypes.c_char_p, i32p, i32p, u8p,
             ]
+            xr = lib.fabric_x509_read
+            xr.restype = ctypes.c_int
+            xr.argtypes = [ctypes.c_int, ctypes.c_char_p, i64p, ctypes.c_int,
+                           i64p] + [u8p] * 5
+            lib.fabric_x509_meta_cols.restype = ctypes.c_int
+            lib.fabric_x509_meta_cols.argtypes = []
+            if lib.fabric_x509_meta_cols() != _X509_COLS:
+                raise RuntimeError("x509.cc and x509_read disagree on a row")
             _lib = lib
         except Exception as exc:
             _lib = None
@@ -212,6 +221,84 @@ def ecdsa_verify_host(items) -> list[bool] | None:
         if len(it.digest) != 32:
             mask[i] = False
     return mask.tolist()
+
+
+# a row of fabric_x509_read's `meta` (x509.cc's Col): the status, six
+# (offset, length) pairs, two times, two counts, then the OU pairs
+_X509_OUS = 8
+_X509_COLS = 17 + 2 * _X509_OUS
+
+
+class X509Fields(typing.NamedTuple):
+    """What `x509_read` read of one certificate it qualified."""
+
+    mspid: bytes            # b"" for a bare PEM
+    pem: bytes              # canonical: what public_bytes(PEM) gives
+    der: bytes
+    issuer: bytes           # the raw issuer Name
+    subject: bytes          # the raw subject Name
+    serial: bytes           # the serial number's INTEGER contents
+    not_before: int         # seconds since 1970
+    not_after: int
+    x_bytes: bytes          # the P-256 key, 32 bytes big-endian each
+    y_bytes: bytes
+    tbs_digest: bytes       # SHA-256 of the TBS bytes
+    r_bytes: bytes          # the signature as it stands
+    s_bytes: bytes
+    low_s_signature: bytes  # DER of (r, min(s, n - s))
+    ous: tuple              # the subject's OU values, undecoded (UTF-8)
+
+
+def x509_read(items, wrapped: bool = False) -> list | None:
+    """The certificates of many identities in one native call that
+    holds no interpreter lock (x509.cc).  `items` are PEM certificates,
+    or with `wrapped` SerializedIdentity messages around them.  For
+    each, an `X509Fields` where the reader qualified it, or its status
+    (an int above 0: x509.cc's Status says which rule handed it back)
+    where it did not, and the caller reads that one as ever.  None
+    where the native library is unavailable or its verifier has no
+    libcrypto: every certificate is then read as ever."""
+    lib = _load()
+    if lib is None:
+        return None
+    n = len(items)
+    if n == 0:
+        return []
+    off = np.zeros(n + 1, np.int64)
+    np.cumsum([len(it) for it in items], out=off[1:])
+    buf = b"".join(items)
+    meta = np.empty((n, _X509_COLS), np.int64)
+    der = np.empty(max(len(buf), 1), np.uint8)
+    digest = np.empty(32 * n, np.uint8)
+    xy = np.empty(64 * n, np.uint8)
+    rs = np.empty(64 * n, np.uint8)
+    lowsig = np.empty(72 * n, np.uint8)
+    if lib.fabric_x509_read(n, buf, off, 1 if wrapped else 0, meta, der,
+                            digest, xy, rs, lowsig) != 0:
+        return None
+    der_b, digest_b = der.tobytes(), digest.tobytes()
+    xy_b, rs_b, lowsig_b = xy.tobytes(), rs.tobytes(), lowsig.tobytes()
+    out = []
+    for i, row in enumerate(meta.tolist()):
+        if row[0]:
+            out.append(row[0])
+            continue
+        (_, mspid_at, mspid_n, pem_at, pem_n, der_at, der_n, iss_at, iss_n,
+         sub_at, sub_n, ser_at, ser_n, not_before, not_after, sig_n,
+         n_ous) = row[:17]
+        out.append(X509Fields(
+            buf[mspid_at:mspid_at + mspid_n], buf[pem_at:pem_at + pem_n],
+            der_b[der_at:der_at + der_n], der_b[iss_at:iss_at + iss_n],
+            der_b[sub_at:sub_at + sub_n], der_b[ser_at:ser_at + ser_n],
+            not_before, not_after,
+            xy_b[64 * i:64 * i + 32], xy_b[64 * i + 32:64 * i + 64],
+            digest_b[32 * i:32 * i + 32],
+            rs_b[64 * i:64 * i + 32], rs_b[64 * i + 32:64 * i + 64],
+            lowsig_b[72 * i:72 * i + sig_n],
+            tuple(der_b[row[17 + 2 * k]:row[17 + 2 * k] + row[18 + 2 * k]]
+                  for k in range(n_ous)),
+        ))
+    return out
 
 
 def collect_block(env_bytes: bytes, env_off: np.ndarray,
@@ -393,5 +480,5 @@ def bn254_pairing_check(pairs) -> bool:
 __all__ = [
     "available", "load_error", "marshal_batch", "collect_block", "bn254_msm",
     "bn254_msm_sets", "bn254_msm_bucket_threshold", "bn254_mul_many",
-    "bn254_pairing_check", "ecdsa_verify_host",
+    "bn254_pairing_check", "ecdsa_verify_host", "x509_read", "X509Fields",
 ]

@@ -329,6 +329,10 @@ enum {
 
 extern "C" {
 
+// This file's SHA-256 (libcrypto's where it loads) for the library's
+// other translation units: x509.cc hashes a certificate's TBS bytes.
+void fabric_sha256(const u8* p, size_t n, u8* out) { sha256(p, n, out); }
+
 // Per-tx arrays sized n; endorsement arrays sized max_endos.  All
 // offsets are relative to `envs`.  Returns the total endorsement count,
 // or -1 when max_endos was exceeded (caller re-runs with more room).

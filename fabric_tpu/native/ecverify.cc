@@ -160,6 +160,10 @@ bool parse_der(const u8* sig, int n, u8* r32, u8* s32) {
 
 extern "C" {
 
+// 1 where fabric_ecdsa_verify_host can verify (libcrypto loaded): what
+// x509.cc asks before it reads certificates for that call's sake.
+int fabric_ecdsa_host_ok() { return ossl().ok ? 1 : 0; }
+
 // Verify n (key, digest, DER signature) triples on the host.
 // qxy: n*64 bytes (32-byte big-endian x || y per lane);
 // digests: n*32; sigs + sig_off/sig_len: concatenated DER signatures.

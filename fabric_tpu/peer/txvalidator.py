@@ -538,15 +538,18 @@ class _CreatorMemo(dict):
     afresh (the memo's misses: every creator once, and in faithful
     mode every transaction), `seconds` their wall, and `chain_batch`
     those whose chain signature the block's one native call decided
-    (`_creators_ahead`; 0 where each was checked in place)."""
+    (`_creators_ahead`; 0 where each was checked in place), and
+    `native_parse` those whose certificate the native reader read in
+    the call beside it (0 where each was parsed in place)."""
 
-    __slots__ = ("validations", "seconds", "chain_batch")
+    __slots__ = ("validations", "seconds", "chain_batch", "native_parse")
 
     def __init__(self):
         super().__init__()
         self.validations = 0
         self.seconds = 0.0
         self.chain_batch = 0
+        self.native_parse = 0
 
 
 class TxValidator:
@@ -774,21 +777,25 @@ class TxValidator:
         """Fill the block's memo with its distinct creators (first-seen
         order) in ONE call on the channel's MSPs: each deserialised and
         validated as `_creator_identity` would, one identity at a time,
-        but with all their chain signatures checked in one native call
-        that holds no interpreter lock (the committer's thread gets it
-        meanwhile).  An anonymous (Idemix) creator goes through its own
-        door inside that call, by its MSP.  Which transaction is
-        refused for which reason stays the per-tx loop's: this only
-        decides who a creator is."""
-        batch = getattr(self._bundle.msp_manager, "deserialize_creators", None)
+        but with the certificates the MSP caches do not hold read in
+        one native call and all their chain signatures checked in
+        another, neither holding the interpreter's lock (the
+        committer's thread gets it meanwhile).  An anonymous (Idemix)
+        creator goes through its own door inside that call, by its MSP.
+        Which transaction is refused for which reason stays the per-tx
+        loop's: this only decides who a creator is."""
+        mgr = self._bundle.msp_manager
+        batch = getattr(mgr, "deserialize_creators", None)
         if batch is None:
             return
         t0 = time.perf_counter()
         distinct = list(dict.fromkeys(creators))
+        read0 = mgr.tally()["creator_parses"]["native"]
         idents, decided = batch(distinct)
         memo.update(zip(distinct, idents))
         memo.validations += len(distinct)
         memo.chain_batch += decided
+        memo.native_parse += mgr.tally()["creator_parses"]["native"] - read0
         memo.seconds += time.perf_counter() - t0
 
     def _collect_tx(self, env_bytes: bytes, seen_txids: set, sink: _ItemSink, work: _TxWork, memo: dict) -> int:
@@ -1240,6 +1247,7 @@ class TxValidator:
                     creator_validations=memo.validations,
                     creator_ms=memo.seconds * 1e3,
                     creator_chain_batch=memo.chain_batch,
+                    creator_native_parse=memo.native_parse,
                     early_lanes=sink.early_lanes,
                     keylevel_reads=count.reads,
                     keylevel_ms=count.seconds * 1e3,
@@ -1390,10 +1398,11 @@ class TxValidator:
         # applied at the exact point _prepare_namespaces would have
         # produced it.  (Creator identities are not prefetched by the
         # pool: they were validated above, as one batch on this thread,
-        # whose chain signatures run in one native call without the
-        # interpreter's lock.  The `creators` stage clock and
-        # `collect{creator_ms, creator_chain_batch}` say what a block's
-        # identities cost and how many signatures that call decided.)
+        # whose certificates and chain signatures go through two native
+        # calls without the interpreter's lock.  The `creators` stage
+        # clock and `collect{creator_ms, creator_chain_batch,
+        # creator_native_parse}` say what a block's identities cost and
+        # how many of them those calls took.)
         prefetched: list | None = None
         width = self._collect_fanout(len(data), native=True)
         if width or ahead:

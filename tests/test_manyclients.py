@@ -567,3 +567,124 @@ def test_a_native_batch_of_any_size_gives_each_lane_its_own_verdict(lanes):
         pytest.skip(f"no native verifier: {native.load_error()}")
     assert got == [sw.verify(it.key, it.signature, it.digest) for it in items]
     assert got.count(False) == len([i for i in range(lanes) if i % 7 == 3])
+
+
+# -- a block's strangers read in one native call -----------------------------
+
+
+def _needs_native_reader():
+    from fabric_tpu import native
+
+    if native.x509_read([]) is None or native.ecdsa_verify_host([]) is None:
+        pytest.skip(f"no native reader: {native.load_error()}")
+
+
+@pytest.mark.parametrize("python_collect", [False, True], ids=["native", "python"])
+def test_the_collect_span_says_how_many_certificates_the_native_reader_read(
+        crowd, serial_flags, python_collect):
+    """The native-walker collect hands the block's distinct creators to
+    the MSPs as one batch, whose strangers' certificates one native call
+    reads: every creator of this world qualifies, the planted ones too
+    (their fault is not their shape).  The Python collector learns a
+    creator a transaction and parses each in place."""
+    _needs_native_reader()
+    world = crowd[3]
+    v = _validator(world, SWCSP(), python_collect=python_collect)
+    with tracing.scope() as rec:
+        got = list(v.validate(_block(world)))
+        events = tracing.export(rec)["traceEvents"]
+    assert got == serial_flags
+    (collect,) = [e for e in events if e.get("name") == "collect"]
+    distinct = world.creators_per_block[0]
+    assert collect["args"]["creator_validations"] == distinct
+    parses = v._bundle.msp_manager.tally()["creator_parses"]
+    if python_collect:
+        assert collect["args"]["creator_native_parse"] == 0
+        assert parses == {"native": 0, "python": 0}      # the batch door was not used
+    else:
+        assert collect["args"]["creator_native_parse"] == distinct
+        assert parses == {"native": distinct, "python": 0}
+        assert collect["args"]["creator_chain_batch"] == distinct
+
+
+def test_a_block_of_few_creators_reads_each_certificate_in_place(monkeypatch):
+    """Under `_NATIVE_BATCH_MIN` strangers the batch door's path is the
+    one it had: the native reader is not called."""
+    from fabric_tpu import native
+    from fabric_tpu.msp import msp as msp_mod
+
+    if not native.available():
+        pytest.skip(f"no native collector: {native.load_error()}")
+    calls = []
+    monkeypatch.setattr(native, "x509_read", lambda items, **kw: calls.append(len(items)))
+    man, held, dep, world = _build(NO_FAULTS, block_txs=8)
+    assert world.creators_per_block[0] < msp_mod._NATIVE_BATCH_MIN
+    v = _validator(world, SWCSP())
+    with tracing.scope() as rec:
+        assert list(v.validate(_block(world))) == [VALID] * 8
+        events = tracing.export(rec)["traceEvents"]
+    (collect,) = [e for e in events if e.get("name") == "collect"]
+    assert calls == []
+    assert collect["args"]["creator_native_parse"] == 0
+    assert collect["args"]["creator_validations"] == world.creators_per_block[0]
+    assert v._bundle.msp_manager.tally()["creator_parses"] == {
+        "native": 0, "python": world.creators_per_block[0]}
+
+
+def test_without_the_native_reader_every_certificate_is_parsed_in_place(
+        crowd, serial_flags, monkeypatch):
+    from fabric_tpu import native
+
+    if not native.available():
+        pytest.skip(f"no native collector: {native.load_error()}")
+    monkeypatch.setattr(native, "x509_read", lambda items, **kw: None)
+    world = crowd[3]
+    v = _validator(world, SWCSP())
+    with tracing.scope() as rec:
+        got = list(v.validate(_block(world)))
+        events = tracing.export(rec)["traceEvents"]
+    assert got == serial_flags
+    (collect,) = [e for e in events if e.get("name") == "collect"]
+    distinct = world.creators_per_block[0]
+    assert collect["args"]["creator_native_parse"] == 0
+    assert collect["args"]["creator_validations"] == distinct
+    assert v._bundle.msp_manager.tally()["creator_parses"] == {"native": 0, "python": distinct}
+
+
+def test_the_creator_parses_count_on_the_metrics_page_by_path(crowd):
+    from fabric_tpu.common.metrics import MSPMetrics, PrometheusProvider
+    from fabric_tpu.msp import cache as msp_cache
+
+    _needs_native_reader()
+    world = crowd[3]
+    distinct = world.creators_per_block[0]
+    prov = PrometheusProvider()
+    msp_cache.set_metrics(MSPMetrics(prov))
+    try:
+        _validator(world, SWCSP()).validate(_block(world))
+        text = prov.registry.expose()
+        assert f'msp_creator_parses_total{{path="native"}} {distinct}' in text
+        assert 'msp_creator_parses_total{path="python"}' not in text
+        # a small block's creators are read one at a time
+        man, held, dep, small = _build(NO_FAULTS, block_txs=8)
+        _validator(small, SWCSP()).validate(_block(small))
+        text = prov.registry.expose()
+    finally:
+        msp_cache.set_metrics(None)
+    assert f'msp_creator_parses_total{{path="native"}} {distinct}' in text
+    assert (f'msp_creator_parses_total{{path="python"}} '
+            f'{small.creators_per_block[0]}') in text
+
+
+def test_disarmed_the_native_readers_site_consults_nothing(crowd, serial_flags):
+    """Off, the span argument's site costs what its neighbours cost: the
+    armed path's counter stays where it was through a crowded block whose
+    strangers the native reader read."""
+    _needs_native_reader()
+    world = crowd[3]
+    assert not tracing.enabled()
+    before = tracing.lookup_count()
+    v = _validator(world, SWCSP())
+    assert list(v.validate(_block(world))) == serial_flags
+    assert v._bundle.msp_manager.tally()["creator_parses"]["native"] == world.creators_per_block[0]
+    assert tracing.lookup_count() == before
