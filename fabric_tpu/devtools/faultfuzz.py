@@ -21,12 +21,13 @@ The canned workload per plan (all phases run UNDER the armed plan, in a
 fresh working directory):
 
 1. **commit stream** — 6 single-block commits + a 2-block commit group,
-   through every ``commit.stage``/``kvstore.txn``/``store.shard_flush``/
-   ``blkstorage.*`` point; a FaultCrash closes the provider and REOPENS
-   it with the plan still armed, so recovery itself is fuzzed (this is
-   where a ``skip`` on ``store.shard_recover`` — the sharded statedb's
-   roll-forward of a committed-but-unapplied flush — turns into
-   detectable corruption);
+   on the store every peer runs (one clustered sqlite file), through
+   every ``commit.stage``/``kvstore.txn``/``blkstorage.*`` point; a
+   FaultCrash closes the provider and REOPENS it with the plan still
+   armed, so recovery itself is fuzzed (this is where a ``skip`` on
+   ``ledger.recovery_replay`` — the replay of the blocks the block
+   file holds past the state savepoint — turns into detectable
+   corruption);
 2. **snapshot export + import** — ``SnapshotManager.generate`` through
    the ``snapshot.export.stage``/``snapshot.manifest`` points, then
    ``create_from_snapshot`` into a second provider through the
@@ -47,7 +48,6 @@ line, nonzero exit on any oracle failure, repro artifacts under
 
 from __future__ import annotations
 
-import contextlib
 import copy
 import json
 import os
@@ -58,33 +58,6 @@ from fabric_tpu.devtools import faultline, invariants
 CHANNEL = "fuzz"
 NS = "cc"
 DEFAULT_BLOCKS = 6  # single-block commits; +2 grouped ride on top
-
-# The canned workload runs on the storage-v2 engine: a 2-way sharded
-# statedb (so the two-phase group flush and its recovery seams are
-# inside the fuzzed surface) with the flush fan-out pinned SERIAL —
-# parallel shard prepare/apply would race the nth-counters of ctx-less
-# rules and break the byte-identical trip-ledger acceptance.  Reopens
-# ignore the env (the persisted shard count wins), so only creation
-# needs the pin.
-STORE_SHARDS = 2
-_STORE_ENV = {
-    "FABRIC_TPU_STORE_SHARDS": str(STORE_SHARDS),
-    "FABRIC_TPU_STORE_POOL": "0",
-}
-
-
-@contextlib.contextmanager
-def _store_env():
-    saved = {k: os.environ.get(k) for k in _STORE_ENV}
-    os.environ.update(_STORE_ENV)
-    try:
-        yield
-    finally:
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
 
 _RAISE_ERRORS = ["FaultInjected", "OSError", "ECONNRESET", "TimeoutError"]
 
@@ -443,7 +416,7 @@ def run_plan(plan: dict, workdir: str, blocks: int = DEFAULT_BLOCKS,
         tracing.reset()
     if profile.enabled():
         profile.reset()
-    with faultline.use_plan(parsed), _store_env():
+    with faultline.use_plan(parsed):
         stats = _drive(workdir, blocks, comm=comm)
         trips = _canonical_trips(faultline.trips(), parsed.label)
     trace = tracing.export() if tracing.enabled() else None
@@ -742,7 +715,7 @@ class Campaign:
         """Run the workload once under the observer plan to enumerate
         the live fault-point registry this campaign samples from."""
         faultline.reset_registry()
-        with faultline.observe(), _store_env():
+        with faultline.observe():
             _drive(os.path.join(root, "discover"), self.blocks,
                    comm=self.comm)
         return faultline.registry()

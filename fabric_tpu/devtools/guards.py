@@ -54,12 +54,6 @@ DECLARED_GUARDS: dict[str, str] = {
         "kvledger.commit_lock",
     "fabric_tpu.ledger.kvledger.KVLedger._durable_hash":
         "kvledger.commit_lock",
-    # -- sharded statedb (PR 17 storage engine v2) -------------------------
-    # the two-phase flush epoch only advances under the flush lock; a
-    # concurrent flush reading it lock-free could stage two batches
-    # under the same epoch and make recovery ambiguous
-    "fabric_tpu.ledger.kvstore.ShardedKVStore._epoch":
-        "kvstore.shard_flush",
     # -- snapshot manager (PR 1/2) -----------------------------------------
     "fabric_tpu.ledger.snapshot.SnapshotManager._pending":
         "snapshot.manager",

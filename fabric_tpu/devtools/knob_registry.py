@@ -83,26 +83,17 @@ KNOBS: dict[str, Knob] = {
            "arm `faultline.soak_plan(seed)` (ignored when "
            "FABRIC_TPU_FAULTLINE is set; falsy disables)"),
         _k("FABRIC_TPU_SQLITE_SYNC", "enum", "NORMAL", "ledger.kvstore",
-           "`PRAGMA synchronous` for the index store (and every "
-           "statedb shard)",
+           "`PRAGMA synchronous` for the index store",
            choices=("OFF", "NORMAL", "FULL", "EXTRA")),
-        _k("FABRIC_TPU_STORE_POOL", "width", "auto", "ledger.kvstore",
-           "per-shard prepare/apply fan-out width (0 = serial; never "
-           "changes results)"),
         _k("FABRIC_TPU_STORE_SEGMENT", "size", "16m", "ledger.blkstorage",
            "block segment preallocation size, `k`/`m` suffixes "
            "(floor 4096)"),
-        _k("FABRIC_TPU_STORE_SHARDS", "int", "1", "ledger.kvstore",
-           "statedb shard files (persisted count wins on reopen)"),
         _k("FABRIC_TPU_THREADWATCH", "flag", "", "devtools.lockwatch",
            "register spawned workers in the threadwatch live "
            "registry and violation ledger"),
         _k("FABRIC_TPU_TRACE", "flag", "", "common.tracing",
            "arm tracelens: `1` = default 8192-event ring, an integer "
            "= ring capacity"),
-        _k("FABRIC_TPU_WAL_CHECKPOINT", "int", "1000", "ledger.kvstore",
-           "`PRAGMA wal_autocheckpoint` pages (0 disables "
-           "auto-checkpoints)"),
     )
 }
 

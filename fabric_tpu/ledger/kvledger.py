@@ -249,6 +249,13 @@ class KVLedger:
         first = 0 if sp is None else sp.block_num + 1
         if first >= height:
             return
+        # guard-style fault point: a "skip" rule leaves the ledger at
+        # the block store's height without the state of the blocks past
+        # the savepoint, which the invariants oracle must catch
+        if not faultline.guard(
+            "ledger.recovery_replay", first=first, height=height,
+        ):
+            return
         group_size = self._recovery_group_size()
         collector = WriteBatchCollector(self._kv)
         state = self._state.rebased(collector)
@@ -589,15 +596,6 @@ class KVLedger:
                 raise
             t2 = time.perf_counter()
             self._observe_stages(fsync=t1 - t0, kv_txn=t2 - t1)
-            # sharded-store engine: fold the two-phase flush's per-phase
-            # and per-shard wall splits into the same accounting the
-            # bench sweeps read (kv_txn already covers their sum; the
-            # splits say WHERE inside the txn the time went)
-            sub = getattr(self._kv, "last_stage_seconds", None)
-            if sub:
-                self._observe_stages(
-                    **{f"kv_{k}": v for k, v in sub.items()}
-                )
             if self._metrics is not None:
                 self._metrics.blocks_per_sync.With(
                     "channel", self.ledger_id
@@ -881,9 +879,6 @@ class LedgerProvider:
         self._snapshots_dir = snapshots_dir
         if root_dir is not None:
             os.makedirs(root_dir, exist_ok=True)
-        # single sqlite file by default; FABRIC_TPU_STORE_SHARDS > 1 (or
-        # an existing sharded layout on disk) mounts the namespace-
-        # sharded two-phase-flush store behind the same KVStore SPI
         self._kv = open_store_root(root_dir)
         self._ledgers: dict[str, KVLedger] = {}
 

@@ -21,7 +21,6 @@ import time
 from fabric_tpu.common import tracing, workpool
 from fabric_tpu.common.hashing import sha256 as _sha256
 from fabric_tpu.devtools import faultline
-from fabric_tpu.ledger.kvstore import shard_of_namespace, store_shards
 from fabric_tpu.ledger.statedb import Height, VersionedDB, VersionedValue
 from fabric_tpu.protos.ledger.rwset import rwset_pb2
 from fabric_tpu.protos.ledger.rwset.kvrwset import kv_rwset_pb2
@@ -822,11 +821,6 @@ class MVCCValidator:
             faultline.point(
                 "mvcc.ns_prepare", stage="prepare", ns=ns_top,
                 txs=len(items),
-                # the statedb shard this namespace group's writes will
-                # route to under the current FABRIC_TPU_STORE_SHARDS —
-                # lets chaos plans and profiles line the MVCC partition
-                # up with the storage partition it feeds
-                shard=shard_of_namespace(ns_top, store_shards()),
             )
             m: dict[str, dict] = {}
             for h, ns, kvrw, colls, pvt_by_coll in items:

@@ -16,7 +16,7 @@ import struct
 
 import pytest
 
-from fabric_tpu.devtools import faultline
+from fabric_tpu.devtools import faultline, invariants
 from fabric_tpu.ledger import LedgerProvider
 from fabric_tpu.ledger.statedb import Height
 
@@ -254,141 +254,47 @@ def test_pinned_parallel_prepare_crash_plan(tmp_path, monkeypatch):
     assert first == second
 
 
-# -- storage engine v2: the two-phase group-flush torn points ----------------
+# -- a graceful failure on the flush path --------------------------------------
 
 
-@pytest.mark.parametrize("stage", ["prepare", "commit", "apply"])
-def test_crash_at_every_shard_flush_stage_recovers(
-    tmp_path, stage, monkeypatch
-):
-    """The sharded statedb's two-phase flush, crashed at each of its
-    three torn points.  The block record is durable BEFORE the kv flush
-    starts, so every arm must land at the same height 3 — what differs
-    is the recovery arm: a crash at prepare or at the coordinator-commit
-    point leaves a pending epoch AHEAD of the committed one (roll back
-    ALL shards, replay block 2 from the file), while a crash at apply
-    leaves pending == committed (roll the staged writes FORWARD — the
-    coordinator savepoint already acknowledged them)."""
-    monkeypatch.setenv("FABRIC_TPU_STORE_SHARDS", "2")
-    monkeypatch.setenv("FABRIC_TPU_STORE_POOL", "0")
+@pytest.mark.parametrize("stage", ["fsync", "kv_txn"])
+def test_graceful_raise_at_a_flush_stage_needs_no_reopen(tmp_path, stage):
+    """A raise-style fault (a failure the process survives, NOT a
+    crash) at the two stage points of the flush path.  After `fsync`
+    the block record is durable and the group's one KV transaction has
+    not begun: the ledger rolls the group back (buffered rows dropped,
+    the file cut to its checkpoint) and the same block commits again.
+    After `kv_txn` the transaction has landed, and it is the commit
+    point: the block stays, the caller's error notwithstanding, and the
+    chain goes on from it.  Neither needs a reopen, and a reopen agrees
+    with the live ledger."""
     provider = LedgerProvider(str(tmp_path))
     ledger = provider.open("chaos")
     ledger.commit(_write_block(ledger, 0, [("cc", "a", b"0")]))
-    ledger.commit(_write_block(ledger, 1, [("qscc", "b", b"1")]))
-
-    # two namespaces so both shards carry staged writes at the crash
-    blk2 = _write_block(
-        ledger, 2, [("cc", "c", b"2"), ("qscc", "d", b"3")]
-    )
-    with faultline.use_plan(
-        _crash_plan("store.shard_flush", {"stage": stage})
-    ):
-        with pytest.raises(faultline.FaultCrash):
-            ledger.commit(blk2)
-        assert faultline.trips(), "the plan never fired"
-    provider.close()
-
-    # reopen under the observer: recovery's own seam tells the two arms
-    # apart — only the apply crash leaves a committed-but-unapplied
-    # epoch for the roll-forward guard to resolve
-    faultline.reset_registry()
-    with faultline.observe():
-        provider2 = LedgerProvider(str(tmp_path))
-        led2 = provider2.open("chaos")
-    rolled_forward = "store.shard_recover" in faultline.registry()
-    assert rolled_forward == (stage == "apply"), faultline.registry()
-
-    _assert_consistent(led2, 3, {
-        ("cc", "a"): b"0", ("qscc", "b"): b"1",
-        ("cc", "c"): b"2", ("qscc", "d"): b"3",
-    })
-    led2.commit(_write_block(led2, 3, [("cc", "next", b"n")]))
-    assert led2.get_state("cc", "next") == b"n"
-    provider2.close()
-
-
-def test_graceful_raise_at_coordinator_txn_rolls_back_shards(
-    tmp_path, monkeypatch
-):
-    """A raise-style fault (graceful failure) at the coordinator txn
-    AFTER both shards staged their pending writes: the ledger rolls the
-    group back, the staged epochs stay invisible to reads, and the next
-    commit's prepare sweeps them — no reopen required."""
-    monkeypatch.setenv("FABRIC_TPU_STORE_SHARDS", "2")
-    monkeypatch.setenv("FABRIC_TPU_STORE_POOL", "0")
-    provider = LedgerProvider(str(tmp_path))
-    ledger = provider.open("chaos")
-    ledger.commit(_write_block(ledger, 0, [("cc", "a", b"0")]))
-    blk1 = _write_block(
-        ledger, 1, [("cc", "b", b"1"), ("qscc", "c", b"2")]
-    )
+    blk1 = _write_block(ledger, 1, [("cc", "b", b"1")])
     with faultline.use_plan({"faults": [{
-        "point": "kvstore.txn", "action": "raise", "error": "OSError",
+        "point": "commit.stage", "ctx": {"stage": stage},
+        "action": "raise", "error": "OSError",
         "message": "injected disk full",
     }]}):
         with pytest.raises(OSError, match="injected disk full"):
             ledger.commit(blk1)
         assert faultline.trips()
-    assert ledger.height == ledger.durable_height == 1
-    assert ledger.get_state("cc", "b") is None
-    assert ledger.get_state("qscc", "c") is None
-    ledger.commit(_write_block(
-        ledger, 1, [("cc", "b", b"1"), ("qscc", "c", b"2")]
-    ))
-    assert ledger.get_state("qscc", "c") == b"2"
+    landed = stage == "kv_txn"
+    assert ledger.height == (2 if landed else 1)
+    assert ledger.get_state("cc", "b") == (b"1" if landed else None)
+    if not landed:
+        assert ledger.durable_height == 1
+        ledger.commit(_write_block(ledger, 1, [("cc", "b", b"1")]))
+    ledger.commit(_write_block(ledger, 2, [("cc", "next", b"n")]))
+    keys = {("cc", "a"): b"0", ("cc", "b"): b"1", ("cc", "next"): b"n"}
+    _assert_consistent(ledger, 3, keys)
+    assert invariants.check_ledger(ledger) == []
     provider.close()
 
-
-def test_pinned_shard_flush_crash_plan_deterministic(tmp_path, monkeypatch):
-    """Pinned seeded plan over the storage-v2 seams: a crash inside the
-    fanned-out shard prepare (store.shard_flush, targeted at one shard's
-    prepare so the trip is deterministic even with pool workers racing)
-    aborts the kv flush after the block record is durable; reopen rolls
-    the staged epochs back and replays the block from the file.  Two
-    runs yield identical trip ledgers — the chaos determinism contract
-    extended to the new seams."""
-    monkeypatch.setenv("FABRIC_TPU_STORE_SHARDS", "4")
-    monkeypatch.setenv("FABRIC_TPU_STORE_POOL", "3")
-    plan = {"seed": 17, "faults": [{
-        "point": "store.shard_flush",
-        "ctx": {"stage": "prepare", "shard": 2},
-        "action": "crash",
-    }]}
-    # namespaces spread across all 4 shards so the fan-out is real
-    items = [
-        (f"ns{j}", f"k{i}", b"v") for j in range(8) for i in range(4)
-    ]
-
-    def run(sub: str) -> list[dict]:
-        provider = LedgerProvider(str(tmp_path / sub))
-        ledger = provider.open("chaos")
-        ledger.commit(_write_block(ledger, 0, [("ns0", "a", b"0")]))
-        blk = _write_block(ledger, 1, items)
-        with faultline.use_plan(plan):
-            with pytest.raises(faultline.FaultCrash):
-                ledger.commit(blk)
-            observed = [
-                t for t in faultline.trips() if t["plan"] != "soak"
-            ]
-        assert observed and all(
-            t["point"] == "store.shard_flush"
-            and t["ctx"]["shard"] == 2
-            for t in observed
-        )
-        provider.close()
-
-        provider2 = LedgerProvider(str(tmp_path / sub))
-        led2 = provider2.open("chaos")
-        _assert_consistent(led2, 2, {
-            ("ns0", "a"): b"0", ("ns1", "k0"): b"v",
-        })
-        led2.commit(_write_block(led2, 2, [("ns2", "z", b"z")]))
-        assert led2.get_state("ns2", "z") == b"z"
-        provider2.close()
-        return observed
-
-    first, second = run("r1"), run("r2")
-    assert first == second
+    provider2 = LedgerProvider(str(tmp_path))
+    _assert_consistent(provider2.open("chaos"), 3, keys)
+    provider2.close()
 
 
 # literal plan rules (not a name parametrized through _crash_plan):

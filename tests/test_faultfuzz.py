@@ -2,8 +2,9 @@
 canned workload, fixed-seed campaign determinism (the acceptance pin:
 two 25-plan seed-7 campaigns produce byte-identical verdicts and
 canonical trip ledgers), an intentionally-seeded oracle violation
-(shard-apply crash + skipped shard roll-forward) caught, shrunk to its
-2-rule minimum, and replayable from the repro artifact, the snapshot
+(a crash between the block-file fsync and the KV transaction + a
+skipped recovery replay) caught, shrunk to its 2-rule minimum, and
+replayable from the repro artifact, the snapshot
 export/import fault points (torn manifest refused, half-import refused
 loudly), and the tier-1 soak mode (slow): the commit+snapshot workload
 under the low-probability background plan to a green oracle."""
@@ -99,16 +100,16 @@ _SEEDED_PLAN = {
     "seed": 3,
     "label": "seeded",
     "faults": [
-        # a crash at the first shard-apply: the coordinator txn
-        # (savepoint + block index + epoch record) is already durable,
-        # the shard's staged writes are not yet folded in...
-        {"point": "store.shard_flush", "action": "crash",
-         "ctx": {"stage": "apply"}, "count": 1},
-        # ...and the reopen roll-forward guard is SKIPPED, so the
-        # committed-but-unapplied pending writes are silently dropped
-        # while the savepoint says the block committed — lost state
-        # below the recovered height
-        {"point": "store.shard_recover", "action": "skip", "count": 5},
+        # a crash once the block file is fsynced: the block record is
+        # durable, the group's one KV transaction (state, history,
+        # index, savepoint) has not begun...
+        {"point": "commit.stage", "action": "crash",
+         "ctx": {"stage": "fsync"}, "count": 1},
+        # ...and the reopen's replay of the blocks past the state
+        # savepoint is SKIPPED: the block store re-indexes the record,
+        # the ledger reports its height, and its writes are in no
+        # state — lost state below the recovered height
+        {"point": "ledger.recovery_replay", "action": "skip", "count": 5},
     ],
 }
 
@@ -135,7 +136,7 @@ def test_seeded_violation_caught_shrunk_and_replayable(tmp_path):
     shrunk, runs = faultfuzz.shrink_plan(_SEEDED_PLAN, still_fails)
     assert len(shrunk["faults"]) == 2
     assert {f["point"] for f in shrunk["faults"]} == {
-        "store.shard_flush", "store.shard_recover",
+        "commit.stage", "ledger.recovery_replay",
     }
     assert runs >= 2  # it really tried to drop both
 
@@ -149,6 +150,51 @@ def test_seeded_violation_caught_shrunk_and_replayable(tmp_path):
     assert replayed["violations"], "the repro artifact did not reproduce"
     assert {v["check"] for v in replayed["violations"]} & \
         {"state", "reopen"}
+    # the trip ledger is the artifact's too, rule for rule
+    assert replayed["trips"] == doc["trips"] == res["trips"]
+
+
+@pytest.mark.parametrize(
+    "crash_stage", [None, "kv_txn"], ids=["no_crash", "after_kv_txn"],
+)
+def test_skipped_replay_alone_is_not_a_defect(tmp_path, crash_stage):
+    """The guard is a defect only behind a crash that leaves blocks
+    past the savepoint.  With no crash nothing is ever past it and the
+    guard is never asked; a crash AFTER the `kv_txn` stage finds the
+    group's transaction landed (it is the commit point), so the reopen
+    has nothing to replay either: the oracle is green in both."""
+    faults = [dict(_SEEDED_PLAN["faults"][1])]
+    if crash_stage:
+        faults.insert(0, {"point": "commit.stage", "action": "crash",
+                          "ctx": {"stage": crash_stage}, "count": 1})
+    res = faultfuzz.run_plan(
+        {"seed": 3, "label": "skip-only", "faults": faults},
+        str(tmp_path / "run"), comm=False,
+    )
+    assert res["violations"] == []
+    assert [t["point"] for t in res["trips"]] == \
+        (["commit.stage"] if crash_stage else [])
+    assert res["stats"]["committed"] == faultfuzz.DEFAULT_BLOCKS + 2 - \
+        bool(crash_stage)
+
+
+def test_canned_workload_runs_on_the_store_the_peer_runs(tmp_path):
+    """The campaigns tear what every peer flushes: one `SqliteKVStore`
+    on the clustered layout, one `index.sqlite` and no other store
+    file."""
+    from fabric_tpu.ledger.kvstore import SqliteKVStore
+
+    faultfuzz._drive(str(tmp_path), blocks=1, comm=False)
+    src = faultfuzz._src_root(str(tmp_path))
+    provider = LedgerProvider(src)
+    try:
+        assert type(provider.kv) is SqliteKVStore
+        assert provider.kv.clustered is True
+    finally:
+        provider.close()
+    assert sorted(
+        f for f in os.listdir(src) if f.endswith(".sqlite")
+    ) == ["index.sqlite"]
 
 
 def test_campaign_writes_repro_for_failing_plan(tmp_path):
@@ -174,8 +220,8 @@ def _seeded_registry():
     kinds the pinned faultmap carries — enough for mutate_plan's
     action-pool lookup."""
     return {
-        "store.shard_flush": {"kinds": ["point"], "ctx": {}},
-        "store.shard_recover": {"kinds": ["guard"], "ctx": {}},
+        "commit.stage": {"kinds": ["point"], "ctx": {}},
+        "ledger.recovery_replay": {"kinds": ["guard"], "ctx": {}},
     }
 
 
@@ -231,7 +277,7 @@ def test_campaign_mutants_ride_the_repro_path_and_stay_deterministic(
     by making the generator emit the seeded failure: the campaign
     derives K seed-addressed mutants, judges each, writes a repro for
     the still-failing one (mutant m5's trigger tweak keeps the
-    shard-apply crash live), counts it in the summary, and two
+    post-fsync crash live), counts it in the summary, and two
     same-seed campaigns agree byte-for-byte once artifact paths are
     stripped."""
     def seeded_generator(rng, registry, label, tripped=frozenset()):

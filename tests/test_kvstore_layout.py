@@ -4,9 +4,8 @@ mapped memory, a transaction's rows reach sqlite in key order — and a
 file an older build made keeps the rowid layout it has and keeps
 working.  One contract over the in-memory store, a fresh file, an
 old-schema file and a file the pragma granted no map; then what each
-layout looks like from outside, a shard's stage / apply / drop round,
-and a ledger directory of the old layout through `KVLedger`: open,
-read, commit, recover."""
+layout looks like from outside, and a ledger directory of the old
+layout through `KVLedger`: open, read, commit, recover."""
 
 import io
 import sqlite3
@@ -15,12 +14,7 @@ import pytest
 
 from fabric_tpu.common import flogging, tracing
 from fabric_tpu.ledger import LedgerProvider, kvstore
-from fabric_tpu.ledger.kvstore import (
-    MemKVStore,
-    ShardedKVStore,
-    SqliteKVStore,
-    _ShardStore,
-)
+from fabric_tpu.ledger.kvstore import MemKVStore, SqliteKVStore
 from fabric_tpu.ledger.statedb import Height
 
 from test_group_commit import _write_block
@@ -297,59 +291,6 @@ def test_every_open_logs_the_layout_it_found(tmp_path):
     assert "new.sqlite: clustered=True mmap_bytes=" in lines[0]
     assert "old.sqlite: clustered=False mmap_bytes=" in lines[1]
     assert not lines[0].endswith("mmap_bytes=0")
-
-
-# -- the sharded engine on the new layout --------------------------------------
-
-
-def test_a_shard_stages_applies_and_drops_on_the_new_layout(tmp_path):
-    path = str(tmp_path / "state_00.sqlite")
-    shard = _ShardStore(path)
-    try:
-        assert shard.clustered is True and shard.mmap_bytes > 0
-        shard.write_batch({b"a": b"1", b"b": b"2", b"c": b"3"})
-        # stage: invisible until applied; a delete wins over the same key's put
-        shard.stage_pending({b"d": b"4", b"a": b"10", b"c": b"30"}, [b"b", b"c"], epoch=7)
-        assert shard.pending_epoch() == 7
-        assert dict(shard.iterate()) == {b"a": b"1", b"b": b"2", b"c": b"3"}
-        shard.apply_pending()
-        assert shard.pending_epoch() is None
-        assert dict(shard.iterate()) == {b"a": b"10", b"d": b"4"}
-        shard.apply_pending()                      # idempotent on a clean shard
-        # drop: a prepared stage the coordinator never committed
-        shard.stage_pending({b"e": b"5"}, [b"a"], epoch=8)
-        shard.drop_pending()
-        assert shard.pending_epoch() is None
-        assert dict(shard.iterate()) == {b"a": b"10", b"d": b"4"}
-    finally:
-        shard.close()
-    # `pending` and `shardmeta` are the tables they were; only `kv` is clustered
-    tables = {name: sql for kind_, name, sql in _schema(path) if kind_ == "table"}
-    assert sorted(tables) == ["kv", "pending", "shardmeta"]
-    assert tables["kv"].upper().endswith("WITHOUT ROWID")
-    assert "WITHOUT ROWID" not in tables["pending"].upper() + tables["shardmeta"].upper()
-
-
-def test_a_sharded_store_says_what_all_of_its_files_have(tmp_path):
-    root = tmp_path / "new"
-    s = ShardedKVStore(str(root), shards=2)
-    try:
-        assert s.clustered is True and s.mmap_bytes == s._coord.mmap_bytes > 0
-        state = b"statedb/ch\x00\xff\x02cc\x00k"
-        s.write_batch({state: b"v", b"blkindex/ch\x00\xffn": b"1"})
-        assert s.get(state) == b"v" and len(list(s.iterate())) == 4
-    finally:
-        s.close()
-    # one file of an older build among them makes the answer no
-    root = tmp_path / "mixed"
-    root.mkdir()
-    _old_file(str(root / "state_01.sqlite"))
-    s = ShardedKVStore(str(root), shards=2)
-    try:
-        assert s.clustered is False
-        assert [f.clustered for f in s._stores] == [True, False]
-    finally:
-        s.close()
 
 
 # -- a ledger directory of the old layout ------------------------------------------

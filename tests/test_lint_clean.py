@@ -1049,6 +1049,40 @@ def test_v6_tree_artifacts_cover_the_real_surfaces():
     assert consumed <= set(mm["exposed"])
 
 
+def test_nothing_names_a_knob_the_registry_lacks():
+    """Fifteen knobs since PR 51 (the two of the withdrawn sharded
+    store and the WAL checkpoint threshold went).  A name that left the
+    registry is left nowhere: not in the README (its generated table
+    is the lint rule's to hold, the prose around it is not), not in a
+    source, script or sample config."""
+    import os
+    import re
+
+    from fabric_tpu.devtools import knob_registry
+    from fabric_tpu.devtools.lint import repo_root
+
+    assert len(knob_registry.KNOBS) == 15
+    root = repo_root()
+    paths = [os.path.join(root, "README.md")]
+    for top in ("fabric_tpu", "scripts", "sampleconfig"):
+        for dirpath, _dirs, files in os.walk(os.path.join(root, top)):
+            paths.extend(
+                os.path.join(dirpath, f) for f in files
+                if f.endswith((".py", ".cc", ".sh", ".yaml", ".json"))
+            )
+    named = {}
+    for path in paths:
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        # a bare prefix ("FABRIC_TPU_*") names no knob
+        for name in re.findall(r"FABRIC_TPU_[A-Z0-9][A-Z0-9_]*", text):
+            named.setdefault(name, os.path.relpath(path, root))
+    strangers = {n: rel for n, rel in named.items()
+                 if n not in knob_registry.KNOBS}
+    assert strangers == {}
+    assert set(named) == set(knob_registry.KNOBS)
+
+
 def test_ci_wrapper_v6_artifacts_byte_identical_cold_vs_hit(tmp_path):
     """scripts/lint.py --rpcmap-out/--knobs-out/--metricmap-out (ISSUE
     19 satellite): all three artifacts land beside the result line,
@@ -1085,7 +1119,7 @@ def test_ci_wrapper_v6_artifacts_byte_identical_cold_vs_hit(tmp_path):
         b = open(hit_paths[kind], "rb").read()
         assert a == b, f"{kind} artifact differs cold vs hit"
     assert hit["rpcmap"]["methods"] >= 25
-    assert hit["knobs"]["knobs"] == 18
-    assert hit["knobs"]["reads"] >= 16
+    assert hit["knobs"]["knobs"] == 15
+    assert hit["knobs"]["reads"] >= 15
     assert hit["metricmap"]["producers"] >= 40
     assert hit["metricmap"]["exposed"] >= 60

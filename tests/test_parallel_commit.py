@@ -813,46 +813,33 @@ def test_mvcc_metadata_write_semantics_after_restructure():
     assert "tail2" in batch2["cc"]
 
 
-# -- sqlite durability knobs --------------------------------------------------
+# -- sqlite durability knob ---------------------------------------------------
 
 
-def test_sqlite_durability_knobs(tmp_path, monkeypatch):
-    """FABRIC_TPU_SQLITE_SYNC / FABRIC_TPU_WAL_CHECKPOINT reach the
-    PRAGMAs; ctor args override env; invalid values refuse loudly."""
+def test_sqlite_durability_knob(tmp_path, monkeypatch):
+    """FABRIC_TPU_SQLITE_SYNC reaches the PRAGMA; the ctor arg overrides
+    env; an invalid value refuses loudly."""
     from fabric_tpu.ledger.kvstore import SqliteKVStore
 
-    def pragmas(store):
-        sync = store._conn.execute("PRAGMA synchronous").fetchone()[0]
-        ckpt = store._conn.execute(
-            "PRAGMA wal_autocheckpoint"
-        ).fetchone()[0]
-        return sync, ckpt
+    def sync(store):
+        return store._conn.execute("PRAGMA synchronous").fetchone()[0]
 
     s = SqliteKVStore(str(tmp_path / "default.db"))
-    assert pragmas(s) == (1, 1000)  # NORMAL, sqlite stock threshold
-    assert (s.sync_level, s.wal_autocheckpoint) == ("NORMAL", 1000)
+    assert (sync(s), s.sync_level) == (1, "NORMAL")
     s.close()
 
     monkeypatch.setenv("FABRIC_TPU_SQLITE_SYNC", "full")
-    monkeypatch.setenv("FABRIC_TPU_WAL_CHECKPOINT", "4000")
     s = SqliteKVStore(str(tmp_path / "env.db"))
-    assert pragmas(s) == (2, 4000)  # FULL
+    assert (sync(s), s.sync_level) == (2, "FULL")
     s.close()
 
-    s = SqliteKVStore(
-        str(tmp_path / "ctor.db"), synchronous="OFF",
-        wal_autocheckpoint=0,
-    )
-    assert pragmas(s) == (0, 0)
+    s = SqliteKVStore(str(tmp_path / "ctor.db"), synchronous="OFF")
+    assert sync(s) == 0
     s.close()
 
     monkeypatch.setenv("FABRIC_TPU_SQLITE_SYNC", "sometimes")
     with pytest.raises(ValueError, match="FABRIC_TPU_SQLITE_SYNC"):
         SqliteKVStore(str(tmp_path / "bad.db"))
-    monkeypatch.setenv("FABRIC_TPU_SQLITE_SYNC", "NORMAL")
-    monkeypatch.setenv("FABRIC_TPU_WAL_CHECKPOINT", "many")
-    with pytest.raises(ValueError, match="FABRIC_TPU_WAL_CHECKPOINT"):
-        SqliteKVStore(str(tmp_path / "bad2.db"))
 
 
 # -- tier-1 smoke: 50-tx pipelined stream, parallel stages on ----------------

@@ -22,6 +22,8 @@ from fabric_tpu.common.operations import System
 from fabric_tpu.comm.rpc import RPCClient, RPCServer
 from fabric_tpu.devtools import faultfuzz, invariants, lockwatch
 
+from test_faultfuzz import _SEEDED_PLAN
+
 CHANNEL = faultfuzz.CHANNEL
 
 
@@ -335,18 +337,10 @@ def test_campaign_writes_profile_artifact_next_to_repro(
 ):
     """A failing campaign plan leaves <repro>.profile.json beside the
     repro JSON when profscope is armed (the trace-artifact contract)."""
-    seeded = {
-        "faults": [
-            {"point": "store.shard_flush", "action": "crash",
-             "ctx": {"stage": "apply"}, "count": 1},
-            {"point": "store.shard_recover", "action": "skip",
-             "count": 5},
-        ],
-    }
     monkeypatch.setattr(
         faultfuzz, "generate_plan",
         lambda rng, registry, label, tripped=frozenset():
-            {**seeded, "label": label, "seed": 3},
+            {**_SEEDED_PLAN, "label": label},
     )
     out_dir = tmp_path / "artifacts"
     with profile.scope(sampler=False):

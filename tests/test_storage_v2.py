@@ -1,19 +1,20 @@
-"""Storage engine v2 (ISSUE 17 tentpole): the namespace-sharded statedb
-behind the KVStore SPI and the preallocated-segment block writer.
+"""The one statedb engine behind `open_store_root` and the
+preallocated-segment block writer (what is left of ISSUE 17's storage
+engine v2: the namespace-sharded store was withdrawn in PR 51, every
+world having one chaincode namespace).
 
-The acceptance contracts pinned here:
+The contracts pinned here:
 
-* **serial parity** — the sharded store is an implementation detail:
-  the same workload at shard widths 1 / 2 / 4 (and at every flush
-  fan-out width) produces a byte-identical ``invariants.state_digest``
-  and identical chain tails;
+* **one engine** — a root opens as the one `SqliteKVStore` on
+  `index.sqlite`; a directory the sharded engine left is REFUSED with
+  the way out, never read as a ledger without its state;
 * **recovery idempotence** — reopening after a crash is a fixed point:
-  a second reopen changes nothing, at every shard width;
-* **snapshot portability** — export from a sharded store imports into
-  a store of a DIFFERENT width and the digests agree (the snapshot
-  stream is the canonical form, not the shard layout);
-* **persisted layout wins** — the shard count recorded at creation
-  overrides the env knob on reopen, so routing never drifts;
+  a second reopen changes nothing, on both layouts of the file (a fresh
+  clustered one, an older build's rowid one);
+* **snapshot portability** — an export from a ledger on a rowid file
+  imports into a fresh clustered store byte-identically (the snapshot
+  stream is the canonical form, not the file's layout; it is also the
+  way across from a layout this build no longer opens);
 * **segment hygiene** — a clean preallocated (zero) tail is NOT
   recovery damage; sealed segments are trimmed to data size; records
   larger than a segment still land and replay.
@@ -27,16 +28,10 @@ import pytest
 from fabric_tpu.devtools import faultline, invariants
 from fabric_tpu.ledger import LedgerProvider
 from fabric_tpu.ledger.blkstorage import DEFAULT_SEGMENT, segment_size
-from fabric_tpu.ledger.kvstore import (
-    ShardedKVStore,
-    SqliteKVStore,
-    open_store_root,
-    shard_of_namespace,
-    state_shard,
-    store_shards,
-)
+from fabric_tpu.ledger.kvstore import SqliteKVStore, open_store_root
 
 from test_group_commit import _write_block
+from test_kvstore_layout import _old_ledger_root
 
 
 WORKLOAD = [
@@ -47,157 +42,13 @@ WORKLOAD = [
 ]
 
 
-def _build(root, monkeypatch, shards, pool="0"):
-    monkeypatch.setenv("FABRIC_TPU_STORE_SHARDS", str(shards))
-    monkeypatch.setenv("FABRIC_TPU_STORE_POOL", pool)
-    provider = LedgerProvider(str(root))
-    ledger = provider.open("v2")
-    for n, items in enumerate(WORKLOAD):
-        ledger.commit(_write_block(ledger, n, items))
-    return provider, ledger
+# -- one engine ---------------------------------------------------------------
 
 
-# -- serial parity ------------------------------------------------------------
-
-
-def test_serial_vs_sharded_parity_byte_identical(tmp_path, monkeypatch):
-    """Shard width (and flush fan-out width) never changes RESULTS:
-    state digest, chain tail, and raw state export are byte-identical
-    at widths 1 / 2 / 4, serial and pooled."""
-    outputs = []
-    for name, shards, pool in (
-        ("w1", 1, "0"), ("w2", 2, "0"), ("w4", 4, "0"), ("w4p", 4, "3"),
-    ):
-        provider, ledger = _build(tmp_path / name, monkeypatch,
-                                  shards, pool)
-        # chain hashes carry wall-clock header timestamps, so parity is
-        # judged on the STORE: digest, raw export stream, height
-        outputs.append((
-            invariants.state_digest(ledger),
-            list(ledger.state_db.export_records()),
-            ledger.height,
-        ))
-        assert invariants.check_ledger(ledger) == []
-        provider.close()
-    first = outputs[0]
-    for other in outputs[1:]:
-        assert other == first
-
-
-def test_sharded_reads_match_routing(tmp_path, monkeypatch):
-    """Point reads, range iteration, and history agree with the write
-    model over a sharded store — and derived pvt/hash namespaces ride
-    with their parent chaincode's shard."""
-    provider, ledger = _build(tmp_path, monkeypatch, shards=4)
-    assert ledger.get_state("cc", "c") == b"2"
-    assert ledger.get_state("qscc", "q") == b"config2"
-    assert ledger.get_state("cc\x00pvt\x00col", "p") == b"private"
-    assert ledger.get_history_for_key("qscc", "q") == [(0, 0), (3, 0)]
-    assert shard_of_namespace("cc\x00pvt\x00col", 4) == \
-        shard_of_namespace("cc", 4)
-    provider.close()
-
-
-# -- recovery idempotence -----------------------------------------------------
-
-
-@pytest.mark.parametrize("shards", [1, 2, 4])
-def test_recovery_is_idempotent_at_every_width(tmp_path, monkeypatch,
-                                               shards):
-    """Crash mid-flush, then reopen TWICE: the second reopen is a
-    no-op (same digest, same height) — recovery is a fixed point at
-    every shard width."""
-    monkeypatch.setenv("FABRIC_TPU_STORE_SHARDS", str(shards))
-    monkeypatch.setenv("FABRIC_TPU_STORE_POOL", "0")
-    provider = LedgerProvider(str(tmp_path))
-    ledger = provider.open("v2")
-    ledger.commit(_write_block(ledger, 0, WORKLOAD[0]))
-    blk1 = _write_block(ledger, 1, WORKLOAD[1])
-    point = "store.shard_flush" if shards > 1 else "kvstore.txn"
-    ctx = {"stage": "apply"} if shards > 1 else None
-    fault = {"point": point, "action": "crash"}
-    if ctx:
-        fault["ctx"] = ctx
-    with faultline.use_plan({"seed": 1, "faults": [fault]}):
-        with pytest.raises(faultline.FaultCrash):
-            ledger.commit(blk1)
-        assert faultline.trips()
-    provider.close()
-
-    snaps = []
-    for _ in range(2):
-        p2 = LedgerProvider(str(tmp_path))
-        led2 = p2.open("v2")
-        snaps.append((invariants.state_digest(led2), led2.height,
-                      led2.durable_height))
-        assert invariants.check_ledger(led2) == []
-        p2.close()
-    assert snaps[0] == snaps[1]
-    assert snaps[0][1] == 2  # the block record was durable: replayed
-
-
-# -- snapshot portability -----------------------------------------------------
-
-
-def test_snapshot_round_trip_across_shard_widths(tmp_path, monkeypatch):
-    """Export from a 2-way sharded store, import into a 4-way one: the
-    snapshot stream is the canonical form — digests agree, the
-    invariants oracle accepts the import, and the destination really is
-    sharded at ITS OWN width."""
-    provider, ledger = _build(tmp_path / "src", monkeypatch, shards=2)
-    export_dir = ledger.snapshots.generate()
-    src_digest = invariants.state_digest(ledger)
-    provider.close()
-
-    monkeypatch.setenv("FABRIC_TPU_STORE_SHARDS", "4")
-    dst = LedgerProvider(str(tmp_path / "dst"))
-    led2 = dst.create_from_snapshot(export_dir)
-    assert invariants.check_import_state(led2, export_dir) == []
-    assert invariants.state_digest(led2) == src_digest
-    assert isinstance(dst.kv, ShardedKVStore) and dst.kv.shards == 4
-    # and the imported ledger keeps committing
-    led2.commit(_write_block(led2, led2.height,
-                             [("cc", "post", b"import")]))
-    assert led2.get_state("cc", "post") == b"import"
-    dst.close()
-
-
-# -- persisted layout wins ----------------------------------------------------
-
-
-def test_persisted_shard_count_wins_over_env(tmp_path, monkeypatch):
-    """A store created 4-way reopens 4-way no matter what the env says
-    — routing is a property of the files on disk, not the process."""
-    provider, ledger = _build(tmp_path, monkeypatch, shards=4)
-    digest = invariants.state_digest(ledger)
-    provider.close()
-
-    monkeypatch.setenv("FABRIC_TPU_STORE_SHARDS", "2")
-    p2 = LedgerProvider(str(tmp_path))
-    led2 = p2.open("v2")
-    assert isinstance(p2.kv, ShardedKVStore) and p2.kv.shards == 4
-    assert invariants.state_digest(led2) == digest
-    p2.close()
-
-    # even with the knob unset (default 1) the sharded layout is
-    # detected and reopened sharded
-    monkeypatch.delenv("FABRIC_TPU_STORE_SHARDS")
-    p3 = LedgerProvider(str(tmp_path))
-    led3 = p3.open("v2")
-    assert isinstance(p3.kv, ShardedKVStore) and p3.kv.shards == 4
-    assert invariants.state_digest(led3) == digest
-    p3.close()
-
-
-def test_unsharded_root_stays_plain_sqlite(tmp_path, monkeypatch):
-    """shards=1 (the default) opens the exact pre-v2 layout: one
-    index.sqlite, no shard files, plain SqliteKVStore — zero migration
-    for existing stores."""
-    monkeypatch.delenv("FABRIC_TPU_STORE_SHARDS", raising=False)
+def test_a_plain_root_opens_as_the_one_sqlite_store(tmp_path):
     kv = open_store_root(str(tmp_path))
     try:
-        assert isinstance(kv, SqliteKVStore)
-        assert not isinstance(kv, ShardedKVStore)
+        assert type(kv) is SqliteKVStore and kv.clustered is True
         kv.write_batch({b"statedb/ch\x00\xff\x02cc\x00k": b"v"})
         assert kv.get(b"statedb/ch\x00\xff\x02cc\x00k") == b"v"
     finally:
@@ -207,18 +58,91 @@ def test_unsharded_root_stays_plain_sqlite(tmp_path, monkeypatch):
     ) == ["index.sqlite"]
 
 
-def test_key_routing_surface():
-    """The routing function's edges: non-statedb keys and savepoint /
-    index / metans records stay in the coordinator; only \\x02-encoded
-    state entries shard."""
-    assert state_shard(b"blkindex/ch\x00\xffn5", 4) is None
-    assert state_shard(b"statedb/ch\x00\xff\x01", 4) is None  # savepoint
-    assert state_shard(b"statedb/ch\x00\xff\x03idx", 4) is None
-    k = b"statedb/ch\x00\xff\x02cc\x00key"
-    assert state_shard(k, 1) is None  # width 1: no routing at all
-    assert state_shard(k, 4) == shard_of_namespace("cc", 4)
-    with pytest.raises(ValueError):
-        store_shards("nope")
+def test_a_sharded_directory_is_refused_not_misread(tmp_path):
+    """What the withdrawn engine left (`state_00.sqlite` beside
+    `index.sqlite`) holds its state rows where this build does not
+    look: the open says so, names the way out, and touches nothing."""
+    provider = LedgerProvider(str(tmp_path))
+    ledger = provider.open("v2")
+    ledger.commit(_write_block(ledger, 0, WORKLOAD[0]))
+    provider.close()
+    (tmp_path / "state_00.sqlite").write_bytes(b"")
+    before = sorted(os.listdir(str(tmp_path)))
+    for opener in (open_store_root, LedgerProvider):
+        with pytest.raises(ValueError, match="sharded statedb") as err:
+            opener(str(tmp_path))
+        assert "PR 51" in str(err.value)
+        assert "join the channel by snapshot" in str(err.value)
+    assert sorted(os.listdir(str(tmp_path))) == before
+
+
+# -- recovery idempotence -----------------------------------------------------
+
+
+@pytest.mark.parametrize("old", [False, True], ids=["clustered", "rowid"])
+def test_recovery_is_idempotent(tmp_path, old):
+    """Crash between the block-file fsync and the KV transaction, then
+    reopen TWICE: the second reopen is a no-op (same digest, same
+    height) — recovery is a fixed point on both layouts."""
+    root = _old_ledger_root(tmp_path) if old else str(tmp_path)
+    provider = LedgerProvider(root)
+    assert provider.kv.clustered is (not old)
+    ledger = provider.open("v2")
+    ledger.commit(_write_block(ledger, 0, WORKLOAD[0]))
+    blk1 = _write_block(ledger, 1, WORKLOAD[1])
+    with faultline.use_plan({"seed": 1, "faults": [
+        {"point": "kvstore.txn", "action": "crash"},
+    ]}):
+        with pytest.raises(faultline.FaultCrash):
+            ledger.commit(blk1)
+        assert faultline.trips()
+    provider.close()
+
+    snaps = []
+    for _ in range(2):
+        p2 = LedgerProvider(root)
+        led2 = p2.open("v2")
+        snaps.append((invariants.state_digest(led2), led2.height,
+                      led2.durable_height))
+        assert invariants.check_ledger(led2) == []
+        assert p2.kv.clustered is (not old)
+        p2.close()
+    assert snaps[0] == snaps[1]
+    assert snaps[0][1] == 2  # the block record was durable: replayed
+
+
+# -- snapshot portability -----------------------------------------------------
+
+
+def test_snapshot_from_a_rowid_file_imports_into_a_clustered_store(
+    tmp_path,
+):
+    """Export from a ledger on an older build's rowid file, import into
+    a fresh root: the destination is clustered, its raw state export is
+    the source's byte for byte, and it keeps committing."""
+    (tmp_path / "src").mkdir()
+    provider = LedgerProvider(_old_ledger_root(tmp_path / "src"))
+    ledger = provider.open("v2")
+    for n, items in enumerate(WORKLOAD):
+        ledger.commit(_write_block(ledger, n, items))
+    assert provider.kv.clustered is False
+    export_dir = ledger.snapshots.generate()
+    src_digest = invariants.state_digest(ledger)
+    src_records = list(ledger.state_db.export_records())
+    provider.close()
+
+    dst = LedgerProvider(str(tmp_path / "dst"))
+    try:
+        led2 = dst.create_from_snapshot(export_dir)
+        assert dst.kv.clustered is True
+        assert invariants.check_import_state(led2, export_dir) == []
+        assert list(led2.state_db.export_records()) == src_records
+        assert invariants.state_digest(led2) == src_digest
+        led2.commit(_write_block(led2, led2.height,
+                                 [("cc", "post", b"import")]))
+        assert led2.get_state("cc", "post") == b"import"
+    finally:
+        dst.close()
 
 
 # -- segment hygiene ----------------------------------------------------------

@@ -20,6 +20,8 @@ from fabric_tpu.common.operations import System
 from fabric_tpu.comm.rpc import RPCClient, RPCServer
 from fabric_tpu.devtools import clockskew, faultfuzz, faultline, invariants
 
+from test_faultfuzz import _SEEDED_PLAN
+
 CHANNEL = faultfuzz.CHANNEL
 
 
@@ -523,21 +525,11 @@ def test_failing_faultfuzz_plan_ships_trace_and_replays_identically(
     returns the flight-recorder export alongside the violations, and
     two same-seed runs produce identical span sequences (timestamps
     aside)."""
-    seeded = {
-        "seed": 3,
-        "label": "seeded",
-        "faults": [
-            {"point": "store.shard_flush", "action": "crash",
-             "ctx": {"stage": "apply"}, "count": 1},
-            {"point": "store.shard_recover", "action": "skip",
-             "count": 5},
-        ],
-    }
     seqs = []
     for i in range(2):
         with tracing.scope():
             res = faultfuzz.run_plan(
-                seeded, str(tmp_path / f"run{i}"), comm=False
+                _SEEDED_PLAN, str(tmp_path / f"run{i}"), comm=False
             )
         assert res["violations"], "seeded violation must fail the oracle"
         assert res["trace"]["traceEvents"]
@@ -550,18 +542,10 @@ def test_campaign_writes_trace_artifact_next_to_repro(
 ):
     """A failing campaign plan leaves <repro>.trace.json beside the
     repro JSON when tracelens is armed."""
-    seeded = {
-        "faults": [
-            {"point": "store.shard_flush", "action": "crash",
-             "ctx": {"stage": "apply"}, "count": 1},
-            {"point": "store.shard_recover", "action": "skip",
-             "count": 5},
-        ],
-    }
     monkeypatch.setattr(
         faultfuzz, "generate_plan",
         lambda rng, registry, label, tripped=frozenset():
-            {**seeded, "label": label, "seed": 3},
+            {**_SEEDED_PLAN, "label": label},
     )
     out_dir = tmp_path / "artifacts"
     with tracing.scope():
