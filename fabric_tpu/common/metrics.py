@@ -413,7 +413,8 @@ class ValidateMetrics:
     decisions it deferred to the policy stage because a block in flight
     could still change a key's VALIDATION_PARAMETER, and the
     endorsement-plan cache's outcomes (`cleared`: the cache ran over
-    its cap and was emptied)."""
+    its cap and was emptied).  Beside them the endorsement signatures
+    that failed in transactions whose policy was met without them."""
 
     STAGES = ("collect", "creators", "verify_wait", "await_commit", "policy")
 
@@ -470,6 +471,16 @@ class ValidateMetrics:
                  "emptied).",
             label_names=("outcome",),
             statsd_format="%{outcome}",
+        ))
+        self.tolerated_bad_endorsements = provider.new_counter(CounterOpts(
+            namespace="validator",
+            subsystem="tolerated",
+            name="bad_endorsements_total",
+            help="Endorsement signatures that failed verification in "
+                 "transactions that stayed VALID because their "
+                 "endorsement policy was met without them.",
+            label_names=("channel",),
+            statsd_format="%{channel}",
         ))
 
 

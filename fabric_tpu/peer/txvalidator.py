@@ -461,6 +461,54 @@ def keylevel_tally() -> dict:
     return _KEYLEVEL.snapshot()
 
 
+class ToleratedTally:
+    """The endorsement lanes the device's mask refused in transactions
+    that came out of the policy stage VALID all the same (their policy
+    was met without them), from the process's start, and of the last
+    RECENT blocks their number and that count, oldest first.  A
+    benchmark's condition reads it
+    (benchmarks/conditions/mixedcc-shape.py); an operator reads the
+    same on /metrics (validator_tolerated_bad_endorsements_total)."""
+
+    RECENT = KeyLevelTally.RECENT
+
+    def __init__(self):
+        self.lanes = 0
+        self._recent: collections.deque = collections.deque(maxlen=self.RECENT)
+
+    def block_done(self, num: int, lanes: int) -> None:
+        # one thread finishes a validator's blocks, in order
+        self.lanes += lanes
+        self._recent.append((num, lanes))
+
+    def snapshot(self) -> dict:
+        return {"tolerated_bad_lanes": self.lanes,
+                "recent_blocks": list(self._recent)}
+
+
+_TOLERATED = ToleratedTally()
+
+
+def tolerated_tally() -> dict:
+    """See ToleratedTally."""
+    return _TOLERATED.snapshot()
+
+
+def _refused_lanes(mask: list) -> set:
+    """The indices of a mask's false bits (`list.index`: a scan at C
+    speed over a mask that is nearly all true)."""
+    refused: set = set()
+    if not isinstance(mask, list):
+        mask = list(mask)
+    j = -1
+    try:
+        while True:
+            j = mask.index(False, j + 1)
+            refused.add(j)
+    except ValueError:
+        return refused
+
+
 def _commit_assist(works: list, envs: list, bspan):
     """ONE per-block assist bundle for KVLedger.commit, from either
     entry point (validate, validate_pipeline): the marshaled rwsets,
@@ -678,15 +726,22 @@ class TxValidator:
         # the CommitAssist of the block validate() saw last, until
         # take_assist() hands it over
         self._assist = None
+        # of the block in hand's collect: the plugin prepares (one a
+        # transaction and written namespace) and those of them whose
+        # namespace a committed definition, not the channel's default,
+        # decided (collect{namespace_prepares, definitions_resolved})
+        self._namespace_prepares = 0
+        self._definitions_resolved = 0
 
     def _plan_counts(self) -> tuple:
         """The builtin plugin's plan-cache outcomes so far (hits,
-        misses, clears); zeros where another plugin stands under its
-        name."""
+        misses, clears) and the seconds it spent building plans; zeros
+        where another plugin stands under its name."""
         plugin = self._registry.plugin("vscc")
         return (getattr(plugin, "plan_hits", 0),
                 getattr(plugin, "plan_misses", 0),
-                getattr(plugin, "plan_clears", 0))
+                getattr(plugin, "plan_clears", 0),
+                getattr(plugin, "plan_build_s", 0.0))
 
     def _count_keylevel(self, count: _KeyLevelCount, deferred: int = 0,
                         waits: int = 0) -> None:
@@ -706,10 +761,12 @@ class TxValidator:
             m.keylevel_deferred.With("channel", self.channel_id).add(deferred)
 
     def _count_plans(self, before: tuple) -> tuple:
-        """The plan cache's outcomes since `before`, onto /metrics."""
+        """The plan cache's outcomes and build seconds since `before`,
+        the outcomes onto /metrics."""
         delta = tuple(a - b for a, b in zip(self._plan_counts(), before))
         m = self._metrics
         if m is not None:
+            # the three outcomes; the fourth of `delta` is seconds
             for outcome, n in zip(("hit", "miss", "cleared"), delta):
                 if n:
                     m.plan_cache.With("outcome", outcome).add(n)
@@ -721,6 +778,7 @@ class TxValidator:
             info = self._definitions.validation_info(namespace)
             if info is not None:
                 name = info[0] or "vscc"
+                self._definitions_resolved += 1
         return self._registry.plugin(name)
 
     # -- phase 1: per-tx syntactic validation + collection ----------------
@@ -1168,6 +1226,7 @@ class TxValidator:
             works = _BlockWorks(n, keylevel)
             count = keylevel.count
             plans0 = self._plan_counts()
+            self._namespace_prepares = self._definitions_resolved = 0
             # ONE check a block of what the ledger already knows, after
             # the window was read: a state without metadata (every
             # block of a channel without key-level policies) takes no
@@ -1255,7 +1314,9 @@ class TxValidator:
                     keylevel_bulk_keys=count.bulk_keys,
                     keylevel_point_reads=count.point_reads,
                     plan_hits=plans[0], plan_misses=plans[1],
-                    plan_clears=plans[2],
+                    plan_clears=plans[2], plan_build_ms=plans[3] * 1e3,
+                    namespace_prepares=self._namespace_prepares,
+                    definitions_resolved=self._definitions_resolved,
                 )
         self._observe_stage("collect", time.perf_counter() - t0)
         # inside collect, not beside it: what of the stage went to
@@ -1583,6 +1644,7 @@ class TxValidator:
             for ns, entry in footprint.per_ns.items()
             if entry["writes"] and ns != cc_id
         ]
+        self._namespace_prepares += len(namespaces)
         for ns in namespaces:
             ctx = ValidationContext(
                 channel_id=self.channel_id,
@@ -1678,6 +1740,10 @@ class TxValidator:
         # ENDORSEMENT_POLICY_FAILURE, never re-evaluated under the new
         # policy).
         updated: set[tuple[str, str]] = set()
+        # the lanes the mask refused: a handful a block, and a VALID
+        # transaction that holds one had its policy met without it
+        refused = _refused_lanes(mask)
+        tolerated = 0
         with tracing.attached(ctx), tracing.span(
             "policy", cat="stage", block=num,
         ) as pspan:
@@ -1724,8 +1790,22 @@ class TxValidator:
                     flags[i] = V.ENDORSEMENT_POLICY_FAILURE
                     continue
                 updated.update(w.meta_keys)
+                if refused:
+                    for _p, idxs in w.pendings:
+                        if not refused.isdisjoint(idxs):
+                            # a lane once, whichever of the
+                            # transaction's namespaces it stands in
+                            tolerated += len(refused.intersection(
+                                j for _q, more in w.pendings for j in more
+                            ))
+                            break
 
             protoutil.set_tx_filter(block, bytes(flags))
+            _TOLERATED.block_done(num, tolerated)
+            if tolerated and self._metrics is not None:
+                self._metrics.tolerated_bad_endorsements.With(
+                    "channel", self.channel_id
+                ).add(tolerated)
             if deferral is not None:
                 self._count_keylevel(count, deferred=deferral.txs, waits=1)
                 plans = self._count_plans(plans0)
@@ -1735,10 +1815,11 @@ class TxValidator:
                     deferred_bulk_keys=count.bulk_keys,
                     deferred_point_reads=count.point_reads,
                     plan_hits=plans[0], plan_misses=plans[1],
-                    plan_clears=plans[2],
+                    plan_clears=plans[2], plan_build_ms=plans[3] * 1e3,
+                    tolerated_bad_lanes=tolerated,
                 )
             else:
-                pspan.annotate(deferred=0)
+                pspan.annotate(deferred=0, tolerated_bad_lanes=tolerated)
         self._observe_stage("policy", time.perf_counter() - t1)
         return flags
 

@@ -32,6 +32,7 @@ nothing in the namespace is still checked against the chaincode policy
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Callable, Sequence
 
 from fabric_tpu.common.flogging import must_get_logger
@@ -475,27 +476,36 @@ class BuiltinV20Plugin:
         # found, a plan built, and the times the cache ran over
         # _PLAN_CAP and was emptied (the validator reads the three a
         # block: collect{plan_hits, plan_misses, plan_clears},
-        # validator_plan_cache_total{outcome})
+        # validator_plan_cache_total{outcome}), and the seconds spent
+        # building plans (collect{plan_build_ms}): what a miss costs
+        # before its first `decide`
         self.plan_hits = 0
         self.plan_misses = 0
         self.plan_clears = 0
+        self.plan_build_s = 0.0
 
     def _plan(self, policies, endorsers: tuple, deserializer) -> EndorsementPlan:
         """The plan of (policies, distinct endorsers), from the cache
         where plans are kept; a plan that cannot be built raises."""
         if not self._use_plans:
-            return EndorsementPlan(policies, endorsers, deserializer)
+            return self._build(policies, endorsers, deserializer)
         key = (tuple(policies), endorsers)
         plan = self._plans.get(key)
         if plan is not None:
             self.plan_hits += 1
             return plan
-        plan = EndorsementPlan(policies, endorsers, deserializer)
+        plan = self._build(policies, endorsers, deserializer)
         self.plan_misses += 1
         if len(self._plans) >= self._PLAN_CAP:
             self._plans.clear()
             self.plan_clears += 1
         self._plans[key] = plan
+        return plan
+
+    def _build(self, policies, endorsers: tuple, deserializer) -> EndorsementPlan:
+        t0 = time.perf_counter()
+        plan = EndorsementPlan(policies, endorsers, deserializer)
+        self.plan_build_s += time.perf_counter() - t0
         return plan
 
     def _plan_pending(self, ctx: ValidationContext, policies) -> PendingValidation | None:
