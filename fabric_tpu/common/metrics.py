@@ -467,8 +467,11 @@ class ValidateMetrics:
             subsystem="plan",
             name="cache_total",
             help="Endorsement-plan cache outcomes: hit, miss (a plan "
-                 "built), cleared (the cache ran over its cap and was "
-                 "emptied).",
+                 "built), shared (of the hits, those on a plan that "
+                 "other identities of the same principal class built), "
+                 "cleared (the young generation ran over its cap and "
+                 "the plans unused since the last overflow were "
+                 "dropped).",
             label_names=("outcome",),
             statsd_format="%{outcome}",
         ))

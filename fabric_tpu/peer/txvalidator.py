@@ -735,12 +735,14 @@ class TxValidator:
 
     def _plan_counts(self) -> tuple:
         """The builtin plugin's plan-cache outcomes so far (hits,
-        misses, clears) and the seconds it spent building plans; zeros
-        where another plugin stands under its name."""
+        misses, clears, and the hits on a plan other identities built)
+        and the seconds it spent building plans; zeros where another
+        plugin stands under its name."""
         plugin = self._registry.plugin("vscc")
         return (getattr(plugin, "plan_hits", 0),
                 getattr(plugin, "plan_misses", 0),
                 getattr(plugin, "plan_clears", 0),
+                getattr(plugin, "plan_shared_hits", 0),
                 getattr(plugin, "plan_build_s", 0.0))
 
     def _count_keylevel(self, count: _KeyLevelCount, deferred: int = 0,
@@ -766,8 +768,9 @@ class TxValidator:
         delta = tuple(a - b for a, b in zip(self._plan_counts(), before))
         m = self._metrics
         if m is not None:
-            # the three outcomes; the fourth of `delta` is seconds
-            for outcome, n in zip(("hit", "miss", "cleared"), delta):
+            # the four outcomes ("shared" is a part of "hit"); the
+            # fifth of `delta` is seconds
+            for outcome, n in zip(("hit", "miss", "cleared", "shared"), delta):
                 if n:
                     m.plan_cache.With("outcome", outcome).add(n)
         return delta
@@ -1314,7 +1317,8 @@ class TxValidator:
                     keylevel_bulk_keys=count.bulk_keys,
                     keylevel_point_reads=count.point_reads,
                     plan_hits=plans[0], plan_misses=plans[1],
-                    plan_clears=plans[2], plan_build_ms=plans[3] * 1e3,
+                    plan_clears=plans[2], plan_shared_hits=plans[3],
+                    plan_build_ms=plans[4] * 1e3,
                     namespace_prepares=self._namespace_prepares,
                     definitions_resolved=self._definitions_resolved,
                 )
@@ -1815,7 +1819,8 @@ class TxValidator:
                     deferred_bulk_keys=count.bulk_keys,
                     deferred_point_reads=count.point_reads,
                     plan_hits=plans[0], plan_misses=plans[1],
-                    plan_clears=plans[2], plan_build_ms=plans[3] * 1e3,
+                    plan_clears=plans[2], plan_shared_hits=plans[3],
+                    plan_build_ms=plans[4] * 1e3,
                     tolerated_bad_lanes=tolerated,
                 )
             else:

@@ -38,7 +38,7 @@ from typing import Callable, Sequence
 from fabric_tpu.common.flogging import must_get_logger
 from fabric_tpu.csp.api import VerifyBatchItem
 from fabric_tpu.ledger.txmgmt import VALIDATION_PARAMETER, hash_ns
-from fabric_tpu.policies.signature_policy import SignaturePolicy
+from fabric_tpu.policies.signature_policy import SignaturePolicy, principals_of
 from fabric_tpu.protos.ledger.rwset import rwset_pb2
 from fabric_tpu.protos.ledger.rwset.kvrwset import kv_rwset_pb2
 from fabric_tpu.protos.common import policies_pb2
@@ -362,6 +362,43 @@ class PolicyProvider:
         return None
 
 
+def _endorser(identity: bytes, questions: list | None, deserializer) -> tuple:
+    """(class, public key) of one endorser: see `BuiltinV20Plugin._learn`.
+    `questions` are the (deserializer, principal) pairs of a policy set
+    (`principals_of`), None where a policy cannot list its own.  The
+    class is a number: bit 0 the lane, bit i the answer to question i
+    (an identity the policy's deserializer does not read satisfies
+    nothing, as in `prepare`)."""
+    read: dict = {}
+
+    def read_by(asker):
+        if id(asker) not in read:
+            try:
+                read[id(asker)] = asker.deserialize_identity(identity)
+            except Exception:
+                # no lane, and to a policy an identity it never sees
+                read[id(asker)] = None
+        return read[id(asker)]
+
+    ident = read_by(deserializer)
+    public_key = None if ident is None else ident.public_key
+    if questions is None:
+        return identity, public_key
+    cls = int(ident is not None)
+    for bit, (asker, principal) in enumerate(questions, 1):
+        ident = read_by(asker)
+        if ident is None:
+            continue
+        try:
+            asker.satisfies_principal(ident, principal)
+        except Exception:
+            # fabriclint: allow[exception-discipline] principal mismatch
+            # is the expected answer, not an error
+            continue
+        cls |= 1 << bit
+    return cls, public_key
+
+
 class EndorsementPlan:
     """Amortized policy combinatorics for one (policy set, ordered unique
     endorser set).
@@ -371,20 +408,20 @@ class EndorsementPlan:
     and signatures differ per tx.  The reference re-runs identity
     deserialization, principal matching, and the cauthdsl closure for
     every tx (common/policies/policy.go:365 + cauthdsl.go:40-92).  A
-    plan does all of that ONCE: it deserializes each unique endorser,
-    prepares every policy against sentinel digests to learn which item
-    lane maps to which endorser, and memoizes `decide(bits)` — the pure
-    function from per-endorser verify outcomes to the policy verdict.
-    Per tx, validation is then k VerifyBatchItem constructions plus one
-    dict lookup."""
+    plan does all of that ONCE: it prepares every policy against
+    sentinel digests to learn which item lane maps to which endorser,
+    and memoizes `decide(bits)` — the pure function from per-endorser
+    verify outcomes to the policy verdict.  Per tx, validation is then
+    k VerifyBatchItem constructions plus one dict lookup.
 
-    def __init__(self, policies, endorser_bytes: tuple, deserializer):
-        self.identities = []
-        for eb in endorser_bytes:
-            try:
-                self.identities.append(deserializer.deserialize_identity(eb))
-            except Exception:
-                self.identities.append(None)
+    A plan holds no key: it is shared by every endorser set whose
+    members, place by place, look alike to its policies (see
+    `BuiltinV20Plugin._learn`), and a transaction's items carry its own
+    endorsers' keys.  `built_for` is the set that built it."""
+
+    def __init__(self, policies, endorser_bytes: tuple):
+        self.built_for = endorser_bytes
+        self.width = len(endorser_bytes)
         # Sentinel digests (1-based: the all-zero digest is the dummy
         # item for identities that fail to deserialize) recover the
         # item-lane -> endorser-index mapping from each policy's prepare.
@@ -414,8 +451,9 @@ class EndorsementPlan:
 
 class _PlanPending(PendingValidation):
     """Per-tx pending bound to a shared EndorsementPlan: `items` carry
-    this tx's digests/signatures for the endorsers that deserialize;
-    `finish` folds the mask into the plan's memoized decision."""
+    this tx's keys/digests/signatures for the endorsers that
+    deserialize; `finish` folds the mask into the plan's memoized
+    decision."""
 
     def __init__(self, plan: EndorsementPlan, lanes: list, items: list):
         self._plan = plan
@@ -423,7 +461,7 @@ class _PlanPending(PendingValidation):
         self.items = items
 
     def finish(self, mask) -> bool:
-        bits = [False] * len(self._plan.identities)
+        bits = [False] * self._plan.width
         for pos, j in enumerate(self._lanes):
             bits[j] = bool(mask[pos])
         return self._plan.decide(tuple(bits))
@@ -434,12 +472,12 @@ class DeferredValidation(PendingValidation):
     still in flight may change (`ValidationContext.pending`): WHICH
     policies decide it is not known while its block is collected.  Its
     signatures do not wait for that: `items` are a lane for each
-    distinct endorser that deserializes, as an EndorsementPlan makes
-    them whatever its policies turn out to be, and they join the
-    block's batch with everybody else's.  `finish` resolves the
-    policies against the committed state, so the validator calls it
-    only once block `waits_on` has landed (its commit durable and
-    readable); the verdict is then the plan's, from the same mask."""
+    distinct endorser that deserializes, as `_plan_pending` makes them
+    whatever its policies turn out to be, and they join the block's
+    batch with everybody else's.  `finish` resolves the policies
+    against the committed state, so the validator calls it only once
+    block `waits_on` has landed (its commit durable and readable); the
+    verdict is then the plan's, from the same mask."""
 
     def __init__(self, plugin: "BuiltinV20Plugin", ctx: ValidationContext,
                  waits_on: int, endorsers: tuple, lanes: list, items: list):
@@ -465,46 +503,127 @@ class BuiltinV20Plugin:
     """The default endorsement-policy plugin ("vscc"), key-level aware.
     Evaluates the single namespace in `ctx.namespace`; the validator
     dispatches one prepare per written namespace, as the reference
-    dispatcher does."""
+    dispatcher does.
 
-    _PLAN_CAP = 256
+    A plan is kept under (policies, the class of each distinct endorser
+    in the endorsements' order): what the policies can observe of the
+    endorsers (`_learn`), not who they are.  Plans live in two
+    generations of at most `_PLAN_CAP` each: one asked for in the old
+    generation moves to the young one; when the young one is full the
+    old one is dropped and the young one takes its place.  So a plan in
+    use survives every overflow, and one nobody asked for while a whole
+    generation filled goes."""
+
+    # plans a generation.  A plan of three or four endorsers weighs 2 KB
+    # (a signature policy) to 5 KB (the channel's default over five
+    # organisations) with its decisions (CHANGES.md, PR 53): a thousand
+    # alive, the most, are 2-5 MB of a channel's validator
+    _PLAN_CAP = 512
+    # endorsers remembered (`_seen`, over all policy sets): run over, it
+    # starts afresh; what it held is asked a question a principal again
+    # and no plan is lost
+    _SEEN_CAP = 4096
 
     def __init__(self, plans: bool = True):
         self._use_plans = plans
+        # (policies, classes) -> plan: the young generation, the old
         self._plans: dict[tuple, EndorsementPlan] = {}
+        self._old_plans: dict[tuple, EndorsementPlan] = {}
+        # policies -> {endorser identity: (class, public key)}.  It
+        # hangs on the policy objects as the plans do: a channel-config
+        # update makes new ones, which find neither
+        self._seen: dict[tuple, dict] = {}
+        self._seen_count = 0
         # the plan cache's outcomes since this plugin was built: a plan
-        # found, a plan built, and the times the cache ran over
-        # _PLAN_CAP and was emptied (the validator reads the three a
-        # block: collect{plan_hits, plan_misses, plan_clears},
+        # found, a plan built, of the plans found those that OTHER
+        # identities built (an identity key would have built one more),
+        # and the overflows that dropped plans: the young generation
+        # was full and the old one, the plans nobody had asked for
+        # since the overflow before, was let go (the validator reads
+        # the four a block: collect{plan_hits, plan_misses,
+        # plan_clears, plan_shared_hits},
         # validator_plan_cache_total{outcome}), and the seconds spent
-        # building plans (collect{plan_build_ms}): what a miss costs
-        # before its first `decide`
+        # learning endorsers' classes and building plans
+        # (collect{plan_build_ms}): what a miss costs before its first
+        # `decide`
         self.plan_hits = 0
         self.plan_misses = 0
         self.plan_clears = 0
+        self.plan_shared_hits = 0
         self.plan_build_s = 0.0
+
+    def _learn(self, pols: tuple, endorsers: tuple, deserializer) -> list:
+        """(class, public key) of each endorser, remembered a policy
+        set.  The class is what the policies can observe of the
+        endorser, found by asking: whether it deserializes (its lane:
+        the key is None where it does not), and the answer of each
+        policy's OWN deserializer to every principal the policy lists.
+        Two endorsers of one class are one to `prepare` and to the
+        compiled closures, whoever they are; an OU, an identity or a
+        combined principal splits them where the policy would.  Under
+        a policy object that cannot list its principals the class is
+        the identity itself."""
+        t0 = time.perf_counter()
+        seen = self._seen.setdefault(pols, {})
+        questions = principals_of(pols)
+        known = []
+        for identity in endorsers:
+            entry = seen.get(identity)
+            if entry is None:
+                if self._seen_count >= self._SEEN_CAP:
+                    self._seen.clear()
+                    self._seen_count = 0
+                    seen = self._seen[pols] = {}
+                entry = seen[identity] = _endorser(
+                    identity, questions, deserializer
+                )
+                self._seen_count += 1
+            known.append(entry)
+        self.plan_build_s += time.perf_counter() - t0
+        return known
+
+    def _find(self, pols: tuple, endorsers: tuple, deserializer) -> tuple:
+        """(the plan of the distinct endorsers' classes under `pols`,
+        the (class, public key) of each): the plan from either
+        generation, built where neither holds it; one that cannot be
+        built raises."""
+        try:
+            seen = self._seen[pols]
+            known = [seen[identity] for identity in endorsers]
+        except KeyError:
+            # a stranger among them: ask
+            known = self._learn(pols, endorsers, deserializer)
+        key = (pols, tuple([c for c, _k in known]))
+        plan = self._plans.get(key)
+        if plan is None:
+            plan = self._old_plans.get(key)
+            built = plan is None
+            if built:
+                plan = self._build(pols, endorsers)
+            if len(self._plans) >= self._PLAN_CAP:
+                if self._old_plans:
+                    self.plan_clears += 1
+                self._old_plans = self._plans
+                self._plans = {}
+            self._plans[key] = plan
+            if built:
+                self.plan_misses += 1
+                return plan, known
+        self.plan_hits += 1
+        if plan.built_for != endorsers:
+            self.plan_shared_hits += 1
+        return plan, known
 
     def _plan(self, policies, endorsers: tuple, deserializer) -> EndorsementPlan:
         """The plan of (policies, distinct endorsers), from the cache
         where plans are kept; a plan that cannot be built raises."""
         if not self._use_plans:
-            return self._build(policies, endorsers, deserializer)
-        key = (tuple(policies), endorsers)
-        plan = self._plans.get(key)
-        if plan is not None:
-            self.plan_hits += 1
-            return plan
-        plan = self._build(policies, endorsers, deserializer)
-        self.plan_misses += 1
-        if len(self._plans) >= self._PLAN_CAP:
-            self._plans.clear()
-            self.plan_clears += 1
-        self._plans[key] = plan
-        return plan
+            return self._build(policies, endorsers)
+        return self._find(tuple(policies), endorsers, deserializer)[0]
 
-    def _build(self, policies, endorsers: tuple, deserializer) -> EndorsementPlan:
+    def _build(self, policies, endorsers: tuple) -> EndorsementPlan:
         t0 = time.perf_counter()
-        plan = EndorsementPlan(policies, endorsers, deserializer)
+        plan = EndorsementPlan(policies, endorsers)
         self.plan_build_s += time.perf_counter() - t0
         return plan
 
@@ -520,32 +639,26 @@ class BuiltinV20Plugin:
                 return None
             if sd.identity not in uniq:
                 uniq[sd.identity] = sd
-        endorsers = tuple(uniq)
-        # the hit, a transaction of every block, without a call
-        plan = self._plans.get((tuple(policies), endorsers))
-        if plan is not None:
-            self.plan_hits += 1
-        else:
-            try:
-                plan = self._plan(
-                    policies, endorsers, ctx.policy_provider.deserializer
-                )
-            except Exception as exc:
-                # fall back to the per-tx generic path; the plan build
-                # failure is logged so a policy that can never be
-                # amortized is visible, not silently slow
-                _logger.warning(
-                    "endorsement-plan build failed for %r (falling back "
-                    "to per-tx evaluation): %s", ctx.namespace, exc,
-                )
-                return None
+        try:
+            plan, known = self._find(
+                tuple(policies), tuple(uniq), ctx.policy_provider.deserializer
+            )
+        except Exception as exc:
+            # fall back to the per-tx generic path; the plan build
+            # failure is logged so a policy that can never be
+            # amortized is visible, not silently slow
+            _logger.warning(
+                "endorsement-plan build failed for %r (falling back "
+                "to per-tx evaluation): %s", ctx.namespace, exc,
+            )
+            return None
         lanes, items = [], []
         for j, sd in enumerate(uniq.values()):
-            ident = plan.identities[j]
-            if ident is not None:
+            public_key = known[j][1]
+            if public_key is not None:
                 lanes.append(j)
                 items.append(
-                    VerifyBatchItem(ident.public_key, sd.digest, sd.signature)
+                    VerifyBatchItem(public_key, sd.digest, sd.signature)
                 )
         return _PlanPending(plan, lanes, items)
 

@@ -18,6 +18,7 @@ from fabric_tpu.policies.signature_policy import (
     PendingEvaluation,
     PolicyError,
     SignaturePolicy,
+    principals_of,
 )
 
 # Reserved policy names (reference common/policies/policy.go)
@@ -61,6 +62,11 @@ class ImplicitMetaPolicy:
         else:
             raise PolicyError(f"unknown implicit meta rule {rule}")
 
+    def principals(self) -> list | None:
+        """Those of the sub-policies (`SignaturePolicy.principals`);
+        None where one of them cannot list its own."""
+        return principals_of(self._subs)
+
     def prepare(self, signed_data: list[SignedData]):
         return _MetaPending([p.prepare(signed_data) for p in self._subs], self._threshold)
 
@@ -80,6 +86,9 @@ class RejectPolicy:
     def __init__(self, name: str, reason: str = ""):
         self.name = name
         self.reason = reason or f"policy {name!r} is not defined"
+
+    def principals(self) -> list:
+        return []  # it asks nothing of anybody
 
     def prepare(self, signed_data):
         return _MetaPending([], 1)

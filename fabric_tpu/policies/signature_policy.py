@@ -110,6 +110,13 @@ class SignaturePolicy:
         self._deserializer = deserializer
         self._closure = _compile(envelope.rule, list(envelope.identities), deserializer)
 
+    def principals(self) -> list:
+        """[(deserializer, principal)]: every question the compiled
+        closure can ask of an identity (`satisfies_principal`), and who
+        answers it.  Two identities with the same answers are one to
+        this policy."""
+        return [(self._deserializer, p) for p in self._envelope.identities]
+
     def prepare(self, signed_data: list[SignedData]) -> PendingEvaluation:
         """Deserialize + dedup identities; no signature verification here.
 
@@ -147,6 +154,21 @@ class SignaturePolicy:
         pending = self.prepare(signed_data)
         mask = csp.verify_batch(pending.items)
         return pending.finish(mask)
+
+
+def principals_of(policies) -> list | None:
+    """Every (deserializer, principal) the policy objects may ask an
+    identity about, from their `principals()`; None where one of them
+    has no such method: all that is known of what it asks is the
+    identity."""
+    asked: list = []
+    for policy in policies:
+        listed = getattr(policy, "principals", None)
+        part = None if listed is None else listed()
+        if part is None:
+            return None
+        asked.extend(part)
+    return asked
 
 
 _DUMMY = None
@@ -216,6 +238,7 @@ __all__ = [
     "PolicyError",
     "SignaturePolicy",
     "PendingEvaluation",
+    "principals_of",
     "signed_by",
     "n_out_of",
     "signed_by_msp_role",
